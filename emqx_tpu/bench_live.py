@@ -37,8 +37,7 @@ sample_rate for the pass — default 0, tracing cold),
 LIVE_TRACE_AB (0 = skip the traced comparison pass the record's
 traced_* / trace_overhead_frac columns come from; the pass reruns
 the workload at LIVE_TRACE_AB_RATE, default 0.01 — the
-docs/OBSERVABILITY.md "Tracing" ≤3%-overhead budget's measurement),
-BENCH_PLATFORM.
+docs/OBSERVABILITY.md "Tracing" ≤3%-overhead budget's measurement).
 
 On a single-core host the loop threads time-share with the harness
 clients — the multi-loop row there documents ring overhead; the
@@ -449,10 +448,6 @@ def live(emit=None) -> None:
 
     from emqx_tpu.profiling import enable_compile_cache
 
-    plat = os.environ.get("BENCH_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
     enable_compile_cache()
     info = asyncio.run(_run())
     print(json.dumps(info), file=sys.stderr, flush=True)
@@ -882,10 +877,6 @@ def devloss(emit=None) -> None:
 
     from emqx_tpu.profiling import enable_compile_cache
 
-    plat = os.environ.get("BENCH_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
     enable_compile_cache()
     info = _run_devloss()
     print(json.dumps(info), file=sys.stderr, flush=True)
@@ -919,10 +910,6 @@ def overload_curve(emit=None) -> None:
 
     from emqx_tpu.profiling import enable_compile_cache
 
-    plat = os.environ.get("BENCH_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
     enable_compile_cache()
     info = asyncio.run(_run_overload())
     print(json.dumps(info), file=sys.stderr, flush=True)
@@ -1071,10 +1058,6 @@ def drain(emit=None) -> None:
 
     from emqx_tpu.profiling import enable_compile_cache
 
-    plat = os.environ.get("BENCH_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
     enable_compile_cache()
     info = asyncio.run(_run_drain())
     print(json.dumps(info), file=sys.stderr, flush=True)
@@ -1108,8 +1091,8 @@ def drain(emit=None) -> None:
 # since one harness process pays 2 fds per loopback conn. Env:
 # FLEET_CONNS, FLEET_SECS, FLEET_LOOPS, FLEET_WORKERS, FLEET_NODES,
 # FLEET_DRIVERS, FLEET_SUBS, FLEET_PUBS, FLEET_CHURN, FLEET_TOPICS,
-# FLEET_PIPELINE, FLEET_BLAST, FLEET_BLAST_TIMEOUT, BENCH_PLATFORM;
-# the frame engine follows EMQX_TPU_FRAME like any broker.
+# FLEET_PIPELINE, FLEET_BLAST, FLEET_BLAST_TIMEOUT; the frame engine
+# follows EMQX_TPU_FRAME like any broker.
 
 
 def _raise_nofile(conns: int) -> None:
@@ -1626,8 +1609,9 @@ def _run_fleet_workers(n_workers: int) -> dict:
     worker RSS is pure server-side (the harness lives elsewhere)."""
     from emqx_tpu.workers import WorkerPool
 
-    plat = os.environ.get("BENCH_PLATFORM") or "cpu"
-    with WorkerPool(n_workers, port=0, platform=plat) as pool:
+    # one process per chip: the bench parent holds it, so the
+    # front-door worker processes match on the host
+    with WorkerPool(n_workers, port=0, platform="cpu") as pool:
         res = asyncio.run(_run_fleet(
             [pool.port],
             delivered_fn=lambda: sum(d for _, d in pool.stats()),
@@ -1743,8 +1727,7 @@ async def _run_fleet_sharded(n_drivers: int) -> dict:
     if n_workers > 1:
         from emqx_tpu.workers import WorkerPool
 
-        plat = os.environ.get("BENCH_PLATFORM") or "cpu"
-        with WorkerPool(n_workers, port=0, platform=plat) as pool:
+        with WorkerPool(n_workers, port=0, platform="cpu") as pool:
             d0 = sum(d for _, d in pool.stats())
             rows = await _spawn_drivers(n_drivers, [pool.port], conns)
             server_delivered = sum(d for _, d in pool.stats()) - d0
@@ -1803,10 +1786,6 @@ def fleet(emit=None) -> None:
 
     from emqx_tpu.profiling import enable_compile_cache
 
-    plat = os.environ.get("BENCH_PLATFORM")
-    if plat:
-        import jax
-        jax.config.update("jax_platforms", plat)
     enable_compile_cache()
     n_workers = int(os.environ.get("FLEET_WORKERS", "1"))
     n_drivers = int(os.environ.get("FLEET_DRIVERS", "1"))

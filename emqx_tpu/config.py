@@ -44,10 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 
-try:
-    import tomllib
-except ModuleNotFoundError:  # py<3.11: tomllib IS tomli, vendored
-    import tomli as tomllib
+import tomllib
 from typing import Any, Dict, List, Optional
 
 from emqx_tpu.zone import Zone, set_zone

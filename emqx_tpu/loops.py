@@ -4,8 +4,7 @@ The reference broker's front door scales inside one BEAM node because
 every connection is a process and the schedulers own every core
 (src/emqx_connection.erl one-process-per-socket, esockd acceptor
 pools). The asyncio build had ONE event loop serving every socket —
-``docs/ROADMAP.md`` names that single loop as the binding limit — and
-PRs 3+5 moved plan construction and wire-byte construction off-loop,
+that single loop was the binding limit — and PRs 3+5 moved plan construction and wire-byte construction off-loop,
 leaving the on-loop delivery tail as little more than buffer writes.
 This module supplies the missing piece: a :class:`LoopGroup` of
 ``n`` event loops (index 0 is the node's main loop; indices 1..n-1

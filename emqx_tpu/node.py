@@ -348,6 +348,12 @@ class Node:
     async def start(self) -> None:
         if self._started:
             return
+        # persistent compile cache for the served path: a restarted
+        # broker must not recompile every batch bucket (README
+        # "Benchmarks"; placed by JAX_COMPILATION_CACHE_DIR or at
+        # <repo>/.jax_cache — profiling.enable_compile_cache)
+        from emqx_tpu.profiling import enable_compile_cache
+        enable_compile_cache()
         if self._load_default_modules:
             self.load_default_modules()
         if self.durability is not None:

@@ -19,10 +19,7 @@ import importlib
 import json
 import os
 
-try:
-    import tomllib
-except ModuleNotFoundError:  # py<3.11: tomllib IS tomli, vendored
-    import tomli as tomllib
+import tomllib
 from typing import Dict, List, Optional
 
 

@@ -17,33 +17,41 @@ wall-clock timing of the compiled steps themselves. Exposed as:
 from __future__ import annotations
 
 import contextlib
+import os
 import time
 from collections import deque
 from typing import Dict, Optional
 
 
-def enable_compile_cache(path: Optional[str] = None) -> bool:
+#: the in-checkout cache location, resolved from the package (never
+#: the working directory — the path is part of the cache's key, so a
+#: directory that moves never hits)
+_REPO_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """Where the persistent compile cache lives: wherever
+    ``JAX_COMPILATION_CACHE_DIR`` places it from outside, else
+    ``<repo>/.jax_cache``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _REPO_CACHE
+
+
+def enable_compile_cache() -> None:
     """Turn on JAX's persistent compilation cache.
 
-    First-compile of a padding bucket costs tens of seconds on the
-    TPU; the cache makes it once per machine, not once per process —
-    the analogue of the reference shipping precompiled BEAM files.
-    Default location: ``EMQX_TPU_JIT_CACHE`` or ``.jax_cache`` next
-    to the process. Safe to call repeatedly; returns whether the
-    cache is active."""
-    import os
-
+    First-compile of a padding bucket costs seconds on the TPU; the
+    cache makes it once per machine, not once per process — the
+    analogue of the reference shipping precompiled BEAM files. With
+    ``JAX_COMPILATION_CACHE_DIR`` set JAX already reads the directory
+    from it and no directory is set in code. Called at ``Node``
+    start-up and by the benches; safe to call repeatedly."""
     import jax
 
-    path = path or os.environ.get("EMQX_TPU_JIT_CACHE", ".jax_cache")
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.5)
-        return True
-    except Exception:
-        return False
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _REPO_CACHE)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 @contextlib.contextmanager

@@ -9,7 +9,14 @@ instead, so the TPU build shards the LISTENER:
 - N worker processes each run a full Node (own event loop, own
   ingress batcher, own device plane) and bind the SAME MQTT port with
   ``SO_REUSEPORT`` — the kernel load-balances accepted connections
-  across the workers;
+  across the workers. ONE PROCESS PER CHIP: a chip belongs to one
+  process at a time, so on a one-chip host only one worker could own
+  the device; there a pool runs on the CPU backend
+  (``platform="cpu"`` / ``EMQX_TPU_WORKER_PLATFORM=cpu``, pool-wide)
+  and matches on the host. Every worker touches its backend before
+  it builds anything, so one that cannot get the device dies at once
+  with the reason on stderr instead of silently host-matching behind
+  its breaker from its first device batch on;
 - the workers join one broker cluster over the socket transport
   (:mod:`emqx_tpu.cluster_net`), so the existing route replication,
   cross-node forwarding, shared-group routing, clientid locking, and
@@ -44,6 +51,18 @@ import jax
 if os.environ.get("EMQX_TPU_WORKER_PLATFORM"):
     jax.config.update("jax_platforms",
                       os.environ["EMQX_TPU_WORKER_PLATFORM"])
+try:
+    # claim the backend NOW: a chip another process holds must fail
+    # this worker at start-up, loudly, not at its first device batch
+    _d0 = jax.devices()[0]
+except Exception as e:
+    print(f"worker {sys.argv[1]}: cannot get a device "
+          f"({type(e).__name__}: {e}); a chip belongs to one process "
+          f"— start the other workers with "
+          f"EMQX_TPU_WORKER_PLATFORM=cpu", file=sys.stderr, flush=True)
+    sys.exit(3)
+print(f"worker {sys.argv[1]}: backend {_d0.platform} "
+      f"({_d0.device_kind})", file=sys.stderr, flush=True)
 
 from emqx_tpu.cluster import Cluster
 from emqx_tpu.cluster_net import SocketTransport

@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# The repo's one-command gate (VERDICT r4 item 7). The reference
+# The repo's one-command gate, on the CPU. The reference
 # gates with dialyzer/xref/elvis + suites in CI
 # (/root/reference/rebar.config:27-34, .github/workflows); this image
 # has no ruff/mypy/coverage and installs are off-limits, so the gate
@@ -16,12 +16,17 @@
 #   5. scripts/cov.py over the suite      (line coverage report;
 #      COV=0 skips — it roughly doubles suite wall time)
 #
+# bench.py's modes are not smoked here: they need the chip and exit
+# non-zero without one (chiprun -- python chip_smoke.py is the
+# on-chip gate; tests/test_chip_smoke.py rehearses it at toy size).
+#
 # Exits nonzero on any violation.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== byte-compile =="
-python -m compileall -q emqx_tpu tests scripts bench.py __graft_entry__.py
+python -m compileall -q emqx_tpu tests scripts bench.py chip_smoke.py \
+    __graft_entry__.py
 
 echo "== static analysis (scripts/lint.py, docs/ANALYSIS.md) =="
 python scripts/lint.py --stats
@@ -56,32 +61,8 @@ echo "== compressed-walk parity (docs/PERF_NOTES.md round 6) =="
 # wide-table walk, fail fast
 python -m pytest tests/test_walk_pallas.py -q
 
-echo "== deep-topic compression smoke (docs/PERF_NOTES.md round 6) =="
-# the BENCH_MODE=deep_smoke gate at toy scale: a 16-level workload
-# must level-compress (walk hop bound strictly below the raw level
-# count) and hold exact host-oracle parity through the compressed
-# tables + the product fetch seam (throughput is not gated here)
-BENCH_MODE=deep_smoke DEEP_FILTERS=400 DEEP_TOPICS=256 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='deep_smoke_parity' \
-    and rec['value'] is not None \
-    and rec['compressed'] is True \
-    and rec['parity_ok'] is True \
-    and rec['walk_hops_deep'] < rec['levels'], rec"
-
 echo "== flap-storm guard (flapping.py + scenario smoke) =="
 python -m pytest tests/test_flapping.py -q
-# the BENCH_MODE=flapstorm scenario end-to-end at toy scale: a
-# reconnect storm + crash-looping flappers + cm takeovers must run
-# to completion and emit its JSON row (numbers are not gated here —
-# the driver's real-scale run is)
-BENCH_MODE=flapstorm BENCH_SUBS=1500 BENCH_BATCH=32 FLAP_SECONDS=2 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='flapstorm_match_p99_ms' and rec['value'] is not None, rec"
 
 echo "== dispatch planner parity (docs/DISPATCH.md) =="
 # planner-on vs legacy per-delivery tail: delivery counts, wire
@@ -122,35 +103,6 @@ python -m pytest tests/test_chaos.py -q \
     -k "device_lost or device_loss or half_open_single_probe \
 or fallback_never or rebuild_under_route or rebuild_off"
 
-echo "== overload degradation smoke (docs/ROBUSTNESS.md) =="
-# the BENCH_MODE=overload scenario end-to-end at toy scale: the
-# stepped offered-load sweep must run to completion and emit its
-# curve row (offered vs delivered vs shed fraction — numbers are not
-# gated here, the driver's real-scale run is)
-BENCH_MODE=overload OVERLOAD_RATES="500,4000" OVERLOAD_STEP_SECS=1 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='overload_delivered_msgs_per_s' \
-    and rec['value'] is not None and rec['curve'], rec"
-
-echo "== device-loss recovery smoke (docs/ROBUSTNESS.md) =="
-# the BENCH_MODE=devloss scenario end-to-end at toy scale: the
-# backend dies mid-batch, every outage batch host-matches, and the
-# breaker must auto-close onto rebuilt tables — the closed boolean
-# and the recovery fields are gated (throughput numbers are not)
-BENCH_MODE=devloss DEVLOSS_FILTERS=64 DEVLOSS_SECS=1 \
-    DEVLOSS_OUTAGE_SECS=1 DEVLOSS_BATCH=32 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='devloss_host_fallback_msgs_per_s' \
-    and rec['value'] is not None and rec['breaker_closed'] \
-    and rec['classified_lost_during_outage'] \
-    and rec['rebuilds'] >= 1 and rec['rebuild_s'] is not None \
-    and rec['first_batch_p99_ms'] is not None \
-    and rec['first_deep_batch_p99_ms'] is not None, rec"
-
 echo "== zero-downtime operations: drain + live reload (docs/OPERATIONS.md) =="
 # graceful drain (CONNECT gate 0x9C + Server-Reference, paced waves
 # with overload-adaptive budget, will suppression, flapping
@@ -168,23 +120,6 @@ echo "== rolling-restart proof (docs/OPERATIONS.md) =="
 # digests byte-equal after the last rejoin
 ROLLING_MSGS=60 python -m pytest \
     tests/test_drain.py::test_rolling_restart_3node -q
-
-echo "== drain smoke (docs/OPERATIONS.md) =="
-# the BENCH_MODE=drain scenario end-to-end at toy scale: live
-# clients redirected, every persistent session's custody handed to
-# the peer — the zero-RPO booleans ARE gated (throughput numbers are
-# not; the driver's 5k-session run is)
-BENCH_MODE=drain DRAIN_SESSIONS=200 DRAIN_LIVE=10 DRAIN_WAVE=50 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='drain_time_to_empty_s' \
-    and rec['value'] is not None \
-    and rec['rpo_records'] == 0 \
-    and rec['handoff_digest_ok'] is True \
-    and rec['exactly_one_holder'] is True \
-    and rec['sessions_on_target'] == 200 \
-    and rec['redirected'] == 10, rec"
 
 echo "== crash recovery (docs/DURABILITY.md) =="
 # journal framing/torn-tail/degrade semantics (per shard), the
@@ -225,24 +160,6 @@ echo "== replication chaos-soak smoke (docs/DURABILITY.md) =="
 SOAK_SEED=1337 SOAK_ROUNDS=4 python -m pytest \
     tests/test_replication_group.py::test_chaos_soak_smoke -q
 
-echo "== recovery smoke (docs/DURABILITY.md) =="
-# the BENCH_MODE=recovery scenario end-to-end at toy scale: durable
-# QoS1 traffic, a kill -9, and a full journal-replay recovery must
-# run to completion and emit its row, incl. the group-commit window
-# sweep columns (numbers are not gated here — the driver's
-# real-scale run is)
-BENCH_MODE=recovery RECOVERY_ROUTES=1500 RECOVERY_SESSIONS=30 \
-    RECOVERY_PUB_ITERS=4 RECOVERY_FSYNC=0 \
-    RECOVERY_GC_FLUSHES=10 RECOVERY_GC_RECS=8 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='recovery_replay_s' \
-    and rec['value'] is not None \
-    and rec['recovery_routes'] == 1500 \
-    and rec['gc_window_sweep'] is not None \
-    and len(rec['gc_window_sweep']) == 4, rec"
-
 echo "== cluster heal matrix (docs/CLUSTER.md) =="
 # failure detector (wedged-peer detection, suspect-parks-not-purges,
 # fast-fail + degraded locker quorum), auto-heal + anti-entropy
@@ -250,28 +167,6 @@ echo "== cluster heal matrix (docs/CLUSTER.md) =="
 # never-partitioned oracle), and the detector-off legacy pin — a
 # regression here is silent cluster divergence, fail fast
 python -m pytest tests/test_cluster_heal.py -q
-
-echo "== partition-heal + failover smoke (docs/CLUSTER.md) =="
-# the BENCH_MODE=partition scenario end-to-end at toy scale: a
-# 3-node partition with churn on both sides must detect, heal, and
-# reconverge all plane digests with zero manual rejoin — AND the
-# warm-standby failover + FAILBACK rows must promote with RPO 0,
-# hand the state back to the restarted primary, and digest-verify
-# byte-exactness on BOTH hops (numbers are not gated here — the
-# driver's real-scale run is; the RPO/digest booleans ARE)
-BENCH_MODE=partition PARTITION_ROUTES=300 PARTITION_SECONDS=1 \
-    FAILOVER_SESSIONS=30 FAILOVER_RETAINED=60 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='partition_heal_converge_s' \
-    and rec['value'] is not None \
-    and rec['partition_detect_s'] is not None \
-    and rec['failover_s'] is not None \
-    and rec['rpo_records'] == 0 \
-    and rec['failover_digest_ok'] is True \
-    and rec['failback_s'] is not None \
-    and rec['failback_digest_ok'] is True, rec"
 
 echo "== telemetry (docs/OBSERVABILITY.md) =="
 # the publish-path telemetry suite, incl. the disabled-mode A/B
@@ -314,26 +209,6 @@ echo "== multi-loop parity under the native frame engine =="
 # native library is not built — make_parser falls back to Python)
 EMQX_TPU_FRAME=native python -m pytest tests/test_frontdoor_loops.py -q
 
-echo "== fleet smoke (docs/PERF_NOTES.md round 7) =="
-# the BENCH_MODE=fleet scenario end-to-end at toy scale: real
-# sockets with wills, persistent sessions, shared subs, keepalive
-# and reconnect churn over a loops=2 native-frame node. The counted
-# QoS1 blast IS gated (zero lost deliveries), as are the engine
-# counters: native frames flowed and nothing fell back (throughput
-# numbers are not gated — the driver's 100K run is)
-BENCH_MODE=fleet FLEET_CONNS=500 FLEET_LOOPS=2 FLEET_SECS=2 \
-    EMQX_TPU_FRAME=native \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='fleet_delivered_msgs_per_s' \
-    and rec['value'] is not None \
-    and rec['blast_lost'] == 0 \
-    and rec['retained_storm_lost'] == 0 \
-    and rec['retained_storm_replayed'] > 0 \
-    and rec['frame_native_frames'] > 0 \
-    and rec['frame_fallback'] == 0, rec"
-
 echo "== retained replay parity (docs/DISPATCH.md \"Retained replay\") =="
 # batched subscribe-time matching vs the T.match host oracle (lax AND
 # forced-Pallas interpret), planner on/off + loops=1/2 replay wire
@@ -341,25 +216,6 @@ echo "== retained replay parity (docs/DISPATCH.md \"Retained replay\") =="
 # devloss riding — a divergence here is a delivery-correctness bug,
 # fail before the long run
 python -m pytest tests/test_retained_replay.py -q
-
-echo "== retained replay smoke (docs/PERF_NOTES.md round 8) =="
-# the BENCH_MODE=retained scenario at toy scale: batched-device vs
-# host-scan parity over every burst (parity_ok), and the live wire
-# phase — every owed replay arrived (zero lost), serialization stayed
-# off-loop, and the storm coalesced into ≤1 replay batch per
-# subscriber (throughput numbers are not gated — the driver's 1M-name
-# run is)
-BENCH_MODE=retained BENCH_SUBS=4000 RETAINED_BURST=24 \
-    RETAINED_BURSTS=3 \
-    BENCH_PLATFORM=cpu BENCH_NO_FALLBACK=1 BENCH_NO_STAGE=1 \
-    python bench.py | python -c "import json,sys; \
-rec=json.loads(sys.stdin.readlines()[-1]); \
-assert rec['metric']=='retained_subs_per_s' \
-    and rec['value'] is not None \
-    and rec['parity_ok'] is True \
-    and rec['wire_received'] == rec['wire_expected'] \
-    and rec['wire_onloop'] == 0 \
-    and rec['wire_batches'] <= rec['wire_subs'], rec"
 
 echo "== pytest =="
 if [[ "${COV:-1}" == "0" ]]; then

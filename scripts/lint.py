@@ -33,6 +33,7 @@ import analysis  # noqa: E402  (needs the scripts/ dir on sys.path)
 
 ROOT = Path(__file__).resolve().parents[1]
 DEFAULT_TARGETS = ["emqx_tpu", "tests", "scripts", "bench.py",
+                   "chip_smoke.py",
                    "__graft_entry__.py"]
 
 

@@ -1,0 +1,256 @@
+"""Ask the TPU v5e's compiler, without a chip, whether it accepts the
+device programs of the served publish path at deployment widths.
+
+The tier-1 suite runs on the CPU, where a Pallas kernel runs in
+interpret mode and XLA never sees the chip's tiling or memory rules.
+The TPU compiler is installed here all the same and compiles for a
+*described* ``v5e:2x2`` topology: whatever it refuses in these tests
+it refuses on the chip. A compile that passes is not a chip run — it
+says nothing about results or times (``chip_smoke.py`` does that).
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and under xdist
+every worker imports every test file.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from emqx_tpu.ops.csr import (NARROW_SLOT, NARROW_SLOTS, WIDE_SLOT,
+                              WIDE_SLOTS, Automaton)
+
+# deployment widths (1M mixed filters, BASELINE config 2/3): the walk
+# tables of that population are ~2^21 buckets / ~2^21 states
+_NB = 1 << 21
+_S2 = 1 << 21
+_FCAP = 1 << 20        # filter-id capacity of the fan tables
+_B = 1024              # ingress batch bucket
+_K = 16                # MatcherConfig.active_k
+_M = 64                # MatcherConfig.max_matches
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip; keep these compiles
+    out of it (and silent)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def _s(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _auto_shapes(sh, wide: bool) -> Automaton:
+    slots, sw = ((WIDE_SLOTS, WIDE_SLOT) if wide
+                 else (NARROW_SLOTS, NARROW_SLOT))
+    return Automaton(
+        row_ptr=None, edge_word=None, edge_child=None,
+        plus_child=None, hash_filter=None, end_filter=None,
+        n_states=0, n_edges=0,
+        wt=_s((_NB, slots * sw), jnp.int32, sh),
+        wt_seed=_s((1,), jnp.uint32, sh),
+        node2=_s((_S2, 4), jnp.int32, sh))
+
+
+def _batch_shapes(sh, B, L):
+    return (_s((B, L), jnp.int32, sh), _s((B,), jnp.int32, sh),
+            _s((B,), jnp.bool_, sh))
+
+
+@pytest.mark.parametrize("pack_ids", [False, True],
+                         ids=["raw", "packed"])
+@pytest.mark.parametrize("mode,L,steps", [
+    ("narrow", 5, 6),     # the 5-level mixed population
+    ("narrow", 16, 17),   # max_levels, full-depth walk
+    ("wide", 16, 4),      # chain-compressed deep tries
+])
+def test_dispatched_walk_compiles(one_chip, mode, L, steps, pack_ids):
+    """The walk that ``match_batch_auto`` selects on a TPU."""
+    from emqx_tpu.ops import walk_pallas
+    from emqx_tpu.ops.match import match_batch
+
+    assert walk_pallas.walk_variant() == "lax"
+    wide = mode == "wide"
+    lowered = match_batch.lower(
+        _auto_shapes(one_chip, wide), *_batch_shapes(one_chip, _B, L),
+        k=_K, m=_M, steps=steps,
+        slots=WIDE_SLOTS if wide else NARROW_SLOTS,
+        take=8 if wide else 1, pack_ids=pack_ids)
+    compiled = lowered.compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_fanout_and_pack_compile(one_chip):
+    """pack_matches → expand_packed (CSR fan-out) → bitmap row
+    translation → pack_union_rows, the tail ``_begin_device`` runs
+    after the walk, at the raw-emit width of a 6-step k=16 walk."""
+    from emqx_tpu.ops.bitmap import BitmapTable, rows_for_matches
+    from emqx_tpu.ops.fanout import FanoutTable, expand_packed
+    from emqx_tpu.ops.pack import (bundle_i32, mask_pad_rows,
+                                   pack_matches, pack_union_rows)
+
+    sh = one_chip
+    ids = _s((_B, 6 * 2 * _K), jnp.int32, sh)
+    pm, pq, W = 8192, 16384, 32768
+    mask_pad_rows.lower(ids, _s((), jnp.int32, sh)).compile()
+    pack_matches.lower(ids, pm=pm).compile()
+    fan = FanoutTable(
+        row_ptr=_s((_FCAP + 1,), jnp.int32, sh),
+        sub_ids=_s((1 << 21,), jnp.int32, sh),
+        n_filters=0, n_entries=0,
+        row_pairs=_s((_FCAP, 2), jnp.int32, sh))
+    expand_packed.lower(fan, _s((_B + 1,), jnp.int32, sh),
+                        _s((pm,), jnp.int32, sh), q=pq).compile()
+
+    def rows(big_row, match_ids):
+        bt = BitmapTable(None, big_row, 0, 0)
+        return rows_for_matches(bt, match_ids, mb=16)
+
+    jax.jit(rows).lower(_s((_FCAP,), jnp.int32, sh), ids).compile()
+    pack_union_rows.lower(_s((_B, W), jnp.uint32, sh),
+                          _s((_B,), jnp.bool_, sh), pr=8).compile()
+    bundle_i32.lower(_s((_B + 1,), jnp.int32, sh),
+                     _s((pm,), jnp.int32, sh),
+                     _s((_B,), jnp.bool_, sh),
+                     _s((8, W), jnp.uint32, sh)).compile()
+
+
+@pytest.mark.parametrize("B", [256, 1024, 8192])
+def test_or_bitmaps_dma_compiles(one_chip, B):
+    """The manual-DMA bitmap OR (``or_bitmaps_auto`` on a TPU):
+    4096 big filters × 1M subscribers, B topics × 16 rows. B = 8192
+    is the batch the chip refused in PR 21 (its scalar-prefetched row
+    ids did not fit the 1 MiB of SMEM)."""
+    from emqx_tpu.ops.bitmap import or_bitmaps_dma
+
+    compiled = or_bitmaps_dma.lower(
+        _s((4096, 32768), jnp.uint32, one_chip),
+        _s((B, 16), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_retained_match_compiles_at_1m_names(one_chip):
+    """The retained-replay match ``match_names_auto`` selects on a
+    TPU, at the index's deployment capacity (2^20 names)."""
+    from emqx_tpu.ops import retained_match as rm
+
+    F, L, cap = 64, 8, 1 << 20
+    sh = one_chip
+    compiled = rm.match_names_many.lower(
+        _s((F, L), jnp.int32, sh), _s((F,), jnp.int32, sh),
+        _s((F,), jnp.bool_, sh), _s((cap, L), jnp.int32, sh),
+        _s((cap,), jnp.int32, sh), _s((cap,), jnp.bool_, sh)).compile()
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    assert out_bytes >= F * cap  # the [F, cap] hit matrix
+
+
+def test_match_cache_and_delta_compile(one_chip):
+    """The match-cache gather/insert and the delta two-probe merge."""
+    from emqx_tpu.ops.delta import _mask_ids, _union_packed
+    from emqx_tpu.ops.match_cache import _insert_jit, _merge_jit
+
+    sh = one_chip
+    table = _s((65536, _M + 1), jnp.int32, sh)
+    i32 = lambda *shape: _s(shape, jnp.int32, sh)  # noqa: E731
+    b1 = lambda *shape: _s(shape, jnp.bool_, sh)   # noqa: E731
+    _merge_jit.lower(table, i32(_B), i32(_B), i32(_B, _M), b1(_B),
+                     b1(_B), i32(_B), b_pad=_B).compile()
+    _insert_jit.lower(table, i32(_B), i32(_B, _M), b1(_B),
+                      b1(_B)).compile()
+    _union_packed.lower(i32(_B, _M), i32(_B, _M), m=_M).compile()
+    _mask_ids.lower(i32(_B, _M), b1(_FCAP)).compile()
+
+
+@pytest.mark.parametrize("n_data,n_trie", [(4, 1), (2, 2)])
+def test_sharded_publish_step_compiles(topo, n_data, n_trie):
+    """BASELINE config 5's step on the four described chips: the
+    shard_map program with per-shard fan tables and bitmaps — the
+    collectives must be in the program and the bitmap OR must be the
+    Pallas kernel (``use_dma`` follows the mesh's devices)."""
+    from emqx_tpu.parallel.sharded import (ShardedAutomaton,
+                                           ShardedBitmaps,
+                                           ShardedFanout, publish_step)
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(n_data, n_trie),
+                ("data", "trie"))
+    tr = NamedSharding(mesh, P("trie"))
+    da = NamedSharding(mesh, P("data"))
+    T = n_trie
+    nb, s2, fcap = _NB // T, _S2 // T, _FCAP
+    auto = ShardedAutomaton(
+        wt=_s((T, nb, NARROW_SLOTS * NARROW_SLOT), jnp.int32, tr),
+        wt_seed=_s((T, 1), jnp.uint32, tr),
+        node2=_s((T, s2, 4), jnp.int32, tr))
+    fan = ShardedFanout(
+        row_ptr=_s((T, fcap + 1), jnp.int32, tr),
+        sub_ids=_s((T, 1 << 20), jnp.int32, tr),
+        row_pairs=_s((T, fcap, 2), jnp.int32, tr))
+    bmt = ShardedBitmaps(
+        bitmaps=_s((T, 16, 32768), jnp.uint32, tr),
+        big_row=_s((T, fcap), jnp.int32, tr))
+    compiled = publish_step.lower(
+        mesh, auto, fan, *_batch_shapes(da, _B, 5), bmt,
+        k=_K, m=_M, d=128, mb=16, with_fanout=True, steps=6,
+        slots=NARROW_SLOTS, take=1).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text          # Pallas bitmap OR
+    assert "all-reduce" in text               # mesh-summed stats
+    if n_trie > 1:
+        assert "all-gather" in text           # trie-axis exchange
+    ma = compiled.memory_analysis()
+    # tables are split over the trie axis, not replicated at full size
+    assert ma.argument_size_in_bytes < 4 * (
+        _NB * 8 + _S2 * 4 + 3 * fcap + (1 << 20) + 16 * 32768
+        + fcap) * 2
+
+
+def test_unselected_pallas_kernels_are_still_refused(one_chip):
+    """The two Pallas kernels dispatch does NOT select, and why: the
+    v5e compiler refuses them as written (ROADMAP C2). The day one of
+    them lowers this test fails — the cue to A/B it on the chip and
+    revisit ``walk_variant()``, not to delete the test."""
+    from emqx_tpu.ops import retained_match as rm
+    from emqx_tpu.ops.walk_pallas import match_batch_pallas
+
+    sh = one_chip
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        match_batch_pallas.lower(
+            _auto_shapes(sh, False), *_batch_shapes(sh, _B, 8),
+            k=_K, m=_M, steps=9, slots=NARROW_SLOTS, take=1,
+            pack_ids=True).compile()
+    F, L, cap = 64, 8, 1 << 20
+    with pytest.raises(Exception, match="vmem"):
+        rm.match_names_many_pallas.lower(
+            _s((F, L), jnp.int32, sh), _s((F,), jnp.int32, sh),
+            _s((F,), jnp.bool_, sh), _s((cap, L), jnp.int32, sh),
+            _s((cap,), jnp.int32, sh),
+            _s((cap,), jnp.bool_, sh)).compile()

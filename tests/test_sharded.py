@@ -12,16 +12,7 @@ from emqx_tpu.ops.tokenize import WordTable, encode_batch
 from emqx_tpu.parallel.mesh import make_mesh
 from emqx_tpu.parallel.sharded import (
     build_sharded, build_sharded_fanout, place_batch, place_sharded,
-    publish_step, shard_filters, shard_map_available)
-
-# capability guard (tier-1 hygiene): a JAX build with NO shard_map
-# implementation at all (neither jax.shard_map nor the experimental
-# module) cannot run the multi-device mesh program — skip the suite
-# instead of erroring it out of the report. The 1×1-mesh paths in
-# other suites keep running (they use the plain-jit fast path).
-pytestmark = pytest.mark.skipif(
-    not shard_map_available(),
-    reason="this JAX build has no shard_map implementation")
+    publish_step, shard_filters)
 
 
 def _rand_filters(rng, n):

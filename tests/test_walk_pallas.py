@@ -138,8 +138,18 @@ def test_pallas_overflow_and_sys_semantics():
 
 
 def test_walk_variant_dispatch(monkeypatch):
+    """The backend rule (PR 21): there is none. The v5e compiler
+    refuses the Pallas walk, so dispatch takes the lax walk whatever
+    the backend is called; only the explicit override selects the
+    kernel."""
+    import jax
+
     monkeypatch.delenv("EMQX_TPU_WALK", raising=False)
     assert walk_variant() == "lax"  # CPU test backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert walk_variant() == "lax"  # ... and on a TPU
+    monkeypatch.setenv("EMQX_TPU_WALK", "auto")
+    assert walk_variant() == "lax"
     monkeypatch.setenv("EMQX_TPU_WALK", "pallas")
     assert walk_variant() == "pallas"
     monkeypatch.setenv("EMQX_TPU_WALK", "lax")
