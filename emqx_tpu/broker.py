@@ -111,7 +111,7 @@ class PendingBatch:
     __slots__ = (
         "done", "results", "live", "host_topics", "inv", "n_uniq",
         "host_matched", "host_inv", "host_only", "span", "tbatch",
-        "plan", "plan_state", "xgroups",
+        "plan", "plan_state", "xgroups", "dev_counts",
         "id_map",
         "epoch", "st", "ids_dev", "ovf_dev", "pm", "pq",
         "m_ptr_d", "ids_packed_d",
@@ -152,6 +152,10 @@ class PendingBatch:
         # owning-loop index -> plan group indices, computed in
         # publish_fetch; None = every group delivers from this loop
         self.xgroups = None
+        # (matches, deliveries, overflows) of this batch as the one
+        # fetch read them (single-chip served path); folded into the
+        # device.* counters by the first finish chunk, on the loop
+        self.dev_counts = None
         self.inv: Optional[List[int]] = None
         self.n_uniq = 0
         self.st = None
@@ -473,7 +477,8 @@ class Broker:
 
     @owner_loop
     def publish_begin(self, msgs: Sequence[Message],
-                      defer_host: bool = False) -> PendingBatch:
+                      defer_host: bool = False,
+                      span=None) -> PendingBatch:
         """Phase 1 — host pre-work + device dispatch, no sync.
 
         Runs hooks/veto/metrics, picks host vs device matching
@@ -485,12 +490,21 @@ class Broker:
         ``defer_host`` postpones host-path ROUTING to
         :meth:`publish_finish` (``pb.done`` stays False): the pipelined
         ingress uses it while earlier batches are still in flight so a
-        host-path batch cannot deliver ahead of them."""
+        host-path batch cannot deliver ahead of them. ``span`` is the
+        telemetry span the ingress batcher already opened at the
+        batch's first arrival (its ``ingress_wait`` is on it)."""
         pb = PendingBatch()
-        tel = self.telemetry
-        if tel is not None and tel.enabled:
-            pb.span = tel.begin(len(msgs))
+        if span is not None:
+            pb.span = span
+        else:
+            tel = self.telemetry
+            if tel is not None and tel.enabled:
+                pb.span = tel.begin(len(msgs))
         sp = pb.span
+        if sp is not None:
+            # metrics, message.publish hooks, veto, dedup: closed by
+            # whichever stage starts next
+            sp.start("prepare")
         trc = self.tracing
         tracing_on = trc is not None and trc.active
         tctxs = None
@@ -570,6 +584,8 @@ class Broker:
         sp = pb.span
         if sp is not None:
             sp.path = "host"
+            # prepare — or the device stage a failed dispatch left
+            sp.stop()
         if defer_host:
             pb.host_topics = topics
         else:
@@ -601,14 +617,15 @@ class Broker:
             sp.n_uniq = pb.n_uniq
         if cfg.mesh is not None:
             return self._publish_begin_mesh(pb, uniq, cfg)
-        t_m = sp.clock() if sp is not None else 0.0
+        if sp is not None:
+            sp.start("match")
         pb.ids_dev, pb.ovf_dev, pb.id_map, pb.epoch = \
             self.router.match_dispatch(uniq)
         if sp is not None:
             # closes the match stage; the router's cache-split path
             # (telemetry-gated) left the cache_gather share to split
-            sp.stamp_match(self.router, t_m)
-            t_p = sp.clock()
+            sp.stop_match(self.router)
+            sp.start("pack")
         # phantom pad-row matches (wildcards match the pad topic) must
         # not reach the fan-out/pack kernels or the learned budgets
         pb.ids_dev = mask_pad_rows(pb.ids_dev, np.int32(len(uniq)))
@@ -637,7 +654,7 @@ class Broker:
                 union_d, has_big, pr=budgets[2])
         if sp is not None:
             sp.bucket = bucket
-            sp.add("pack", t_p)
+            sp.stop()
         return pb
 
     def _publish_begin_mesh(self, pb: PendingBatch, uniq: List[str],
@@ -658,7 +675,7 @@ class Broker:
         sp = pb.span
         if sp is not None:
             sp.path = "mesh"
-            t_m = sp.clock()
+            sp.start("match")
         (pb.ids_dev, subs_d, src_d, bm, pb.ovf_dev, pb.movf_d,
          pb.id_map, pb.epoch, pb.sh_big) = \
             self.router.publish_dispatch_sharded(uniq, fan_provider)
@@ -667,8 +684,8 @@ class Broker:
             # all-gather enqueued as one program); the sharded
             # cache-split path leaves its gather share like the
             # single-chip one
-            sp.stamp_match(self.router, t_m)
-            t_p = sp.clock()
+            sp.stop_match(self.router)
+            sp.start("pack")
         n_uniq = np.int32(pb.n_uniq)
         pb.ids_dev = mask_pad_rows(pb.ids_dev, n_uniq)
         bucket = pb.ids_dev.shape[0]
@@ -695,7 +712,7 @@ class Broker:
                 union_d, pb.has_big_d, pr=budgets[2])
         if sp is not None:
             sp.bucket = bucket
-            sp.add("pack", t_p)
+            sp.stop()
         return pb
 
     def _publish_host(self, pb: PendingBatch, topics: List[str]) -> None:
@@ -705,8 +722,8 @@ class Broker:
         sp = pb.span
         tb = pb.tbatch
         if sp is not None:
-            t_m = sp.clock()
-        elif tb is not None:
+            sp.start("match")  # host regime: the actual trie walk
+        if tb is not None:
             t_m = time.perf_counter()
         uniq, inv = dedup_topics(topics)
         pb.n_uniq = len(uniq)
@@ -714,8 +731,7 @@ class Broker:
                    if pb.host_only else self.router.match_filters(uniq))
         if sp is not None:
             sp.n_uniq = pb.n_uniq
-            sp.add("match", t_m)  # host regime: the actual trie walk
-            t_d = sp.clock()
+            sp.start("dispatch")  # closes match
         if tb is not None:
             self.tracing.mark_match(tb, t_m)
         for row, (i, msg) in enumerate(pb.live):
@@ -725,13 +741,15 @@ class Broker:
                 continue
             pb.results[i] = self._route(filters, msg)
         if sp is not None:
-            sp.add("dispatch", t_d)
+            sp.stop()
 
     def _span_finish(self, pb: PendingBatch) -> None:
         """Close a batch's telemetry span and trace batch (idempotent;
         no-op when both are off)."""
-        if pb.span is not None:
-            self.telemetry.finish(pb.span)
+        sp = pb.span
+        if sp is not None:
+            sp.stop()  # a stage an early exit left open
+            self.telemetry.finish(sp)
             pb.span = None
         if pb.tbatch is not None:
             self.tracing.close_batch(pb.tbatch)
@@ -748,6 +766,10 @@ class Broker:
         transfer is recorded and the batch converts to the exact
         host-oracle path — results stay correct, the breaker decides
         whether the NEXT batch rides the device."""
+        sp = pb.span
+        if sp is not None:
+            # run_in_executor call → this thread picked the batch up
+            sp.wait_mark("executor_wait")
         try:
             if pb.done or pb.host_topics is not None:
                 return
@@ -784,6 +806,9 @@ class Broker:
                 # on the executor thread the fetch already occupies
                 # (docs/DURABILITY.md "one append per batch")
                 d.on_batch()
+            if sp is not None:
+                # "fetch returned": chain_wait / loop_wait start here
+                sp.t_mark = sp.clock()
 
     @executor_thread
     def _fetch_device(self, pb: PendingBatch) -> None:
@@ -802,7 +827,7 @@ class Broker:
             # the ONE synchronizing stage: device execution queued by
             # publish_begin surfaces as transfer wait here (no
             # block_until_ready added — device_get already syncs)
-            t_f = sp.clock()
+            sp.start("fetch")
         cfg = self.router.config
         Bp = pb.ids_dev.shape[0]
         budgets = self._pack_budgets.get(Bp)
@@ -940,19 +965,26 @@ class Broker:
             pb.sel = sel
             pb.rows_packed = rows_p
             pb.bovf = bovf
+            if cfg.mesh is None:
+                # the totals the one transfer already brought to the
+                # host (the mesh step psums its own: router._dev_stats)
+                pb.dev_counts = (
+                    int(m_ptr[-1]),
+                    int(f_ptr[-1]) if f_ptr is not None else 0, n_fb)
             if sp is not None:
                 sp.fallbacks = n_fb
-                sp.add("fetch", t_f)
+                sp.stop()
             tb = pb.tbatch
             if tb is not None:
                 # device regime: walk + fan-out + coalesced transfer,
                 # timed from batch begin (the dispatch was async)
                 self.tracing.mark_match(tb, tb.t0p)
             if self.dispatch_config.planner:
-                t_pl = sp.clock() if sp is not None else 0.0
+                if sp is not None:
+                    sp.start("dispatch_plan")
                 pb.plan = self._build_plan(pb, subs_occ, src_occ)
                 if sp is not None:
-                    sp.add("dispatch_plan", t_pl)
+                    sp.stop()
                 if pb.plan is not None \
                         and self.dispatch_config.preserialize:
                     # egress pre-serialization: prime the messages'
@@ -961,15 +993,14 @@ class Broker:
                     # executor — so the delivery tail patches bytes
                     # instead of serializing (docs/DISPATCH.md)
                     if sp is not None:
-                        t_s = sp.clock()
-                    else:
-                        t_s = time.perf_counter() \
-                            if tb is not None else 0.0
+                        sp.start("serialize")
+                    t_s = time.perf_counter() \
+                        if tb is not None else 0.0
                     preserialize_plan(pb.plan, pb.live, pb.id_map,
                                       self._subscribers,
                                       self.helper.registry.lookup)
                     if sp is not None:
-                        sp.add("serialize", t_s)
+                        sp.stop()
                     if tb is not None:
                         self.tracing.span_mark(tb, "serialize", t_s)
                 if pb.plan is not None and self.loop_group is not None:
@@ -1170,8 +1201,9 @@ class Broker:
         plan = pb.plan
         sp = pb.span
         if sp is not None:
-            t_d = sp.clock()
+            sp.start("dispatch")
         if gstart == 0:
+            self._fold_device_counts(pb)
             self._plan_prologue(pb)
         ps = pb.plan_state
         counts = ps.counts
@@ -1191,7 +1223,7 @@ class Broker:
             self._plan_fold(pb)
             folded = True
         if sp is not None:
-            sp.add("dispatch", t_d)
+            sp.stop()
         if folded:
             self._span_finish(pb)
 
@@ -1477,8 +1509,8 @@ class Broker:
         tb = pb.tbatch
         if pb.host_matched is None:
             if sp is not None:
-                t_m = sp.clock()
-            elif tb is not None:
+                sp.start("match")
+            if tb is not None:
                 t_m = time.perf_counter()
             uniq, pb.host_inv = dedup_topics(pb.host_topics)
             pb.host_matched = (
@@ -1486,11 +1518,11 @@ class Broker:
                 else self.router.match_filters(uniq))
             if sp is not None:
                 sp.n_uniq = len(uniq)
-                sp.add("match", t_m)
+                sp.stop()
             if tb is not None:
                 self.tracing.mark_match(tb, t_m)
         if sp is not None:
-            t_d = sp.clock()
+            sp.start("dispatch")
         for row in range(start, stop):
             i, msg = pb.live[row]
             filters = pb.host_matched[pb.host_inv[row]]
@@ -1499,7 +1531,7 @@ class Broker:
                 continue
             pb.results[i] = self._route(filters, msg)
         if sp is not None:
-            sp.add("dispatch", t_d)
+            sp.stop()
         if stop >= len(pb.live):
             self._span_finish(pb)
 
@@ -1515,7 +1547,9 @@ class Broker:
         m_ptr = pb.m_ptr
         sp = pb.span
         if sp is not None:
-            t_d = sp.clock()
+            sp.start("dispatch")
+        if start == 0:
+            self._fold_device_counts(pb)
         for row in range(start, stop):
             i, msg = pb.live[row]
             urow = pb.inv[row]  # packed results are per UNIQUE topic
@@ -1547,9 +1581,23 @@ class Broker:
             pb.results[i] = self._route_packed(urow, row_ids, filters,
                                                msg, pb)
         if sp is not None:
-            sp.add("dispatch", t_d)
+            sp.stop()
         if stop >= len(pb.live):
             self._span_finish(pb)
+
+    @owner_loop
+    def _fold_device_counts(self, pb: PendingBatch) -> None:
+        """``device.matches`` / ``device.deliveries`` /
+        ``device.overflows`` on the single-chip served path: what
+        :meth:`_fetch_device` read off the one transfer, folded where
+        the counters are owned."""
+        dc = pb.dev_counts
+        if dc is not None:
+            pb.dev_counts = None
+            m = self.metrics
+            m.inc("device.matches", dc[0])
+            m.inc("device.deliveries", dc[1])
+            m.inc("device.overflows", dc[2])
 
     def _drop_no_subs(self, msg: Message) -> None:
         self.metrics.inc("messages.dropped")

@@ -24,6 +24,7 @@ from emqx_tpu import faults
 from emqx_tpu.channel import Channel
 from emqx_tpu.gc import GcPolicy
 from emqx_tpu.limiter import TokenBucket
+from emqx_tpu.metrics import I_FLUSH_NS, I_READ_NS
 from emqx_tpu.mqtt import reason_codes as RC
 from emqx_tpu.mqtt.frame import (FrameError, FrameTooLarge, NativeParser,
                                  make_parser, resolve_frame_mode, serialize)
@@ -31,6 +32,8 @@ from emqx_tpu.mqtt.packet import Publish
 from emqx_tpu.zone import Zone, get_zone
 
 log = logging.getLogger("emqx_tpu.connection")
+
+_now = time.perf_counter
 
 #: strong references to fire-and-forget tasks (accepted sockets,
 #: close-bounding flushes): the event loop keeps only a WEAK
@@ -111,6 +114,13 @@ class Connection:
         self._timers: list = []
         self._loop = None  # serving loop, captured by run()
         self._flush_scheduled = False  # coalesced delivery wakeups
+        # the loop's time outside publish batches (telemetry.py):
+        # read chunks and flush wake-ups are timed into Metrics
+        # counters while telemetry is enabled; None = untimed, one
+        # branch per section
+        tel = getattr(broker, "telemetry", None)
+        self._lc = tel.loop_clock() if tel is not None else None
+        self._flush_t = 0.0  # when the outbox first filled
         self._send_guard: Optional[asyncio.Task] = None
 
     # -- IO ----------------------------------------------------------------
@@ -220,6 +230,8 @@ class Connection:
         if self._flush_scheduled:
             return
         self._flush_scheduled = True
+        if self._lc is not None:
+            self._flush_t = _now()
         # wakeups that survived coalescing; the planner's grouped
         # delivery tail targets ≤1 per connection per batch
         self.broker.metrics.inc("delivery.wakeups")
@@ -243,6 +255,10 @@ class Connection:
         self._flush_scheduled = False
         if self._closing:
             return
+        lc = self._lc
+        if lc is not None:
+            t0 = _now()
+            n0 = lc.inner
         try:
             self._send_packets(self.channel.handle_deliver())
         except (ConnectionResetError, BrokenPipeError, OSError):
@@ -270,6 +286,8 @@ class Connection:
             if over:
                 self._send_guard = self._loop.create_task(
                     self._send_timeout_guard())
+        if lc is not None:
+            lc.loop_leave(I_FLUSH_NS, t0, n0, t0 - self._flush_t)
 
     async def _send_timeout_guard(self) -> None:
         try:
@@ -384,6 +402,13 @@ class Connection:
                         await asyncio.sleep(wait)
                 if self._gc is not None:
                     self._gc.inc(1, len(data))
+                # loop.read.*: parse → channel → ingress submit of
+                # this chunk, timed in slices that end where the
+                # handler gives the loop back
+                lc = self._lc
+                if lc is not None:
+                    t0 = _now()
+                    n0 = lc.inner
                 pkts = await self._decode(data)
                 for idx, pkt in enumerate(pkts or []):
                     if not await self._process(pkt):
@@ -398,13 +423,20 @@ class Connection:
                         # packets interleaves deliveries at ~ms
                         # granularity; throughput is unchanged (the
                         # work is conserved, just sliced).
+                        if lc is not None:
+                            lc.loop_leave(I_READ_NS, t0, n0)
                         await asyncio.sleep(0)
+                        if lc is not None:
+                            t0 = _now()
+                            n0 = lc.inner
                 if pkts is None or self._finish_after_batch:
                     # framing violation / transport-level close: any
                     # packets decoded before it were processed above,
                     # and their responses flushed before the close
                     await self._drain_and_close()
                     break
+                if lc is not None:
+                    lc.loop_leave(I_READ_NS, t0, n0)
                 if not self._closing:
                     await self.writer.drain()
                 if pkts:

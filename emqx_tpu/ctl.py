@@ -96,7 +96,8 @@ class Ctl:
                               "set-level <debug|info|warning|error> | show")
         self.register_command(
             "telemetry", self._telemetry,
-            "stages | slow | reset — publish-path stage latency")
+            "stages | slow | stalls | reset — publish-path stage "
+            "latency, slow batches, loop stalls")
         self.register_command(
             "cache", self._cache,
             "publish match-cache: hit/miss/stale, epoch-bump split, "
@@ -257,6 +258,11 @@ class Ctl:
             return "\n".join(lines)
         if args[0] == "slow":
             recs = tel.slow_records()
+            return json.dumps(recs, indent=2) if recs else "(none)"
+        if args[0] == "stalls":
+            # the loop's heartbeat overdue by more than 50 ms
+            # (monitors.SysMon): start, length, the loop's stack
+            recs = tel.stall_records()
             return json.dumps(recs, indent=2) if recs else "(none)"
         if args[0] == "reset":
             tel.reset()

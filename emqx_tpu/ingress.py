@@ -85,6 +85,13 @@ class IngressBatcher:
         # rows still route
         self.finish_chunk = max(1, finish_chunk)
         self._pending: List[Tuple[Message, asyncio.Future]] = []
+        # telemetry (per batch, never per message): when the first
+        # message landed in the empty accumulator — the start of the
+        # batch's ``ingress_wait`` and of its span's ``end_to_end`` —
+        # and when the ordered chain's last batch completed (the end
+        # of the next one's ``chain_wait``). 0.0 = not stamped
+        self._t_first = 0.0
+        self._t_done = 0.0
         self._handle = None
         self._inflight = 0
         self._chain: Optional[asyncio.Task] = None  # ordered delivery
@@ -175,6 +182,9 @@ class IngressBatcher:
             # home loop, the direct flush is the legacy fast path
             self._flush()
         elif len(self._pending) == 1:
+            tel = self.broker.telemetry
+            if tel is not None and tel.enabled:
+                self._t_first = time.perf_counter()
             if self.linger_ms > 0:
                 self._handle = loop.call_later(
                     self.linger_ms / 1000.0, self._flush)
@@ -198,6 +208,10 @@ class IngressBatcher:
             n = len(self._pending)
             if n > self.max_queue:
                 self.max_queue = n
+            if n == 1:
+                tel = self.broker.telemetry
+                if tel is not None and tel.enabled:
+                    self._t_first = time.perf_counter()
         home = self._home or loop
         if loop is home:
             if n >= self.batch_size:
@@ -368,11 +382,29 @@ class IngressBatcher:
             # reads, and probe latency tripled while throughput fell.)
             chain_active = (self._chain is not None
                             and not self._chain.done())
+            span = None
+            tel = self.broker.telemetry
+            if tel is not None and tel.enabled:
+                # the span starts at the batch's first arrival; a
+                # capped take restarts the clock for what it leaves
+                # behind (those arrivals carry no stamp of their own)
+                span = tel.begin(len(pending),
+                                 t_first=self._t_first or None,
+                                 inflight=self._inflight)
+                self._t_first = span.t_mark if self._pending else 0.0
             try:
-                pb = self.broker.publish_begin(
-                    [m for m, _ in pending], defer_host=chain_active)
+                msgs = [m for m, _ in pending]
+                if span is not None:
+                    pb = self.broker.publish_begin(
+                        msgs, defer_host=chain_active, span=span)
+                else:
+                    # the pre-telemetry call, unchanged
+                    pb = self.broker.publish_begin(
+                        msgs, defer_host=chain_active)
             except Exception as e:
                 log.exception("ingress batch publish failed")
+                if span is not None:
+                    span.stop()  # the stage the failure left open
                 self._resolve_exc(pending, e)
                 continue
             if pb.done and not chain_active:
@@ -388,7 +420,13 @@ class IngressBatcher:
     async def _complete(self, pb, pending, prev) -> None:
         """Fetch off-loop, then deliver in batch order."""
         loop = asyncio.get_running_loop()
+        # the span (None = telemetry off, or a host batch that already
+        # closed it); kept here because the last chunk closes pb.span
+        sp = pb.span
         try:
+            if sp is not None:
+                # publish_begin returned → this task got the loop
+                sp.wait_mark("loop_wait")
             if not pb.done and pb.host_topics is None:
                 if faults.enabled and self._pool is not None \
                         and faults.fire("executor.death"):
@@ -420,6 +458,14 @@ class IngressBatcher:
                     await asyncio.shield(prev)
                 except Exception:
                     pass
+            if sp is not None:
+                # fetch returned (publish_fetch left the mark) → the
+                # chain's previous batch completed → the loop came
+                # back to this one
+                if prev is not None and self._t_done > sp.t_mark:
+                    sp.wait("chain_wait", sp.t_mark, self._t_done)
+                    sp.t_mark = self._t_done
+                sp.wait_mark("loop_wait")
             if pb.done:
                 results = self.broker.publish_finish(pb)
             else:
@@ -444,6 +490,9 @@ class IngressBatcher:
                     chunk_fn(pb, s, min(s + self.finish_chunk, n_units))
                     if s + self.finish_chunk < n_units:
                         await asyncio.sleep(0)
+                        if sp is not None:
+                            # what the tail gave back to the loop
+                            sp.wait_mark("tail_yield")
                 if pb.plan is not None:
                     # multi-loop: the batch's results/metrics fold —
                     # and therefore the ack futures below — wait for
@@ -475,6 +524,8 @@ class IngressBatcher:
             return
         finally:
             self._inflight -= 1
+            if sp is not None:
+                self._t_done = time.perf_counter()
             if self._pending:
                 # a slot freed while messages accumulated — but
                 # flushing HERE would run inside this batch's
@@ -541,6 +592,7 @@ class IngressBatcher:
         loop-less callers); in-flight async batches are awaited by
         :meth:`drain`."""
         pending = self._take_pending()
+        self._t_first = 0.0
         if not pending:
             return
         try:

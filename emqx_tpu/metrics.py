@@ -298,6 +298,38 @@ TRACING_METRICS = [
 FRAME_METRICS = [
     "frame.native.frames", "frame.fallback", "frame.oversize",
 ]
+# the loop's time outside publish batches and its stalls
+# (telemetry.py "The loop outside publish batches"; gated on
+# [telemetry] enabled). ``*.ns`` are nanosecond sums EXCLUSIVE of
+# what nested inside the section, ``*.calls`` the sections counted;
+# each ``.ns`` is followed by its count (and ``loop.flush.ns`` by
+# ``wait_ns`` after that): Telemetry.loop_leave / gc_done index them
+# as base, base + 1, base + 2
+LOOP_METRICS = [
+    # socket read → parse → channel → ingress submit, per read chunk
+    # (connection.Connection.run)
+    "loop.read.ns", "loop.read.calls",
+    # Connection._flush_deliver per wake-up; wait_ns = outbox first
+    # filled (_schedule_flush) → the flush ran
+    "loop.flush.ns", "loop.flush.calls", "loop.flush.wait_ns",
+    # the loop inside its selector: waiting for a socket or a timer,
+    # or polling with work queued (monitors.SysMon wraps the
+    # selector's select) — 1 − select ÷ wall is the loop's busy share
+    "loop.select.ns", "loop.select.calls",
+    # every garbage collection by generation (monitors.SysMon's
+    # gc.callbacks hook), not only those over long_gc_ms
+    "gc.ns.gen0", "gc.collections.gen0",
+    "gc.ns.gen1", "gc.collections.gen1",
+    "gc.ns.gen2", "gc.collections.gen2",
+    # the loop's heartbeat overdue by more than 50 ms
+    # (monitors.SysMon._beat): stalls and their summed length
+    "loop.stalls", "loop.stall.ns",
+    # wall clock on the same terms as the sums above: the heartbeat
+    # adds the time since its last beat, so a window's delta of any
+    # ``*.ns`` over this one's is that section's share of the window
+    # (to one beat, 20 ms), whoever cut the window and however late
+    "loop.wall.ns",
+]
 
 ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + WILL_METRICS
@@ -306,7 +338,7 @@ ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + AUTOMATON_METRICS + TRANSPORT_METRICS
                + OVERLOAD_METRICS + BREAKER_METRICS + FAULT_METRICS
                + OPS_METRICS + DURABILITY_METRICS + CLUSTER_METRICS
-               + TRACING_METRICS + FRAME_METRICS)
+               + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS)
 
 #: registry names that are NOT monotonic — ``Metrics.dec`` runs on
 #: them in steady state (today: the retainer's live-entry count,
@@ -378,6 +410,19 @@ class Metrics:
             with lock:
                 self._counters[self._index[name]] -= n
 
+    @any_thread
+    def add_at(self, idx: int, n: int) -> None:
+        """``inc`` by registry index (the ``I_*`` constants below):
+        the timed sections' path, which cannot afford the name
+        lookup."""
+        lock = self._lock
+        if lock is None:
+            # lint: ok-CD102 single-writer fast path, as in inc()
+            self._counters[idx] += n
+        else:
+            with lock:
+                self._counters[idx] += n
+
     def val(self, name: str) -> int:
         return int(self._counters[self._index[name]])
 
@@ -434,6 +479,14 @@ _QOS_SENT = ("messages.qos0.sent", "messages.qos1.sent",
              "messages.qos2.sent")
 
 _global = Metrics()
+
+# every Metrics registers ALL_METRICS first and in order, so these
+# indexes hold for any instance
+I_READ_NS = _global._index["loop.read.ns"]
+I_FLUSH_NS = _global._index["loop.flush.ns"]
+I_SELECT_NS = _global._index["loop.select.ns"]
+I_WALL_NS = _global._index["loop.wall.ns"]
+I_GC_NS = _global._index["gc.ns.gen0"]
 
 
 def global_metrics() -> Metrics:

@@ -10,9 +10,11 @@ Linux, so:
   - :class:`OsMon` reads ``/proc/stat`` deltas and ``/proc/meminfo``;
   - :class:`VmMon` watches a supplied count (connections by default —
     the asyncio analogue of the process count) against a watermark;
-  - :class:`SysMon` measures event-loop lag (the analogue of
-    long_schedule: the scheduler not getting to our task on time) and
-    Python GC pauses via ``gc.callbacks`` (the analogue of long_gc).
+  - :class:`SysMon` measures event-loop lag with a heartbeat on the
+    loop (the analogue of long_schedule: the scheduler not getting
+    to our task on time), records each stall with the stack a
+    watcher thread took of it, and times every Python collection via
+    ``gc.callbacks`` (the analogue of long_gc).
 
 Each monitor has a pure ``check(...)`` (unit-testable with injected
 readings) and an async ``run()`` loop the node supervises. Alarm
@@ -25,10 +27,18 @@ from __future__ import annotations
 import asyncio
 import gc as _gc
 import logging
+import sys
+import threading
 import time
 from typing import Callable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from emqx_tpu.alarm import AlarmManager
+from emqx_tpu.concurrency import bg_thread
+from emqx_tpu.metrics import I_SELECT_NS, I_WALL_NS
+from emqx_tpu.telemetry import STALL_S
+from emqx_tpu.tracing import frame_stack
 
 log = logging.getLogger("emqx_tpu.monitors")
 
@@ -156,23 +166,44 @@ class VmMon:
 class SysMon:
     """Runtime-event monitor: event-loop lag ≈ long_schedule, GC
     pauses ≈ long_gc (emqx_sys_mon publishes these to '$SYS' and
-    counts them; we count + log + optionally alarm)."""
+    counts them; we count + log + optionally alarm).
+
+    Lag comes from one heartbeat on the loop: a timer that re-arms
+    itself every :attr:`BEAT_S` and reads how late it ran. A beat
+    more than ``telemetry.STALL_S`` (50 ms) late is a STALL: while
+    telemetry is enabled a watcher thread has by then taken the loop
+    thread's stack once (and the other busy threads' innermost
+    frames), and the beat — the loop is back — writes one record
+    (start, length, frames, whether a collection or an automaton
+    rebuild overlapped) into the telemetry's stall ring
+    (``ctl telemetry stalls``) and counts ``loop.stalls`` /
+    ``loop.stall.ns``. A beat later than ``long_schedule_ms`` is a
+    long_schedule event, whose log line names the frame. The gc hook
+    feeds ``gc.ns.gen*`` / ``gc.collections.gen*`` for every
+    collection and wraps each in an ``emqx/gc`` profiler annotation."""
+
+    #: the heartbeat's period, seconds
+    BEAT_S = 0.02
+    #: innermost frames kept in a stall record
+    STALL_FRAMES = 12
 
     def __init__(self, metrics=None, hooks=None,
                  long_schedule_ms: float = 240.0,
                  long_gc_ms: float = 100.0,
-                 tick: float = 1.0) -> None:
+                 tick: float = 1.0, telemetry=None) -> None:
         self.metrics = metrics
         if metrics is not None:
             metrics.new("sysmon.long_gc")
             metrics.new("sysmon.long_schedule")
         self.hooks = hooks
+        self.telemetry = telemetry
         self.long_schedule_ms = long_schedule_ms
         self.long_gc_ms = long_gc_ms
         self.tick = tick
         self.long_schedule_count = 0
         self.long_gc_count = 0
         self._gc_t0: Optional[float] = None
+        self._gc_ann = None
         self._gc_installed = False
         # per-loop scheduling lag (ms), index 0 = the main loop.
         # Peer entries are written by their own loop's probe callback
@@ -182,6 +213,20 @@ class SysMon:
         self.loop_lags: List[float] = [0.0]
         self._probe_seq: List[int] = [0]
         self._seen_seq: List[int] = [0]
+        # heartbeat state (written on the loop, read by the watcher)
+        self._loop = None
+        self._loop_tid = 0
+        self._beat_h = None
+        self._beat_at = 0.0    # when the last beat ran
+        self._beat_due = 0.0   # when the next one should
+        self._beat_gc_s = 0.0  # telemetry.gc_s at the last beat
+        self._lag_max_s = 0.0  # worst lateness since the last tick
+        # (due, loop frames, other threads) of the stall the watcher
+        # saw last
+        self._stall_stack: Optional[tuple] = None
+        self._watch_thread: Optional[threading.Thread] = None
+        self._watch_stop = threading.Event()
+        self._selector = None  # the loop's selector while wrapped
 
     def bind_loops(self, loop_group) -> None:
         """Extend lag monitoring over every LoopGroup loop: each tick
@@ -215,10 +260,22 @@ class SysMon:
 
     def _on_gc(self, phase: str, info: dict) -> None:
         if phase == "start":
+            tel = self.telemetry
+            if tel is not None and tel.config.enabled:
+                ann = TraceAnnotation(
+                    "emqx/gc", gen=info.get("generation", 0))
+                ann.__enter__()
+                self._gc_ann = ann
             self._gc_t0 = time.perf_counter()
         elif phase == "stop" and self._gc_t0 is not None:
-            ms = (time.perf_counter() - self._gc_t0) * 1000.0
+            dt = time.perf_counter() - self._gc_t0
             self._gc_t0 = None
+            ann = self._gc_ann
+            if ann is not None:
+                self._gc_ann = None
+                ann.__exit__(None, None, None)
+                self.telemetry.gc_done(info.get("generation", 0), dt)
+            ms = dt * 1000.0
             if ms > self.long_gc_ms:
                 self.on_long_gc(ms)
 
@@ -232,29 +289,187 @@ class SysMon:
         if self.hooks is not None:
             self.hooks.run("sysmon.long_gc", (ms,))
 
-    def on_long_schedule(self, ms: float) -> None:
+    def on_long_schedule(self, ms: float,
+                         frame: Optional[str] = None) -> None:
         self.long_schedule_count += 1
-        log.warning("long_schedule: event loop lagged %.1fms", ms)
+        if frame is not None:
+            log.warning("long_schedule: event loop lagged %.1fms "
+                        "in %s", ms, frame)
+        else:
+            log.warning("long_schedule: event loop lagged %.1fms", ms)
         if self.metrics is not None:
             self.metrics.inc("sysmon.long_schedule")
         if self.hooks is not None:
             self.hooks.run("sysmon.long_schedule", (ms,))
 
-    def check_lag(self, expected_s: float, actual_s: float) -> None:
+    def check_lag(self, expected_s: float, actual_s: float,
+                  frame: Optional[str] = None) -> None:
         lag_ms = (actual_s - expected_s) * 1000.0
         if lag_ms > self.long_schedule_ms:
-            self.on_long_schedule(lag_ms)
+            self.on_long_schedule(lag_ms, frame)
+
+    # -- the heartbeat and the stall watcher ---------------------------
+
+    def start_heartbeat(self, loop) -> None:
+        """Arm the heartbeat on ``loop`` (the calling thread's) and,
+        while telemetry is enabled, the watcher thread beside it."""
+        self._loop = loop
+        self._loop_tid = threading.get_ident()
+        now = time.perf_counter()
+        self._beat_at = now
+        self._beat_due = now + self.BEAT_S
+        self._beat_h = loop.call_later(self.BEAT_S, self._beat)
+        tel = self.telemetry
+        if tel is not None and tel.config.enabled \
+                and self._watch_thread is None:
+            self._watch_stop.clear()
+            self._watch_thread = threading.Thread(
+                target=self._watch, name="loop-watch", daemon=True)
+            self._watch_thread.start()
+            self._time_selector(loop, tel.loop_clock())
+
+    def _time_selector(self, loop, lc) -> None:
+        """``loop.select.ns``: time the loop spends inside its
+        selector, by shadowing the selector's ``select`` on the
+        instance (asyncio has no hook for it; a loop without a
+        ``_selector`` — not the stock selector loop — goes untimed)."""
+        sel = getattr(loop, "_selector", None)
+        if lc is None or sel is None \
+                or "select" in getattr(sel, "__dict__", {"select": 0}):
+            return  # untimed; or another node on this loop times it
+        select = sel.select
+        now = time.perf_counter
+
+        def timed_select(timeout=None):
+            t0 = now()
+            n0 = lc.inner
+            try:
+                return select(timeout)
+            finally:
+                lc.loop_leave(I_SELECT_NS, t0, n0)
+
+        sel.select = timed_select
+        self._selector = sel
+
+    def stop_heartbeat(self) -> None:
+        if self._beat_h is not None:
+            self._beat_h.cancel()
+            self._beat_h = None
+        sel = self._selector
+        if sel is not None:
+            self._selector = None
+            try:
+                del sel.select  # the instance shadow; the method stays
+            except AttributeError:
+                pass
+        t = self._watch_thread
+        if t is not None:
+            self._watch_stop.set()
+            t.join(2.0)
+            self._watch_thread = None
+
+    def _beat(self) -> None:
+        now = time.perf_counter()
+        due = self._beat_due
+        late = now - due
+        if late > self._lag_max_s:
+            self._lag_max_s = late
+        tel = self.telemetry
+        if late > STALL_S:
+            self._on_stall(due, late)
+        if tel is not None:
+            self._beat_gc_s = tel.gc_s
+            m = tel.metrics
+            if m is not None and tel.config.enabled:
+                m.add_at(I_WALL_NS, int((now - self._beat_at) * 1e9))
+        self._beat_at = now
+        self._beat_due = now + self.BEAT_S
+        self._beat_h = self._loop.call_later(self.BEAT_S, self._beat)
+
+    def _on_stall(self, due: float, late: float) -> None:
+        """The loop is back from a stall that began around ``due``
+        and lasted ``late`` seconds."""
+        frames: List[str] = []
+        others: dict = {}
+        seen = self._stall_stack
+        if seen is not None and seen[0] == due:
+            frames = seen[1][-self.STALL_FRAMES:]
+            others = seen[2]
+        tel = self.telemetry
+        if tel is not None and tel.config.enabled:
+            tel.note_stall({
+                "ts": time.time() - late,
+                "ms": round(late * 1000.0, 3),
+                # outermost first; the last one is where the loop was
+                "frames": frames,
+                # thread name -> innermost frames of the threads that
+                # were not parked at that instant
+                "others": others,
+                "gc_ms": round((tel.gc_s - self._beat_gc_s) * 1000.0,
+                               3),
+                "rebuild": (tel.rebuilding > 0
+                            or tel.rebuild_end > self._beat_at),
+            })
+        self.check_lag(0.0, late, frames[-1] if frames else None)
+
+    @bg_thread
+    def _watch(self) -> None:
+        """The watcher: when the beat is overdue past the stall mark,
+        take the loop thread's stack ONCE, hold an ``emqx/stall``
+        annotation open until the loop beats again."""
+        stop = self._watch_stop
+        while not stop.wait(self.BEAT_S):
+            due = self._beat_due
+            if time.perf_counter() - due <= STALL_S:
+                continue
+            try:
+                frames, others = self._stacks()
+            except Exception:
+                # a torn frame walk must not kill the watcher
+                frames, others = [], {}
+            self._stall_stack = (due, frames, others)
+            with TraceAnnotation("emqx/stall"):
+                while self._beat_due == due \
+                        and not stop.wait(0.005):
+                    pass
+
+    #: innermost frames that mean "this thread is parked": such
+    #: threads are left out of a stall record's ``others``
+    _PARKED = ("threading.py:wait", "queue.py:get", "thread.py:_worker",
+               "selectors.py:select")
+
+    def _stacks(self) -> tuple:
+        """The loop thread's stack, and the innermost frames of every
+        other thread that is not parked: a loop caught at an ordinary
+        line with no collection running was held off the GIL or the
+        CPU, and the thread that held it is in this list."""
+        me = threading.get_ident()
+        names = {t.ident: t.name for t in threading.enumerate()}
+        frames: List[str] = []
+        others = {}
+        current = sys._current_frames()
+        try:
+            for ident, frame in current.items():
+                if ident == self._loop_tid:
+                    frames = frame_stack(frame)
+                elif ident != me:
+                    top = frame_stack(frame, 4)
+                    if top and top[-1] not in self._PARKED:
+                        others[names.get(ident, str(ident))] = top
+        finally:
+            del current  # drop the frame references promptly
+        return frames, others
 
     async def run(self) -> None:
         self.install_gc_hook()
+        self.start_heartbeat(asyncio.get_running_loop())
         try:
             while True:
-                t0 = time.perf_counter()
                 await asyncio.sleep(self.tick)
-                elapsed = time.perf_counter() - t0
-                self.check_lag(self.tick, elapsed)
-                self.loop_lags[0] = max(
-                    0.0, (elapsed - self.tick) * 1000.0)
+                # the heartbeat's worst lateness over this tick (its
+                # long_schedule events fired as they happened)
+                self.loop_lags[0] = self._lag_max_s * 1000.0
+                self._lag_max_s = 0.0
                 lg = self.loop_group
                 if lg is not None:
                     # fold last tick's peer probes (event firing stays
@@ -273,4 +488,5 @@ class SysMon:
                             except RuntimeError:
                                 pass  # loop died since alive()
         finally:
+            self.stop_heartbeat()
             self.remove_gc_hook()

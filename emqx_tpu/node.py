@@ -185,7 +185,8 @@ class Node:
         # stamps the spans, the router's cache-split dispatch leaves
         # its probe/merge share for the span to pick up
         self.telemetry = Telemetry(telemetry, tracer=self.tracer,
-                                   alarms=self.alarms, node=name)
+                                   alarms=self.alarms, node=name,
+                                   metrics=self.metrics)
         self.broker.telemetry = self.telemetry
         self.router.telemetry = self.telemetry
         # per-message span tracing ([tracing], tracing.py): always
@@ -203,7 +204,8 @@ class Node:
         self.os_mon = OsMon(self.alarms)
         self.vm_mon = VmMon(self.alarms, self.cm.connection_count,
                             max_count=1024000)
-        self.sys_mon = SysMon(metrics=self.metrics, hooks=self.hooks)
+        self.sys_mon = SysMon(metrics=self.metrics, hooks=self.hooks,
+                              telemetry=self.telemetry)
         self.global_gc = GlobalGc()
         # extension system
         self.modules = ModuleRegistry(self)

@@ -1,26 +1,31 @@
-"""Device profiling: jax-profiler traces + per-kernel timing.
+"""Device profiling: jax-profiler traces and their reading.
 
 The reference profiles with BEAM VM introspection (emqx_vm.erl) and
 system monitors (SURVEY §5 "Tracing/profiling"); the TPU equivalent
-is the XLA profiler (TensorBoard-format traces of every kernel) plus
-wall-clock timing of the compiled steps themselves. Exposed as:
+is the XLA profiler (TensorBoard-format traces of every kernel).
+Exposed as:
 
   - :func:`trace` — context manager writing a profiler trace dir
     (inspect with TensorBoard / xprof);
-  - :class:`KernelTimer` — named wall-clock accumulators with
-    block-until-ready semantics (per-kernel timing for bench modes
-    and the ``profile`` ctl command);
-  - ctl integration: ``profile start <dir>`` / ``profile stop`` on a
-    live node (registered by Node via :func:`register_ctl`).
+  - :func:`report` — what an operator needs from such a trace
+    without TensorBoard: the device's busy share, and the longest
+    device idle gaps, each with the host annotations
+    (``emqx/<stage>``, telemetry.py) that overlap it. The
+    annotations and the device's ``XLA Ops`` line sit in one
+    ``xplane.pb``, so they share a clock;
+  - ctl integration: ``profile start <dir>`` / ``profile stop`` /
+    ``profile report <dir>`` on a live node (registered by Node via
+    :func:`register_ctl`).
 """
 
 from __future__ import annotations
 
 import contextlib
+import glob
 import os
-import time
-from collections import deque
-from typing import Dict, Optional
+from typing import Dict, List, Optional
+
+from emqx_tpu.telemetry import union_s
 
 
 #: the in-checkout cache location, resolved from the package (never
@@ -66,72 +71,135 @@ def trace(logdir: str):
         jax.profiler.stop_trace()
 
 
-class KernelTimer:
-    """Named wall-clock timing for compiled steps.
+#: where the trace keeps host annotations and device operations
+HOST_PLANE = "/host:CPU"
+DEVICE_PLANE_PREFIX = "/device:"
+DEVICE_OP_LINE = "XLA Ops"
+#: every annotation this program writes starts with this
+ANNOTATION_PREFIX = "emqx/"
+#: what a gap's remainder is called: host time in no publish stage
+OUTSIDE = "loop outside publish stages"
 
-    Usage — the span yields a capture function; pass it the step's
-    output so the timer can block on it (otherwise only async
-    DISPATCH time is measured, microseconds instead of the device
-    execution)::
 
-        with timer.span("match") as done:
-            done(step(x))
+def read_trace(trace_dir: str) -> tuple:
+    """The newest ``*.xplane.pb`` under ``trace_dir`` as ``(ops,
+    annotations)``: device operations ``(start_s, end_s, name)`` off
+    the ``XLA Ops`` line of every ``/device:`` plane, and this
+    program's host annotations ``(start_s, end_s, name, seq)`` off
+    the ``/host:CPU`` plane (one line per thread)."""
+    from jax.profiler import ProfileData
 
-    p50/p99 per name; samples ring-buffered (a long-lived node must
-    not grow timing lists without bound).
-    """
+    found = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    if not found:
+        raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
+    data = ProfileData.from_file(sorted(found)[-1])
+    ops: List[tuple] = []
+    anns: List[tuple] = []
+    for plane in data.planes:
+        if plane.name.startswith(DEVICE_PLANE_PREFIX):
+            for line in plane.lines:
+                if line.name == DEVICE_OP_LINE:
+                    ops.extend(
+                        (ev.start_ns * 1e-9,
+                         (ev.start_ns + ev.duration_ns) * 1e-9, ev.name)
+                        for ev in line.events)
+        elif plane.name == HOST_PLANE:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith(ANNOTATION_PREFIX):
+                        a = ev.start_ns * 1e-9
+                        anns.append(
+                            (a, a + ev.duration_ns * 1e-9, ev.name,
+                             next((v for k, v in ev.stats
+                                   if k == "seq"), None)))
+    return ops, anns
 
-    MAX_SAMPLES = 4096
 
-    def __init__(self) -> None:
-        self._samples: Dict[str, deque] = {}
+def attribute(ops: List[tuple], anns: List[tuple],
+              top: int = 5) -> dict:
+    """Device busy share, and the ``top`` longest device idle gaps
+    with the host annotations that overlap each.
 
-    @contextlib.contextmanager
-    def span(self, name: str):
-        import jax
-
-        t0 = time.perf_counter()
-        holder = {}
-
-        def _block(x):
-            holder["out"] = x
-            return x
-
-        try:
-            yield _block
-        finally:
-            if "out" in holder:
-                jax.block_until_ready(holder["out"])
-            self.record(name, (time.perf_counter() - t0) * 1000.0)
-
-    def record(self, name: str, ms: float) -> None:
-        self._samples.setdefault(
-            name, deque(maxlen=self.MAX_SAMPLES)).append(ms)
-
-    def stats(self) -> Dict[str, Dict[str, float]]:
-        import numpy as np
-
-        out = {}
-        for name, xs in self._samples.items():
-            arr = np.asarray(xs)
-            out[name] = {
-                "count": int(arr.size),
-                "p50_ms": float(np.percentile(arr, 50)),
-                "p99_ms": float(np.percentile(arr, 99)),
-                "total_ms": float(arr.sum()),
-            }
+    Returns ``{"window_s", "device_busy_s", "device_busy_share",
+    "device_ops", "annotations", "gaps"}``; a gap is ``{"start_s"``
+    (from the first event of the trace), ``"seconds", "before"`` (the
+    op that ended it), ``"host"``: ``[[name, seq, share of the gap],
+    ...]`` largest first, closed by ``[OUTSIDE, None, share]`` — the
+    part of the gap no annotation covers``}``. Without a device op
+    (the CPU backend has no device plane) the busy share is None."""
+    if not ops and not anns:
+        raise ValueError("the trace holds neither a device operation "
+                         "nor a host annotation of this program")
+    t_lo = min(x[0] for x in ops + anns)
+    t_hi = max(x[1] for x in ops + anns)
+    out = {"window_s": t_hi - t_lo, "device_ops": len(ops),
+           "annotations": len(anns), "device_busy_s": None,
+           "device_busy_share": None, "gaps": []}
+    if not ops:
         return out
+    busy = union_s([(a, b) for a, b, _n in ops])
+    out["device_busy_s"] = busy
+    out["device_busy_share"] = busy / (t_hi - t_lo)
+    gaps = []
+    end = None
+    for a, b, name in sorted(ops):
+        if end is not None and a > end:
+            gaps.append((a - end, end, a, name))
+        end = b if end is None else max(end, b)
+    gaps.sort(reverse=True)
+    for length, g0, g1, name in gaps[:top]:
+        by: Dict[tuple, float] = {}
+        clipped = []
+        for a, b, ann, seq in anns:
+            lo, hi = max(a, g0), min(b, g1)
+            if hi > lo:
+                by[(ann, seq)] = by.get((ann, seq), 0.0) + hi - lo
+                clipped.append((lo, hi))
+        host = [[ann, seq, secs / length]
+                for (ann, seq), secs in
+                sorted(by.items(), key=lambda kv: -kv[1])]
+        host.append([OUTSIDE, None, 1.0 - union_s(clipped) / length])
+        out["gaps"].append({
+            "start_s": g0 - t_lo, "seconds": length,
+            "before": name.partition(" = ")[0].strip()[:80],
+            "host": host})
+    return out
 
-    def reset(self) -> None:
-        self._samples.clear()
+
+def report(trace_dir: str, top: int = 5) -> dict:
+    """:func:`attribute` over :func:`read_trace`."""
+    ops, anns = read_trace(trace_dir)
+    return attribute(ops, anns, top)
+
+
+def render_report(rep: dict, per_gap: int = 8) -> str:
+    """:func:`report` as the text ``ctl profile report`` prints."""
+    lines = [f"trace: {rep['window_s']:.3f}s, {rep['device_ops']} "
+             f"device ops, {rep['annotations']} host annotations"]
+    if rep["device_busy_s"] is None:
+        lines.append("device: no device plane in this trace")
+        return "\n".join(lines)
+    lines.append(f"device: busy {rep['device_busy_s']:.6f}s = "
+                 f"{100.0 * rep['device_busy_share']:.3f}% of the "
+                 f"trace")
+    for i, g in enumerate(rep["gaps"], 1):
+        lines.append(f"gap {i}: {g['seconds'] * 1e3:.3f}ms at "
+                     f"+{g['start_s']:.3f}s, ended by {g['before']}")
+        # the largest annotations, then always the uncovered rest
+        for name, seq, share in g["host"][:-1][:per_gap - 1] \
+                + g["host"][-1:]:
+            tag = f" seq={seq}" if seq is not None else ""
+            lines.append(f"    {100.0 * share:6.2f}%  {name}{tag}")
+    return "\n".join(lines)
 
 
 _active: Dict[str, Optional[str]] = {"dir": None}
 
 
 def register_ctl(ctl) -> None:
-    """``profile start <dir> | stop | kernels`` on a live node."""
-    import json
+    """``profile start <dir> | stop | report <dir>`` on a live
+    node."""
 
     def _profile_loops(args):
         # the per-loop sampling profiler (tracing.LoopProfiler):
@@ -172,17 +240,34 @@ def register_ctl(ctl) -> None:
             trc = getattr(getattr(ctl, "node", None), "tracing", None)
             loops = ("on" if trc is not None and trc.profiler.running
                      else "off")
-            return (f"profiling: "
-                    f"{'on -> ' + _active['dir'] if _active['dir'] else 'off'}"
-                    f" | loops: {loops}")
+            out = (f"profiling: "
+                   f"{'on -> ' + _active['dir'] if _active['dir'] else 'off'}"
+                   f" | loops: {loops}")
+            tel = getattr(getattr(ctl, "node", None), "telemetry", None)
+            if tel is not None and tel.enabled:
+                # automaton rebuilds, the one thing timed here that
+                # is no publish stage (telemetry's `rebuild` stage)
+                st = tel.stage_stats()["rebuild"]
+                out += (f"\nrebuild: {st['count']} "
+                        f"p50 {st['p50_ms']:.3f}ms "
+                        f"p99 {st['p99_ms']:.3f}ms "
+                        f"sum {st['sum_ms']:.3f}ms")
+            return out
         if args[0] == "loops":
             return _profile_loops(args[1:])
         if args[0] == "start":
             if _active["dir"] is not None:
                 return f"already tracing to {_active['dir']}"
             logdir = args[1] if len(args) > 1 else "/tmp/emqx_tpu_trace"
+            # a trace on a serving node: the Python function tracer
+            # off (it would time every call of the busy loop), host
+            # tracer at the level that keeps TraceAnnotation events —
+            # the emqx/<stage> annotations `report` reads
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
             try:
-                jax.profiler.start_trace(logdir)
+                jax.profiler.start_trace(logdir, profiler_options=opts)
             except Exception as e:
                 # an unwritable dir must not strand a half-started
                 # trace with _active["dir"] unset (the next `start`
@@ -211,16 +296,15 @@ def register_ctl(ctl) -> None:
                 # already cleared so the next `start` works
                 return f"profile stop failed: {e}"
             return f"trace written to {out}"
-        if args[0] == "kernels":
-            return json.dumps(timer.stats(), indent=2)
+        if args[0] == "report":
+            logdir = args[1] if len(args) > 1 else "/tmp/emqx_tpu_trace"
+            try:
+                return render_report(report(logdir))
+            except (OSError, ValueError) as e:
+                return f"profile report failed: {e}"
         raise ValueError(f"bad subcommand: {args[0]}")
 
     ctl.register_command(
         "profile", _profile,
-        "start [dir] | stop | kernels | "
+        "start [dir] | stop | report [dir] | "
         "loops start|stop|show|dump [path]")
-
-
-#: process-wide timer the router/bench feed (opt-in: spans only
-#: recorded where instrumented)
-timer = KernelTimer()

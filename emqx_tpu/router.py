@@ -338,7 +338,7 @@ class Router:
         # alongside broker.telemetry. When enabled, the cache-split
         # dispatch leaves its per-batch probe/merge timing + hit/miss
         # split in _last_dispatch for the broker's span to consume
-        # (PublishSpan.stamp_match pops it) — None otherwise, and the
+        # (PublishSpan.stop_match pops it) — None otherwise, and the
         # dispatch path pays nothing
         self.telemetry = None
         self._last_dispatch: Optional[dict] = None
@@ -828,16 +828,19 @@ class Router:
     def _rebuild_locked(self):
         import time as _time
 
-        from emqx_tpu.profiling import timer as _ktimer
-
+        tel = self.telemetry
+        ann = tel.rebuild_begin() \
+            if tel is not None and tel.enabled else None
         t0 = _time.perf_counter()
         try:
             if self.config.mesh is not None:
                 return self._rebuild_sharded_locked()
             return self._rebuild_single_locked()
         finally:
-            _ktimer.record("automaton.rebuild",
-                           (_time.perf_counter() - t0) * 1000.0)
+            if ann is not None:
+                tel.rebuild_done(ann)
+                tel.observe_stage(
+                    "rebuild", (_time.perf_counter() - t0) * 1000.0)
 
     def _rebuild_single_locked(self) -> Automaton:
         prev = self._auto
@@ -1140,8 +1143,6 @@ class Router:
         exactly that."""
         import time as _time
 
-        from emqx_tpu.profiling import timer as _ktimer
-
         t_begin = _time.perf_counter()
         with self._lock:
             t0 = _time.perf_counter()
@@ -1160,48 +1161,51 @@ class Router:
                 cap_s2 = prev.node2.shape[0] * self._grow["state"]
                 nb = prev.wt.shape[0] * self._grow["edge"]
             stall = _time.perf_counter() - t0
-        try:
-            t_fl = _time.perf_counter()
-            host_auto = self._flatten_main(cap_s2, nb)
-            auto = device_view(host_auto)
-            if self.config.use_device:
-                auto = jax.device_put(auto)
-            _ktimer.record("automaton.rebuild",
-                           (_time.perf_counter() - t_fl) * 1000.0)
-        except BaseException:
-            with self._lock:
-                self._unfreeze_locked()
-            raise
-        with self._lock:
-            t1 = _time.perf_counter()
-            self._install_walk_meta(host_auto)
-            self._auto = auto
-            self._patcher = None  # delta mode: no main-table mirror
-            self._auto_map = list(self._id_to_filter)
-            # recycle ONLY ids quarantined before the freeze: an id
-            # freed DURING the flatten may still be emitted by the
-            # new tables (its path was in the snapshot) — it waits a
-            # generation
-            self._free_ids.extend(self._pending_free[:n_pend])
-            del self._pending_free[:n_pend]
-            self._dirty = False
-            self._grow = {"state": 1, "edge": 1}
-            self._rebuilds += 1
-            self._bump_cache_rev()
-            self._published = (auto, self._auto_map, self._rebuilds,
-                               self._cache_rev)
-            # fold: log entries before the mark are in the new tables;
-            # the rest replay into a fresh delta generation
-            if self._delta is not None:
-                self._delta = self._delta.split_after(mark)
-            self._delta_ver += 1
-            self._delta_merges += 1
-            self._unfreeze_locked()
-            self._publish_pair_locked()
-            stall += _time.perf_counter() - t1
-        self._rebuild_stall_ms += stall * 1000.0
         tel = self.telemetry
-        if tel is not None and tel.enabled:
+        ann = tel.rebuild_begin() \
+            if tel is not None and tel.enabled else None
+        try:
+            try:
+                host_auto = self._flatten_main(cap_s2, nb)
+                auto = device_view(host_auto)
+                if self.config.use_device:
+                    auto = jax.device_put(auto)
+            except BaseException:
+                with self._lock:
+                    self._unfreeze_locked()
+                raise
+            with self._lock:
+                t1 = _time.perf_counter()
+                self._install_walk_meta(host_auto)
+                self._auto = auto
+                self._patcher = None  # delta mode: no main-table mirror
+                self._auto_map = list(self._id_to_filter)
+                # recycle ONLY ids quarantined before the freeze: an id
+                # freed DURING the flatten may still be emitted by the
+                # new tables (its path was in the snapshot) — it waits a
+                # generation
+                self._free_ids.extend(self._pending_free[:n_pend])
+                del self._pending_free[:n_pend]
+                self._dirty = False
+                self._grow = {"state": 1, "edge": 1}
+                self._rebuilds += 1
+                self._bump_cache_rev()
+                self._published = (auto, self._auto_map, self._rebuilds,
+                                   self._cache_rev)
+                # fold: log entries before the mark are in the new tables;
+                # the rest replay into a fresh delta generation
+                if self._delta is not None:
+                    self._delta = self._delta.split_after(mark)
+                self._delta_ver += 1
+                self._delta_merges += 1
+                self._unfreeze_locked()
+                self._publish_pair_locked()
+                stall += _time.perf_counter() - t1
+        finally:
+            if ann is not None:
+                tel.rebuild_done(ann)
+        self._rebuild_stall_ms += stall * 1000.0
+        if ann is not None:
             tel.observe_stage(
                 "rebuild", (_time.perf_counter() - t_begin) * 1000.0)
 

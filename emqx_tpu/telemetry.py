@@ -25,21 +25,27 @@ this batch spend its time in". This module answers it with:
     :class:`~emqx_tpu.tracer.Tracer`) and drives the sustained-breach
     :class:`~emqx_tpu.alarm.AlarmManager` alarm.
 
-Stage semantics (all host wall-clock, milliseconds):
+Stage semantics (all host wall-clock, milliseconds; each stage is
+kept as intervals ``(stage, start, end, thread)`` on the span and
+summed into ``stages``). Busy stages first, then the waits:
 
+  ``prepare``        ``publish_begin`` entry → start of ``match``:
+                     message metrics, ``message.publish`` hooks, the
+                     veto filter, topic dedup. On the loop.
   ``match``          async dispatch of the NFA walk (device regime:
                      encode + enqueue, NOT device execution — that
                      surfaces in ``fetch``); host regime: the actual
                      trie walk.
   ``cache_gather``   match-cache probe + HBM-row merge dispatch
-                     (cache-split batches only).
+                     (cache-split batches only; carved out of the
+                     tail of the ``match`` interval).
   ``pack``           fan-out + sparse-compaction kernel dispatch.
   ``fetch``          the ONE coalesced device→host transfer — the
                      only synchronizing stage, so queued device
                      execution time surfaces here. No NEW
                      ``block_until_ready`` is introduced anywhere:
                      spans only read the clock at boundaries the
-                     pipeline already crosses.
+                     pipeline already crosses. Executor thread.
   ``dispatch_plan``  the batch dispatch planner's numpy grouping pass
                      (ops/dispatch_plan.py): CSR/bitmap expansion +
                      subscriber argsort over the fetched packed
@@ -61,20 +67,69 @@ Stage semantics (all host wall-clock, milliseconds):
                      ``dispatch`` time, recorded separately so
                      fallback cost is attributable).
   ``dispatch``       the host delivery tail (packed-row expansion +
-                     session ``deliver`` calls), summed over chunks.
+                     session ``deliver`` calls), one interval per
+                     chunk.
   ``xloop``          the cross-loop delivery ring (docs/DISPATCH.md
                      "Multi-loop front door"): handoff post → last
                      owning loop's group enqueue complete. Overlaps
                      ``dispatch`` (the main loop delivers its own
                      groups while peer loops run theirs); zero with
                      ``[node] loops = 1``.
-  ``end_to_end``     ``publish_begin`` entry → last delivery chunk.
+  ``gc_inside``      garbage collection (any generation) that ran
+                     inside this batch's on-loop busy stages. Those
+                     stages stay inclusive of it; the loop's time
+                     accounting subtracts it so a collection is
+                     counted once (``gc.ns.gen*``).
+  ``ingress_wait``   first arrival in the empty accumulator
+                     (``IngressBatcher.submit``) → ``_take_pending``:
+                     the linger, the tick, or every pipeline slot
+                     busy (tag ``inflight`` = slots busy at the take).
+  ``executor_wait``  ``run_in_executor`` call → ``publish_fetch``
+                     entry on the executor thread.
+  ``chain_wait``     fetch returned → the previous batch's completion
+                     (ordered delivery across batches).
+  ``loop_wait``      the batch was ready and the loop was busy with
+                     something else: ``publish_begin`` returned → its
+                     completion task first ran, and fetch returned (or
+                     the chain freed) → first delivery chunk.
+  ``tail_yield``     time the delivery tail gave back to the loop
+                     between finish chunks.
+  ``unattributed``   ``end_to_end`` minus the union of all intervals:
+                     the span's own completeness check.
+  ``end_to_end``     first arrival in the accumulator (or
+                     ``publish_begin`` entry for callers that bypass
+                     it) → last delivery chunk.
+
+Every busy stage is also a ``jax.profiler.TraceAnnotation`` named
+``emqx/<stage>`` with the batch's sequence number as its ``seq``
+argument: one native check when no profiler trace runs, an event on
+the thread's line of the ``/host:CPU`` plane when one does — the same
+``xplane.pb`` as the device's ``XLA Ops`` line, so host stages and
+device ops share a clock (``ctl profile report``). Waits are the gaps
+between one batch's annotations.
+
+The loop outside publish batches is counted, not spanned:
+``loop.read.*`` (socket read → parse → channel → submit, per read
+chunk), ``loop.flush.*`` (``Connection._flush_deliver`` per wake-up),
+``loop.select.*`` (the loop inside its selector: waiting, or polling
+with work queued) and ``gc.ns.gen*`` / ``gc.collections.gen*`` are
+``Metrics`` counters holding nanoseconds EXCLUSIVE of whatever nested
+inside them (:attr:`Telemetry.inner`), so on-loop stages −
+``gc_inside`` + read + flush + select + gc sum to the loop's
+attributed time without counting a second twice; what is left of the
+wall clock is loop work that has no name yet. Exact on a single-loop node; with ``[node] loops > 1``
+the peer loops share the one accumulator and the split is
+approximate. Loop stalls (the heartbeat of ``monitors.SysMon``
+overdue by more than 50 ms) land in a bounded ring beside the
+slow-publish ring (:meth:`Telemetry.note_stall`).
 
 Cost model: disabled (``[telemetry] enabled = false``) the broker
 takes one predicate branch per batch and records nothing — the
 dispatch byte-stream is identical to the un-instrumented path (pinned
-by tests/test_telemetry.py). Enabled, the cost is a handful of
-``perf_counter`` reads per batch (not per message).
+by tests/test_telemetry.py) and none of the counters above moves.
+Enabled, the cost is ~20 ``perf_counter`` reads and ~8 annotation
+objects per batch (not per message), two clock reads per socket read
+chunk and three per delivery-flush wake-up.
 """
 
 from __future__ import annotations
@@ -86,6 +141,10 @@ import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from emqx_tpu.metrics import I_GC_NS
 
 log = logging.getLogger("emqx_tpu.telemetry")
 
@@ -101,9 +160,18 @@ _observe_lock = threading.Lock()
 #: via :meth:`Telemetry.observe_stage` — it shares the histogram
 #: surfaces so a churn-driven rebuild storm shows up next to the
 #: publish latencies it would otherwise silently explain
-STAGES = ("match", "cache_gather", "pack", "fetch", "dispatch_plan",
-          "serialize", "host_fallback", "dispatch", "xloop",
-          "rebuild", "end_to_end")
+STAGES = ("ingress_wait", "prepare", "match", "cache_gather", "pack",
+          "executor_wait", "fetch", "dispatch_plan", "serialize",
+          "chain_wait", "loop_wait", "host_fallback", "dispatch",
+          "tail_yield", "xloop", "gc_inside", "rebuild",
+          "unattributed", "end_to_end")
+
+#: profiler annotation names, built once (``emqx/<stage>``)
+_ANN = {s: "emqx/" + s for s in STAGES}
+
+#: the heartbeat's lateness past which the loop counts as stalled
+#: (fixed, not a configuration key: monitors.SysMon)
+STALL_S = 0.05
 
 #: fixed log-spaced bucket upper bounds, milliseconds (1-2.5-5 per
 #: decade, 10µs..5s). Fixed — not adaptive — so scrapes from
@@ -114,6 +182,21 @@ BUCKETS_MS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
               2500.0, 5000.0)
 
 _now = time.perf_counter
+
+
+def union_s(intervals) -> float:
+    """Length covered by ``(start, end)`` pairs; where they overlap
+    the time counts once."""
+    total = 0.0
+    end = None
+    for t0, t1 in sorted(intervals):
+        if end is None or t0 > end:
+            total += t1 - t0
+            end = t1
+        elif t1 > end:
+            total += t1 - end
+            end = t1
+    return total
 
 
 @dataclasses.dataclass
@@ -209,17 +292,35 @@ class PublishSpan:
     :meth:`Telemetry.begin`, carried on ``PendingBatch.span``, closed
     by :meth:`Telemetry.finish` when the last delivery chunk lands.
 
+    Each stage is kept as an interval ``(stage, start, end, thread)``
+    in ``ivs`` (``thread`` 0 = the span's home thread, i.e. the loop;
+    -1 = a wait, which no thread spends; else the executor thread's
+    ident) and summed into ``stages`` as it closes.
+
     Writers hand off in pipeline order (begin on the event loop,
     fetch possibly on an executor thread, finish back on the loop) —
     the ingress pipeline sequences those with happens-before edges,
     so no stage field is ever written concurrently."""
 
-    __slots__ = ("t0", "stages", "batch", "n_uniq", "bucket", "path",
-                 "cache_hit", "cache_miss", "fallbacks", "topic",
-                 "closed")
+    __slots__ = ("seq", "t0", "t_mark", "home", "tel", "ivs", "stages",
+                 "batch", "n_uniq", "bucket", "path", "cache_hit",
+                 "cache_miss", "fallbacks", "inflight", "topic",
+                 "closed", "open")
 
-    def __init__(self, batch: int) -> None:
-        self.t0 = _now()
+    def __init__(self, batch: int, seq: int = 0, tel=None,
+                 t_first: Optional[float] = None,
+                 inflight: int = 0) -> None:
+        now = _now()
+        #: where ``end_to_end`` starts: the first arrival in the
+        #: accumulator when the ingress batcher made the span
+        self.t0 = now if t_first is None else t_first
+        #: end of the last interval on the batch's critical path —
+        #: the next wait starts here (:meth:`wait_mark`)
+        self.t_mark = now
+        self.seq = seq
+        self.home = threading.get_ident()
+        self.tel = tel
+        self.ivs: List[tuple] = []
         self.stages: Dict[str, float] = {}
         self.batch = batch
         self.n_uniq = 0
@@ -228,48 +329,132 @@ class PublishSpan:
         self.cache_hit = -1      # -1 = batch wasn't cache-split
         self.cache_miss = -1
         self.fallbacks = 0
+        self.inflight = inflight  # pipeline slots busy at the take
         self.topic: Optional[str] = None  # sample (tracer tee)
         self.closed = False
+        self.open = None         # the open busy stage (start/stop)
+        if t_first is not None:
+            self.wait("ingress_wait", t_first, now)
+
+    # -- busy stages ------------------------------------------------------
+
+    def start(self, stage: str) -> None:
+        """Open a busy stage on the calling thread: a profiler
+        annotation plus the clock. One stage is open at a time: a
+        stage still open (``prepare``, or one an exception skipped
+        past) closes here, where the next begins."""
+        if self.open is not None:
+            self.stop()
+        ann = TraceAnnotation(_ANN[stage], seq=self.seq)
+        ann.__enter__()
+        tel = self.tel
+        self.open = (stage, ann, tel.inner if tel is not None else 0.0,
+                     _now())
+
+    def stop(self) -> None:
+        """Close the open stage, on the thread that opened it (no-op
+        when none is open)."""
+        tok = self.open
+        if tok is None:
+            return
+        t1 = _now()
+        self.open = None
+        stage, ann, n0, t0 = tok
+        ann.__exit__(None, None, None)
+        tid = threading.get_ident()
+        if tid == self.home:
+            tid = 0
+            tel = self.tel
+            if tel is not None:
+                # the loop's exclusive-time ledger: what nested in
+                # this stage (a collection) is already counted
+                # elsewhere; hand the whole stage up to an enclosing
+                # section (a read chunk that flushed a full batch)
+                nested = tel.inner - n0
+                if nested > 0.0:
+                    self.add_ms("gc_inside", nested * 1000.0)
+                tel.inner = n0 + (t1 - t0)
+        self.ivs.append((stage, t0, t1, tid))
+        self.add_ms(stage, (t1 - t0) * 1000.0)
+        self.t_mark = t1
+
+    def stop_match(self, router) -> None:
+        """Close the match-dispatch stage, splitting out the
+        cache-gather share when the router's cache-split path left
+        its per-dispatch info (set only while telemetry is enabled —
+        see Router._match_dispatch_cached). The share is carved out
+        of the interval's tail; the ``emqx/match`` annotation covers
+        both."""
+        self.stop()
+        info = router._last_dispatch
+        if info is None:
+            return
+        router._last_dispatch = None
+        self.cache_hit = info["hit"]
+        self.cache_miss = info["miss"]
+        _stage, t0, t1, tid = self.ivs[-1]
+        gather = min((t1 - t0) * 1000.0, info["cache_gather_ms"])
+        cut = t1 - gather / 1000.0
+        self.ivs[-1] = ("match", t0, cut, tid)
+        self.ivs.append(("cache_gather", cut, t1, tid))
+        self.add_ms("match", -gather)
+        self.add_ms("cache_gather", gather)
+
+    # -- waits ------------------------------------------------------------
+
+    def wait(self, stage: str, t_start: float, t_end: float) -> None:
+        """Record a wait: an interval no thread spent on the batch."""
+        if t_end > t_start:
+            self.ivs.append((stage, t_start, t_end, -1))
+            self.add_ms(stage, (t_end - t_start) * 1000.0)
+
+    def wait_mark(self, stage: str) -> None:
+        """The wait from the end of the batch's last interval to
+        now; moves the mark."""
+        now = _now()
+        self.wait(stage, self.t_mark, now)
+        self.t_mark = now
+
+    # -- sums only --------------------------------------------------------
 
     @staticmethod
     def clock() -> float:
         return _now()
 
     def add(self, stage: str, t_start: float) -> None:
-        """Accumulate ``now - t_start`` into a stage (chunked stages
-        call this once per chunk)."""
+        """Accumulate ``now - t_start`` into a stage that lies inside
+        another stage's interval (``host_fallback`` inside
+        ``dispatch``): summed, not an interval of its own."""
         self.add_ms(stage, (_now() - t_start) * 1000.0)
 
     def add_ms(self, stage: str, ms: float) -> None:
         self.stages[stage] = self.stages.get(stage, 0.0) + ms
 
-    def stamp_match(self, router, t_start: float) -> None:
-        """Close the match-dispatch stage, splitting out the
-        cache-gather share when the router's cache-split path left
-        its per-dispatch info (set only while telemetry is enabled —
-        see Router._match_dispatch_cached)."""
-        total = (_now() - t_start) * 1000.0
-        info = router._last_dispatch
-        if info is not None:
-            router._last_dispatch = None
-            self.cache_hit = info["hit"]
-            self.cache_miss = info["miss"]
-            gather = min(total, info["cache_gather_ms"])
-            self.add_ms("cache_gather", gather)
-            self.add_ms("match", total - gather)
-        else:
-            self.add_ms("match", total)
+    def covered_s(self) -> float:
+        """Seconds covered by the union of the span's intervals."""
+        return union_s((iv[1], iv[2]) for iv in self.ivs)
 
     def record(self) -> dict:
         """The structured form (slow log / ctl telemetry slow)."""
+        t0 = self.t0
         rec = {
+            "seq": self.seq,
             "batch": self.batch,
             "n_uniq": self.n_uniq,
             "path": self.path,
             "bucket": self.bucket,
             "fallbacks": self.fallbacks,
+            "inflight": self.inflight,
             "stages_ms": {k: round(v, 3)
                           for k, v in self.stages.items()},
+            # [stage, start (ms after the span's t0), length (ms),
+            # where]
+            "intervals": [
+                [st, round((a - t0) * 1000.0, 3),
+                 round((b - a) * 1000.0, 3),
+                 "loop" if tid == 0 else
+                 "wait" if tid == -1 else "executor"]
+                for st, a, b, tid in self.ivs],
         }
         if self.cache_hit >= 0:
             rec["cache_hit"] = self.cache_hit
@@ -287,18 +472,35 @@ class Telemetry:
 
     def __init__(self, config: Optional[TelemetryConfig] = None,
                  tracer=None, alarms=None,
-                 node: str = "local") -> None:
+                 node: str = "local", metrics=None) -> None:
         self.config = config or TelemetryConfig()
         self.tracer = tracer
         self.alarms = alarms
         self.node = node
+        #: where the loop counters live (``loop.*``, ``gc.*``); None
+        #: (a bare Telemetry in a unit test) = spans only
+        self.metrics = metrics
         self.hists: Dict[str, Histogram] = {
             s: Histogram(self.config.ring_size) for s in STAGES}
         self.spans_total = 0
         self.slow_total = 0
+        self._seq = 0
         self._slow_streak = 0
         self._slow_ring: deque = deque(
             maxlen=max(1, self.config.slow_log_size))
+        self._stall_ring: deque = deque(
+            maxlen=max(1, self.config.slow_log_size))
+        #: the loop's exclusive-time ledger: running seconds already
+        #: attributed to an INNER section. Every timed section on the
+        #: loop (span stage, read chunk, flush wake-up, collection)
+        #: reads it on entry (n0) and on exit takes ``dt − (inner −
+        #: n0)`` as its own, then sets ``inner = n0 + dt``
+        self.inner = 0.0
+        #: running seconds of garbage collection (all generations)
+        self.gc_s = 0.0
+        #: automaton rebuilds running now / when the last one ended
+        self.rebuilding = 0
+        self.rebuild_end = 0.0
 
     @property
     def enabled(self) -> bool:
@@ -306,13 +508,16 @@ class Telemetry:
 
     # -- span lifecycle ---------------------------------------------------
 
-    def begin(self, batch: int) -> Optional[PublishSpan]:
+    def begin(self, batch: int, t_first: Optional[float] = None,
+              inflight: int = 0) -> Optional[PublishSpan]:
         """A new span, or None when disabled (the broker stores the
         None and every instrumented section reduces to one ``is not
-        None`` branch — the near-zero disabled cost)."""
+        None`` branch — the near-zero disabled cost). ``t_first`` is
+        the batch's first arrival in the ingress accumulator."""
         if not self.config.enabled:
             return None
-        return PublishSpan(batch)
+        self._seq += 1
+        return PublishSpan(batch, self._seq, self, t_first, inflight)
 
     def finish(self, span: PublishSpan) -> None:
         """Fold a finished span into the stage histograms; slow-log /
@@ -322,6 +527,8 @@ class Telemetry:
             return
         span.closed = True
         e2e = (_now() - span.t0) * 1000.0
+        span.stages["unattributed"] = max(
+            0.0, e2e - span.covered_s() * 1000.0)
         span.stages["end_to_end"] = e2e
         for stage, ms in span.stages.items():
             h = self.hists.get(stage)
@@ -371,6 +578,75 @@ class Telemetry:
         with _observe_lock:
             h.observe(ms)
 
+    # -- the loop outside publish batches ---------------------------------
+
+    def loop_leave(self, idx: int, t0: float, n0: float,
+                   wait_s: float = -1.0) -> None:
+        """Close a timed section of the loop (a read chunk, a flush
+        wake-up) opened with ``t0 = clock(); n0 = tel.inner``: its
+        exclusive nanoseconds go to the counter at ``idx``, one call
+        to ``idx + 1``, and ``wait_s`` (when given) to ``idx + 2``."""
+        dt = _now() - t0
+        own = dt - (self.inner - n0)
+        self.inner = n0 + dt
+        m = self.metrics
+        lock = m._lock
+        if lock is None:
+            c = m._counters
+            c[idx] += int(own * 1e9)
+            c[idx + 1] += 1
+            if wait_s >= 0.0:
+                c[idx + 2] += int(wait_s * 1e9)
+        else:
+            with lock:
+                c = m._counters
+                c[idx] += int(own * 1e9)
+                c[idx + 1] += 1
+                if wait_s >= 0.0:
+                    c[idx + 2] += int(wait_s * 1e9)
+
+    def loop_clock(self) -> Optional["Telemetry"]:
+        """``self`` when the loop counters are live (enabled, and a
+        Metrics to count into), else None: callers cache the answer
+        and branch on it once per section."""
+        if self.config.enabled and self.metrics is not None:
+            return self
+        return None
+
+    def gc_done(self, gen: int, seconds: float) -> None:
+        """One finished collection (monitors.SysMon's gc hook, any
+        thread — the collector holds the GIL, so the loop stood still
+        for it whichever thread ran it)."""
+        self.inner += seconds
+        self.gc_s += seconds
+        m = self.metrics
+        if m is not None:
+            i = I_GC_NS + 2 * min(gen, 2)
+            m.add_at(i, int(seconds * 1e9))
+            m.add_at(i + 1, 1)
+
+    def rebuild_begin(self) -> TraceAnnotation:
+        """An automaton rebuild starts (router, any thread): the
+        annotation to close with :meth:`rebuild_done`."""
+        self.rebuilding += 1
+        ann = TraceAnnotation("emqx/rebuild")
+        ann.__enter__()
+        return ann
+
+    def rebuild_done(self, ann: TraceAnnotation) -> None:
+        ann.__exit__(None, None, None)
+        self.rebuilding -= 1
+        self.rebuild_end = _now()
+
+    def note_stall(self, rec: dict) -> None:
+        """One loop stall (monitors.SysMon's heartbeat, on the loop
+        once it is back): ring + counters."""
+        self._stall_ring.append(rec)
+        m = self.metrics
+        if m is not None:
+            m.inc("loop.stalls")
+            m.inc("loop.stall.ns", int(rec["ms"] * 1e6))
+
     # -- read surfaces ----------------------------------------------------
 
     def stage_stats(self) -> Dict[str, dict]:
@@ -388,6 +664,10 @@ class Telemetry:
         """The last-N slow batches, oldest first."""
         return list(self._slow_ring)
 
+    def stall_records(self) -> List[dict]:
+        """The last-N loop stalls, oldest first."""
+        return list(self._stall_ring)
+
     def reset(self) -> None:
         for h in self.hists.values():
             h.reset()
@@ -395,3 +675,4 @@ class Telemetry:
         self.slow_total = 0
         self._slow_streak = 0
         self._slow_ring.clear()
+        self._stall_ring.clear()

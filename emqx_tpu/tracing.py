@@ -226,6 +226,22 @@ class SlowSubs:
         self.breach_streak = 0
 
 
+def frame_stack(frame, max_depth: int = 64) -> List[str]:
+    """A thread's Python stack as ``file.py:function`` strings,
+    outermost first — the loop profiler's sample and the stall
+    watcher's culprit (monitors.SysMon) are the same walk."""
+    stack = []
+    f, depth = frame, 0
+    while f is not None and depth < max_depth:
+        co = f.f_code
+        stack.append(
+            f"{co.co_filename.rsplit('/', 1)[-1]}:{co.co_name}")
+        f = f.f_back
+        depth += 1
+    stack.reverse()
+    return stack
+
+
 class LoopProfiler:
     """Low-overhead continuous profiler over the front-door loop
     threads, the ingress executor, and the main loop: one sampler
@@ -294,17 +310,8 @@ class LoopProfiler:
                 name = names.get(ident)
                 if name is None:
                     continue
-                stack = []
-                f, depth = frame, 0
-                while f is not None and depth < self.MAX_DEPTH:
-                    co = f.f_code
-                    stack.append(
-                        f"{co.co_filename.rsplit('/', 1)[-1]}"
-                        f":{co.co_name}")
-                    f = f.f_back
-                    depth += 1
-                stack.reverse()
-                key = name + ";" + ";".join(stack)
+                key = name + ";" + ";".join(
+                    frame_stack(frame, self.MAX_DEPTH))
                 with self._lock:
                     c = self._counts
                     if key in c or len(c) < self.MAX_STACKS:

@@ -8,14 +8,15 @@ an observed stage that is NOT in the tuple silently drops every
 sample (``Telemetry.finish`` and ``observe_stage`` both no-op on an
 unknown name rather than KeyError):
 
-  RD231  a literal stage observed via ``span.add``/``span.add_ms``/
+  RD231  a literal stage observed via ``span.start``/``span.wait``/
+         ``span.wait_mark``/``span.add``/``span.add_ms``/
          ``observe_stage`` (or a ``span.stages["..."]`` store) is
          not in STAGES — its samples vanish without a trace.
   RD232  a STAGES entry has no observe site anywhere — a stage that
          renders as a permanently-zero histogram row in every
          surface (the usual smell after a pipeline refactor).
 
-Receivers accepted for ``add``/``add_ms`` are span-shaped only
+Receivers accepted for the span methods are span-shaped only
 (``span.…``, ``…​.span.…``, ``self`` inside telemetry.py) so
 ``set.add("...")`` never false-positives.
 """
@@ -31,6 +32,10 @@ RULES = {
     "RD231": "observed telemetry stage not in STAGES",
     "RD232": "STAGES entry with no observe site (always-zero row)",
 }
+
+
+#: PublishSpan methods whose first argument names a stage
+_SPAN_METHODS = ("start", "wait", "wait_mark", "add", "add_ms")
 
 
 def _applies(path: str) -> bool:
@@ -72,7 +77,7 @@ def check(fi: FileInfo, ctx) -> List[Finding]:
                    and isinstance(node.args[0].value, str))
             if attr == "observe_stage" and lit:
                 stage, line = node.args[0].value, node.lineno
-            elif attr in ("add", "add_ms") and lit and \
+            elif attr in _SPAN_METHODS and lit and \
                     _span_receiver(node.func, fi.path):
                 stage, line = node.args[0].value, node.lineno
         elif isinstance(node, (ast.Assign, ast.AugAssign)):
