@@ -1284,8 +1284,21 @@ class Broker:
             return ()
         dm = getattr(sub, "deliver_many", None)
         if dm is not None:
+            runs = None
+            if plan.g_runs is not None \
+                    and len(items) == g_ptr[g + 1] - g_ptr[g]:
+                # every planned delivery accepted, so item k is the
+                # slice's delivery k: a run whose every item is fast
+                # holds those items' messages, in order — the session
+                # may take each as one outbox entry (docs/DISPATCH.md
+                # "Wire runs")
+                runs = [seg for seg in plan.g_runs[g] or ()
+                        if all([it[3] for it in items[seg[0]:seg[1]]])]
             try:
-                dm(items)
+                if runs:
+                    dm(items, runs)
+                else:
+                    dm(items)
             except Exception:
                 log.exception("deliver_many to %r failed", sub)
                 return ()

@@ -44,7 +44,8 @@ from emqx_tpu.mqtt.packet import (Auth, Connack, Connect, Disconnect,
                                   Unsuback, Unsubscribe, check, to_message,
                                   from_message, will_msg)
 from emqx_tpu.cm import SessionUnavailableError
-from emqx_tpu.session import (PUBREL_MARKER, Session, SessionError)
+from emqx_tpu.session import (PUBREL_MARKER, WIRE_RUN, Session,
+                              SessionError)
 from emqx_tpu.types import Message, SubOpts
 from emqx_tpu.utils.base62 import encode as b62encode
 from emqx_tpu.utils.guid import new_guid
@@ -854,6 +855,12 @@ class Channel:
         if self.session is None:
             return []
         out: List[Packet] = []
+        self._emit(self.session.drain_outbox(), out)
+        return out
+
+    def _emit(self, entries, out: List[Packet]) -> None:
+        """Turn outbox ``entries`` into packets (or ready wire bytes)
+        appended to ``out``, in order."""
         # fast-path (shared QoS0 wire image / pid-patched template)
         # metric increments batched per drain: the planner hands a
         # session its whole batch in one enqueue, so one drain here
@@ -861,13 +868,29 @@ class Channel:
         n_fast = 0
         n_tpl1 = n_tpl2 = 0
         n_onloop = 0
+        n_runs = n_run_frames = 0
         wire_ok = (self.wire_fast and not self.mountpoint
                    and not self.client_alias_max)
         trc = self.broker.tracing
         trace_on = trc is not None and trc.active
-        for pid, item in self.session.drain_outbox():
+        for pid, item in entries:
             if pid == PUBREL_MARKER:
                 out.append(self._ack(C.PUBREL, item))
+                continue
+            if pid is WIRE_RUN:
+                # a whole planned batch as one entry: what the loop
+                # below checks per frame is checked once, and the
+                # run's pre-joined bytes leave as ONE write — or the
+                # run expands into that loop, before any byte of it
+                # is written (docs/DISPATCH.md "Wire runs")
+                blob = self._run_blob(item) \
+                    if wire_ok and not trace_on else None
+                if blob is None:
+                    self._emit([(None, m) for m in item.msgs], out)
+                else:
+                    n_runs += 1
+                    n_run_frames += blob.frames
+                    out.append(blob)
                 continue
             msg = item
             if msg.is_expired():
@@ -960,6 +983,10 @@ class Channel:
             n_onloop += 1
             out.append(pub)
         m = self.broker.metrics
+        if n_runs:
+            m.inc("delivery.wire_runs", n_runs)
+            m.inc("delivery.wire_run.frames", n_run_frames)
+            n_fast += n_run_frames
         if n_fast:
             # the fast path is QoS0 by construction (pid is None)
             m.inc("packets.publish.sent", n_fast)
@@ -977,7 +1004,25 @@ class Channel:
             # (ineligible traffic, or pre-serialization off) — the
             # LIVE_PRESER bench A/B reads this per delivery
             m.inc("delivery.serialize.onloop", n_onloop)
-        return out
+
+    def _run_blob(self, run):
+        """The joined bytes of a wire run for this connection's
+        protocol version, or None where the client's Maximum-Packet-
+        Size is under the run's largest frame (the per-frame gate then
+        drops exactly the frames over it). A version the planner's
+        serialize stage did not join — a session resumed on another
+        protocol version — joins here, ON the loop, once for every
+        socket of the run."""
+        blob = run.joined(self.proto_ver)
+        if blob is None:
+            blob, built = run.join(self.proto_ver)
+            if built:
+                self.broker.metrics.inc("delivery.serialize.onloop",
+                                        built)
+        if self.client_max_packet \
+                and blob.max_frame > self.client_max_packet:
+            return None
+        return blob
 
     def _wire_cached(self, msg) -> Optional[bytes]:
         """One serialized QoS0 PUBLISH per (message, proto version),

@@ -27,7 +27,8 @@ from emqx_tpu.limiter import TokenBucket
 from emqx_tpu.metrics import I_FLUSH_NS, I_READ_NS
 from emqx_tpu.mqtt import reason_codes as RC
 from emqx_tpu.mqtt.frame import (FrameError, FrameTooLarge, NativeParser,
-                                 make_parser, resolve_frame_mode, serialize)
+                                 WireBlob, make_parser,
+                                 resolve_frame_mode, serialize)
 from emqx_tpu.mqtt.packet import Publish
 from emqx_tpu.zone import Zone, get_zone
 
@@ -132,13 +133,20 @@ class Connection:
 
     def _writev(self, frames) -> None:
         """Flush a run of pre-serialized MQTT frames in ONE transport
-        ``writelines`` (the writev-coalesced egress path). ``frames``
-        are RAW MQTT bytes: plain TCP writes them as-is (``_wrap_out``
-        is the identity here); the WS transport overrides this to
-        emit a flat (header, payload, header, payload, …) run instead
-        of wrapping — and copying — each frame. A subclass overriding
-        ``_wrap_out`` must override this too."""
-        self.writer.writelines(frames)
+        ``writelines`` (the writev-coalesced egress path) — or one
+        plain ``write`` where the run is a single piece, a planned
+        batch's pre-joined :class:`WireBlob` above all: ``writelines``
+        pays a ``memoryview``, a deque and a ``sendmsg`` iovec per
+        piece. ``frames`` are RAW MQTT bytes: plain TCP writes them
+        as-is (``_wrap_out`` is the identity here); the WS transport
+        overrides this to emit a flat (header, payload, header,
+        payload, …) run instead of wrapping — and copying — each
+        frame. A subclass overriding ``_wrap_out`` must override this
+        too."""
+        if len(frames) == 1:
+            self.writer.write(frames[0])
+        else:
+            self.writer.writelines(frames)
 
     def _send_packets(self, pkts) -> None:
         from emqx_tpu.mqtt.packet import Publish
@@ -156,12 +164,16 @@ class Connection:
         wire_run: list = []
         try:
             for pkt in pkts:
-                if type(pkt) is bytes:
-                    # egress fast path: the channel already produced
-                    # (and size-gated) the wire bytes
+                # egress fast path: the channel already produced (and
+                # size-gated) the wire bytes — one frame, or a planned
+                # batch's pre-joined wire run (WireBlob): one piece of
+                # the write, counted as the frames it holds
+                held = 1 if type(pkt) is bytes else \
+                    pkt.frames if type(pkt) is WireBlob else 0
+                if held:
                     self.send_bytes += len(pkt)
-                    self.send_pkts += 1
-                    n_pkts += 1
+                    self.send_pkts += held
+                    n_pkts += held
                     n_bytes += len(pkt)
                     if not self._closing:
                         wire_run.append(pkt)

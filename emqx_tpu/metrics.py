@@ -69,6 +69,13 @@ DELIVERY_METRICS = [
     # planner this is ≤1 per connection per batch — the bench's
     # wakeups/batch column divides it by ingress flushes
     "delivery.wakeups",
+    # wire runs (docs/DISPATCH.md "Wire runs"): a planned batch's
+    # QoS0 broadcast to one session written as ONE pre-joined piece
+    # — the runs written, and the PUBLISH frames that left inside
+    # them (Channel._emit stamps both, beside messages.sent: frames
+    # over messages.sent is the share of egress the runs carry)
+    "delivery.wire_runs",
+    "delivery.wire_run.frames",
     # PUBLISH frames serialized ON the event loop (the per-delivery
     # slow path, plus template/image cache misses that build there).
     # With egress pre-serialization on (docs/DISPATCH.md) eligible

@@ -13,7 +13,7 @@ mirrors :401-749.
 from __future__ import annotations
 
 import struct
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from emqx_tpu.mqtt import constants as C
 from emqx_tpu.mqtt import props as P
@@ -678,6 +678,22 @@ def publish_template(pkt: Publish,
         i += 1
     off = i + 1 + 2 + len(pkt.topic.encode("utf-8"))
     return data, off
+
+
+class WireBlob(bytes):
+    """Several serialized frames joined for ONE transport write (a
+    wire run's image, ops/dispatch_plan.WireRun): still plain bytes to
+    every transport, carrying what the per-frame accounting needs —
+    ``frames`` (how many control packets it holds) and ``max_frame``
+    (the largest one, for the client's Maximum-Packet-Size gate).
+    Built once per (run, protocol version) and shared by every socket
+    of the run, never per delivery."""
+
+    def __new__(cls, frames: Sequence[bytes]) -> "WireBlob":
+        self = super().__new__(cls, b"".join(frames))
+        self.frames = len(frames)
+        self.max_frame = max(map(len, frames))
+        return self
 
 
 def serialize(pkt: Packet, version: int = C.MQTT_V4) -> bytes:

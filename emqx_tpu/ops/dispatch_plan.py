@@ -47,7 +47,7 @@ import numpy as np
 
 from emqx_tpu.broker_helper import unpack_sids
 from emqx_tpu.mqtt.constants import MQTT_V5
-from emqx_tpu.mqtt.frame import publish_template
+from emqx_tpu.mqtt.frame import WireBlob, publish_template
 from emqx_tpu.mqtt.frame import serialize as wire_serialize
 from emqx_tpu.mqtt.packet import Publish, from_message
 
@@ -68,12 +68,19 @@ class DispatchPlan:
     Groups: ``g_ptr[g]:g_ptr[g+1]`` slices group ``g``; ``g_sids[g]``
     is its subscriber id. ``n_groups`` is the chunking unit the
     ingress yields between (one group = one session's whole batch).
+
+    ``g_runs`` (set by :func:`preserialize_plan`, else ``None``):
+    per group its wire runs as ``(a, b, run)`` segments — deliveries
+    ``a:b`` of the group's slice are :class:`WireRun` ``run``'s frames,
+    one pre-joined write — or ``None`` where the group forms none.
     """
 
-    __slots__ = ("fids", "rows", "g_ptr", "g_sids", "n_deliveries")
+    __slots__ = ("fids", "rows", "g_ptr", "g_sids", "n_deliveries",
+                 "g_runs")
 
     def __init__(self, sids: np.ndarray, fids: np.ndarray,
                  rows: np.ndarray) -> None:
+        self.g_runs: Optional[List[Optional[tuple]]] = None
         self.n_deliveries = int(sids.shape[0])
         if self.n_deliveries:
             order = np.argsort(sids, kind="stable")
@@ -97,6 +104,121 @@ class DispatchPlan:
 #: ftab memo sentinel — a filter whose subscriber table resolved to
 #: None must not be re-resolved per delivery
 _NO_FTAB = object()
+
+#: a group forms a wire run from this many frames up (a single frame
+#: is one write already)
+MIN_RUN_FRAMES = 2
+
+
+def _broadcast_image(msg, wire: dict, key: tuple) -> bytes:
+    """Build and cache one shared QoS0 wire image of ``msg`` under
+    ``key`` = ``(proto_ver, 0, retain, dup)`` in its ``_wire`` dict —
+    the bytes ``Channel._wire_cached`` would build lazily on the
+    loop."""
+    ver = key[0]
+    pub = from_message(None, msg)
+    pub.qos = 0
+    pub.retain = key[2]
+    if ver != MQTT_V5:
+        pub.properties = {}
+    data = wire[key] = wire_serialize(pub, ver)
+    return data
+
+
+def run_eligible(msg) -> bool:
+    """The message half of the wire-run predicate: a QoS0,
+    non-retained message whose shared image every plain subscriber
+    can take as it is — no Message-Expiry countdown, no
+    Subscription-Identifier, and not traced (the egress-flush span
+    is stamped per frame)."""
+    if msg.qos != 0 or msg.flags.get("retain"):
+        return False
+    headers = msg.headers
+    if "_trace" in headers:
+        return False
+    props = headers.get("properties")
+    return not (props and ("Message-Expiry-Interval" in props
+                           or "Subscription-Identifier" in props))
+
+
+class WireRun:
+    """A stretch of one subscriber group's batch on the QoS0
+    broadcast fast path — the whole batch, where nothing interrupts
+    it — as ONE outbox entry and ONE transport write (docs/
+    DISPATCH.md "Wire runs"): the live messages in delivery order,
+    and per protocol version their shared wire images joined into one
+    :class:`~emqx_tpu.mqtt.frame.WireBlob`. Groups whose slices of the
+    plan's ``rows`` hold an equal stretch receive the same frames in
+    the same order, so they share one run object and one join per
+    version — 1,000 of them in a broadcast.
+
+    Immutable once :func:`preserialize_plan` returns, except the
+    blob cache: a version nobody hinted joins lazily where it is
+    first flushed (possibly on two loops at once — both build the
+    same bytes, the last store wins)."""
+
+    __slots__ = ("msgs", "n", "_blobs")
+
+    def __init__(self, msgs: tuple) -> None:
+        self.msgs = msgs
+        self.n = len(msgs)
+        self._blobs: Dict[int, WireBlob] = {}
+
+    def joined(self, ver: int) -> Optional[WireBlob]:
+        return self._blobs.get(ver)
+
+    def join(self, ver: int) -> Tuple[WireBlob, int]:
+        """Join (and cache) the run's image for ``ver``. Returns
+        ``(blob, built)`` — ``built`` counts the frames that had no
+        shared image yet and were serialized here."""
+        frames = []
+        built = 0
+        for msg in self.msgs:
+            headers = msg.headers
+            wire = headers.get("_wire")
+            if wire is None:
+                wire = headers["_wire"] = {}
+            key = (ver, 0, False, msg.flags.get("dup", False))
+            data = wire.get(key)
+            if data is None:
+                data = _broadcast_image(msg, wire, key)
+                built += 1
+            frames.append(data)
+        blob = self._blobs[ver] = WireBlob(frames)
+        return blob, built
+
+
+def _run_segments(rkey: tuple, live, ok: bytearray,
+                  runs: Dict[tuple, WireRun]) -> tuple:
+    """Cut one group's slice of the plan's rows into its wire runs:
+    the maximal stretches of :func:`run_eligible` messages,
+    ``MIN_RUN_FRAMES`` long or more, as ``(a, b, run)`` offsets into
+    the slice. ``ok`` memoizes the predicate per live row (0 unknown,
+    1 eligible, 2 not); ``runs`` shares a run between slices that hold
+    the same stretch."""
+    segs = []
+    a = -1
+    n = len(rkey)
+    for i in range(n + 1):
+        e = 2
+        if i < n:
+            r = rkey[i]
+            e = ok[r]
+            if not e:
+                e = ok[r] = 1 if run_eligible(live[r][1]) else 2
+        if e == 1:
+            if a < 0:
+                a = i
+        elif a >= 0:
+            if i - a >= MIN_RUN_FRAMES:
+                key = rkey[a:i]
+                run = runs.get(key)
+                if run is None:
+                    run = runs[key] = WireRun(
+                        tuple([live[r][1] for r in key]))
+                segs.append((a, i, run))
+            a = -1
+    return tuple(segs)
 
 
 def preserialize_plan(plan: "DispatchPlan",
@@ -124,6 +246,15 @@ def preserialize_plan(plan: "DispatchPlan",
     countdown — are detected here and skipped; those deliveries take
     the existing per-delivery serialize path unchanged.
 
+    The same walk builds the plan's wire runs (``plan.g_runs``): a
+    hinted session's group is cut into its maximal stretches of
+    :func:`run_eligible` messages (:func:`_run_segments`), each a
+    :class:`WireRun` shared with every group that holds the same
+    stretch and joined once per hinted protocol version. Whether a
+    group may USE a run is the delivery walk's call (every delivery
+    of the group accepted, every one of the stretch ``fast``:
+    ``Broker._deliver_plan_group``).
+
     Runs wherever :meth:`~emqx_tpu.broker.Broker.publish_fetch` runs
     (possibly an ingress executor thread): every broker read is a
     plain dict get (GIL-atomic, same discipline as the plan build's
@@ -146,7 +277,15 @@ def preserialize_plan(plan: "DispatchPlan",
     classes: Dict[tuple, None] = {}
     g_ptr = plan.g_ptr
     fids = plan.fids
+    rows = plan.rows
     ftab_of: Dict[int, object] = {}
+    # the run table: a group's slice of ``rows`` -> [its segments,
+    # the protocol versions the slice's sessions hint]; equal slices
+    # (1,000 of them in a broadcast) share one entry
+    slices: Dict[tuple, list] = {}
+    runs: Dict[tuple, WireRun] = {}
+    row_ok = bytearray(len(live))
+    g_runs: List[Optional[tuple]] = [None] * plan.n_groups
     for g in range(plan.n_groups):
         sub = lookup(plan.g_sids[g])
         if sub is None:
@@ -154,6 +293,15 @@ def preserialize_plan(plan: "DispatchPlan",
         ver = getattr(sub, "proto_ver", None)
         if ver is None or not getattr(sub, "wire_fast_hint", False):
             continue
+        if g_ptr[g + 1] - g_ptr[g] >= MIN_RUN_FRAMES:
+            rkey = tuple(rows[g_ptr[g]:g_ptr[g + 1]])
+            ent = slices.get(rkey)
+            if ent is None:
+                ent = slices[rkey] = [
+                    _run_segments(rkey, live, row_ok, runs), {}]
+            if ent[0]:
+                g_runs[g] = ent[0]
+                ent[1][ver] = None
         upgrade = getattr(sub, "upgrade_qos", False)
         last_fid = -1          # within a group the same fid repeats
         seen: Optional[set] = None   # row-major — catch runs cheaply
@@ -214,12 +362,7 @@ def preserialize_plan(plan: "DispatchPlan",
                     if wire is None:
                         wire = headers["_wire"] = {}
                 if key not in wire:
-                    pub = from_message(None, msg)
-                    pub.qos = 0
-                    pub.retain = key[2]
-                    if ver != MQTT_V5:
-                        pub.properties = {}
-                    wire[key] = wire_serialize(pub, ver)
+                    _broadcast_image(msg, wire, key)
                     built += 1
                 continue
             key = (ver, qos,
@@ -237,6 +380,15 @@ def preserialize_plan(plan: "DispatchPlan",
                     payload=msg.payload)
                 tpl[key] = publish_template(pub, ver)
                 built += 1
+    # the runs' joins, once per (run, hinted version), over the
+    # images pass 2 just built
+    for segs, vers in slices.values():
+        for _a, _b, run in segs:
+            for ver in vers:
+                if run.joined(ver) is None:
+                    built += run.join(ver)[1]
+    if runs:
+        plan.g_runs = g_runs
     return built
 
 

@@ -566,7 +566,7 @@ class OverloadMonitor:
             if sess is None:
                 continue
             try:
-                qlen = len(sess.mqueue) + len(sess.outbox)
+                qlen = len(sess.mqueue) + sess.outbox_frames()
             except Exception:
                 continue
             if qlen > pol:

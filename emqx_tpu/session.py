@@ -24,7 +24,8 @@ enriches + windows the message and appends ready-to-send publishes to
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import (Any, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 from emqx_tpu import topic as T
 from emqx_tpu.concurrency import owner_loop
@@ -42,6 +43,26 @@ RC_RECEIVE_MAXIMUM_EXCEEDED = 0x93
 RC_QUOTA_EXCEEDED = 0x97
 
 PUBREL_MARKER = "pubrel"
+#: outbox entry ``(WIRE_RUN, run)``: a whole planned batch on the
+#: QoS0 broadcast fast path as ONE entry, standing for the ordered
+#: entries ``(None, msg)`` of ``run.msgs``
+#: (ops/dispatch_plan.WireRun, docs/DISPATCH.md "Wire runs")
+WIRE_RUN = "wire_run"
+
+
+def expand_outbox(entries: Iterable[Tuple[Any, Any]]
+                  ) -> List[Tuple[Any, Any]]:
+    """``entries`` with every wire run replaced by the per-message
+    entries it stands for — what the outbox held before runs existed,
+    and what leaves the process (a run is shared, loop-local state,
+    never data)."""
+    out: List[Tuple[Any, Any]] = []
+    for entry in entries:
+        if entry[0] is WIRE_RUN:
+            out.extend([(None, m) for m in entry[1].msgs])
+        else:
+            out.append(entry)
+    return out
 
 
 class SessionError(Exception):
@@ -91,7 +112,8 @@ class Session:
         self.max_awaiting_rel = max_awaiting_rel
         self.await_rel_timeout = await_rel_timeout
         self.expiry_interval = expiry_interval
-        # (packet_id | None, Message) or (PUBREL_MARKER, packet_id)
+        # (packet_id | None, Message), (PUBREL_MARKER, packet_id) or
+        # (WIRE_RUN, run)
         self.outbox: List[Tuple[Any, Any]] = []
         # wakeup hook: the owning connection sets this so broker-driven
         # deliveries flush to the socket (the BEAM's message-send wakeup
@@ -165,7 +187,7 @@ class Session:
             "max_awaiting_rel": self.max_awaiting_rel,
             "await_rel_timeout": self.await_rel_timeout,
             "expiry_interval": self.expiry_interval,
-            "outbox": list(self.outbox),
+            "outbox": expand_outbox(self.outbox),
             "mq_max_len": self.mqueue.max_len,
             "mq_store_qos0": self.mqueue.store_qos0,
             "mq_priorities": self.mqueue.p_table,
@@ -357,8 +379,18 @@ class Session:
         if self.outbox and self.notify is not None:
             self.notify()
 
+    def outbox_frames(self) -> int:
+        """Frames waiting in the outbox: a wire run counts as the
+        frames it stands for."""
+        n = len(self.outbox)
+        for pid, item in self.outbox:
+            if pid is WIRE_RUN:
+                n += item.n - 1
+        return n
+
     @owner_loop
-    def deliver_many(self, items: Iterable[tuple]) -> None:
+    def deliver_many(self, items: Sequence[tuple],
+                     runs: Sequence[tuple] = ()) -> None:
         """Batched broker→client delivery — the dispatch planner's
         grouped enqueue (docs/DISPATCH.md). Each item is
         ``(topic_filter, msg, opts, fast)``: the broker already
@@ -368,7 +400,25 @@ class Session:
         QoS0/plain-subopts broadcast fast path per (row, filter)
         group. Everything enqueues, then ONE notify fires for the
         whole group — the batch-wide wakeup coalescing that turns
-        N-deliveries-per-batch into one flush per connection."""
+        N-deliveries-per-batch into one flush per connection.
+
+        ``runs``: the group's wire runs as ``(a, b, run)`` — items
+        ``a:b`` are all ``fast`` and ``run.msgs`` are exactly their
+        messages, in order. A connected session enqueues each as ONE
+        entry and the items between them as ever; a disconnected one
+        takes the items."""
+        if runs and self.connected:
+            pos = 0
+            for a, b, run in runs:
+                if pos < a:
+                    self.deliver_many(items[pos:a])
+                self.outbox.append((WIRE_RUN, run))
+                pos = b
+            if pos < len(items):
+                self.deliver_many(items[pos:])
+            elif self.notify is not None:
+                self.notify()
+            return
         now = None  # one inflight timestamp per delivery group
         dirty = False
         for flt, msg, opts, fast in items:

@@ -201,7 +201,10 @@ def _metric_deltas(broker):
     return {k: v for k, v in broker.metrics.all().items()
             if v and (k.startswith("messages.")
                       or k.startswith("delivery."))
-            and k != "delivery.serialize.onloop"}
+            and k != "delivery.serialize.onloop"
+            # wire runs are built by the serialize stage: none with
+            # preserialize off, by design (tests/test_wire_run.py)
+            and not k.startswith("delivery.wire_run")}
 
 
 def test_session_state_parity_preser_on_off():
@@ -309,7 +312,8 @@ async def _egress_run(preserialize: bool):
         got.append({k: v for k, v in node.metrics.all().items()
                     if v and (k.startswith(("messages.", "delivery.",
                                             "packets.publish")))
-                    and k != "delivery.serialize.onloop"})
+                    and k != "delivery.serialize.onloop"
+                    and not k.startswith("delivery.wire_run")})
         onloop = node.metrics.val("delivery.serialize.onloop")
         for cli in clients:
             await cli.close()
