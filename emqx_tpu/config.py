@@ -179,21 +179,48 @@ def _build_zone(name: str, raw: Dict[str, Any]) -> Zone:
     return Zone(name=name, **kwargs)
 
 
+def _build_mesh(raw: Any) -> Optional[Dict[str, int]]:
+    """``[matcher] mesh = { data = <int>, trie = <int> }`` → the
+    validated axis sizes (both powers of two >= 1), or ``None`` for
+    ``{1, 1}``: one chip is today's node, field by field. The
+    ``jax.sharding.Mesh`` itself is built with the node
+    (:func:`build_node`): parsing a file touches no device."""
+    if not isinstance(raw, dict):
+        raise ConfigError(
+            "matcher.mesh must be a table { data = <int>, trie = <int> }")
+    axes = {"data": 1, "trie": 1}
+    for key, val in raw.items():
+        if key not in axes:
+            raise ConfigError(f"unknown matcher setting: matcher.mesh.{key}")
+        if isinstance(val, bool) or not isinstance(val, int) \
+                or val < 1 or (val & (val - 1)):
+            raise ConfigError(
+                f"matcher.mesh.{key} must be a power of two >= 1, "
+                f"got {val!r}")
+        axes[key] = val
+    from emqx_tpu.parallel.mesh import mesh_axes
+
+    return mesh_axes(axes)
+
+
 def _build_matcher(raw: Dict[str, Any]):
     """``[matcher]`` table → :class:`~emqx_tpu.router.MatcherConfig`.
     Unknown keys are startup errors (same closed-schema rule as
     zones: a typo'd ``match_cache = false`` must not silently leave
-    the cache on); ``mesh`` is runtime-only and not configurable
-    from a file."""
+    the cache on). ``mesh`` stays the file's axis sizes here;
+    :func:`build_node` places it on the devices."""
     import dataclasses as _dc
 
     from emqx_tpu.router import MatcherConfig
 
-    known = {f.name for f in _dc.fields(MatcherConfig)} - {"mesh"}
+    known = {f.name for f in _dc.fields(MatcherConfig)}
     kwargs: Dict[str, Any] = {}
     for key, val in raw.items():
         if key not in known:
             raise ConfigError(f"unknown matcher setting: matcher.{key}")
+        if key == "mesh":
+            kwargs[key] = _build_mesh(val)
+            continue
         want = MatcherConfig.__dataclass_fields__[key].type
         if want == "bool" and not isinstance(val, bool):
             raise ConfigError(f"matcher.{key} must be a boolean")
@@ -210,6 +237,25 @@ def _build_matcher(raw: Dict[str, Any]):
             f"matcher.cache_partitions must be a power of two >= 1, "
             f"got {p}")
     return MatcherConfig(**kwargs)
+
+
+def _place_mesh(matcher):
+    """The matcher a node is built with: a configured ``mesh`` (axis
+    sizes from the file) becomes the ``jax.sharding.Mesh`` over the
+    first ``data x trie`` of ``jax.devices()``. Too few devices ends
+    start-up: never a silent single-chip node."""
+    if matcher is None or not isinstance(matcher.mesh, dict):
+        return matcher
+    from emqx_tpu.parallel.mesh import make_mesh
+
+    axes = matcher.mesh
+    try:
+        mesh = make_mesh(axes["data"], axes["trie"])
+    except ValueError as e:  # "need N devices, have M"
+        raise ConfigError(
+            f"matcher.mesh = {{ data = {axes['data']}, trie = "
+            f"{axes['trie']} }}: {e}") from None
+    return dataclasses.replace(matcher, mesh=mesh)
 
 
 def _build_telemetry(raw: Dict[str, Any]):
@@ -562,8 +608,19 @@ def load_config(path: str) -> NodeConfig:
     return cfg
 
 
+#: every top-level table parse_config reads. A section it does not
+#: know is a start-up error like a key it does not know: a misspelt
+#: ``[matchr]`` would otherwise build the default node
+_SECTIONS = ("node", "matcher", "telemetry", "tracing", "dispatch",
+             "overload", "faults", "durability", "cluster", "drain",
+             "zones", "listeners", "modules")
+
+
 def parse_config(raw: Dict[str, Any]) -> NodeConfig:
     cfg = NodeConfig()
+    for key in raw:
+        if key not in _SECTIONS:
+            raise ConfigError(f"unknown config section: {key}")
     node = raw.get("node", {})
     for key in node:
         if key not in ("name", "sys_interval", "cookie", "cluster_port",
@@ -688,7 +745,7 @@ def build_node(cfg: NodeConfig):
                                            cfg.durability.dir)
     default = cfg.zones.get("default")
     node = Node(name=cfg.name, zone=default,
-                matcher=cfg.matcher,
+                matcher=_place_mesh(cfg.matcher),
                 telemetry=cfg.telemetry,
                 tracing=cfg.tracing,
                 dispatch_config=cfg.dispatch,

@@ -338,6 +338,19 @@ LOOP_METRICS = [
     "loop.wall.ns",
 ]
 
+# what a [matcher] mesh adds to the match dispatch (router.py's mesh
+# branch; gated on [telemetry] enabled like the loop's counters):
+# ``batches`` = publish batches dispatched on the mesh path and
+# ``topics`` the unique topics in them; ``steps`` = collective
+# publish_step programs enqueued (a batch whose topics all hit the
+# sharded match cache takes none) and ``step.topics`` the unique
+# topics that walked in them, before padding. steps ÷ batches and
+# step.topics ÷ topics say how much of the traffic the collectives
+# carry and how much the cache's gather
+MESH_METRICS = [
+    "mesh.batches", "mesh.topics", "mesh.steps", "mesh.step.topics",
+]
+
 ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + WILL_METRICS
                + DELIVERY_METRICS + CLIENT_METRICS + SESSION_METRICS
@@ -345,7 +358,8 @@ ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + AUTOMATON_METRICS + TRANSPORT_METRICS
                + OVERLOAD_METRICS + BREAKER_METRICS + FAULT_METRICS
                + OPS_METRICS + DURABILITY_METRICS + CLUSTER_METRICS
-               + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS)
+               + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS
+               + MESH_METRICS)
 
 #: registry names that are NOT monotonic — ``Metrics.dec`` runs on
 #: them in steady state (today: the retainer's live-entry count,

@@ -21,7 +21,7 @@ from emqx_tpu.cm import ConnectionManager
 from emqx_tpu.connection import Listener
 from emqx_tpu.ctl import Ctl
 from emqx_tpu.flapping import Flapping
-from emqx_tpu.gc import GlobalGc
+from emqx_tpu.gc import GlobalGc, freeze_resident
 from emqx_tpu.hooks import Hooks
 from emqx_tpu.ingress import IngressBatcher
 from emqx_tpu.monitors import OsMon, SysMon, VmMon
@@ -365,6 +365,9 @@ class Node:
             # resurrected (docs/DURABILITY.md). Runs with modules
             # loaded so the retainer can take its store back
             self.durability.recover()
+        # what was restored at boot lives as long as the node does:
+        # out of the collector's reach before the node serves
+        freeze_resident(self.router.stats()["topics.count"])
         if self.boot_listeners and not self.listeners:
             self.add_listener()
         if self.loop_group is not None:

@@ -41,3 +41,16 @@ def default_mesh(n_devices: Optional[int] = None) -> Mesh:
     if n <= 2:
         return make_mesh(n, 1)
     return make_mesh(n // 2, 2)
+
+
+def mesh_axes(mesh) -> Optional[dict]:
+    """``{"data": d, "trie": t}`` of a mesh in either form (the
+    ``[matcher] mesh`` table of a file, or the placed
+    :class:`~jax.sharding.Mesh` a node runs on); ``None`` for no
+    mesh and for 1 x 1, which is one chip."""
+    if mesh is None:
+        return None
+    shape = mesh if isinstance(mesh, dict) else mesh.shape
+    axes = {"data": int(shape.get("data", 1)),
+            "trie": int(shape.get("trie", 1))}
+    return None if axes == {"data": 1, "trie": 1} else axes

@@ -34,7 +34,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from emqx_tpu.oracle import TrieOracle
-from emqx_tpu.ops.csr import Automaton, build_automaton
+from emqx_tpu.ops.csr import Automaton, build_automaton, capacity_for
 from emqx_tpu.ops.match import match_batch
 from emqx_tpu.ops.fanout import (FanoutTable, build_fanout,
                                  gather_subscribers_src)
@@ -239,9 +239,11 @@ def build_sharded_fanout(
     filter_capacity: int | None = None,
     entry_capacity: int | None = None,
 ) -> ShardedFanout:
-    fans = [build_fanout(rows, num_filters) for rows in rows_per_shard]
-    f_cap = max(f.row_ptr.shape[0] - 1 for f in fans)
-    e_cap = max(f.sub_ids.shape[0] for f in fans)
+    # the shards' common capacities, as build_fanout would choose them
+    # (10M filters: the tables are built once, not once to be measured)
+    f_cap = capacity_for(num_filters)
+    e_cap = max(capacity_for(sum(len(v) for v in rows.values()) + 1)
+                for rows in rows_per_shard)
     if filter_capacity is not None:
         f_cap = max(f_cap, filter_capacity)
     if entry_capacity is not None:

@@ -81,8 +81,7 @@ def classification() -> Dict[str, Dict[str, str]]:
                      else "boot_only") for k in NODE_KEYS}}
     for name, cls in _sections().items():
         reloadable = getattr(cls, "RELOADABLE", frozenset())
-        fields = [f.name for f in dataclasses.fields(cls)
-                  if f.name != "mesh"]  # runtime-only, never in TOML
+        fields = [f.name for f in dataclasses.fields(cls)]
         unknown = reloadable - set(fields)
         if unknown:  # a typo'd RELOADABLE entry must never pass silently
             raise ValueError(f"[{name}] RELOADABLE names unknown "
@@ -179,6 +178,8 @@ def diff_config(node, cfg) -> List[Change]:
     from the file produce no changes."""
     import os as _os
 
+    from emqx_tpu.parallel.mesh import mesh_axes
+
     table = classification()
     running = _running_sections(node)
     changes: List[Change] = []
@@ -241,6 +242,11 @@ def diff_config(node, cfg) -> List[Change]:
             else:
                 old = getattr(run_cfg, key)
                 reason = ""
+            if (section, key) == ("matcher", "mesh"):
+                # the file holds axis sizes, the node the placed
+                # Mesh; the tables are laid out over it at boot
+                old, new = mesh_axes(old), mesh_axes(new)
+                reason = "the device mesh is built with the node"
             if old != new:
                 changes.append(Change(section, key, old, new, kind,
                                       reason=reason))

@@ -62,7 +62,10 @@ class MatcherConfig:
     # multi-chip: a (data × trie) jax Mesh shards the filter set over
     # the 'trie' axis and the publish batch over 'data'; matching goes
     # through parallel.sharded.publish_step (ICI all-gather of match
-    # ids). BASELINE config 5's product path.
+    # ids). BASELINE config 5's product path. From a file it is
+    # ``[matcher] mesh = { data = 2, trie = 2 }``: config.parse_config
+    # keeps the axis sizes, config.build_node places the Mesh over the
+    # host's first data x trie devices. Restart-only.
     mesh: Optional[object] = None
     # device fan-out (broker_helper): filters with more subscribers
     # than the threshold move from the CSR gather to bitmap rows
@@ -1914,12 +1917,24 @@ class Router:
         cached (ids, subs, src) rows gather from HBM and only the
         misses walk. A pre-``placed`` batch bypasses the cache (its
         host half was already paid, and splitting it would re-encode)."""
+        if topics is not None:
+            self._count_mesh("mesh.batches", "mesh.topics", len(topics))
         if placed is None and topics is not None:
             out = self._sharded_dispatch_cached(topics, fan_provider)
             if out is not None:
                 return out
         return self._dispatch_sharded(topics, fan=fan_provider,
                                       with_big=True, placed=placed)
+
+    def _count_mesh(self, events: str, topics: str, n: int) -> None:
+        """One event of the mesh dispatch and the unique topics it
+        carries (metrics.MESH_METRICS), stamped where the decision is
+        taken; live only while [telemetry] is enabled, like the
+        loop's counters."""
+        tel = self.telemetry
+        if tel is not None and tel.loop_clock() is not None:
+            tel.metrics.inc(events)
+            tel.metrics.inc(topics, n)
 
     def _sharded_cache_for(self, n_trie: int, d: int):
         """The mesh publish cache, sized for the CURRENT (T, m, d)
@@ -2075,6 +2090,11 @@ class Router:
         else:
             ids, n, sysm, _ = self.encode_place_sharded(topics)
         use_fan = fan_tables is not None
+        if topics is not None:
+            # a collective program is enqueued for these topics (the
+            # cache-split path sends only its misses here)
+            self._count_mesh("mesh.steps", "mesh.step.topics",
+                             len(topics))
         all_ids, subs, src, bm, ovf, movf, stats = publish_step(
             mesh, auto, fan_tables if use_fan else self._dummy_fan,
             ids, n, sysm, bmt, k=self.effective_k(), m=cfg.max_matches,
@@ -2097,14 +2117,21 @@ class Router:
         return all_ids, ids_np, ovf_np, id_map, epoch
 
     def drain_device_stats(self) -> Dict[str, int]:
-        """Sum and clear the accumulated device-side counters (one
-        host transfer per pending step — called from the periodic
-        stats flush, not the publish path)."""
+        """Sum and clear the accumulated device-side counters (all
+        pending steps come to the host in one ``device_get`` — called
+        from the periodic stats flush, on the event loop: a served
+        mesh queues thousands of steps between two flushes, and a
+        transfer per scalar would stall it)."""
         out = {"matches": 0, "deliveries": 0, "overflows": 0}
+        pending = []
         while self._dev_stats:
-            st = self._dev_stats.popleft()
-            for k in out:
-                out[k] += int(st[k])
+            pending.append(self._dev_stats.popleft())
+        if pending:
+            import jax
+
+            for st in jax.device_get(pending):
+                for k in out:
+                    out[k] += int(st[k])
         return out
 
     def match_filters(self, topics: Sequence[str]) -> List[List[str]]:

@@ -104,7 +104,7 @@ def validate(topic: str, kind: str = "filter") -> bool:
             if i != len(ws) - 1:
                 raise TopicError("topic_invalid_#")
         elif w not in (PLUS, EMPTY):
-            if any(c in ("#", "+", "\x00") for c in w):
+            if "#" in w or "+" in w or "\x00" in w:
                 raise TopicError("topic_invalid_char")
     return True
 

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import ast
 import re
-from typing import Dict, List, Set, Tuple
+from typing import Dict, List, Tuple
 
 from analysis import Finding
 
@@ -44,12 +44,6 @@ SECTIONS: Dict[str, Tuple[str, str]] = {
     "durability": ("emqx_tpu/durability.py", "DurabilityConfig"),
     "cluster": ("emqx_tpu/cluster.py", "ClusterConfig"),
     "drain": ("emqx_tpu/drain.py", "DrainConfig"),
-}
-
-#: schema fields that are runtime-only by design (config.py refuses
-#: them from a file) — exempt from the example-toml requirement
-RUNTIME_ONLY: Dict[str, Set[str]] = {
-    "matcher": {"mesh"},
 }
 
 _SECTION_RE = re.compile(r"^#?\s*\[\[?([a-z_.]+)\]\]?\s*$")
@@ -133,10 +127,7 @@ def finalize(ctx) -> List[Finding]:
             # whole section absent from the example — report once
             # per field so the fix (document the section) is sized
             toml = {}
-        exempt = RUNTIME_ONLY.get(section, set())
         for field, (rel, line) in sorted(fields.items()):
-            if field in exempt:
-                continue
             if field not in toml:
                 out.append(Finding(
                     rel, line, "RD221",

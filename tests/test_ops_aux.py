@@ -4,6 +4,8 @@ emqx_logger parity)."""
 
 import logging
 
+import pytest
+
 from emqx_tpu import logger as elog
 from emqx_tpu.alarm import AlarmManager
 from emqx_tpu.gc import GcPolicy, GlobalGc
@@ -122,6 +124,59 @@ def test_global_gc_runs():
     g = GlobalGc(interval=None)
     freed = g.run_gc()
     assert g.runs == 1 and freed >= 0
+
+
+@pytest.mark.parametrize("routes, frozen", [
+    (0, False), (99_999, False), (100_000, True), (4_000_000, True)])
+def test_freeze_resident_only_where_the_tables_are_large(routes, frozen):
+    import gc
+
+    from emqx_tpu.gc import freeze_resident
+
+    before, thresholds = gc.get_freeze_count(), gc.get_threshold()
+    try:
+        assert freeze_resident(routes) is frozen
+        assert (gc.get_freeze_count() > before) is frozen
+        # a frozen heap switches the collector's quarter rule off:
+        # full collections are held to every hundredth gen-1 one
+        assert gc.get_threshold() == (
+            thresholds[:2] + (100,) if frozen else thresholds)
+    finally:
+        gc.unfreeze()  # a test process keeps its collector
+        gc.set_threshold(*thresholds)
+
+
+def test_node_start_freezes_what_it_restored_at_boot(monkeypatch):
+    """A node that starts to serve with its subscription tables in
+    place moves them out of the collector's reach; a small node (every
+    other test's) does not."""
+    import asyncio
+    import gc
+
+    from emqx_tpu import gc as egc
+    from emqx_tpu.node import Node
+
+    class Sink:
+        def deliver(self, topic_filter, msg):
+            pass
+
+    async def started(n_filters):
+        node = Node(boot_listeners=False)
+        sink = Sink()
+        for i in range(n_filters):
+            node.broker.subscribe(sink, f"t/{i}/+")
+        before, thresholds = gc.get_freeze_count(), gc.get_threshold()
+        await node.start()
+        try:
+            return gc.get_freeze_count() - before
+        finally:
+            await node.stop()
+            gc.unfreeze()
+            gc.set_threshold(*thresholds)
+
+    monkeypatch.setattr(egc, "FREEZE_MIN_ROUTES", 50)
+    assert asyncio.run(started(10)) == 0
+    assert asyncio.run(started(50)) > 0
 
 
 # -- logger -----------------------------------------------------------------

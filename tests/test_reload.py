@@ -205,7 +205,7 @@ def test_classification_covers_every_knob():
     table = classification()
     from emqx_tpu.reload import _sections
     for section, cls in _sections().items():
-        fields = {f.name for f in dataclasses.fields(cls)} - {"mesh"}
+        fields = {f.name for f in dataclasses.fields(cls)}
         assert set(table[section]) == fields, section
         reloadable = getattr(cls, "RELOADABLE", frozenset())
         assert reloadable <= fields, (

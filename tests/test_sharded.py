@@ -630,3 +630,23 @@ def test_finalize_parts_demotes_all_shards_on_wide_guard():
     parts = finalize_parts([raw([deep_ok]), raw([too_deep])])
     assert len({p.wt_slots for p in parts}) == 1
     assert all(p.wt_take == 1 for p in parts)  # demoted to narrow
+
+
+def test_sharded_fanout_is_built_once_at_the_shards_common_capacities():
+    """Every shard's table at the largest shard's capacities, floors
+    honoured: what two passes of build_fanout chose before."""
+    from emqx_tpu.ops.fanout import build_fanout
+    from emqx_tpu.parallel.sharded import build_sharded_fanout
+
+    rows = [{0: [1, 2], 5: [3]}, {1: list(range(40))}, {}]
+    fan = build_sharded_fanout(rows, 20)
+    assert fan.row_ptr.shape == (3, 33) and fan.sub_ids.shape == (3, 64)
+    for i, r in enumerate(rows):
+        one = build_fanout(r, 20, filter_capacity=32, entry_capacity=64)
+        assert (fan.row_ptr[i] == one.row_ptr).all()
+        assert (fan.sub_ids[i] == one.sub_ids).all()
+        assert (fan.row_pairs[i] == one.row_pairs).all()
+    floored = build_sharded_fanout(rows, 20, filter_capacity=128,
+                                   entry_capacity=16)
+    assert floored.row_ptr.shape == (3, 129)
+    assert floored.sub_ids.shape == (3, 64)
