@@ -42,7 +42,7 @@ from emqx_tpu.ops.dispatch_plan import (big_rows_for, build_plan,
 from emqx_tpu.ops.fanout import expand_packed
 from emqx_tpu.ops.pack import (budget_for, bundle_i32, mask_pad_flags,
                                mask_pad_rows, pack_fanout, pack_matches,
-                               pack_union_rows)
+                               pack_mesh, pack_union_rows)
 from emqx_tpu.router import MatcherConfig, Router
 from emqx_tpu.shared_sub import SharedSub
 from emqx_tpu.types import Message, SubOpts
@@ -118,7 +118,7 @@ class PendingBatch:
         "f_ptr_d", "subs_packed_d", "src_packed_d",
         "bovf_d", "sel_d", "rows_packed_d", "bm_total_d",
         "subs_dense_d", "src_dense_d", "union_dense_d", "has_big_d",
-        "sh_big", "movf_d", "movf",
+        "sh_big", "movf_d", "movf", "bundle_d",
         "m_ptr", "ids_packed", "ovf",
         "f_ptr", "subs_packed", "src_packed",
         "bovf", "sel", "rows_packed",
@@ -173,6 +173,9 @@ class PendingBatch:
         self.union_dense_d = self.has_big_d = None
         self.sh_big: frozenset = frozenset()
         self.movf_d = self.movf = None
+        # the fetch's one buffer where the packer already laid it
+        # (mesh path; a re-pack bundles anew)
+        self.bundle_d = None
         self.f_ptr = self.subs_packed = None
         self.src_packed = None
         self.bovf = self.sel = self.rows_packed = None
@@ -663,11 +666,13 @@ class Broker:
         per-shard subscriber gather + ICI all-gather
         (``publish_step(with_fanout=True)`` with the FanoutManager's
         per-shard tables); the dense gathered (subs, src) then pack
-        on device for the coalesced fetch. Filters too big for the
-        ``d`` bound deliver host-side from ``pb.sh_big``. Repeat
-        topics hit the router's sharded match cache (cached
-        ids/subs/src rows gather from HBM; only misses run the
-        collective step — see Router._sharded_dispatch_cached)."""
+        on device for the coalesced fetch, ids and fan-out in one
+        program (``pack_mesh``). Filters too big for the ``d`` bound
+        deliver host-side from ``pb.sh_big``. Repeat topics hit the
+        router's sharded match cache (cached ids/subs/src rows gather
+        from HBM; only misses run the collective step — see
+        Router._dispatch_fused: with this packer a batch costs the
+        loop one transfer and two or three programs)."""
         def fan_provider(epoch, id_map):
             return self.helper.sharded_state(
                 epoch, id_map, cfg.mesh, self.router.effective_d())
@@ -676,38 +681,44 @@ class Broker:
         if sp is not None:
             sp.path = "mesh"
             sp.start("match")
-        (pb.ids_dev, subs_d, src_d, bm, pb.ovf_dev, pb.movf_d,
-         pb.id_map, pb.epoch, pb.sh_big) = \
+        # ids / subs / src come back with their pad rows blanked
+        # (phantom pad-row matches must not reach the packers or the
+        # learned budgets)
+        (pb.ids_dev, pb.subs_dense_d, pb.src_dense_d, bm, pb.ovf_dev,
+         pb.movf_d, pb.id_map, pb.epoch, pb.sh_big) = \
             self.router.publish_dispatch_sharded(uniq, fan_provider)
         if sp is not None:
-            # the collective step dispatch (match + gather + ICI
-            # all-gather enqueued as one program); the sharded
-            # cache-split path leaves its gather share like the
-            # single-chip one
+            # the cache probe, the collective step's enqueue for the
+            # misses (match + gather + ICI all-gather + the cache
+            # insert as one program) and the merge's; the cache-split
+            # path leaves its gather share like the single-chip one
             sp.stop_match(self.router)
             sp.start("pack")
-        n_uniq = np.int32(pb.n_uniq)
-        pb.ids_dev = mask_pad_rows(pb.ids_dev, n_uniq)
         bucket = pb.ids_dev.shape[0]
         budgets = self._pack_budgets.setdefault(
             bucket, [budget_for(bucket, self.router.config.pack_m),
                      budget_for(bucket, self.router.config.pack_q),
                      max(1, self.router.config.pack_rows)])
         pb.pm = budgets[0]
-        pb.m_ptr_d, pb.ids_packed_d = pack_matches(pb.ids_dev, pm=pb.pm)
-        if subs_d is not None:
-            # phantom pad-row deliveries masked like the match ids
-            pb.subs_dense_d = mask_pad_rows(subs_d, n_uniq)
-            pb.src_dense_d = mask_pad_rows(src_d, n_uniq)
+        if pb.subs_dense_d is not None:
             pb.pq = budgets[1]
-            pb.f_ptr_d, pb.subs_packed_d, pb.src_packed_d = \
-                pack_fanout(pb.subs_dense_d, pb.src_dense_d, pq=pb.pq)
+            (pb.m_ptr_d, pb.ids_packed_d, pb.f_ptr_d, pb.subs_packed_d,
+             pb.src_packed_d, bundle) = pack_mesh(
+                pb.ids_dev, pb.subs_dense_d, pb.src_dense_d,
+                pb.ovf_dev, pb.movf_d, pm=pb.pm, pq=pb.pq)
+            if bm is None:
+                # what the fetch would bundle, already laid
+                pb.bundle_d = bundle
+        else:
+            pb.m_ptr_d, pb.ids_packed_d = pack_matches(pb.ids_dev,
+                                                       pm=pb.pm)
         if bm is not None:
             # big-filter bitmap unions (per-shard OR + ICI combine):
             # pack only the rows that actually matched a big filter
             union_d, has_big_d, pb.bovf_d = bm
             pb.union_dense_d = union_d
-            pb.has_big_d = mask_pad_flags(has_big_d, n_uniq)
+            pb.has_big_d = mask_pad_flags(has_big_d,
+                                          np.int32(pb.n_uniq))
             pb.sel_d, pb.rows_packed_d, pb.bm_total_d = pack_union_rows(
                 union_d, pb.has_big_d, pr=budgets[2])
         if sp is not None:
@@ -843,7 +854,9 @@ class Broker:
             if pb.sel_d is not None:
                 fetch += [pb.sel_d, pb.rows_packed_d, pb.bm_total_d,
                           pb.bovf_d]
-            buf = jax.device_get(bundle_i32(*fetch))
+            bundle, pb.bundle_d = pb.bundle_d, None
+            buf = jax.device_get(bundle_i32(*fetch)
+                                 if bundle is None else bundle)
             off = 0
 
             def take(n):

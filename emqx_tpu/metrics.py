@@ -346,9 +346,14 @@ LOOP_METRICS = [
 # sharded match cache takes none) and ``step.topics`` the unique
 # topics that walked in them, before padding. steps ÷ batches and
 # step.topics ÷ topics say how much of the traffic the collectives
-# carry and how much the cache's gather
+# carry and how much the cache's gather; ``fused`` = batches that
+# left as one transfer and two or three programs
+# (Router._dispatch_fused); batches − fused fell to the legacy whole
+# dispatch (cache off, a big-filter bitmap live, a snapshot that moved
+# under the split, a pre-placed batch)
 MESH_METRICS = [
     "mesh.batches", "mesh.topics", "mesh.steps", "mesh.step.topics",
+    "mesh.fused",
 ]
 
 ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS

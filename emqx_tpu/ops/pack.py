@@ -131,6 +131,24 @@ def bundle_i32(*parts: jax.Array) -> jax.Array:
     return jnp.concatenate(flat)
 
 
+@functools.partial(jax.jit, static_argnames=("pm", "pq"))
+def pack_mesh(ids: jax.Array, subs: jax.Array, src: jax.Array,
+              ovf: jax.Array, movf: jax.Array, *, pm: int, pq: int):
+    """:func:`pack_matches`, :func:`pack_fanout` and the fetch's
+    :func:`bundle_i32` of one mesh batch as ONE program, keyed by
+    (batch bucket, ``pm``, ``pq``): the event loop enqueues one packer
+    a batch, and a grown budget costs one new program a bucket.
+    Returns ``(m_ptr, packed_ids, f_ptr, packed_subs, packed_src,
+    bundle)``, ``bundle`` in ``Broker._fetch_device``'s order for a
+    batch without bitmap rows; the fetch's re-pack on overflow still
+    calls the two packers apart."""
+    m_ptr, packed_ids = pack_matches(ids, pm=pm)
+    f_ptr, packed_subs, packed_src = pack_fanout(subs, src, pq=pq)
+    return (m_ptr, packed_ids, f_ptr, packed_subs, packed_src,
+            bundle_i32(m_ptr, packed_ids, ovf, movf, f_ptr, packed_subs,
+                       packed_src))
+
+
 @functools.partial(jax.jit, static_argnames=("pr",))
 def pack_union_rows(union: jax.Array, has_big: jax.Array, *, pr: int):
     """Compact the bitmap-union rows: only rows with ``has_big`` set

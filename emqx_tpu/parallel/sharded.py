@@ -469,6 +469,51 @@ def publish_step(
     )(*args)
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("mesh", "lay", "k", "m", "d", "mb", "steps",
+                     "slots", "take"))
+def publish_step_insert(
+    mesh: Mesh,
+    auto: ShardedAutomaton,
+    fan: ShardedFanout,
+    table: jax.Array,      # the mesh match cache's table, replicated
+    buf: jax.Array,        # the batch's one int32 buffer, replicated
+    *,
+    lay,                   # ops.match_cache.MeshLayout with hit = 0
+    k: int, m: int, d: int, mb: int, steps: int | None, slots: int,
+    take: int,
+):
+    """The cache-split mesh dispatch's step as ONE program: slice the
+    misses' operands out of the batch buffer (a replicated operand
+    under ``P("data")`` is cut locally, no collective), run
+    :func:`publish_step` on them, lay each topic's ``flag | ids | subs
+    | src`` cache row, gather the rows over ``data`` once, and scatter
+    them into the cache's table (functionally: the old table stays
+    whole for the probes that hold it).
+
+    Returns ``(new_table, (miss_vals [MB, 1 + width], stats))``, rows
+    and table replicated; keyed by the miss bucket (and the buffer's
+    capacity), never by the batch's hits."""
+    from emqx_tpu.ops.match_cache import flag_rows, insert_rows
+
+    rep = NamedSharding(mesh, P())
+    # held replicated up to the shard_map's edge, where each chip cuts
+    # its own rows out (left to itself the partitioner shards the
+    # slices of the buffer and moves rows between chips)
+    word_ids, n_words, sys_mask, slots_ = (
+        jax.lax.with_sharding_constraint(x, rep)
+        for x in lay.step_sections(buf))
+    ids, subs, src, _bm, ovf, movf, stats = publish_step(
+        mesh, auto, fan, word_ids, n_words, sys_mask, None, k=k, m=m,
+        d=d, mb=mb, with_fanout=True, steps=steps, slots=slots,
+        take=take)
+    vals = jax.lax.with_sharding_constraint(
+        flag_rows(jnp.concatenate([ids, subs, src], axis=1), ovf, movf),
+        rep)
+    return insert_rows(table, slots_, vals), (vals, stats)
+
+
 @functools.partial(jax.jit, static_argnames=("mesh", "k", "m", "steps",
                                              "slots", "take"))
 def shared_pick_step(

@@ -337,6 +337,9 @@ class Router:
         self._match_cache_obj = None
         self._sharded_cache_obj = None
         self._sharded_cache_meta = None  # (T, m, d) the table is sized for
+        # the mesh batch buffer's capacity so far (a shape of every
+        # mesh program: ops/match_cache.py header); only ever grows
+        self._mesh_buf_len = 0
         # publish-path telemetry (telemetry.Telemetry), wired by Node
         # alongside broker.telemetry. When enabled, the cache-split
         # dispatch leaves its per-batch probe/merge timing + hit/miss
@@ -1909,24 +1912,37 @@ class Router:
         ovf_dev [B_pad], movf_dev [B_pad], id_map, epoch, big_fids)``
         — ``movf_dev`` is the match-only overflow (the ``boost_k``
         signal; fan overflow must not grow k); no device→host sync.
+        Given ``topics``, the padding rows of ids / subs / src (row ≥
+        ``len(topics)``: wildcards match the pad topic) come back
+        blanked to -1, ready for the packers.
         Reference: the dispatch fold src/emqx_broker.erl:283-309 run
         as one compiled mesh program.
 
         With the publish match cache enabled (and no big-filter
-        bitmaps live), repeat topics skip the collective step: the
-        cached (ids, subs, src) rows gather from HBM and only the
-        misses walk. A pre-``placed`` batch bypasses the cache (its
-        host half was already paid, and splitting it would re-encode)."""
+        bitmaps live), repeat topics skip the collective step and the
+        whole batch leaves as one transfer and two or three programs
+        (:meth:`_dispatch_fused`). A pre-``placed`` batch bypasses the
+        cache (its host half was already paid, and splitting it would
+        re-encode)."""
         if topics is not None:
             self._count_mesh("mesh.batches", "mesh.topics", len(topics))
-        if placed is None and topics is not None:
-            out = self._sharded_dispatch_cached(topics, fan_provider)
-            if out is not None:
-                return out
-        return self._dispatch_sharded(topics, fan=fan_provider,
-                                      with_big=True, placed=placed)
+            if placed is None:
+                out = self._dispatch_fused(topics, fan_provider)
+                if out is not None:
+                    self._count_mesh("mesh.fused")
+                    return out
+        out = self._dispatch_sharded(topics, fan=fan_provider,
+                                     with_big=True, placed=placed)
+        if topics is None:
+            return out
+        from emqx_tpu.ops.pack import mask_pad_rows
 
-    def _count_mesh(self, events: str, topics: str, n: int) -> None:
+        n = np.int32(len(topics))
+        return tuple(x if x is None else mask_pad_rows(x, n)
+                     for x in out[:3]) + out[3:]
+
+    def _count_mesh(self, events: str, topics: Optional[str] = None,
+                    n: int = 0) -> None:
         """One event of the mesh dispatch and the unique topics it
         carries (metrics.MESH_METRICS), stamped where the decision is
         taken; live only while [telemetry] is enabled, like the
@@ -1934,37 +1950,52 @@ class Router:
         tel = self.telemetry
         if tel is not None and tel.loop_clock() is not None:
             tel.metrics.inc(events)
-            tel.metrics.inc(topics, n)
+            if topics is not None:
+                tel.metrics.inc(topics, n)
 
     def _sharded_cache_for(self, n_trie: int, d: int):
         """The mesh publish cache, sized for the CURRENT (T, m, d)
         row widths — a ``boost_d`` regrows it (entries drop; they
-        were keyed to the old d anyway)."""
-        from emqx_tpu.ops.match_cache import MatchCache
-
+        were keyed to the old d anyway). Its table is replicated over
+        the mesh."""
         cfg = self.config
         meta = (n_trie, cfg.max_matches, d)
         if self._sharded_cache_obj is None \
                 or self._sharded_cache_meta != meta:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            from emqx_tpu.ops.match_cache import MatchCache
+
             width = n_trie * cfg.max_matches + 2 * n_trie * d
             self._sharded_cache_obj = MatchCache(
-                cfg.match_cache_slots, width)
+                cfg.match_cache_slots, width,
+                sharding=NamedSharding(cfg.mesh, PartitionSpec()))
             self._sharded_cache_meta = meta
         return self._sharded_cache_obj
 
-    def _sharded_dispatch_cached(self, topics: Sequence[str],
-                                 fan_provider):
+    def _dispatch_fused(self, topics: Sequence[str], fan_provider):
         """Cache-split mesh publish dispatch, or None when the cache
         does not apply (disabled, no fan state, or big-filter bitmaps
         live — a bitmap union row is megabytes at 10M subs, far past
-        any sane per-entry budget, so that regime stays uncached).
+        any sane per-entry budget, so that regime stays uncached) or
+        the snapshot moved under the split.
 
         One cache entry is a topic's concatenated (match ids [T·m],
         gathered subs [T·d], src [T·d]) rows — everything the
         collective step produces for it except the per-step stats
         psums (device.match counters therefore count WALKED topics
-        only; the host-side hit counters carry the rest)."""
-        import jax.numpy as jnp
+        only; the host-side hit counters carry the rest).
+
+        What the event loop hands the device for the batch is ONE
+        host→device transfer (the batch's int32 buffer, replicated:
+        ops/match_cache.py's header has its layout) and at most two
+        programs here: the step with the insert, for the misses, and
+        the merge, which also splits the row and blanks the pad rows.
+        No eager operation: each is a dispatch to every chip of the
+        mesh from Python."""
+        import jax
+
+        from emqx_tpu.parallel.sharded import publish_step_insert
 
         cfg = self.config
         if not cfg.match_cache or cfg.match_cache_slots <= 0:
@@ -1998,34 +2029,45 @@ class Router:
         t0 = time.perf_counter() if timed else 0.0
         probe = cache.probe(topics, key, keys)
         t1 = time.perf_counter() if timed else 0.0
-        miss_rows = miss_ovf = miss_movf = None
-        if probe.miss_topics:
-            (m_ids, m_subs, m_src, m_bm, m_ovf, m_movf, m_map,
-             m_epoch, m_big) = self._dispatch_sharded(
-                probe.miss_topics, fan=lambda e, im: st,
-                with_big=True)
-            if m_bm is not None or m_big or m_subs is None \
-                    or m_epoch != epoch:
-                # the snapshot moved (or big filters appeared) while
-                # we split: abandon the cached path for this batch —
-                # the pending miss slots stay keyless (permanent
-                # miss), and the caller re-runs the legacy dispatch
+        misses = probe.miss_topics
+        enc = None
+        if misses:
+            mb = unit
+            while mb < len(misses):
+                mb *= 2
+            padded = list(misses) + ["\x00/pad"] * (mb - len(misses))
+            with self._wt_lock:
+                enc = self._encode(padded, cfg.max_levels)
+            if self.snapshot_cached()[2] != epoch:
+                # the snapshot moved while we split: abandon the
+                # cached path for this batch — the pending miss slots
+                # stay keyless (permanent miss), and the caller runs
+                # the legacy dispatch on the new snapshot
                 return None
-            miss_rows = jnp.concatenate([m_ids, m_subs, m_src], axis=1)
-            miss_ovf, miss_movf = m_ovf, m_movf
-            cache.insert(probe, miss_rows, miss_ovf, miss_movf)
+        lay, buf = cache.mesh_buffer(bucket, probe, enc, cfg.max_levels,
+                                     len(topics), self._mesh_buf_len)
+        self._mesh_buf_len = lay.size
+        buf = jax.device_put(buf, cache.sharding)
+        miss_vals = None
+        if misses:
+            # a collective program is enqueued for these topics
+            self._count_mesh("mesh.steps", "mesh.step.topics",
+                             len(misses))
+            miss_vals, stats = cache.insert_through(
+                probe, lambda table: publish_step_insert(
+                    cfg.mesh, auto, st.fan, table, buf,
+                    lay=lay._replace(hit=0), k=self.effective_k(),
+                    m=cfg.max_matches, d=d, mb=cfg.fanout_mb,
+                    **self._walk_kw(cfg.max_levels)))
+            self._dev_stats.append(stats)
         t2 = time.perf_counter() if timed else 0.0
-        merged, ovf, movf = cache.merge(bucket, probe, miss_rows,
-                                        miss_ovf, miss_movf)
-        mw = n_trie * cfg.max_matches
-        dw = n_trie * d
-        ids = merged[:, :mw]
-        subs = merged[:, mw:mw + dw]
-        src = merged[:, mw + dw:]
+        ids, subs, src, ovf, movf = cache.merge_mesh(
+            bucket, probe, lay, buf, miss_vals,
+            (n_trie * cfg.max_matches, n_trie * d))
         if timed:
             self._last_dispatch = {
                 "hit": len(probe.hit_pos),
-                "miss": len(probe.miss_topics),
+                "miss": len(misses),
                 "cache_gather_ms": ((t1 - t0) + (
                     time.perf_counter() - t2)) * 1000.0,
             }

@@ -233,6 +233,66 @@ def test_sharded_publish_step_compiles(topo, n_data, n_trie):
         + fcap) * 2
 
 
+@pytest.mark.parametrize("n_data,n_trie", [(4, 1), (2, 2)])
+def test_fused_mesh_dispatch_compiles(topo, n_data, n_trie):
+    """The three programs a served mesh batch enqueues
+    (``Router._dispatch_fused``), at the cell's widths: the step with
+    the cache insert keeps ``publish_step``'s collectives and adds ONE
+    all-gather (the fresh rows over ``data``) and no other kind; the
+    merge and the packer run replicated, with no collective at all."""
+    import re
+
+    from emqx_tpu.ops.match_cache import (MESH_BUF_FLOOR, MeshLayout,
+                                          _mesh_merge_jit)
+    from emqx_tpu.ops.pack import pack_mesh
+    from emqx_tpu.parallel.sharded import (ShardedAutomaton,
+                                           ShardedFanout, publish_step,
+                                           publish_step_insert)
+
+    def collectives(compiled):
+        return sorted(m.group(1) for m in re.finditer(
+            r"\b(all-gather|all-reduce|collective-permute|all-to-all|"
+            r"reduce-scatter)(?:-start)?\(", compiled.as_text()))
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(n_data, n_trie),
+                ("data", "trie"))
+    tr = NamedSharding(mesh, P("trie"))
+    da = NamedSharding(mesh, P("data"))
+    rep = NamedSharding(mesh, P())
+    T, L, MB, HB, B, d = n_trie, 16, 32, 256, 256, 128
+    width = T * _M + 2 * T * d
+    auto = ShardedAutomaton(
+        wt=_s((T, _NB // T, NARROW_SLOTS * NARROW_SLOT), jnp.int32, tr),
+        wt_seed=_s((T, 1), jnp.uint32, tr),
+        node2=_s((T, _S2 // T, 4), jnp.int32, tr))
+    fan = ShardedFanout(
+        row_ptr=_s((T, _FCAP + 1), jnp.int32, tr),
+        sub_ids=_s((T, 1 << 20), jnp.int32, tr),
+        row_pairs=_s((T, _FCAP, 2), jnp.int32, tr))
+    kw = dict(k=_K, m=_M, d=d, mb=16, steps=6, slots=NARROW_SLOTS, take=1)
+    lay = MeshLayout(L, MB, HB, MESH_BUF_FLOOR)
+    table = _s((65536, 1 + width), jnp.int32, rep)
+    buf = _s((lay.size,), jnp.int32, rep)
+    step = publish_step.lower(
+        mesh, auto, fan, *_batch_shapes(da, MB, L), None,
+        with_fanout=True, **kw).compile()
+    fused = publish_step_insert.lower(
+        mesh, auto, fan, table, buf, lay=lay._replace(hit=0),
+        **kw).compile()
+    assert collectives(fused) == sorted(collectives(step) + ["all-gather"])
+    vals = _s((MB, 1 + width), jnp.int32, rep)
+    for miss_vals, miss in ((vals, MB), (None, 0)):
+        merge = _mesh_merge_jit.lower(
+            table, buf, miss_vals, lay=lay._replace(miss=miss), b_pad=B,
+            splits=(T * _M, T * d)).compile()
+        assert collectives(merge) == []
+    i32 = lambda *shape: _s(shape, jnp.int32, rep)  # noqa: E731
+    flags = _s((B,), jnp.bool_, rep)
+    pack = pack_mesh.lower(i32(B, T * _M), i32(B, T * d), i32(B, T * d),
+                           flags, flags, pm=8192, pq=8192).compile()
+    assert collectives(pack) == []
+
+
 def test_unselected_pallas_kernels_are_still_refused(one_chip):
     """The two Pallas kernels dispatch does NOT select, and why: the
     v5e compiler refuses them as written (ROADMAP C2). The day one of

@@ -43,7 +43,7 @@ LEVELS, WORDS = 5, 12
 BURST = 12          # QoS 0 publishes between two QoS 1 fences
 BREAKER = ("breaker.failures", "breaker.trips", "breaker.fallback.batches")
 MESH_COUNTERS = ("mesh.batches", "mesh.topics", "mesh.steps",
-                 "mesh.step.topics")
+                 "mesh.step.topics", "mesh.fused")
 
 
 # -- the configuration's edges -----------------------------------------------
@@ -393,12 +393,17 @@ def test_served_mesh_counters(runs):
     assert 0 < ca["mesh.steps"] <= ca["mesh.batches"] == len(a["spans"])
     assert ca["mesh.step.topics"] == sum(s["miss"] for s in a["spans"])
     assert 0 < ca["mesh.step.topics"] < ca["mesh.topics"]
+    # no filter over fanout_threshold yet: every batch left as one
+    # transfer and two or three programs (Router._dispatch_fused)
+    assert ca["mesh.fused"] == ca["mesh.batches"]
     # with a bitmap filter live every batch walks whole (uncached)
     c = mesh["counters"]
     assert c["mesh.batches"] == len(mesh["spans"])
     b = {k: c[k] - ca[k] for k in MESH_COUNTERS}
     assert b["mesh.steps"] == b["mesh.batches"] > 0
     assert b["mesh.step.topics"] == b["mesh.topics"]
+    # ... through the legacy whole dispatch
+    assert b["mesh.fused"] == 0 and c["mesh.fused"] <= c["mesh.batches"]
 
 
 def test_mesh_device_counters_reach_the_registry():
