@@ -1001,8 +1001,7 @@ class Channel:
                 m.inc("messages.qos2.sent", n_tpl2)
         if n_onloop:
             # PUBLISHes that paid a full serialize on the event loop
-            # (ineligible traffic, or pre-serialization off) — the
-            # LIVE_PRESER bench A/B reads this per delivery
+            # (ineligible traffic, or pre-serialization off)
             m.inc("delivery.serialize.onloop", n_onloop)
 
     def _run_blob(self, run):

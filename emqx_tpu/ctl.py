@@ -203,9 +203,8 @@ class Ctl:
             # cumulative lock-stall the off-lock compaction design
             # keeps near zero
             "delta": r.delta_info(),
-            # walk kernel variant (pallas | lax) + the live tables'
-            # level-compression snapshot (docs/PERF_NOTES.md
-            # "Round 6: path compression and the VMEM walk")
+            # the live tables' level-compression snapshot
+            # (docs/PERF_NOTES.md "Round 6: path compression")
             "walk": r.walk_info(),
         }
         for name, c in (("single", r._match_cache_obj),
@@ -226,7 +225,7 @@ class Ctl:
         tombstones, dropped/expired, replay batches + last batch
         size) and the reverse index's device state (live/deep rows,
         capacity, epoch, dirty-row backlog, breaker/suspension
-        fallback, walk variant)."""
+        fallback)."""
         mod = self.node.modules._loaded.get("retainer") \
             if hasattr(self.node, "modules") else None
         if mod is None:

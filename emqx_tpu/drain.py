@@ -131,7 +131,7 @@ class DrainManager:
         #: ran; False = deadline hit with a digest mismatch — the
         #: final state was still sent, counted in handoff.errors)
         self.handoff_ok: Optional[bool] = None
-        #: per-wave redirect durations (ms) — the bench's wave p99
+        #: per-wave redirect durations (ms)
         self.wave_ms: List[float] = []
         self._task: Optional[asyncio.Task] = None
 

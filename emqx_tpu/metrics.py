@@ -66,8 +66,7 @@ DELIVERY_METRICS = [
     "delivery.dropped.queue_full", "delivery.dropped.expired",
     # connection flush wakeups actually scheduled (after
     # Connection._schedule_flush coalescing): with the dispatch
-    # planner this is ≤1 per connection per batch — the bench's
-    # wakeups/batch column divides it by ingress flushes
+    # planner this is ≤1 per connection per batch
     "delivery.wakeups",
     # wire runs (docs/DISPATCH.md "Wire runs"): a planned batch's
     # QoS0 broadcast to one session written as ONE pre-joined piece
@@ -79,8 +78,7 @@ DELIVERY_METRICS = [
     # PUBLISH frames serialized ON the event loop (the per-delivery
     # slow path, plus template/image cache misses that build there).
     # With egress pre-serialization on (docs/DISPATCH.md) eligible
-    # traffic patches pre-built frames instead, so this stays ~0 —
-    # the bench's LIVE_PRESER A/B reads it per delivery
+    # traffic patches pre-built frames instead, so this stays ~0
     "delivery.serialize.onloop",
     # cross-loop delivery ring (docs/DISPATCH.md "Multi-loop front
     # door"): handoffs posted to a session's owning event loop — at

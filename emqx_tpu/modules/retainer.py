@@ -330,7 +330,7 @@ class RetainIndex:
                            ) -> List[List[str]]:
         import jax.numpy as jnp
 
-        from emqx_tpu.ops.retained_match import match_names_auto
+        from emqx_tpu.ops.retained_match import match_names_many
 
         F = len(filters)
         # pad the burst to a power of two so compile count stays
@@ -357,7 +357,7 @@ class RetainIndex:
                 # result, and subscribe traffic can't grow the table
                 fw[i, j] = _PLUS_ID if w == "+" else self._table.lookup(w)
         dev = self._device_arrays()
-        ok = np.asarray(match_names_auto(
+        ok = np.asarray(match_names_many(
             jnp.asarray(fw), jnp.asarray(fn), jnp.asarray(hh),
             dev[2], dev[3], dev[4]))
         self._last_batch = F
@@ -390,8 +390,6 @@ class RetainIndex:
         """Diagnostic snapshot for ``ctl retained``
         (docs/OPERATIONS.md): live/deep row counts, device-cache
         state, breaker/suspension state and the last batch size."""
-        from emqx_tpu.ops.walk_pallas import walk_variant
-
         r = self._router
         suspended = False
         if r is not None:
@@ -409,7 +407,6 @@ class RetainIndex:
             "device_broken": self._device_broken,
             "suspended": suspended,
             "last_batch": self._last_batch,
-            "walk": walk_variant(),
         }
 
 

@@ -5,7 +5,7 @@ ops/pack.py) used to be walked one ``(filter, subscriber)`` pair at a
 time through ``Broker._route_packed`` → ``_deliver_one`` →
 ``Session.deliver`` — one registry lookup, one subopts dict fetch and
 one notify wakeup **per delivery**. At live fan-outs that Python walk
-is the whole publish tail (BENCH ``live_socket_throughput``); the
+is the whole publish tail; the
 reference's own hot loop 2 is the same walk (``emqx_broker:dispatch/2``,
 src/emqx_broker.erl:283-309), and its ``emqx_batch.erl``
 accumulate-then-flush idea applies to the tail as much as to ingress.

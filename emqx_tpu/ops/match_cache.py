@@ -3,8 +3,8 @@
 The publish hot loop (``emqx_broker:publish/1`` →
 ``emqx_trie:match/1``, SURVEY §3.1 "HOT LOOP 1") re-walks every
 unique topic per batch, yet real traffic is massively repetitive:
-the Zipf bench rows see the same hot topics re-walked from scratch
-every tick (and EMQX itself ships a host-side route cache in front of
+Zipf traffic would see the same hot topics re-walked from scratch
+every batch (and EMQX itself ships a host-side route cache in front of
 ``emqx_router:match_routes/1`` for exactly this reason). This module
 memoizes per-topic match rows in a fixed-shape HBM table so a repeat
 topic costs one gather instead of an NFA walk + per-topic compaction.

@@ -519,7 +519,7 @@ def _compress_native(lib, auto, state_capacity: Optional[int] = None):
 
     Returns ``(compressed_auto, V2Edges)`` byte-identical to
     ``csr.compress_automaton`` (parity pinned field-for-field by
-    tests/test_walk_pallas.py::test_native_compress_parity)
+    tests/test_compressed_walk.py::test_native_compress_parity)
     or None when the numpy path should run instead: narrow-mode tries
     (no deep chains worth fusing — the numpy narrow path is a cheap
     renumber) or a pre-rebuild .so without the symbol."""

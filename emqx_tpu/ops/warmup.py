@@ -4,8 +4,7 @@ After :meth:`Router.rebuild_device_state` publishes fresh tables on a
 fresh backend, the walk/fetch jit kernels for the batch shapes live
 traffic actually uses must be executed once OFF the hot path — the
 first post-recovery publish batch must pay zero compile
-(docs/ROBUSTNESS.md "Device-loss recovery"; the devloss bench's
-``first_batch_p99_ms`` column is the proof).
+(docs/ROBUSTNESS.md "Device-loss recovery").
 
 This module is pure host planning (no jax imports, nothing to sync —
 the device work happens in ``Broker.warm_device_path``, which drives
@@ -21,7 +20,7 @@ that age out under the clock sweep.
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Tuple
+from typing import Iterable, List, Tuple
 
 #: bound on warm batches per recovery: the floor bucket plus the
 #: largest observed live buckets (each is one compile family)
@@ -76,11 +75,3 @@ def warm_plan(observed: Iterable[int], min_batch: int,
     return [(b, warm_topics(b, min_batch, lv))
             for b in warm_buckets(observed, min_batch, cap)
             for lv in lvls]
-
-
-def stamp_first_batch(record: Dict[str, object],
-                      first_batch_ms: float) -> None:
-    """Fold the first post-recovery batch latency into a devloss
-    bench record (one seam so the bench and the smoke assert the
-    same field name)."""
-    record["first_batch_p99_ms"] = round(float(first_batch_ms), 3)

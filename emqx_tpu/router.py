@@ -39,8 +39,7 @@ from emqx_tpu import faults
 from emqx_tpu import topic as T
 from emqx_tpu.oracle import TrieOracle
 from emqx_tpu.ops.csr import Automaton, build_automaton, device_view
-from emqx_tpu.ops.match import depth_bucket
-from emqx_tpu.ops.walk_pallas import match_batch_auto, walk_variant
+from emqx_tpu.ops.match import depth_bucket, match_batch
 from emqx_tpu.ops.patch import AutoPatcher, PatchOverflow
 from emqx_tpu.ops.tokenize import WordTable, encode_batch
 from emqx_tpu.types import Route
@@ -1584,10 +1583,10 @@ class Router:
         with self._wt_lock:
             ids, n, sysm = self._encode(padded, cfg.max_levels)
         ids, n = depth_bucket(ids, n)
-        res = match_batch_auto(auto, ids, n, sysm,
-                               k=self.effective_k(),
-                               m=cfg.max_matches, pack_ids=False,
-                               **self._walk_kw(ids.shape[1]))
+        res = match_batch(auto, ids, n, sysm,
+                          k=self.effective_k(),
+                          m=cfg.max_matches, pack_ids=False,
+                          **self._walk_kw(ids.shape[1]))
         out_ids, out_ovf = res.ids, res.overflow
         if dsnap is not None:
             # two-probe: union the side-automaton's raw emits +
@@ -1666,10 +1665,10 @@ class Router:
             with self._wt_lock:
                 ids, n, sysm = self._encode(padded, cfg.max_levels)
             ids, n = depth_bucket(ids, n)
-            res = match_batch_auto(auto, ids, n, sysm,
-                                   k=self.effective_k(),
-                                   m=cfg.max_matches, pack_ids=True,
-                                   **self._walk_kw(ids.shape[1]))
+            res = match_batch(auto, ids, n, sysm,
+                              k=self.effective_k(),
+                              m=cfg.max_matches, pack_ids=True,
+                              **self._walk_kw(ids.shape[1]))
             miss_rows, miss_ovf = res.ids, res.overflow
             if dsnap is not None:
                 # two-probe: fold the side-automaton + tombstone mask
@@ -1845,11 +1844,10 @@ class Router:
         }
 
     def walk_info(self) -> Dict[str, object]:
-        """Live walk-kernel facts for `ctl cache` / bench: the variant
-        dispatch would pick right now (pallas | lax) and the level-
-        compression snapshot of the live tables (mode, fused chains,
-        permille of deepest-walk steps saved)."""
-        return {"variant": walk_variant(), **self._compaction}
+        """Live walk facts for `ctl cache`: the level-compression
+        snapshot of the live tables (mode, fused chains, permille of
+        deepest-walk steps saved)."""
+        return dict(self._compaction)
 
     def delta_info(self) -> Dict[str, object]:
         """Live delta-automaton state for `ctl cache` / bench

@@ -397,7 +397,7 @@ class WalGroup:
         self.coalesced = 0
         #: duration of the last leader commit pass (window sleep +
         #: write + fsync across shards) — the group_commit_window_ms
-        #: tuning signal (BENCH_MODE=recovery sweep)
+        #: tuning signal (docs/DURABILITY.md)
         self.last_commit_ms = 0.0
 
     # -- shard routing -----------------------------------------------------

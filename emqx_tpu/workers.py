@@ -77,8 +77,7 @@ async def main():
     cookie = sys.argv[5]
     name = f"worker{idx}@{os.getpid()}"
     n = Node(name=name, boot_listeners=False)
-    # the fleet bench's retained-replay storm needs the retainer
-    # serving replays on every worker
+    # retained replays are served on every worker
     from emqx_tpu.modules.retainer import RetainerModule
     n.modules.load(RetainerModule)
     tr = SocketTransport(name, cookie=cookie)

@@ -88,8 +88,10 @@ class Context:
         # -- example toml: section -> {key -> line}; plus path
         self.toml_keys: Dict[str, Dict[str, int]] = {}
         self.toml_path: str = "etc/emqx_tpu.toml"
-        # -- device-purity whitelist: ops/ function names that ARE
-        # the sanctioned fetch/transfer seams
+        # -- device-purity whitelist (DP301): ops/ function names that
+        # ARE sanctioned device→host fetch seams — a sync inside one
+        # is the coalesced fetch the dispatch pipeline planned for.
+        # None in the tree today
         self.device_whitelist: Set[str] = set()
         # -- per-file scratch the finalize passes read
         self.fire_sites: List[Tuple[str, int, str]] = []
@@ -146,19 +148,9 @@ def _read(root: Path, rel: str) -> str:
         return ""
 
 
-#: ops/ functions that ARE the sanctioned device→host transfer seams
-#: (DP301): each one exists so every other kernel call can stay
-#: async — a sync inside any of these is the coalesced fetch the
-#: dispatch pipeline planned for, not a stall
-DEVICE_FETCH_SEAMS = frozenset({
-    "fetch_walk_result",  # ops/walk_pallas.py — walk parity/bench
-})
-
-
 def build_context(root: Path) -> Context:
     ctx = Context()
     ctx.root = root
-    ctx.device_whitelist = set(DEVICE_FETCH_SEAMS)
     # metrics registry: every *_METRICS list literal in metrics.py,
     # the GAUGE_METRICS set, plus .new("literal") registrations
     # anywhere in the package (retainer/monitors register at attach)

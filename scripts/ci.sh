@@ -16,16 +16,16 @@
 #   5. scripts/cov.py over the suite      (line coverage report;
 #      COV=0 skips — it roughly doubles suite wall time)
 #
-# bench.py's modes are not smoked here: they need the chip and exit
-# non-zero without one (chiprun -- python chip_smoke.py is the
-# on-chip gate; tests/test_chip_smoke.py rehearses it at toy size).
+# Nothing here needs the chip: chiprun -- python chip_smoke.py is the
+# on-chip gate (tests/test_chip_smoke.py rehearses it at toy size) and
+# python3 benchmark/run.py the measurement (BENCHMARK.json).
 #
 # Exits nonzero on any violation.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== byte-compile =="
-python -m compileall -q emqx_tpu tests scripts bench.py chip_smoke.py \
+python -m compileall -q emqx_tpu tests scripts chip_smoke.py \
     __graft_entry__.py
 
 echo "== static analysis (scripts/lint.py, docs/ANALYSIS.md) =="
@@ -54,12 +54,12 @@ echo "== delta-automaton parity + off-lock compaction (docs/DELTA.md) =="
 python -m pytest tests/test_delta.py -q
 
 echo "== compressed-walk parity (docs/PERF_NOTES.md round 6) =="
-# Pallas-vs-lax byte identity (CPU interpret mode), native-vs-numpy
+# walk-vs-oracle parity on narrow and wide tables, native-vs-numpy
 # chain-fuser parity, and the randomized compressed-walk property
 # suite (deep spines, $share, churn, devloss rebuild, checkpoint
 # round-trip) — a divergence here is a match-correctness bug in the
 # wide-table walk, fail fast
-python -m pytest tests/test_walk_pallas.py -q
+python -m pytest tests/test_compressed_walk.py -q
 
 echo "== flap-storm guard (flapping.py + scenario smoke) =="
 python -m pytest tests/test_flapping.py -q
@@ -171,8 +171,8 @@ python -m pytest tests/test_cluster_heal.py -q
 echo "== telemetry (docs/OBSERVABILITY.md) =="
 # the publish-path telemetry suite, incl. the disabled-mode A/B
 # guard (telemetry off => dispatch byte-identical to the
-# un-instrumented broker) — run before any bench smoke so an
-# instrumentation regression fails fast
+# un-instrumented broker) — run early so an instrumentation
+# regression fails fast
 python -m pytest tests/test_telemetry.py -q
 
 echo "== tracing + slow_subs (docs/OBSERVABILITY.md \"Tracing\") =="
@@ -210,11 +210,11 @@ echo "== multi-loop parity under the native frame engine =="
 EMQX_TPU_FRAME=native python -m pytest tests/test_frontdoor_loops.py -q
 
 echo "== retained replay parity (docs/DISPATCH.md \"Retained replay\") =="
-# batched subscribe-time matching vs the T.match host oracle (lax AND
-# forced-Pallas interpret), planner on/off + loops=1/2 replay wire
-# parity, the ≤1-wakeup / onloop==0 delivery contract, will batching,
-# devloss riding — a divergence here is a delivery-correctness bug,
-# fail before the long run
+# batched subscribe-time matching vs the T.match host oracle,
+# planner on/off + loops=1/2 replay wire parity, the ≤1-wakeup /
+# onloop==0 delivery contract, will batching, devloss riding — a
+# divergence here is a delivery-correctness bug, fail before the long
+# run
 python -m pytest tests/test_retained_replay.py -q
 
 echo "== pytest =="

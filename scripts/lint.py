@@ -32,8 +32,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 import analysis  # noqa: E402  (needs the scripts/ dir on sys.path)
 
 ROOT = Path(__file__).resolve().parents[1]
-DEFAULT_TARGETS = ["emqx_tpu", "tests", "scripts", "bench.py",
-                   "chip_smoke.py",
+DEFAULT_TARGETS = ["emqx_tpu", "tests", "scripts", "chip_smoke.py",
                    "__graft_entry__.py"]
 
 

@@ -1,7 +1,8 @@
 """Bitmap fan-out parity: Pallas kernel vs XLA scan vs numpy oracle.
 
 On the CPU test mesh the Pallas kernel runs in interpret mode; the
-compiled path is exercised on real TPU by bench.py BENCH_MODE=bigfan.
+v5e compile is pinned by tests/test_chip_compile.py
+(``test_or_bitmaps_dma_compiles``).
 """
 
 import numpy as np

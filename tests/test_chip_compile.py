@@ -94,11 +94,9 @@ def _batch_shapes(sh, B, L):
     ("wide", 16, 4),      # chain-compressed deep tries
 ])
 def test_dispatched_walk_compiles(one_chip, mode, L, steps, pack_ids):
-    """The walk that ``match_batch_auto`` selects on a TPU."""
-    from emqx_tpu.ops import walk_pallas
+    """The walk the router dispatches."""
     from emqx_tpu.ops.match import match_batch
 
-    assert walk_pallas.walk_variant() == "lax"
     wide = mode == "wide"
     lowered = match_batch.lower(
         _auto_shapes(one_chip, wide), *_batch_shapes(one_chip, _B, L),
@@ -159,8 +157,8 @@ def test_or_bitmaps_dma_compiles(one_chip, B):
 
 
 def test_retained_match_compiles_at_1m_names(one_chip):
-    """The retained-replay match ``match_names_auto`` selects on a
-    TPU, at the index's deployment capacity (2^20 names)."""
+    """The retained-replay match the retainer dispatches, at the
+    index's deployment capacity (2^20 names)."""
     from emqx_tpu.ops import retained_match as rm
 
     F, L, cap = 64, 8, 1 << 20
@@ -291,26 +289,3 @@ def test_fused_mesh_dispatch_compiles(topo, n_data, n_trie):
     pack = pack_mesh.lower(i32(B, T * _M), i32(B, T * d), i32(B, T * d),
                            flags, flags, pm=8192, pq=8192).compile()
     assert collectives(pack) == []
-
-
-def test_unselected_pallas_kernels_are_still_refused(one_chip):
-    """The two Pallas kernels dispatch does NOT select, and why: the
-    v5e compiler refuses them as written (ROADMAP C2). The day one of
-    them lowers this test fails — the cue to A/B it on the chip and
-    revisit ``walk_variant()``, not to delete the test."""
-    from emqx_tpu.ops import retained_match as rm
-    from emqx_tpu.ops.walk_pallas import match_batch_pallas
-
-    sh = one_chip
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        match_batch_pallas.lower(
-            _auto_shapes(sh, False), *_batch_shapes(sh, _B, 8),
-            k=_K, m=_M, steps=9, slots=NARROW_SLOTS, take=1,
-            pack_ids=True).compile()
-    F, L, cap = 64, 8, 1 << 20
-    with pytest.raises(Exception, match="vmem"):
-        rm.match_names_many_pallas.lower(
-            _s((F, L), jnp.int32, sh), _s((F,), jnp.int32, sh),
-            _s((F,), jnp.bool_, sh), _s((cap, L), jnp.int32, sh),
-            _s((cap,), jnp.int32, sh),
-            _s((cap,), jnp.bool_, sh)).compile()

@@ -1,10 +1,10 @@
-"""VMEM-resident Pallas walk + path compression (ISSUE 16).
+"""Path compression and the compressed walk (ISSUE 16).
 
 Three contracts pinned here:
 
-  1. **Pallas-vs-lax byte identity** on CPU interpret mode — same
-     ids, counts, overflow flags, bit for bit, on both table layouts
-     (narrow / wide) and both packing modes.
+  1. **Walk-vs-oracle parity on both table layouts** — the lax walk
+     returns exactly the host ``TrieOracle``'s filters on narrow and
+     wide (chain-fused) tables, packed and raw emit slots.
   2. **Native-vs-numpy compression parity** — the C++ ``csr_compress``
      chain fuser must reproduce ``csr.compress_automaton`` exactly
      (same edges, same renumbering, same hop bounds, same wt).
@@ -12,8 +12,7 @@ Three contracts pinned here:
      fuzz (``+``/``#``/``$share``, deep literal spines, single-char
      and empty levels) against the host ``TrieOracle`` across
      add/delete churn, delta flatten, devloss rebuild and checkpoint
-     round-trip, with the router's dispatch seam forced through the
-     Pallas kernel.
+     round-trip.
 """
 
 import random
@@ -21,34 +20,9 @@ import random
 import numpy as np
 import pytest
 
-from emqx_tpu import topic as T
 from emqx_tpu.oracle import TrieOracle
-from emqx_tpu.ops.csr import (attach_walk_tables, build_automaton,
-                              compress_automaton)
-from emqx_tpu.ops.match import match_batch, walk_params
-from emqx_tpu.ops.tokenize import WordTable, encode_batch
-from emqx_tpu.ops.walk_pallas import (fetch_walk_result,
-                                      match_batch_pallas, walk_variant)
 from emqx_tpu.router import MatcherConfig, Router
-
-
-def _build(filters, mode=None):
-    trie = TrieOracle()
-    table = WordTable()
-    fids = {}
-    for f in filters:
-        trie.insert(f)
-        fids[f] = len(fids)
-        for w in T.words(f):
-            table.intern(w)
-    if mode is None:
-        auto = build_automaton(trie, fids, table)
-    else:
-        raw = build_automaton(trie, fids, table, skip_hash=True)
-        auto, edges = compress_automaton(raw, force_mode=mode)
-        auto = attach_walk_tables(auto, edges)
-    inv = {v: k for k, v in fids.items()}
-    return trie, table, auto, inv
+from tests.test_match_parity import _check_parity
 
 
 def _rand_word(rng):
@@ -98,62 +72,18 @@ def _rand_topics(rng, n, L=16):
     return out
 
 
-# -- 1. Pallas vs lax byte identity ----------------------------------------
+# -- 1. walk vs oracle on both layouts --------------------------------------
 
 
 @pytest.mark.parametrize("mode", ["narrow", "wide"])
 @pytest.mark.parametrize("pack_ids", [True, False])
-def test_pallas_lax_byte_identity(mode, pack_ids):
+def test_walk_oracle_parity(mode, pack_ids):
     rng = random.Random(20160 + pack_ids)
     filters = _rand_filters(rng, 150)
     topics = _rand_topics(rng, 32)
-    trie, table, auto, inv = _build(filters, mode=mode)
-    ids, n, sysm = encode_batch(table, topics, 16)
-    kw = dict(k=16, m=64, pack_ids=pack_ids,
-              **walk_params(auto, ids.shape[1]))
-    ref = match_batch(auto, ids, n, sysm, **kw)
-    got = match_batch_pallas(auto, ids, n, sysm, interpret=True, **kw)
-    r_ids, r_cnt, r_ovf = fetch_walk_result(ref)
-    g_ids, g_cnt, g_ovf = fetch_walk_result(got)
-    np.testing.assert_array_equal(g_ids, r_ids)
-    np.testing.assert_array_equal(g_cnt, r_cnt)
-    np.testing.assert_array_equal(g_ovf, r_ovf)
-
-
-def test_pallas_overflow_and_sys_semantics():
-    """Edge semantics must survive the kernel port: tiny K overflow
-    flags, $SYS root masking, topics past max_levels."""
-    filters = ["#", "+/#", "$SYS/#", "a/+/c", "a/b/c", "a/b/#"]
-    trie, table, auto, inv = _build(filters, mode="narrow")
-    topics = ["a/b/c", "$SYS/broker", "a/x/c", "q",
-              "/".join(["d"] * 40)]
-    ids, n, sysm = encode_batch(table, topics, 16)
-    kw = dict(k=2, m=8, pack_ids=True, **walk_params(auto, 16))
-    ref = match_batch(auto, ids, n, sysm, **kw)
-    got = match_batch_pallas(auto, ids, n, sysm, interpret=True, **kw)
-    for a, b in zip(fetch_walk_result(got), fetch_walk_result(ref)):
-        np.testing.assert_array_equal(a, b)
-    # the >16-level topic must be flagged, not truncated
-    assert bool(fetch_walk_result(got)[2][-1])
-
-
-def test_walk_variant_dispatch(monkeypatch):
-    """The backend rule (PR 21): there is none. The v5e compiler
-    refuses the Pallas walk, so dispatch takes the lax walk whatever
-    the backend is called; only the explicit override selects the
-    kernel."""
-    import jax
-
-    monkeypatch.delenv("EMQX_TPU_WALK", raising=False)
-    assert walk_variant() == "lax"  # CPU test backend
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert walk_variant() == "lax"  # ... and on a TPU
-    monkeypatch.setenv("EMQX_TPU_WALK", "auto")
-    assert walk_variant() == "lax"
-    monkeypatch.setenv("EMQX_TPU_WALK", "pallas")
-    assert walk_variant() == "pallas"
-    monkeypatch.setenv("EMQX_TPU_WALK", "lax")
-    assert walk_variant() == "lax"
+    ovf = _check_parity(filters, topics, L=16, k=16, m=64, mode=mode,
+                        pack_ids=pack_ids)
+    assert not ovf.any()
 
 
 # -- 2. native chain-fuser parity ------------------------------------------
@@ -304,25 +234,3 @@ def test_rewarm_plan_covers_deep_buckets():
         for lv in seen:
             assert (b, lv) in depths
     assert len(warm_topics(64, 8, levels=16)) == 33  # bucket select
-
-
-@pytest.mark.slow
-def test_pallas_dispatch_through_router(monkeypatch):
-    """The dispatch seam end-to-end: force the Pallas kernel (CPU ⇒
-    interpret mode) through Router.match_filters and hold oracle
-    parity, including a mid-test mutation + re-flatten."""
-    monkeypatch.setenv("EMQX_TPU_WALK", "pallas")
-    rng = random.Random(5150)
-    r = _mk(match_cache=False, active_k=8, min_batch=4)
-    oracle = TrieOracle()
-    for f in _rand_filters(rng, 40):
-        r.add_route(f)
-        oracle.insert(f)
-    probe = _rand_topics(rng, 4)
-    assert r.walk_info()["variant"] == "pallas"
-    _assert_parity(r, oracle, probe, "pallas-warm")
-    f = "mid/flight/route"
-    r.add_route(f)
-    oracle.insert(f)
-    _assert_parity(r, oracle, probe + [f.replace("+", "a")],
-                   "pallas-churn")

@@ -1,8 +1,6 @@
 """Flap detection → auto-ban (emqx_tpu/flapping.py; reference
 src/emqx_flapping.erl): detect/ban thresholds, window reset, gc, and
-the flapping→banned interaction under a reconnect-storm shape — the
-live-path guard the flap-storm bench scenario
-(``BENCH_MODE=flapstorm``) leans on."""
+the flapping→banned interaction under a reconnect-storm shape."""
 
 import time
 

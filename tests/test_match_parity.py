@@ -35,16 +35,19 @@ def _build(filters, mode=None):
     return trie, table, auto, inv
 
 
-def _match_device(auto, table, topics, L=16, k=64, m=128):
+def _match_device(auto, table, topics, L=16, k=64, m=128,
+                  pack_ids=True):
     ids, n, sysm = encode_batch(table, topics, L)
-    res = match_batch(auto, ids, n, sysm, k=k, m=m,
+    res = match_batch(auto, ids, n, sysm, k=k, m=m, pack_ids=pack_ids,
                       **walk_params(auto, ids.shape[1]))
     return res
 
 
-def _check_parity(filters, topics, L=16, k=64, m=128, mode=None):
+def _check_parity(filters, topics, L=16, k=64, m=128, mode=None,
+                  pack_ids=True):
     trie, table, auto, inv = _build(filters, mode=mode)
-    res = _match_device(auto, table, topics, L=L, k=k, m=m)
+    res = _match_device(auto, table, topics, L=L, k=k, m=m,
+                        pack_ids=pack_ids)
     ids = np.asarray(res.ids)
     cnt = np.asarray(res.count)
     ovf = np.asarray(res.overflow)
@@ -159,13 +162,32 @@ def test_deep_chain_parity(mode):
     assert not ovf.any()  # no '+' edges: active set is 1 lane
 
 
-def test_overflow_flagged_not_silent():
-    """With a tiny K, dense '+' chains overflow — flag must be set."""
+def _dense_plus_filters():
     rng = random.Random(5)
-    filters = list({_random_filter(rng, maxlen=4) for _ in range(200)})
-    topics = ["a/b/c", "a/a/a", "x/yy/z0"]
-    # k=2 forces active-set overflow on wide NFA frontiers
-    _check_parity(filters, topics, L=8, k=2, m=256)
+    return list({_random_filter(rng, maxlen=4) for _ in range(200)})
+
+
+@pytest.mark.parametrize("filters,topics,kw,flags", [
+    # k=2 forces active-set overflow on wide NFA frontiers; which
+    # rows overflow is the kernel's business, flagged is the contract
+    pytest.param(_dense_plus_filters(), ["a/b/c", "a/a/a", "x/yy/z0"],
+                 dict(L=8, k=2, m=256), None, id="dense_plus"),
+    # at k=2, m=8 the root wildcards stay masked for a $SYS topic, and
+    # the one row flagged is the topic past max_levels — flagged, not
+    # truncated and matched
+    pytest.param(["#", "+/#", "$SYS/#", "a/+/c", "a/b/c", "a/b/#"],
+                 ["a/b/c", "$SYS/broker", "a/x/c", "q",
+                  "/".join(["d"] * 40)],
+                 dict(L=16, k=2, m=8, mode="narrow"),
+                 [False, False, False, False, True],
+                 id="sys_and_too_deep"),
+])
+def test_overflow_flagged_not_silent(filters, topics, kw, flags):
+    """With a tiny K an overflow must be flagged, never silent: every
+    unflagged row equals the oracle's answer."""
+    ovf = _check_parity(filters, topics, **kw)
+    if flags is not None:
+        assert ovf.tolist() == flags
 
 
 def test_large_scale_smoke():

@@ -1,7 +1,6 @@
 """Device-resident retained replay (PR 19, docs/DISPATCH.md
 "Retained replay"): batched subscribe-time matching parity against
-the ``T.match`` host oracle (lax AND forced-Pallas variants),
-planner-egress replay wire/metric parity (planner on/off, loops=1
+the ``T.match`` host oracle, planner-egress replay wire/metric parity (planner on/off, loops=1
 vs 2), the ≤1-wakeup / onloop==0 delivery contract, device-path will
 batching, and devloss riding of the retain index."""
 
@@ -71,35 +70,18 @@ def _burst(rng, live):
     return flts
 
 
-@pytest.mark.parametrize("variant", ["lax", "pallas"])
-def test_match_many_fuzz_parity(monkeypatch, variant):
+def test_match_many_fuzz_parity():
     """Exact oracle parity of the BATCHED device match across mixed
-    bursts, for both kernel variants (the forced-Pallas run goes
-    through interpret mode on CPU — slow, byte-exact)."""
-    monkeypatch.setenv("EMQX_TPU_WALK", variant)
-    rng = random.Random(77 if variant == "lax" else 78)
-    rounds = 6 if variant == "lax" else 2  # interpret mode is slow
-    for _ in range(rounds):
+    bursts."""
+    rng = random.Random(77)
+    for _ in range(6):
         idx, live = _fuzz_index(rng)
         flts = _burst(rng, live)
         got = idx.match_many(flts, device_threshold=0)
         assert len(got) == len(flts)
         for flt, hits in zip(flts, got):
-            assert sorted(hits) == _oracle(live, flt), (variant, flt)
+            assert sorted(hits) == _oracle(live, flt), flt
         assert idx._last_batch == len(flts)
-
-
-def test_match_many_lax_pallas_byte_parity(monkeypatch):
-    """Same index, same burst, both kernels: identical hit lists
-    (the Pallas tiles are a pure reimplementation, pinned here)."""
-    rng = random.Random(5)
-    idx, live = _fuzz_index(rng, n=300)
-    flts = _burst(rng, live)
-    monkeypatch.setenv("EMQX_TPU_WALK", "lax")
-    lax = idx.match_many(flts, device_threshold=0)
-    monkeypatch.setenv("EMQX_TPU_WALK", "pallas")
-    pal = idx.match_many(flts, device_threshold=0)
-    assert [sorted(h) for h in lax] == [sorted(h) for h in pal]
 
 
 def test_match_many_interleaved_mutations():
@@ -505,7 +487,6 @@ async def test_ctl_retained_snapshot():
         assert idx["rows"] == len(_SEED)
         assert idx["last_batch"] == 2  # two wildcard filters batched
         assert idx["device_broken"] == 0 and not idx["suspended"]
-        assert idx["walk"] in ("lax", "pallas")
     finally:
         await n.stop()
 
