@@ -62,6 +62,13 @@ class AccessControl:
                   cache: Optional[AclCache] = None) -> str:
         """ALLOW or DENY (with per-connection cache)."""
         assert pubsub in (PUB, SUB)
+        if not self.hooks.has("client.check_acl"):
+            # no ACL module, no plugin: the fold over an empty chain
+            # is the zone's default, and a constant is not worth a
+            # cache entry (nor the clientinfo copy the fold is fed)
+            if self.metrics is not None:
+                self.metrics.inc("client.check_acl")
+            return self.zone.acl_nomatch
         if cache is not None:
             hit = cache.get(pubsub, topic)
             if hit is not None:

@@ -48,7 +48,7 @@ from emqx_tpu.session import (PUBREL_MARKER, WIRE_RUN, Session,
                               SessionError)
 from emqx_tpu.types import Message, SubOpts
 from emqx_tpu.utils.base62 import encode as b62encode
-from emqx_tpu.utils.guid import new_guid
+from emqx_tpu.utils.guid import new_guid, new_guids
 from emqx_tpu.zone import Zone, get_zone
 
 log = logging.getLogger("emqx_tpu.channel")
@@ -147,8 +147,17 @@ class Channel:
         self._quota = (TokenBucket(*self.zone.quota_conn_messages)
                        if self.zone.quota_conn_messages else None)
         self._quota_blocked_until = 0.0
+        # what every message published through this channel carries
+        # in its headers, rebuilt at CONNECT; a message gets a copy
+        # (tracing and the session write into theirs)
+        self._pub_headers = self._publish_headers()
 
     # -- helpers ----------------------------------------------------------
+
+    def _publish_headers(self) -> Dict[str, Any]:
+        return {"proto_ver": self.proto_ver,
+                "peerhost": self.peername[0],
+                "username": self.username}
 
     def _ack(self, ptype: int, pid: int, rc: int = RC.SUCCESS) -> PubAck:
         return PubAck(type=ptype, packet_id=pid, reason_code=rc)
@@ -279,6 +288,7 @@ class Channel:
             return self._connack_error(RC.CLIENT_IDENTIFIER_NOT_VALID)
         self.client_id = client_id
         self.username = username
+        self._pub_headers = self._publish_headers()
         # every later log line from this task carries the client
         # context (src/emqx_channel.erl:1161-1162)
         set_metadata_clientid(client_id)
@@ -460,52 +470,10 @@ class Channel:
                 # is negotiated separately in handle_deliver)
                 pkt.properties = {k: v for k, v in pkt.properties.items()
                                   if k != "Topic-Alias"}
-        try:
-            check(pkt)
-        except PacketError:
-            # wildcard/empty topic in PUBLISH is a protocol violation:
-            # disconnect, as the reference does (t_publish_wildtopic)
-            self.broker.metrics.inc("packets.publish.error")
-            return self._disconnect_with(RC.TOPIC_NAME_INVALID)
-        # quota gate — the head of the routing pipeline (reference
-        # check_quota_exceeded, src/emqx_channel.erl:458,1304-1310):
-        # while the bucket is in refill pause, QoS0 drops silently,
-        # QoS1 PUBACKs and QoS2 PUBRECs carry QUOTA_EXCEEDED (v5;
-        # v3/v4 clients get the plain ack, the reference's handle_out
-        # compat). Runs AFTER alias resolution and validation — unlike
-        # the reference's pipeline order — so a quota drop can neither
-        # swallow an alias registration the client relies on for its
-        # post-pause publishes nor mask a protocol violation that must
-        # stay fatal regardless of quota state.
-        if self._quota is not None and \
-                time.monotonic() < self._quota_blocked_until:
-            if pkt.qos == C.QOS_0:
-                self.broker.metrics.inc("packets.publish.dropped")
-                return []
-            return self._puback_for(pkt, RC.QUOTA_EXCEEDED)
-        # caps
-        cap_rc = check_pub(self.zone, pkt.qos, pkt.retain, pkt.topic)
-        if cap_rc is not None:
-            if cap_rc in PUB_DROP_CODES:
-                self.broker.metrics.inc("packets.publish.dropped")
-            return self._puback_for(pkt, cap_rc)
-        # acl
-        if self.zone.enable_acl and not self.clientinfo.get("is_superuser"):
-            if self.access.check_acl(self.clientinfo, PUB, pkt.topic,
-                                     self.acl_cache) == DENY:
-                self.broker.metrics.inc("packets.publish.auth_error")
-                self.broker.metrics.inc("client.acl.deny")
-                if self.zone.acl_deny_action == "disconnect":
-                    # src/emqx_channel.erl:470-478: deny escalates to
-                    # a disconnect when the zone says so
-                    return self._disconnect_with(RC.NOT_AUTHORIZED)
-                return self._puback_for(pkt, RC.NOT_AUTHORIZED)
-        msg = to_message(pkt, self.client_id,
-                         headers={"proto_ver": self.proto_ver,
-                                  "peerhost": self.peername[0],
-                                  "username": self.username})
-        if self.mountpoint:
-            msg.topic = mount(self.mountpoint, msg.topic)
+        refusal = self._admit_publish(pkt)
+        if refusal is not None:
+            return self._refuse_publish(pkt, *refusal)
+        msg = self._message(pkt)
         try:
             if pkt.qos == C.QOS_2:
                 self.session.check_awaiting_rel(pkt.packet_id)
@@ -535,6 +503,135 @@ class Channel:
             return [self._ack(C.PUBACK, pkt.packet_id,
                               rc if self.proto_ver == C.MQTT_V5 else 0)]
         return []
+
+    def _admit_publish(self, pkt: Publish) -> Optional[Tuple[str, int]]:
+        """The verdict on one PUBLISH (its alias resolved): packet
+        check, quota gate, zone caps, ACL, in that order. None admits;
+        else ``(why, rc)`` for :meth:`_refuse_publish`. Nothing is
+        closed and no refusal counted here, so a caller can queue
+        what it admitted before it acts on a refusal."""
+        try:
+            check(pkt)
+        except PacketError:
+            return ("packet", RC.TOPIC_NAME_INVALID)
+        # quota gate — the head of the routing pipeline (reference
+        # check_quota_exceeded, src/emqx_channel.erl:458,1304-1310):
+        # while the bucket is in refill pause, QoS0 drops silently,
+        # QoS1 PUBACKs and QoS2 PUBRECs carry QUOTA_EXCEEDED (v5;
+        # v3/v4 clients get the plain ack, the reference's handle_out
+        # compat). Runs AFTER alias resolution and validation — unlike
+        # the reference's pipeline order — so a quota drop can neither
+        # swallow an alias registration the client relies on for its
+        # post-pause publishes nor mask a protocol violation that must
+        # stay fatal regardless of quota state.
+        if self._quota is not None and \
+                time.monotonic() < self._quota_blocked_until:
+            return ("quota", RC.QUOTA_EXCEEDED)
+        cap_rc = check_pub(self.zone, pkt.qos, pkt.retain, pkt.topic)
+        if cap_rc is not None:
+            return ("caps", cap_rc)
+        if self.zone.enable_acl \
+                and not self.clientinfo.get("is_superuser") \
+                and self.access.check_acl(self.clientinfo, PUB, pkt.topic,
+                                          self.acl_cache) == DENY:
+            return ("acl", RC.NOT_AUTHORIZED)
+        return None
+
+    def _refuse_publish(self, pkt: Publish, why: str,
+                        rc: int) -> List[Packet]:
+        """Act on a refusal of :meth:`_admit_publish`: its counters,
+        the ack that carries ``rc``, or the disconnect."""
+        m = self.broker.metrics
+        if why == "packet":
+            # wildcard/empty topic in PUBLISH is a protocol violation:
+            # disconnect, as the reference does (t_publish_wildtopic)
+            m.inc("packets.publish.error")
+            return self._disconnect_with(rc)
+        if why == "quota":
+            if pkt.qos == C.QOS_0:
+                m.inc("packets.publish.dropped")
+                return []
+            return self._puback_for(pkt, rc)
+        if why == "caps":
+            if rc in PUB_DROP_CODES:
+                m.inc("packets.publish.dropped")
+            return self._puback_for(pkt, rc)
+        m.inc("packets.publish.auth_error")
+        m.inc("client.acl.deny")
+        if self.zone.acl_deny_action == "disconnect":
+            # src/emqx_channel.erl:470-478: deny escalates to
+            # a disconnect when the zone says so
+            return self._disconnect_with(rc)
+        return self._puback_for(pkt, rc)
+
+    def _message(self, pkt: Publish,
+                 guid: Optional[int] = None) -> Message:
+        """PUBLISH packet → routable message with the channel's
+        headers and mountpoint."""
+        msg = to_message(pkt, self.client_id, self._pub_headers, guid)
+        if self.mountpoint:
+            msg.topic = mount(self.mountpoint, msg.topic)
+        return msg
+
+    # the publish run ----------------------------------------------------
+
+    def handle_publish_run(self, pkts: List[Packet], start: int,
+                           stop: int) -> Tuple[int, List[Packet]]:
+        """The plain PUBLISH packets (QoS 0 and no properties, so no
+        ack and no alias) at the head of ``pkts[start:stop]``: admit
+        each as :meth:`_in_publish` does and queue them in arrival
+        order with one submit and one count a counter. Returns how
+        many packets it took, from ``start`` on, and what to send; the
+        caller goes on from there packet by packet. It takes none
+        where a PUBLISH costs more than that: a channel not
+        connected, closing, with a quota to draw per message or with
+        no ingress queue to take them. It stops at the first packet
+        that is not plain, and after one whose refusal closed the
+        channel."""
+        if (self.state != CONNECTED or self._quota is not None
+                or self.closed or self.close_after_send
+                or self.send_oob is None
+                or getattr(self.broker, "ingress", None) is None):
+            return 0, []
+        admitted: List[Publish] = []
+        out: List[Packet] = []
+        i = start
+        while i < stop:
+            pkt = pkts[i]
+            if type(pkt) is not Publish or pkt.qos or pkt.properties:
+                break
+            i += 1
+            refusal = self._admit_publish(pkt)
+            if refusal is None:
+                admitted.append(pkt)
+                continue
+            # everything before it is in the queue before the refusal
+            # acts (a disconnect publishes the will)
+            self._queue_run(admitted)
+            admitted = []
+            out.extend(self._refuse_publish(pkt, *refusal))
+            if self.closed or self.close_after_send:
+                break
+        if i > start:
+            self._queue_run(admitted)
+            self.broker.metrics.inc("packets.publish.received", i - start)
+        return i - start, out
+
+    def _queue_run(self, pkts: List[Publish]) -> None:
+        if not pkts:
+            return
+        msgs = [self._message(pkt, guid)
+                for pkt, guid in zip(pkts, new_guids(len(pkts)))]
+        if self.broker.ingress.submit_many(msgs):
+            self.broker.metrics.inc("channel.publish_run.msgs", len(msgs))
+            return
+        # no running loop (a sync driver): publish inline, as
+        # _in_publish does when the batcher hands a message back
+        for msg in msgs:
+            try:
+                self.session.publish(None, msg)
+            except SessionError:
+                pass
 
     def _ensure_quota(self, routed) -> None:
         """Post-publish quota draw (reference ensure_quota,

@@ -422,10 +422,23 @@ class Connection:
                     t0 = _now()
                     n0 = lc.inner
                 pkts = await self._decode(data)
-                for idx, pkt in enumerate(pkts or []):
-                    if not await self._process(pkt):
-                        return
-                    if idx % 32 == 31:
+                i, n = 0, len(pkts or ())
+                while i < n:
+                    # the publish run: the plain PUBLISH packets from
+                    # here to the quantum's boundary below are the
+                    # channel's to take in one call; it takes none
+                    # where the packet here is anything else
+                    done = self._process_run(pkts, i, min(n, (i | 31) + 1))
+                    if done:
+                        i += done
+                        if self.channel.close_after_send:
+                            await self._drain_and_close()
+                            return
+                    else:
+                        if not await self._process(pkts[i]):
+                            return
+                        i += 1
+                    if i % 32 == 0:
                         # bound this handler's event-loop quantum: a
                         # 64KB read can hold ~650 PUBLISHes (~20ms of
                         # channel work), and several such handlers
@@ -560,6 +573,21 @@ class Connection:
             await self._drain_and_close()
             return False
         return True
+
+    def _process_run(self, pkts, start: int, stop: int) -> int:
+        """Offer ``pkts[start:stop]`` to the channel as a publish run
+        (:meth:`Channel.handle_publish_run`); returns how many packets
+        it took, 0 where the first is no plain PUBLISH. What
+        :meth:`_process` does a packet, done once."""
+        done, out = self.channel.handle_publish_run(pkts, start, stop)
+        if not done:
+            return 0
+        self.recv_pkts += done
+        self.broker.metrics.inc("packets.received", done)
+        if out:
+            self._send_packets(out)
+        self._send_packets(self.channel.handle_deliver())
+        return done
 
     def _start_timers(self) -> None:
         loop = asyncio.get_event_loop()

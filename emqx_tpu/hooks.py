@@ -53,6 +53,12 @@ class Hooks:
     def lookup(self, name: str) -> List[Callback]:
         return list(self._chains.get(name, ()))
 
+    def has(self, name: str) -> bool:
+        """Whether any callback is registered at ``name``: a caller
+        whose fold over an empty chain is its own default can skip
+        building the fold's arguments."""
+        return bool(self._chains.get(name))
+
     def run(self, name: str, args: Tuple = ()) -> None:
         """Run the chain; a callback returning STOP halts it
         (emqx_hooks.erl do_run/2:123-135)."""

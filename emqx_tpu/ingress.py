@@ -171,26 +171,74 @@ class IngressBatcher:
         if loop is None:
             return None
         fut = loop.create_future() if want_result else None
+        first = not self._pending
         # lint: ok-CD102 single-loop mode: _plock is None and every
         # submit runs on the node's one event loop (the multi-loop
         # build takes _submit_threadsafe above instead)
         self._pending.append((msg, fut))
         self.submitted += 1
-        self.max_queue = max(self.max_queue, len(self._pending))
-        if len(self._pending) >= self.batch_size:
-            # lint: ok-CD101 single-loop mode: this thread IS the
-            # home loop, the direct flush is the legacy fast path
-            self._flush()
-        elif len(self._pending) == 1:
+        self._appended(loop, first)
+        return fut if fut is not None else self._DONE
+
+    def _appended(self, loop, first: bool) -> None:
+        """After an append in single-loop mode (this thread IS the
+        home loop): flush at the ``batch_size`` boundary, else arm
+        the tick's flush behind the accumulator's ``first`` message."""
+        n = len(self._pending)
+        if n > self.max_queue:
+            self.max_queue = n
+        if first and self.batch_size > 1:
             tel = self.broker.telemetry
             if tel is not None and tel.enabled:
                 self._t_first = time.perf_counter()
+        if n >= self.batch_size:
+            self._flush()  # the direct flush is the legacy fast path
+        elif first:
             if self.linger_ms > 0:
                 self._handle = loop.call_later(
                     self.linger_ms / 1000.0, self._flush)
             else:
                 self._handle = loop.call_soon(self._flush)
-        return fut if fut is not None else self._DONE
+
+    @any_thread
+    def submit_many(self, msgs: List[Message]) -> bool:
+        """Queue a connection's run of fire-and-forget messages
+        (QoS0: no future) as ``submit(msg, want_result=False)`` called
+        on each in turn would — a flush at exactly the ``batch_size``
+        boundary, so the batches are the same lists — with one look
+        at the loop and at tracing for the run. False = no running
+        loop, nothing queued: the caller publishes synchronously.
+
+        On a multi-loop node (``_plock``) the run is submitted one
+        message at a time: appends from peer loops interleave under
+        the lock, and the home loop owns every flush decision."""
+        trc = self.broker.tracing
+        if trc is not None and trc.active:
+            for msg in msgs:
+                trc.stamp(msg)
+        try:
+            loop = asyncio.get_running_loop()
+        except RuntimeError:
+            loop = None
+        if self._plock is not None:
+            for msg in msgs:
+                self._submit_threadsafe(msg, False, loop)
+            return True
+        if loop is None:
+            return False
+        i, n = 0, len(msgs)
+        while i < n:
+            pend = self._pending  # single-loop mode, as in submit()
+            first = not pend
+            # up to the boundary; over it (a standing backlog: every
+            # slot was busy at the last flush) one at a time, each
+            # with its own try at a flush, as submit() does
+            j = min(n, i + max(1, self.batch_size - len(pend)))
+            pend.extend([(m, None) for m in msgs[i:j]])
+            self.submitted += j - i
+            i = j
+            self._appended(loop, first)
+        return True
 
     @any_thread
     def _submit_threadsafe(self, msg: Message, want_result: bool,

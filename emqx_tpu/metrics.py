@@ -354,6 +354,15 @@ MESH_METRICS = [
     "mesh.fused",
 ]
 
+# the publish run (connection.Connection.run →
+# channel.Channel.handle_publish_run, docs/OBSERVABILITY.md "The
+# publish run"): PUBLISH packets that went from a read chunk to the
+# ingress queue inside a run (over ``messages.received``: the share
+# of ingress that skipped the per-packet path)
+CHANNEL_METRICS = [
+    "channel.publish_run.msgs",
+]
+
 ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + WILL_METRICS
                + DELIVERY_METRICS + CLIENT_METRICS + SESSION_METRICS
@@ -362,7 +371,7 @@ ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + OVERLOAD_METRICS + BREAKER_METRICS + FAULT_METRICS
                + OPS_METRICS + DURABILITY_METRICS + CLUSTER_METRICS
                + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS
-               + MESH_METRICS)
+               + MESH_METRICS + CHANNEL_METRICS)
 
 #: registry names that are NOT monotonic — ``Metrics.dec`` runs on
 #: them in steady state (today: the retainer's live-entry count,

@@ -14,6 +14,7 @@ from emqx_tpu import topic as T
 from emqx_tpu.mqtt import constants as C
 from emqx_tpu.mqtt import reason_codes as RC
 from emqx_tpu.types import Message
+from emqx_tpu.utils.guid import new_guid
 
 
 @dataclass
@@ -167,18 +168,18 @@ def _check(pkt: Packet) -> None:
 
 
 def to_message(pkt: Publish, client_id: str,
-               headers: Optional[dict] = None) -> Message:
-    """PUBLISH packet -> routable message (emqx_packet:to_message/2)."""
-    msg = Message(
-        topic=pkt.topic, payload=pkt.payload, qos=pkt.qos,
-        from_=client_id,
-        flags={"dup": pkt.dup, "retain": pkt.retain},
-    )
+               headers: Optional[dict] = None,
+               guid: Optional[int] = None) -> Message:
+    """PUBLISH packet -> routable message (emqx_packet:to_message/2).
+    The message gets its own copy of ``headers`` (tracing and the
+    session write into it); ``guid`` is its id where the caller drew
+    one (a run of them: ``utils.guid.new_guids``)."""
+    hdrs = dict(headers) if headers else {}
     if pkt.properties:
-        msg.set_header("properties", dict(pkt.properties))
-    for k, v in (headers or {}).items():
-        msg.set_header(k, v)
-    return msg
+        hdrs = {"properties": dict(pkt.properties), **hdrs}
+    return Message(pkt.topic, pkt.payload, pkt.qos, client_id,
+                   {"dup": pkt.dup, "retain": pkt.retain}, hdrs,
+                   new_guid() if guid is None else guid)
 
 
 def from_message(packet_id: Optional[int], msg: Message) -> Publish:

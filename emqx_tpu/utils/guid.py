@@ -45,6 +45,24 @@ def new_guid() -> int:
     return (ts << 64) | (_node_bits << 32) | seq
 
 
+def new_guids(n: int) -> list:
+    """``n`` ids in one turn of the lock, strictly increasing and
+    after every id handed out before: one clamped timestamp, ``n``
+    consecutive sequence numbers. A stretch that would wrap the
+    sequence is drawn one id at a time (``new_guid`` advances the
+    timestamp there)."""
+    global _seq, _last_ts
+    with _lock:
+        if _seq + n <= 0xFFFFFFFF:
+            ts = max(int(time.time() * 1_000_000), _last_ts)
+            _last_ts = ts
+            base = (ts << 64) | (_node_bits << 32)
+            first = _seq + 1
+            _seq += n
+            return [base | seq for seq in range(first, first + n)]
+    return [new_guid() for _ in range(n)]
+
+
 def guid_timestamp(guid: int) -> float:
     """Microsecond timestamp embedded in a guid, as seconds."""
     return (guid >> 64) / 1_000_000

@@ -18,3 +18,44 @@ async def broker_node(**kw):
 
 def node_port(node):
     return node.listeners[0].port
+
+
+class Wire:
+    """A ``StreamWriter`` and its transport for a ``Connection`` that
+    a test drives from an ``asyncio.StreamReader`` through
+    ``Connection.run``; keeps what was written."""
+
+    def __init__(self):
+        self.transport = self
+        self.out = bytearray()
+        self.closed = False
+
+    def get_extra_info(self, key, default=None):
+        return ("10.1.2.3", 4242) if key == "peername" else default
+
+    def write(self, data):
+        self.out += data
+
+    def writelines(self, pieces):
+        for p in pieces:
+            self.out += p
+
+    def close(self):
+        self.closed = True
+
+    abort = close
+
+    def is_closing(self):
+        return self.closed
+
+    async def drain(self):
+        pass
+
+    async def wait_closed(self):
+        pass
+
+    def get_write_buffer_size(self):
+        return 0
+
+    def set_write_buffer_limits(self, high=None, low=None):
+        pass

@@ -24,6 +24,7 @@ from emqx_tpu.telemetry import (STAGES, STALL_S, PublishSpan, Telemetry,
                                 TelemetryConfig)
 from emqx_tpu.types import Message
 
+from helpers import Wire
 from indie_mqtt import IndieClient
 
 #: the stages ISSUE 24 added to the span
@@ -220,6 +221,87 @@ async def test_loop_counters_follow_the_telemetry_gate(enabled):
             assert vals["loop.wall.ns"] > vals["loop.read.ns"]
         await pub.disconnect()
         await sub.disconnect()
+    finally:
+        await node.stop()
+
+
+async def _read_chunk(node, n_pubs, runs=True, topic="rs/t"):
+    """One connection, a CONNECT, then ``n_pubs`` plain QoS 0
+    publishes fed as ONE chunk; returns what the chunk moved."""
+    from emqx_tpu.connection import Connection
+    from emqx_tpu.mqtt.frame import serialize
+    from emqx_tpu.mqtt.packet import Connect, Publish
+
+    reader = asyncio.StreamReader()
+    conn = Connection(reader, Wire(), node.broker, node.cm)
+    if not runs:
+        conn.channel.handle_publish_run = lambda pkts, i, stop: (0, [])
+    task = asyncio.get_running_loop().create_task(conn.run())
+    reader.feed_data(serialize(Connect(client_id="rs", keepalive=0), 4))
+    for _ in range(5):
+        await asyncio.sleep(0)
+    assert conn.channel.state == "connected"
+    m = node.metrics
+    base = m.all()
+    reader.feed_data(b"".join(
+        serialize(Publish(topic=topic, qos=0, payload=b"x"), 4)
+        for _ in range(n_pubs)))
+    for _ in range(20):
+        await asyncio.sleep(0)
+    await node.broker.ingress.drain()
+    moved = {k: v - base[k] for k, v in m.all().items()}
+    reader.feed_eof()
+    await asyncio.wait_for(task, 5)
+    return moved
+
+
+@pytest.mark.parametrize("runs", [True, False])
+async def test_read_slices_close_at_the_32_packet_yield(runs):
+    """100 packets in one chunk: the handler gives the loop back
+    after packets 32, 64 and 96, runs engaged or not, and each slice
+    is one ``loop.read.calls``."""
+    node = Node(name=f"slices{int(runs)}@test", boot_listeners=False)
+    await node.start()
+    try:
+        moved = await _read_chunk(node, 100, runs)
+        assert moved["loop.read.calls"] == 4
+        assert moved["loop.read.ns"] > 0
+        assert moved["packets.received"] == 100
+        assert moved["channel.publish_run.msgs"] == (100 if runs else 0)
+        moved = await _read_chunk(node, 64, runs)
+        # packets 32 and 64 each end a slice; the chunk's end a third
+        assert moved["loop.read.calls"] == 3
+    finally:
+        await node.stop()
+
+
+@pytest.mark.parametrize("runs", [True, False])
+async def test_batch_flushed_inside_a_read_is_not_read_time(runs):
+    """A batch that fills at the ``batch_size`` boundary is flushed
+    from inside the read chunk — from ``submit_many`` inside a run as
+    from ``submit`` packet by packet — and its stages are no part of
+    ``loop.read.ns``."""
+    node = Node(name=f"nest{int(runs)}@test", boot_listeners=False,
+                batch_size=8)
+    await node.start()
+    try:
+        slept = [0]
+
+        def slow(msg):
+            if msg.topic == "rs/slow":
+                time.sleep(0.004)       # inside the span's `prepare`
+                slept[0] += 1
+            return msg
+        node.broker.hooks.add("message.publish", slow)
+        flushes0 = node.broker.ingress.flushes
+        moved = await _read_chunk(node, 31, runs, topic="rs/slow")
+        assert slept[0] == 31
+        # 24 of them slept inside flushes taken at the boundary,
+        # inside the read chunk: ≥ 96 ms that the read does not own
+        assert node.broker.ingress.flushes - flushes0 >= 4
+        assert moved["loop.read.calls"] == 1
+        assert moved["loop.read.ns"] < 40_000_000, moved["loop.read.ns"]
+        assert moved["channel.publish_run.msgs"] == (31 if runs else 0)
     finally:
         await node.stop()
 
