@@ -1064,30 +1064,49 @@ class Broker:
     @bg_thread
     def warm_device_path(self) -> int:
         """Device-loss recovery, step 3 (devloss.py): execute the
-        real dispatch → fetch kernel chain once per observed batch
-        shape on the recovery thread, so the first post-recovery
-        publish batch pays zero compile (docs/ROBUSTNESS.md
-        "Device-loss recovery"). Drives :meth:`_begin_device` /
-        :meth:`_fetch_device` over synthetic NUL-rooted topics
-        (ops/warmup.py) that no real filter can match — nothing
-        delivers, no hooks or message metrics fire, and the fan-out
+        real dispatch → fetch kernel chain once for every program the
+        match dispatch can be asked for by a batch no larger than
+        live traffic has formed (the learned pack-budget keys ARE the
+        observed batch buckets), on the recovery thread, so the first
+        post-recovery publish batch pays zero compile
+        (docs/ROBUSTNESS.md "Device-loss recovery"). The fan-out
         manager's device tables re-derive at the rebuilt epoch as a
-        side effect. Returns the number of warmed buckets."""
-        from emqx_tpu.ops.warmup import warm_plan
+        side effect. Returns the number of warmed batches."""
+        return sum(1 for _ in self.warm_dispatch(
+            max(self._pack_budgets, default=1)))
 
-        cfg = self.router.config
-        warmed = 0
-        for _bucket, topics in warm_plan(
-                self._pack_budgets, cfg.min_batch,
-                levels=self.router.observed_levels()):
+    def warm_dispatch(self, max_topics: int):
+        """Drive every batch of :meth:`Router.dispatch_shapes` — one
+        for each (miss bucket × depth) walk variant and each (batch,
+        hit, miss) merge triple a batch of up to ``max_topics`` unique
+        topics can ask for (the ingress forms up to its
+        ``batch_cap``) — through
+        :meth:`_begin_device` / :meth:`_fetch_device`, over synthetic
+        NUL-rooted topics (ops/warmup.py) that no real filter can
+        match: nothing delivers, no hooks or message metrics fire.
+        After it, traffic of those sizes first-uses no program of the
+        dispatch; what the broker learns from traffic (pack budgets,
+        ``boost_k``) it still learns from traffic.
+
+        A generator, one batch a step, yielding ``(seconds, shape)``
+        as each is fetched: a caller on the event loop yields to the
+        loop between batches, the device-loss rewarm runs it through
+        on its own thread."""
+        from emqx_tpu.ops.warmup import warm_batches
+        from emqx_tpu.router import DispatchShape
+
+        router = self.router
+        cfg = router.config
+        for shape, topics in warm_batches(
+                router.dispatch_shapes(max_topics), router.cache_slots()):
+            t0 = time.monotonic()
             pb = PendingBatch()
             pb.results = [0] * len(topics)
             pb.live = [(i, Message(topic=t, payload=b""))
                        for i, t in enumerate(topics)]
             self._begin_device(pb, topics, cfg)
             self._fetch_device(pb)
-            warmed += 1
-        return warmed
+            yield time.monotonic() - t0, DispatchShape(*shape)
 
     @owner_loop
     def publish_finish(self, pb: PendingBatch) -> List[int]:

@@ -354,6 +354,17 @@ MESH_METRICS = [
     "mesh.fused",
 ]
 
+# the one-chip match dispatch, per batch (router.py's
+# ``_count_dispatch``; gated on [telemetry] enabled like the mesh's
+# above, whose twins they are): ``topics`` = unique topics dispatched
+# (after the batch's own dedup, before padding), ``walk.topics`` =
+# those of them that walked the automaton; the rest were gathered
+# from the match cache. Current at any instant, where
+# ``cache.match.hit`` / ``.miss`` wait for the stats flush
+DISPATCH_METRICS = [
+    "dispatch.topics", "dispatch.walk.topics",
+]
+
 # the publish run (connection.Connection.run →
 # channel.Channel.handle_publish_run, docs/OBSERVABILITY.md "The
 # publish run"): PUBLISH packets that went from a read chunk to the
@@ -371,7 +382,7 @@ ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + OVERLOAD_METRICS + BREAKER_METRICS + FAULT_METRICS
                + OPS_METRICS + DURABILITY_METRICS + CLUSTER_METRICS
                + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS
-               + MESH_METRICS + CHANNEL_METRICS)
+               + MESH_METRICS + DISPATCH_METRICS + CHANNEL_METRICS)
 
 #: registry names that are NOT monotonic — ``Metrics.dec`` runs on
 #: them in steady state (today: the retainer's live-entry count,

@@ -89,8 +89,10 @@ arrays wide and goes on to ``pack_fanout`` from dense rows:
   - **the padding rule is a contract** with the benchmark's sweep
     (``benchmark/warmers/mesh_buckets.py`` walks every (batch, hit,
     miss) triple through ``publish_batch``): batch and misses pad to
-    a power of two from ``min_batch × data``, hits from ``_MIN_PAD``.
-    Change it and runs first use programs inside their window.
+    a power of two from ``min_batch × data`` (``Router.pad_topics``),
+    hits from ``_MIN_PAD`` (:func:`pad_hits`); ``Router.
+    dispatch_shapes`` lists the batches the rule allows. Change it and
+    runs first use programs inside their window.
 """
 
 from __future__ import annotations
@@ -104,7 +106,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["MatchCache", "MeshLayout", "MESH_BUF_FLOOR", "flag_rows",
-           "insert_rows"]
+           "insert_rows", "pad_hits", "ring_slots"]
 
 #: flag column values: _VALID = cached ids are the exact match set;
 #: _OVF = the walk overflowed (host fallback, match-only bound);
@@ -127,6 +129,18 @@ def _pow2(n: int, floor: int = 1) -> int:
     while out < n:
         out *= 2
     return out
+
+
+def ring_slots(slots: int) -> int:
+    """The slots a cache configured with ``slots`` really has."""
+    return _pow2(max(2, int(slots)))
+
+
+def pad_hits(n: int) -> int:
+    """The padded length of a batch's ``n`` cache hits (a shape of the
+    merge's program): a power of two from ``_MIN_PAD``, also for none.
+    ``Router.dispatch_shapes`` lists the programs from it."""
+    return _pow2(max(n, 1), _MIN_PAD)
 
 
 @functools.partial(jax.jit, static_argnames=("b_pad",))
@@ -276,7 +290,7 @@ class MatchCache:
     """
 
     def __init__(self, slots: int, width: int, sharding=None) -> None:
-        self.slots = _pow2(max(2, int(slots)))
+        self.slots = ring_slots(slots)
         self.width = int(width)
         # where the table lives: None = the default device; the mesh
         # cache passes the mesh's replicated sharding, so its programs
@@ -387,7 +401,7 @@ class MatchCache:
         ``(ids[b_pad, width], ovf[b_pad], movf[b_pad])`` device
         arrays. Pass the miss walk outputs (or nothing when the batch
         fully hit)."""
-        hb = _pow2(max(len(probe.hit_pos), 1), _MIN_PAD)
+        hb = pad_hits(len(probe.hit_pos))
         hit_slots = np.zeros((hb,), np.int32)
         hit_pos = np.full((hb,), b_pad, np.int32)  # OOB pad -> drop
         if probe.hit_pos:
@@ -415,7 +429,7 @@ class MatchCache:
         the layout's is that or the next power of two that holds the
         batch."""
         mb = 0 if enc is None else int(enc[0].shape[0])
-        hb = _pow2(max(len(probe.hit_pos), 1), _MIN_PAD)
+        hb = pad_hits(len(probe.hit_pos))
         lay = MeshLayout(levels, mb, hb, _pow2(
             MeshLayout.need(levels, mb, hb), max(size, MESH_BUF_FLOOR)))
         buf = np.zeros((lay.size,), np.int32)

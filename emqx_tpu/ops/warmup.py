@@ -1,77 +1,60 @@
-"""Kernel re-warm planning for device-loss recovery.
+"""Warm batches for the match dispatch's shape list.
 
-After :meth:`Router.rebuild_device_state` publishes fresh tables on a
-fresh backend, the walk/fetch jit kernels for the batch shapes live
-traffic actually uses must be executed once OFF the hot path — the
-first post-recovery publish batch must pay zero compile
-(docs/ROBUSTNESS.md "Device-loss recovery").
+:meth:`Router.dispatch_shapes` lists one batch — so many cache hits,
+so many misses, the deepest miss so many levels — for every program
+the dispatch can be asked for. This module turns that list into the
+topics of each batch; the device work happens in
+``Broker.warm_dispatch``, which drives the REAL ``_begin_device`` /
+``_fetch_device`` seams over them, so exactly the production kernel
+set compiles: encode → walk (cache-miss shape) → cache insert and
+merge → pack → fan-out expand → bundle → fetch. The device-loss
+rewarm (``Broker.warm_device_path``, docs/ROBUSTNESS.md "Device-loss
+recovery") and a harness's warm-up are the same walk.
 
-This module is pure host planning (no jax imports, nothing to sync —
-the device work happens in ``Broker.warm_device_path``, which drives
-the REAL ``_begin_device``/``_fetch_device`` seams over the batches
-planned here, so exactly the production kernel set compiles: encode →
-walk (cache-miss shape) → pack → fan-out expand → bundle → fetch).
-
-Synthetic warm topics are rooted at ``"\\x00devloss"`` — no real
-filter matches them (MQTT topics cannot contain NUL), so a warm batch
-delivers nothing, and their match-cache entries are ordinary slots
-that age out under the clock sweep.
+Pure host planning (no jax imports, nothing to sync). Warm topics are
+rooted at ``"\\x00devloss"`` — no real filter matches them (MQTT
+topics cannot contain NUL), so a warm batch delivers nothing, and
+their match-cache entries are ordinary slots that age out under the
+clock sweep.
 """
 
 from __future__ import annotations
 
-from typing import Iterable, List, Tuple
+import itertools
+from typing import Iterable, Iterator, List, Tuple
 
-#: bound on warm batches per recovery: the floor bucket plus the
-#: largest observed live buckets (each is one compile family)
-MAX_WARM_BUCKETS = 4
-
-
-def warm_buckets(observed: Iterable[int], min_batch: int,
-                 cap: int = MAX_WARM_BUCKETS) -> List[int]:
-    """The padded-batch buckets worth warming: the configured floor
-    bucket (every small batch lands there) plus the largest buckets
-    live traffic was actually seen using (``Broker._pack_budgets``
-    keys — the budget table is learned per bucket, so its key set IS
-    the observed shape set)."""
-    buckets = sorted({int(b) for b in observed if int(b) > 0}
-                     | {int(min_batch)})
-    return buckets[-max(1, cap):]
+ROOT = "\x00devloss"
 
 
-def warm_topics(bucket: int, min_batch: int,
-                levels: int = 4) -> List[str]:
-    """A unique-topic list whose padded dispatch lands exactly in
-    ``bucket``: the dispatch pads to the smallest power-of-two bucket
-    ≥ the topic count (floored at ``min_batch``), so ``bucket//2 + 1``
-    topics select ``bucket`` for any bucket above the floor.
+def warm_batches(shapes: Iterable[Tuple[int, int, int]], slots: int
+                 ) -> Iterator[Tuple[Tuple[int, int, int], List[str]]]:
+    """``(shape, topics)`` for every ``(hits, misses, depth)`` of
+    ``shapes``, in their order: ``hits`` topics an earlier batch of
+    this walk left in the match cache, then ``misses`` fresh ones, the
+    first of them ``depth`` levels deep (the walk compiles for the
+    batch's deepest topic; one spine selects the variant, the rest
+    stay at two levels).
 
-    ``levels`` pins the batch's level-bucket shape: the walk slices
-    its level axis to the batch's deepest topic (``depth_bucket``)
-    and compiles per resulting depth, so the FIRST topic carries
-    exactly ``levels`` levels — one deep spine is enough to select
-    the compile family, the rest stay short."""
-    n = 1 if bucket <= min_batch else bucket // 2 + 1
-    out = ["\x00devloss/warm/%d/%d" % (bucket, i) for i in range(n)]
-    spine = ["\x00devloss", "warm", str(bucket), "0"][:max(2, levels)]
-    spine += ["d"] * (max(2, levels) - len(spine))
-    out[0] = "/".join(spine)
-    return out
-
-
-def warm_plan(observed: Iterable[int], min_batch: int,
-              cap: int = MAX_WARM_BUCKETS,
-              levels: Iterable[int] = ()
-              ) -> List[Tuple[int, List[str]]]:
-    """``(bucket, topics)`` warm batches, smallest bucket first (the
-    floor bucket compiles fastest — recovery reaches "some shape is
-    warm" as early as possible). ``levels`` is the set of observed
-    level-bucket shapes (``Router.observed_levels``) — each is its
-    own compile family, so every bucket replays every depth; the
-    compressed-walk deep buckets (16-level spines, ISSUE 16) warm
-    here exactly like the shallow ones. Empty = the historical
-    4-level shape only."""
-    lvls = sorted({int(l) for l in levels if int(l) >= 2}) or [4]
-    return [(b, warm_topics(b, min_batch, lv))
-            for b in warm_buckets(observed, min_batch, cap)
-            for lv in lvls]
+    ``slots`` is the cache's size. The hits are the head of a *hot*
+    list that one batch of fresh topics put there — an extra batch,
+    yielded under its own shape ``(0, n, 2)``, before the first shape
+    that needs it — and the clock sweep takes a slot for every fresh
+    topic since, so the list is laid again once the sweep has reached
+    it. With the cache off (``slots`` 0) no shape has hits."""
+    shapes = list(shapes)
+    fresh = itertools.count()
+    n_hot = max((h for h, _m, _d in shapes), default=0)
+    hot: List[str] = []
+    room = -1  # fresh topics the ring takes before it reaches `hot`
+    for shape in shapes:
+        hits, misses, depth = shape
+        if hits and room < 0:
+            hot = [f"{ROOT}/h{next(fresh)}" for _ in range(n_hot)]
+            room = slots - n_hot
+            yield (0, n_hot, 2), list(hot)
+        topics = hot[:hits]
+        for i in range(misses):
+            tail = ["d"] * ((depth if i == 0 else 2) - 2)
+            topics.append("/".join([ROOT, f"m{next(fresh)}"] + tail))
+        room -= misses
+        yield shape, topics

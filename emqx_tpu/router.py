@@ -30,7 +30,7 @@ import time
 import zlib
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -45,6 +45,19 @@ from emqx_tpu.ops.tokenize import WordTable, encode_batch
 from emqx_tpu.types import Route
 
 log = logging.getLogger("emqx_tpu.router")
+
+
+class DispatchShape(NamedTuple):
+    """One batch as the match dispatch's programs see it: how many of
+    its unique topics the match cache serves, how many walk the
+    automaton, and the levels of the deepest that walks. The padding
+    rule (:meth:`Router.shape_programs`) turns it into the shapes the
+    programs are compiled for; :meth:`Router.dispatch_shapes` lists
+    one batch for every program."""
+
+    hits: int
+    misses: int
+    depth: int
 
 
 @dataclass
@@ -1547,6 +1560,104 @@ class Router:
             self._publish_pair_locked()
             self._device_suspended = False
 
+    # -- the dispatch's shapes (the one padding rule, and its list) --------
+
+    def pad_topics(self, n: int) -> int:
+        """The padding rule of the match dispatch: a batch's unique
+        topics, and those of them that miss the match cache, each pad
+        to a power of two from ``min_batch`` (on a mesh from
+        ``min_batch × data``: a bucket has to split evenly over the
+        data axis); the cache pads the hits (``match_cache.pad_hits``).
+        Every padded length is a shape some program is compiled for."""
+        cfg = self.config
+        bucket = cfg.min_batch
+        if cfg.mesh is not None:
+            bucket *= cfg.mesh.shape["data"]
+        while bucket < n:
+            bucket *= 2
+        return bucket
+
+    def cache_slots(self) -> int:
+        """Topics the publish match cache holds (0 = disabled): no
+        batch hits more."""
+        cfg = self.config
+        if not cfg.match_cache or cfg.match_cache_slots <= 0:
+            return 0
+        from emqx_tpu.ops.match_cache import ring_slots
+
+        return ring_slots(cfg.match_cache_slots)
+
+    def shape_programs(self, shape: DispatchShape):
+        """``(walk, merge)``: the keys of the programs a batch of this
+        shape asks the dispatch for. ``walk`` = ``(miss bucket,
+        depth)``, the automaton walk (with the cache's insert) over
+        the topics that miss, None where none does; the mesh encodes
+        at ``max_levels`` whatever the topics, so its depth is that.
+        ``merge`` = ``(batch, hit, miss)`` buckets, the cache's merge
+        (miss 0 = the batch fully hit); None with the match cache
+        off, where the batch walks whole. The packers and the fetch's
+        bundle that follow are keyed by the batch bucket and the
+        budgets the broker learns for it."""
+        cfg = self.config
+        hits, misses, depth = shape
+        if cfg.mesh is not None:
+            depth = cfg.max_levels
+        if not self.cache_slots():
+            return (self.pad_topics(hits + misses), depth), None
+        from emqx_tpu.ops.match_cache import pad_hits
+
+        mb = self.pad_topics(misses) if misses else 0
+        return ((mb, depth) if misses else None,
+                (self.pad_topics(hits + misses), pad_hits(hits), mb))
+
+    def dispatch_shapes(self, max_topics: int) -> List[DispatchShape]:
+        """One batch for every program the match dispatch can be asked
+        for by a batch of up to ``max_topics`` unique topics (the
+        ingress forms up to ``batch_cap``): every miss bucket at every
+        depth from 2 to the deepest level live traffic has used
+        (:meth:`observed_levels`), then every reachable (batch, hit,
+        miss) triple of the cache's merge, smallest first. A bucket is
+        reached by the fewest and by the most topics that pad to it,
+        and a batch hits no more topics than the cache holds.
+
+        This is the list the served path must have met before traffic
+        is free of first-use stalls (2–7 s each on the event loop):
+        ``Broker.warm_dispatch`` drives it through the real seams, for
+        the device-loss rewarm and for a harness's warm-up. The
+        learned axes (``boost_k`` / ``boost_d``, the pack budgets, a
+        pending delta) are at what they are now."""
+        from emqx_tpu.ops.match_cache import pad_hits
+
+        floor, top = self.pad_topics(1), self.pad_topics(max_topics)
+        buckets = [floor]
+        while buckets[-1] < top:
+            buckets.append(buckets[-1] * 2)
+
+        def ends(b: int, lowest: int, most: int):
+            # the fewest and the most topics that pad to bucket b
+            return (1 if b == lowest else b // 2 + 1, min(b, most))
+
+        depths = [2] if self.config.mesh is not None else list(
+            range(2, max(self.observed_levels() + [2]) + 1))
+        shapes = [DispatchShape(0, ends(mb, floor, top)[0], d)
+                  for mb in buckets for d in depths]
+        slots = self.cache_slots()
+        if not slots:
+            return shapes
+        done = {self.shape_programs(s)[1] for s in shapes}
+        hb = pad_hits(0)
+        while hb <= pad_hits(min(top, slots)):
+            for mb in [0] + buckets:
+                for h in ends(hb, pad_hits(0), min(top, slots)):
+                    for m in ends(mb, floor, top) if mb else (0,):
+                        shape = DispatchShape(h, m, depths[-1])
+                        merge = self.shape_programs(shape)[1]
+                        if h + m <= top and merge not in done:
+                            done.add(merge)
+                            shapes.append(shape)
+            hb *= 2
+        return shapes
+
     def match_dispatch(self, topics: Sequence[str]):
         """Dispatch-only device match: encode + enqueue the compiled
         walk and return WITHOUT any device→host sync.
@@ -1571,9 +1682,8 @@ class Router:
             auto, id_map, epoch = main[:3]
         else:
             auto, id_map, epoch = self.automaton()
-        bucket = cfg.min_batch
-        while bucket < len(topics):
-            bucket *= 2
+        self._count_dispatch(len(topics), len(topics))
+        bucket = self.pad_topics(len(topics))
         padded = list(topics) + ["\x00/pad"] * (bucket - len(topics))
         # the word table must not be read (wt_lookup) while a
         # concurrent add_route interns into it — ctypes calls drop
@@ -1605,7 +1715,7 @@ class Router:
         """The single-chip publish match cache, lazily built (None =
         disabled by config)."""
         cfg = self.config
-        if not cfg.match_cache or cfg.match_cache_slots <= 0:
+        if not self.cache_slots():
             return None
         if self._match_cache_obj is None:
             from emqx_tpu.ops.match_cache import MatchCache
@@ -1647,19 +1757,16 @@ class Router:
             keys = [key + (part_snap[zlib.crc32(
                 t.partition("/")[0].encode()) & mask],)
                 for t in topics]
-        bucket = cfg.min_batch
-        while bucket < len(topics):
-            bucket *= 2
+        bucket = self.pad_topics(len(topics))
         tel = self.telemetry
         timed = tel is not None and tel.enabled
         t0 = time.perf_counter() if timed else 0.0
         probe = cache.probe(topics, key, keys)
         t1 = time.perf_counter() if timed else 0.0
+        self._count_dispatch(len(topics), len(probe.miss_topics))
         miss_rows = miss_ovf = None
         if probe.miss_topics:
-            mb = cfg.min_batch
-            while mb < len(probe.miss_topics):
-                mb *= 2
+            mb = self.pad_topics(len(probe.miss_topics))
             padded = list(probe.miss_topics) + \
                 ["\x00/pad"] * (mb - len(probe.miss_topics))
             with self._wt_lock:
@@ -1709,8 +1816,7 @@ class Router:
                 continue
             for k2, v in c.drain_stats().items():
                 out[k2] = out.get(k2, 0) + v
-        cfg = self.config
-        if cfg.match_cache and cfg.match_cache_slots > 0:
+        if self.cache_slots():
             g, p = self._bump_global, self._bump_partition
             out["bump.global"] = g - self._bump_drained[0]
             out["bump.partition"] = p - self._bump_drained[1]
@@ -1734,10 +1840,9 @@ class Router:
         """Partition epoch keys in effect for the publish match cache
         (the ``match.cache.partition.live`` gauge): 0 = cache
         disabled, 1 = legacy whole-epoch, else ``cache_partitions``."""
-        cfg = self.config
-        if not cfg.match_cache or cfg.match_cache_slots <= 0:
+        if not self.cache_slots():
             return 0
-        return cfg.cache_partitions
+        return self.config.cache_partitions
 
     def quarantined_ids(self) -> int:
         """Freed filter ids quarantined until the next flatten (the
@@ -1939,17 +2044,37 @@ class Router:
         return tuple(x if x is None else mask_pad_rows(x, n)
                      for x in out[:3]) + out[3:]
 
+    def _live_metrics(self):
+        """Where the dispatch's per-batch counters go: the node's
+        Metrics while [telemetry] is enabled, like the loop's
+        counters; else None, and the dispatch counts nothing."""
+        tel = self.telemetry
+        if tel is not None and tel.loop_clock() is not None:
+            return tel.metrics
+        return None
+
+    def _count_dispatch(self, topics: int, walked: int) -> None:
+        """One batch of the one-chip match dispatch
+        (metrics.DISPATCH_METRICS): its unique topics, and those that
+        walk the automaton (the rest are the match cache's gather),
+        before padding; stamped per batch, so current at any instant,
+        where ``cache.match.hit`` / ``.miss`` wait for the stats
+        flush."""
+        m = self._live_metrics()
+        if m is not None:
+            m.inc("dispatch.topics", topics)
+            m.inc("dispatch.walk.topics", walked)
+
     def _count_mesh(self, events: str, topics: Optional[str] = None,
                     n: int = 0) -> None:
         """One event of the mesh dispatch and the unique topics it
         carries (metrics.MESH_METRICS), stamped where the decision is
-        taken; live only while [telemetry] is enabled, like the
-        loop's counters."""
-        tel = self.telemetry
-        if tel is not None and tel.loop_clock() is not None:
-            tel.metrics.inc(events)
+        taken."""
+        m = self._live_metrics()
+        if m is not None:
+            m.inc(events)
             if topics is not None:
-                tel.metrics.inc(topics, n)
+                m.inc(topics, n)
 
     def _sharded_cache_for(self, n_trie: int, d: int):
         """The mesh publish cache, sized for the CURRENT (T, m, d)
@@ -1996,7 +2121,7 @@ class Router:
         from emqx_tpu.parallel.sharded import publish_step_insert
 
         cfg = self.config
-        if not cfg.match_cache or cfg.match_cache_slots <= 0:
+        if not self.cache_slots():
             return None
         boosts = (self._k_boost, self._d_boost)
         # partition revisions snapshot BEFORE the automaton snapshot
@@ -2018,10 +2143,7 @@ class Router:
             keys = [key + (part_snap[zlib.crc32(
                 t.partition("/")[0].encode()) & mask],)
                 for t in topics]
-        unit = cfg.min_batch * cfg.mesh.shape["data"]
-        bucket = unit
-        while bucket < len(topics):
-            bucket *= 2
+        bucket = self.pad_topics(len(topics))
         tel = self.telemetry
         timed = tel is not None and tel.enabled
         t0 = time.perf_counter() if timed else 0.0
@@ -2030,9 +2152,7 @@ class Router:
         misses = probe.miss_topics
         enc = None
         if misses:
-            mb = unit
-            while mb < len(misses):
-                mb *= 2
+            mb = self.pad_topics(len(misses))
             padded = list(misses) + ["\x00/pad"] * (mb - len(misses))
             with self._wt_lock:
                 enc = self._encode(padded, cfg.max_levels)
@@ -2090,10 +2210,7 @@ class Router:
         # reverse
         rev = self._mut_rev
         B = len(topics)
-        unit = cfg.min_batch * mesh.shape["data"]
-        bucket = unit  # bucket must split evenly over the data axis
-        while bucket < B:
-            bucket *= 2
+        bucket = self.pad_topics(B)
         padded = list(topics) + ["\x00/pad"] * (bucket - B)
         with self._wt_lock:
             ids, n, sysm = self._encode(padded, cfg.max_levels)

@@ -59,3 +59,24 @@ class Wire:
 
     def set_write_buffer_limits(self, high=None, low=None):
         pass
+
+
+class Compiles:
+    """Programs made ready for first use, as the benchmark's
+    ``CompileClock`` counts them (a backend compile and a load from
+    the persistent cache alike). ``close()`` when done."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.compiles = 0
+        mon.register_event_duration_secs_listener(self._dur)
+
+    def _dur(self, name, secs, **_kw):
+        if name.endswith("backend_compile_duration"):
+            self.compiles += 1
+
+    def close(self):
+        from jax._src import monitoring
+
+        monitoring.unregister_event_duration_listener(self._dur)

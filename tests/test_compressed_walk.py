@@ -215,8 +215,10 @@ def test_compressed_walk_devloss_and_checkpoint(tmp_path):
 def test_rewarm_plan_covers_deep_buckets():
     """Devloss rewarm must replay every observed level-bucket shape
     (each is its own compile family): a router that served 16-level
-    traffic gets a 16-level warm spine per bucket (ISSUE 16)."""
-    from emqx_tpu.ops.warmup import warm_plan, warm_topics
+    traffic gets a 16-level warm spine per bucket (ISSUE 16). Since
+    the router lists its own dispatch shapes the rewarm's batches are
+    that list's (``Router.dispatch_shapes`` → ``warm_batches``)."""
+    from emqx_tpu.ops.warmup import warm_batches
 
     r = _mk()
     for f in ["a/b", "/".join(["s0"] * 16)]:
@@ -225,12 +227,14 @@ def test_rewarm_plan_covers_deep_buckets():
     r.match_filters(["/".join(["s0"] * 16)])
     seen = r.observed_levels()
     assert 16 in seen
-    plan = warm_plan([8, 64], 8, levels=seen)
-    # every (bucket, level) pair present; the first topic of a deep
-    # batch carries exactly the deep level count (depth_bucket keys
-    # the compile on the batch's deepest topic)
-    depths = {(b, len(topics[0].split("/"))) for b, topics in plan}
+    plan = list(warm_batches(r.dispatch_shapes(64), r.cache_slots()))
+    # every (bucket, level) pair present; the first fresh topic of a
+    # deep batch carries exactly the deep level count (depth_bucket
+    # keys the compile on the batch's deepest topic)
+    depths = {(r.pad_topics(len(topics)), len(topics[0].split("/")))
+              for (hits, _m, _d), topics in plan if not hits}
     for b in (8, 64):
         for lv in seen:
             assert (b, lv) in depths
-    assert len(warm_topics(64, 8, levels=16)) == 33  # bucket select
+    # bucket select: the fewest topics that pad to 64
+    assert [len(t) for s, t in plan if s == (0, 33, 16)] == [33]

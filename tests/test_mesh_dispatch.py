@@ -32,6 +32,7 @@ from emqx_tpu.parallel.sharded import publish_step_insert
 from emqx_tpu.router import MatcherConfig, Router
 from emqx_tpu.types import Message
 from emqx_tpu.utils.batch import dedup_topics
+from helpers import Compiles
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -314,29 +315,9 @@ def test_a_warm_batch_is_one_transfer_and_two_or_three_programs(
 # -- the programs a run loads -------------------------------------------------
 
 
-class _Compiles:
-    """Programs made ready for first use, as the benchmark's
-    ``CompileClock`` counts them."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-
-        self.compiles = 0
-        mon.register_event_duration_secs_listener(self._dur)
-
-    def _dur(self, name, secs, **_kw):
-        if name.endswith("backend_compile_duration"):
-            self.compiles += 1
-
-    def close(self):
-        from jax._src import monitoring
-
-        monitoring.unregister_event_duration_listener(self._dur)
-
-
 @pytest.fixture
 def compiles():
-    c = _Compiles()
+    c = Compiles()
     yield c
     c.close()
 
