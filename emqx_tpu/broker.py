@@ -20,6 +20,7 @@ time; here ingress batches per tick, SURVEY §2.2).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import logging
 import threading
@@ -84,15 +85,32 @@ class _PlanState:
     between its prologue (per-row routing) and group chunks — plus,
     on a multi-loop node, the cross-loop delivery ring's join state
     (docs/DISPATCH.md "Multi-loop front door"): the set of handed-off
-    groups, the per-handoff delivered-count results, and the events
+    groups, the per-handoff accepted deliveries, and the events
     the fold joins on. Everything after the prologue is read-only to
     the handoff loops except the ``xloop_*`` fields, which mutate
-    under ``xloop_lock``."""
+    under ``xloop_lock``.
 
-    __slots__ = ("row_local", "row_fast", "ftabs", "counts",
-                 "xg_set", "xloop_results", "xloop_deliveries",
+    ``accepted`` is what the group walks delivered, one entry a
+    delivery: the live row, or ``(row, filter)`` when ``hooked``
+    (a ``message.delivered`` callback is registered, so the fold
+    owes it every (message, filter) count); ``resolves`` counts the
+    walks' (group, filter) resolutions (``delivery.plan.resolves``)."""
+
+    __slots__ = ("row_local", "row_fast", "ftabs", "accepted",
+                 "hooked", "resolves",
+                 "xg_set", "xloop_results", "xloop_resolves",
                  "xloop_left", "xloop_lock", "xloop_tev", "xloop_aev",
                  "xloop_t0", "xloop_tdone", "folded")
+
+
+#: what the planned tail's prologue knows of a matched filter id whose
+#: one destination is this node, and of one that routes nowhere (freed
+#: in the snapshot's id map): shared constants, so a distinct filter of
+#: a batch costs two dict gets and builds no object. Any other filter
+#: (a shared group, a remote node, a route deleted since the snapshot)
+#: is a ``(filter, local, shared_items, remote_nodes)`` tuple
+_DEST_LOCAL = ("local",)
+_DEST_NONE = ("none",)
 
 
 class PendingBatch:
@@ -1131,80 +1149,111 @@ class Broker:
     @owner_loop
     def _plan_prologue(self, pb: PendingBatch) -> None:
         """Per-batch routing pass before grouped delivery: classify
-        every matched filter id ONCE (local / shared / remote —
-        ``lookup_routes`` per unique fid per batch, not per message),
-        then walk the live rows in order doing only the per-message
-        host work the plan cannot carry: no-subscriber drops, shared-
-        group picks, remote forwards. Local delivery is the plan's."""
+        every matched filter id ONCE (local / shared / remote — per
+        distinct fid of the batch, not per message) and every unique
+        topic once (its filters are the same for each message that
+        bears it), then walk the live rows in order doing only the
+        per-message host work the plan cannot carry: no-subscriber
+        drops, shared-group picks, remote forwards. Local delivery is
+        the plan's.
+
+        A filter whose one destination is this node resolves to a
+        shared constant from the route table's own destinations (no
+        ``Route``, dict or tuple is built); a topic all of whose
+        filters are such costs its later messages one byte read."""
         ps = _PlanState()
-        n_live = len(pb.live)
-        ps.row_local = bytearray(n_live)
-        ps.row_fast = bytearray(n_live)
-        ps.counts = [None] * n_live
-        ps.ftabs = {}
+        live = pb.live
+        n_live = len(live)
+        row_local = ps.row_local = bytearray(n_live)
+        row_fast = ps.row_fast = bytearray(n_live)
+        ftabs = ps.ftabs = {}
+        ps.accepted = []
+        ps.resolves = 0
+        # the one thing the fold owes a ``message.delivered`` callback
+        # is the per-(message, filter) count: kept only while one is
+        # registered (modules/topic_metrics.py), decided per batch
+        ps.hooked = self.hooks.has("message.delivered")
         id_map = pb.id_map
-        m_ptr = pb.m_ptr
+        m_ptr = pb.m_ptr.tolist()
         ids_packed = pb.ids_packed
         inv = pb.inv
-        ftabs = ps.ftabs
+        results = pb.results
+        node = self.node
+        routes_get = self.router._routes.get
+        subs_get = self._subscribers.get
         route_of: Dict[int, tuple] = {}
+        # per unique topic: 0 not looked at yet, 1 no live filter (a
+        # drop), 2 every live filter local-only, 3 anything else (a
+        # shared group, a remote node, a vanished route: walked per
+        # message, below)
+        topic_class = bytearray(len(m_ptr) - 1)
         for r in range(n_live):
-            i, msg = pb.live[r]
             urow = inv[r]
-            seen_filter = False
-            local = False
-            n = 0
-            for j in ids_packed[m_ptr[urow]:m_ptr[urow + 1]]:
-                if j < 0:
-                    continue  # pad slot: id_map[-1] would alias
-                info = route_of.get(j)
-                if info is None:
-                    flt = id_map[j]
-                    if flt is None:
-                        info = (None, False, (), ())
-                    else:
-                        loc = False
-                        sh: Dict[str, List[str]] = {}
-                        rem: Dict[object, bool] = {}
-                        for route in self.router.lookup_routes(flt):
-                            dest = route.dest
-                            if isinstance(dest, tuple):
-                                sh.setdefault(dest[0], []) \
-                                    .append(dest[1])
-                            elif dest == self.node:
-                                loc = True
+            c = topic_class[urow]
+            if c == 0:
+                c = 1
+                for j in ids_packed[m_ptr[urow]:m_ptr[urow + 1]]:
+                    if j < 0:
+                        continue  # pad slot: id_map[-1] would alias
+                    info = route_of.get(j)
+                    if info is None:
+                        flt = id_map[j]
+                        if flt is None:
+                            info = _DEST_NONE
+                        else:
+                            ftabs[j] = subs_get(flt)
+                            dests = routes_get(flt)
+                            if dests is not None and len(dests) == 1 \
+                                    and node in dests:
+                                info = _DEST_LOCAL
                             else:
-                                rem[dest] = True
-                        ftabs[j] = self._subscribers.get(flt)
-                        info = (flt, loc, tuple(sh.items()),
-                                tuple(rem))
-                    route_of[j] = info
-                flt, loc, sh_items, rem_nodes = info
-                if flt is None:
-                    continue
-                seen_filter = True
-                local = local or loc
-                for group, nodes in sh_items:
-                    if self.shared_router is not None:
-                        # cluster: ONE delivery per group, all nodes
-                        n += self.shared_router(group, flt, nodes, msg)
-                    elif self.node in nodes:
-                        n += self.shared.dispatch(group, flt, msg)
-                for nd in rem_nodes:
-                    if self.forwarder is not None:
-                        self.forwarder(nd, flt, msg)
-                        self.metrics.inc("messages.forward")
-            if not seen_filter:
+                                info = self._classify_dests(flt, dests)
+                        route_of[j] = info
+                    if info is _DEST_LOCAL:
+                        if c == 1:
+                            c = 2
+                    elif info is not _DEST_NONE:
+                        c = 3
+                topic_class[urow] = c
+            i, msg = live[r]
+            if c == 1:
                 self._drop_no_subs(msg)
                 continue
-            pb.results[i] = n
-            if local:
-                ps.row_local[r] = 1
+            if c == 2:
+                row_local[r] = 1
+            else:
+                n = 0
+                local = False
+                for j in ids_packed[m_ptr[urow]:m_ptr[urow + 1]]:
+                    if j < 0:
+                        continue
+                    info = route_of[j]
+                    if info is _DEST_LOCAL:
+                        local = True
+                        continue
+                    if info is _DEST_NONE:
+                        continue
+                    flt, loc, sh_items, rem_nodes = info
+                    local = local or loc
+                    for group, nodes in sh_items:
+                        if self.shared_router is not None:
+                            # cluster: ONE delivery per group, all nodes
+                            n += self.shared_router(group, flt, nodes,
+                                                    msg)
+                        elif node in nodes:
+                            n += self.shared.dispatch(group, flt, msg)
+                    for nd in rem_nodes:
+                        if self.forwarder is not None:
+                            self.forwarder(nd, flt, msg)
+                            self.metrics.inc("messages.forward")
+                results[i] = n
+                if local:
+                    row_local[r] = 1
             if msg.qos == 0 and not msg.flags.get("retain"):
                 # the message half of the QoS0 broadcast fast-path
                 # predicate, hoisted to once per row; the subopts half
                 # joins it per (group, filter) below
-                ps.row_fast[r] = 1
+                row_fast[r] = 1
         ps.xg_set = None
         ps.folded = False
         pb.plan_state = ps
@@ -1213,6 +1262,24 @@ class Broker:
             # share of the plan NOW, so peer loops enqueue their
             # sessions' batches while this loop walks its own groups
             self._post_xloop_handoffs(pb, ps)
+
+    def _classify_dests(self, flt: str, dests) -> tuple:
+        """The prologue's slow class: a filter with other
+        destinations than this node alone (``dests`` is the route
+        table's ``dest -> refs`` for it, None where the route went
+        since the snapshot) as ``(filter, local, ((group, nodes),
+        ...), remote nodes)``."""
+        loc = False
+        sh: Dict[str, List[str]] = {}
+        rem: List[object] = []
+        for dest in dests or ():
+            if isinstance(dest, tuple):
+                sh.setdefault(dest[0], []).append(dest[1])
+            elif dest == self.node:
+                loc = True
+            else:
+                rem.append(dest)
+        return (flt, loc, tuple(sh.items()), tuple(rem))
 
     @owner_loop
     def publish_finish_planned(self, pb: PendingBatch, gstart: int,
@@ -1225,9 +1292,9 @@ class Broker:
         and one notify wakeup. The first chunk runs the routing
         prologue (which also posts the cross-loop handoffs on a
         multi-loop node — handed-off groups are skipped here); the
-        chunk that crosses the last group folds the per-(message,
-        filter) delivery counts into metrics/hooks/results (the
-        legacy walk's accounting, batched) — unless handoffs are
+        chunk that crosses the last group folds the accepted
+        deliveries into metrics/hooks/results (the legacy walk's
+        accounting, batched) — unless handoffs are
         still in flight, in which case the fold belongs to the join
         (:meth:`xloop_fold` / :meth:`xloop_join_sync`)."""
         plan = pb.plan
@@ -1238,17 +1305,15 @@ class Broker:
             self._fold_device_counts(pb)
             self._plan_prologue(pb)
         ps = pb.plan_state
-        counts = ps.counts
+        accepted = ps.accepted
         xg_set = ps.xg_set
         n_groups = plan.n_groups
+        resolves = 0
         for g in range(gstart, min(gstop, n_groups)):
             if xg_set is not None and g in xg_set:
                 continue  # handed to its owning loop
-            for r, flt in self._deliver_plan_group(pb, ps, g):
-                d = counts[r]
-                if d is None:
-                    d = counts[r] = {}
-                d[flt] = d.get(flt, 0) + 1
+            resolves += self._deliver_plan_group(pb, ps, g, accepted)
+        ps.resolves += resolves
         folded = False
         if gstop >= n_groups and (xg_set is None
                                   or ps.xloop_left == 0):
@@ -1261,11 +1326,18 @@ class Broker:
 
     @owner_loop
     def _deliver_plan_group(self, pb: PendingBatch, ps: _PlanState,
-                            g: int):
+                            g: int, out: list) -> int:
         """Deliver one plan group — one subscriber's whole batch:
-        resolve the session once, enqueue everything in one
-        ``deliver_many``, fire one notify. Returns the delivered
-        ``(row, filter)`` pairs for the caller's count fold. Runs on
+        resolve the session once and each of the group's filters once
+        (``fid -> (filter, SubOpts, the subopts half of the fast
+        predicate, no-local)``, or False where the subscriber no longer
+        holds it: a filter that recurs in the slice costs one dict
+        get), enqueue everything in one ``deliver_many``, fire one
+        notify; a plain subscriber (``deliver(filter, msg)`` alone:
+        tests, sinks, in-process consumers) is called straight from
+        the walk. Appends one entry per accepted delivery to ``out``
+        (the live row; ``(row, filter)`` when ``ps.hooked``) and
+        returns the number of resolutions made. Runs on
         whichever loop owns the group's session: the main loop for
         local groups, an owning peer loop inside a cross-loop handoff
         (everything read here — plan arrays, prologue tables, live
@@ -1274,89 +1346,104 @@ class Broker:
         plan = pb.plan
         sub = self.helper.registry.lookup(plan.g_sids[g])
         if sub is None:
-            return ()  # unsubscribed since the tables were built
+            return 0  # unsubscribed since the tables were built
         id_map = pb.id_map
         live = pb.live
-        g_ptr = plan.g_ptr
-        rows_s = plan.rows
-        fids_s = plan.fids
         row_local = ps.row_local
         row_fast = ps.row_fast
         ftabs = ps.ftabs
-        sub_cid = getattr(sub, "client_id", None)
-        upgrade = getattr(sub, "upgrade_qos", False)
+        hooked = ps.hooked
+        a = plan.g_ptr[g]
+        b = plan.g_ptr[g + 1]
+        dm = getattr(sub, "deliver_many", None)
         items: List[tuple] = []
-        accepted: List[tuple] = []
-        for k in range(g_ptr[g], g_ptr[g + 1]):
-            r = rows_s[k]
+        if dm is None:
+            # the per-delivery protocol: nothing is staged (an object
+            # that cannot deliver fails inside the per-delivery try)
+            deliver = getattr(sub, "deliver", None)
+            acc = out
+        else:
+            acc = []
+        # fid -> (filter, SubOpts, subopts half of `fast`, no-local),
+        # or False where the subscriber does not hold the filter
+        resolved: Dict[int, object] = {}
+        for r, fid in zip(plan.rows[a:b], plan.fids[a:b]):
             if not row_local[r]:
                 continue
-            fid = fids_s[k]
-            ftab = ftabs.get(fid)
-            if ftab is None:
+            ent = resolved.get(fid)
+            if ent is None:
+                ftab = ftabs.get(fid)
+                opts = ftab.get(sub) if ftab is not None else None
+                if opts is None:
+                    resolved[fid] = False
+                    continue
+                flt = id_map[fid]
+                nl = opts.nl
+                ofast = opts.share is None and not nl \
+                    and opts.subid is None \
+                    and (opts.qos == 0
+                         or not getattr(sub, "upgrade_qos", False))
+                resolved[fid] = (flt, opts, ofast, nl)
+            elif ent is False:
                 continue
-            opts = ftab.get(sub)
-            if opts is None:
-                continue
-            i, msg = live[r]
-            if opts.nl and sub_cid == msg.from_:
+            else:
+                flt, opts, ofast, nl = ent
+            msg = live[r][1]
+            if nl and getattr(sub, "client_id", None) == msg.from_:
                 self.metrics.inc("delivery.dropped")
                 self.metrics.inc("delivery.dropped.no_local")
                 continue
             if "_wire" not in msg.headers:
                 # shared wire-image cache, as _deliver_one primes
                 msg.headers["_wire"] = {}
-            flt = id_map[fid]
-            fast = bool(row_fast[r]) and opts.share is None \
-                and not opts.nl and opts.subid is None \
-                and (opts.qos == 0 or not upgrade)
-            items.append((flt, msg, opts, fast))
-            accepted.append((r, flt))
+            if dm is None:
+                try:
+                    deliver(flt, msg)
+                except Exception:
+                    log.exception("deliver to %r failed", sub)
+                    continue
+            else:
+                items.append((flt, msg, opts,
+                              ofast and row_fast[r] == 1))
+            acc.append((r, flt) if hooked else r)
         if not items:
-            return ()
-        dm = getattr(sub, "deliver_many", None)
-        if dm is not None:
-            runs = None
-            if plan.g_runs is not None \
-                    and len(items) == g_ptr[g + 1] - g_ptr[g]:
-                # every planned delivery accepted, so item k is the
-                # slice's delivery k: a run whose every item is fast
-                # holds those items' messages, in order — the session
-                # may take each as one outbox entry (docs/DISPATCH.md
-                # "Wire runs")
-                runs = [seg for seg in plan.g_runs[g] or ()
-                        if all([it[3] for it in items[seg[0]:seg[1]]])]
-            try:
-                if runs:
-                    dm(items, runs)
-                else:
-                    dm(items)
-            except Exception:
-                log.exception("deliver_many to %r failed", sub)
-                return ()
-            return accepted
-        # plain subscriber objects (tests, sinks): the per-delivery
-        # protocol, still one resolve per batch
-        delivered: List[tuple] = []
-        for (flt, msg, _o, _f), rf in zip(items, accepted):
-            try:
-                sub.deliver(flt, msg)
-                delivered.append(rf)
-            except Exception:
-                log.exception("deliver to %r failed", sub)
-        return delivered
+            return len(resolved)
+        runs = None
+        if plan.g_runs is not None and len(items) == b - a:
+            # every planned delivery accepted, so item k is the
+            # slice's delivery k: a run whose every item is fast
+            # holds those items' messages, in order — the session
+            # may take each as one outbox entry (docs/DISPATCH.md
+            # "Wire runs")
+            runs = [seg for seg in plan.g_runs[g] or ()
+                    if all([it[3] for it in items[seg[0]:seg[1]]])]
+        try:
+            if runs:
+                dm(items, runs)
+            else:
+                dm(items)
+        except Exception:
+            log.exception("deliver_many to %r failed", sub)
+        else:
+            out.extend(acc)
+        return len(resolved)
 
     @owner_loop
     def _plan_fold(self, pb: PendingBatch) -> None:
-        """Fold the batch's per-(message, filter) delivery counts into
-        metrics/hooks/results — the legacy walk's accounting, batched.
+        """Fold the batch's accepted deliveries into
+        metrics/hooks/results — the legacy walk's accounting, batched:
+        each row's count into ``results``, the batch's total into
+        ``messages.delivered`` in one add and, only where a
+        ``message.delivered`` callback is registered, one call per
+        (message, filter count) in the legacy order.
         Runs exactly once, on the main loop, after every cross-loop
         handoff reported back (idempotent via ``ps.folded``)."""
         ps = pb.plan_state
         if ps.folded:
             return
         ps.folded = True
-        counts = ps.counts
+        accepted = ps.accepted
+        resolves = ps.resolves
         if ps.xg_set and ps.xloop_left:
             # folding with handoffs still outstanding (join timed
             # out, handoff dropped, owning loop died): their groups'
@@ -1367,18 +1454,15 @@ class Broker:
                         "reported back — folding partial counts",
                         ps.xloop_left)
         if ps.xg_set:
-            # merge the handoff loops' delivered counts (no more
+            # merge the handoff loops' accepted deliveries (no more
             # writers once xloop_left hit zero)
+            n_x = 0
             for rc in ps.xloop_results:
-                for r, d in rc.items():
-                    tgt = counts[r]
-                    if tgt is None:
-                        tgt = counts[r] = {}
-                    for flt, c in d.items():
-                        tgt[flt] = tgt.get(flt, 0) + c
-            if ps.xloop_deliveries:
-                self.metrics.inc("delivery.xloop.deliveries",
-                                 ps.xloop_deliveries)
+                n_x += len(rc)
+                accepted.extend(rc)
+            resolves += ps.xloop_resolves
+            if n_x:
+                self.metrics.inc("delivery.xloop.deliveries", n_x)
             sp = pb.span
             if sp is not None:
                 sp.add_ms("xloop",
@@ -1388,16 +1472,32 @@ class Broker:
                 self.tracing.span_abs(
                     tb, "xloop", ps.xloop_t0,
                     (ps.xloop_tdone - ps.xloop_t0) * 1000.0)
+        if resolves:
+            tel = self.telemetry
+            if tel is not None and tel.loop_clock() is not None:
+                tel.metrics.inc("delivery.plan.resolves", resolves)
+        if not accepted:
+            return
+        self.metrics.inc("messages.delivered", len(accepted))
+        live = pb.live
         results = pb.results
-        for r, (i, msg) in enumerate(pb.live):
-            d = counts[r]
-            if not d:
-                continue
+        if not ps.hooked:
+            for r, cnt in collections.Counter(accepted).items():
+                results[live[r][0]] += cnt
+            return
+        counts: Dict[int, Dict[str, int]] = {}
+        for r, flt in accepted:
+            d = counts.get(r)
+            if d is None:
+                d = counts[r] = {}
+            d[flt] = d.get(flt, 0) + 1
+        run_hook = self.hooks.run
+        for r in sorted(counts):
+            i, msg = live[r]
             n = 0
-            for flt, cnt in d.items():
+            for cnt in counts[r].values():
                 n += cnt
-                self.metrics.inc("messages.delivered", cnt)
-                self.hooks.run("message.delivered", (msg, cnt))
+                run_hook("message.delivered", (msg, cnt))
             results[i] += n
 
     # -- cross-loop delivery ring (docs/DISPATCH.md) ----------------------
@@ -1440,7 +1540,7 @@ class Broker:
             xg_set.update(gids)
         ps.xg_set = xg_set
         ps.xloop_results = []
-        ps.xloop_deliveries = 0
+        ps.xloop_resolves = 0
         ps.xloop_lock = threading.Lock()
         ps.xloop_left = len(pb.xgroups)
         ps.xloop_t0 = ps.xloop_tdone = time.perf_counter()
@@ -1466,24 +1566,21 @@ class Broker:
         this loop's subscriber groups (each session still gets its
         whole batch in one ``deliver_many`` + one notify — the
         single-loop invariants, preserved across the ring), then
-        report the delivered counts back for the main-loop fold."""
+        report the accepted deliveries and the resolutions made back
+        for the main-loop fold."""
         ps = pb.plan_state
-        counts: Dict[int, Dict[str, int]] = {}
-        n = 0
+        accepted: list = []
+        resolves = 0
         try:
             for g in gids:
-                for r, flt in self._deliver_plan_group(pb, ps, g):
-                    d = counts.get(r)
-                    if d is None:
-                        d = counts[r] = {}
-                    d[flt] = d.get(flt, 0) + 1
-                    n += 1
+                resolves += self._deliver_plan_group(pb, ps, g,
+                                                     accepted)
         except Exception:
             log.exception("cross-loop delivery handoff failed")
         finally:
             with ps.xloop_lock:
-                ps.xloop_results.append(counts)
-                ps.xloop_deliveries += n
+                ps.xloop_results.append(accepted)
+                ps.xloop_resolves += resolves
                 ps.xloop_left -= 1
                 done = ps.xloop_left == 0
                 if done:

@@ -75,6 +75,15 @@ DELIVERY_METRICS = [
     # over messages.sent is the share of egress the runs carry)
     "delivery.wire_runs",
     "delivery.wire_run.frames",
+    # the planned tail's group walk (Broker._deliver_plan_group,
+    # docs/DISPATCH.md "The delivery walk"): (group, filter)
+    # resolutions made — a subscriber's SubOpts for one filter of its
+    # slice of the plan, looked up once however often the filter
+    # recurs there. Folded once a batch (Broker._plan_fold), gated on
+    # [telemetry] enabled like ``dispatch.*``; 1 − resolves ÷
+    # ``messages.delivered`` is the share of deliveries served from an
+    # entry already resolved
+    "delivery.plan.resolves",
     # PUBLISH frames serialized ON the event loop (the per-delivery
     # slow path, plus template/image cache misses that build there).
     # With egress pre-serialization on (docs/DISPATCH.md) eligible

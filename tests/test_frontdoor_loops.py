@@ -33,7 +33,10 @@ _TIMING_KEYS = ("delivery.wakeups", "delivery.xloop.handoffs",
                 # which QoS0 publishes share a batch tick decides
                 # which groups form a wire run (tests/test_wire_run.py
                 # pins the frames and counters either way)
-                "delivery.wire_runs", "delivery.wire_run.frames")
+                "delivery.wire_runs", "delivery.wire_run.frames",
+                # ... and how often a filter recurs inside one group
+                # (tests/test_delivery_walk.py pins the count)
+                "delivery.plan.resolves")
 
 
 async def _workload(loops: int):

@@ -438,7 +438,11 @@ async def test_trace_chain_is_continuous_across_two_loops():
         await s1.subscribe("tr/+", qos=1)
         await s2.subscribe("tr/t", qos=0)
         for i in range(4):
-            await pub.publish("tr/t", payload=b"%d" % i, qos=1)
+            # the first publish pays this node's cold compiles on the
+            # loop (4–5 s beside five other test workers): its PUBACK
+            # gets the time, the chain below is what is tested
+            await pub.publish("tr/t", payload=b"%d" % i, qos=1,
+                              timeout=30.0 if i == 0 else 5.0)
         for c in (s1, s2):
             for _ in range(4):
                 await c.recv(timeout=5.0)
