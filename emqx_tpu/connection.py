@@ -480,8 +480,18 @@ class Connection:
                         # ([overload] ingress_wait_timeout_s): a
                         # queue that never drains sheds the publisher
                         # instead of parking it forever
-                        if not await ing.wait_ready(
-                                ing.submit_wait_timeout):
+                        if lc is not None:
+                            t_park = _now()
+                        ready = await ing.wait_ready(
+                            ing.submit_wait_timeout)
+                        if lc is not None:
+                            # ingress.park.ns: to the reader's
+                            # resumption, the wake-up's queue included
+                            m = self.broker.metrics
+                            m.inc("ingress.parks")
+                            m.inc("ingress.park.ns",
+                                  int((_now() - t_park) * 1e9))
+                        if not ready:
                             self.broker.metrics.inc(
                                 "overload.shed.ingress_timeout")
                             alarms = getattr(self.broker, "alarms",

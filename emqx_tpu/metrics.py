@@ -383,6 +383,18 @@ CHANNEL_METRICS = [
     "channel.publish_run.msgs",
 ]
 
+# ingest backpressure as the connections feel it
+# (connection.Connection.run around IngressBatcher.wait_ready; gated
+# on [telemetry] enabled like ``dispatch.*``): ``parks`` = read loops
+# that found the accumulator at its high-water mark after submitting
+# what they had read and stopped reading, ``park.ns`` = from each park
+# to the reader's resumption on the loop (a shed publisher's park
+# counts up to its time-out). ns ÷ parks is what one park costs a
+# publisher; parks ÷ ``messages.received`` how often traffic meets it
+INGRESS_METRICS = [
+    "ingress.parks", "ingress.park.ns",
+]
+
 ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + WILL_METRICS
                + DELIVERY_METRICS + CLIENT_METRICS + SESSION_METRICS
@@ -391,7 +403,8 @@ ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + OVERLOAD_METRICS + BREAKER_METRICS + FAULT_METRICS
                + OPS_METRICS + DURABILITY_METRICS + CLUSTER_METRICS
                + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS
-               + MESH_METRICS + DISPATCH_METRICS + CHANNEL_METRICS)
+               + MESH_METRICS + DISPATCH_METRICS + CHANNEL_METRICS
+               + INGRESS_METRICS)
 
 #: registry names that are NOT monotonic — ``Metrics.dec`` runs on
 #: them in steady state (today: the retainer's live-entry count,

@@ -370,6 +370,17 @@ class Node:
         freeze_resident(self.router.stats()["topics.count"])
         if self.boot_listeners and not self.listeners:
             self.add_listener()
+        if self.listeners:
+            # a connection is a descriptor: take what the host allows
+            # before the first accept (vm.raise_fd_limit)
+            from emqx_tpu.vm import raise_fd_limit
+            fd = raise_fd_limit()
+            low = 0 <= fd["soft"] < 4096
+            log.log(logging.WARNING if low else logging.INFO,
+                    "descriptor limit %d (was %d, hard limit %d)%s",
+                    fd["soft"], fd["was"], fd["hard"],
+                    ": this node can hold fewer connections than "
+                    "that; raise `ulimit -n`" if low else "")
         if self.loop_group is not None:
             # multi-loop front door: peer loops come up BEFORE the
             # listeners (a dispatched socket needs a running owner),

@@ -38,6 +38,25 @@ def get_memory() -> Dict[str, int]:
     return out
 
 
+def raise_fd_limit() -> Dict[str, int]:
+    """Raise the process's soft descriptor limit to its hard one —
+    what EMQX's tuning guide has operators do with ``ulimit -n``
+    before a broker is to hold thousands of sockets (a connection is
+    a descriptor; most shells start a process at 1024). Returns
+    ``{"soft", "hard", "was"}`` (-1 = unlimited). A hard limit the
+    kernel refuses as a soft one leaves it where it was: the caller
+    says so and serves on."""
+    was, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    soft = was
+    if was != hard:
+        try:
+            resource.setrlimit(resource.RLIMIT_NOFILE, (hard, hard))
+            soft = hard
+        except (OSError, ValueError):
+            pass
+    return {"soft": soft, "hard": hard, "was": was}
+
+
 def get_process_info() -> Dict[str, int]:
     """Thread/fd/task counts — the process-count analogue
     (emqx_vm:get_process_count/0, get_port_count)."""

@@ -323,10 +323,12 @@ def test_plan_resolve_share_file_matches_its_benchmark_entry(name, cells):
     for key in ("unit", "better", "source", "layer", "moves"):
         assert listed[0][key] == entry[key], key
     assert listed[0]["workloads"] == cells
-    # appended at PR 34, the pair last of all and in this order; the
-    # mesh cell's per-layer list is pinned elsewhere: not touched
-    assert [m["name"] for m in spec["per_layer"][-2:]] == [
-        "plan_resolve_share", "plan_resolve_share.uniform"]
+    # appended at PR 34, the pair in this order (later PRs append
+    # after it); the mesh cell's per-layer list is pinned elsewhere:
+    # not touched
+    names = [m["name"] for m in spec["per_layer"]]
+    at = names.index("plan_resolve_share")
+    assert names[at + 1] == "plan_resolve_share.uniform"
     assert os.path.exists(os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "benchmark", "reducers", entry["reducer"] + ".py"))
