@@ -422,6 +422,39 @@ class Connection:
                     t0 = _now()
                     n0 = lc.inner
                 pkts = await self._decode(data)
+                n_pubs = 0
+                if pkts:
+                    ing = getattr(self.broker, "ingress", None)
+                    if ing is not None or self._msg_limiter is not None:
+                        n_pubs = sum(1 for p in pkts
+                                     if isinstance(p, Publish))
+                    if n_pubs and ing is not None:
+                        # ingest backpressure (active_n analogue,
+                        # src/emqx_connection.erl:99): before the
+                        # chunk's PUBLISHes go to the channel, ask
+                        # the shared accumulator for room for them.
+                        # At its high-water mark this reader waits
+                        # its turn in the batcher's line and reads
+                        # nothing more, so the standing queue lives
+                        # in the publisher's TCP buffer, not in the
+                        # broker, and delivery tail latency stays
+                        # bounded at saturation. Nothing of the chunk
+                        # runs ahead of the wait. The wait is bounded
+                        # ([overload] ingress_wait_timeout_s): a
+                        # queue that never drains sheds the publisher
+                        # instead of parking it forever
+                        if lc is not None:
+                            held = _now() - t0  # the decode's share
+                        ready = await ing.admit(n_pubs)
+                        if lc is not None:
+                            # the slice goes on where the reader does
+                            t0 = _now() - held
+                            n0 = lc.inner
+                        if not ready:
+                            self._shed_saturated(ing)
+                            break
+                        if self._closing:
+                            break  # closed while it waited
                 i, n = 0, len(pkts or ())
                 while i < n:
                     # the publish run: the plain PUBLISH packets from
@@ -464,68 +497,15 @@ class Connection:
                     lc.loop_leave(I_READ_NS, t0, n0)
                 if not self._closing:
                     await self.writer.drain()
-                if pkts:
-                    ing = getattr(self.broker, "ingress", None)
-                    if (ing is not None and ing.backlogged()
-                            and any(isinstance(p, Publish)
-                                    for p in pkts)):
-                        # ingest backpressure (active_n analogue,
-                        # src/emqx_connection.erl:99): the shared
-                        # accumulator is at its high-water mark —
-                        # stop READING this publisher until a flush
-                        # drains it. The standing queue then lives in
-                        # the publisher's TCP buffer, not in the
-                        # broker, so delivery tail latency stays
-                        # bounded at saturation. The wait is bounded
-                        # ([overload] ingress_wait_timeout_s): a
-                        # queue that never drains sheds the publisher
-                        # instead of parking it forever
-                        if lc is not None:
-                            t_park = _now()
-                        ready = await ing.wait_ready(
-                            ing.submit_wait_timeout)
-                        if lc is not None:
-                            # ingress.park.ns: to the reader's
-                            # resumption, the wake-up's queue included
-                            m = self.broker.metrics
-                            m.inc("ingress.parks")
-                            m.inc("ingress.park.ns",
-                                  int((_now() - t_park) * 1e9))
-                        if not ready:
-                            self.broker.metrics.inc(
-                                "overload.shed.ingress_timeout")
-                            alarms = getattr(self.broker, "alarms",
-                                             None)
-                            if alarms is not None:
-                                alarms.activate(
-                                    "ingress_saturated",
-                                    details={"queue": len(
-                                        ing._pending)},
-                                    message="ingress accumulator "
-                                            "saturated past the "
-                                            "submit wait bound; "
-                                            "shedding publishers")
-                            log.warning(
-                                "shedding publisher %s: ingress "
-                                "saturated > %.0fs",
-                                self.channel.peername,
-                                ing.submit_wait_timeout)
-                            self.channel.disconnect_reason = \
-                                "ingress_saturated"
-                            break
-                if self._msg_limiter is not None and pkts:
+                if self._msg_limiter is not None and n_pubs:
                     # like the reference, the already-parsed batch is
                     # processed first, then the socket pauses (state
                     # `blocked` + limit_timeout timer there; a plain
                     # sleep before the next read here)
-                    n_pubs = sum(1 for p in pkts
-                                 if isinstance(p, Publish))
-                    if n_pubs:
-                        wait = self._msg_limiter.consume(n_pubs)
-                        if wait > 0:
-                            self._paused_until = \
-                                time.monotonic() + wait
-                            await asyncio.sleep(wait)
+                    wait = self._msg_limiter.consume(n_pubs)
+                    if wait > 0:
+                        self._paused_until = time.monotonic() + wait
+                        await asyncio.sleep(wait)
         except (ConnectionResetError, BrokenPipeError):
             pass
         finally:
@@ -539,6 +519,22 @@ class Connection:
                     self.channel.disconnect_reason = "sock_closed"
                 self.channel._shutdown()
             self._close_transport()
+
+    def _shed_saturated(self, ing) -> None:
+        """The wait for ingress room outlasted its bound: count it,
+        raise the alarm and name the reason; the caller ends the
+        read loop, which closes the publisher."""
+        self.broker.metrics.inc("overload.shed.ingress_timeout")
+        alarms = getattr(self.broker, "alarms", None)
+        if alarms is not None:
+            alarms.activate(
+                "ingress_saturated",
+                details={"queue": len(ing._pending)},
+                message="ingress accumulator saturated past the "
+                        "submit wait bound; shedding publishers")
+        log.warning("shedding publisher %s: ingress saturated > %.0fs",
+                    self.channel.peername, ing.submit_wait_timeout)
+        self.channel.disconnect_reason = "ingress_saturated"
 
     async def _decode(self, data: bytes):
         """Inbound framing seam: bytes → MQTT packets, or ``None`` to

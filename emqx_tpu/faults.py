@@ -99,8 +99,9 @@ POINTS: Dict[str, tuple] = {
                      "Connection._send_packets — the client socket "
                      "resets mid-flush"),
     "ingress.saturate": ("drop",
-                         "IngressBatcher.backlogged — the ingress "
-                         "accumulator reports saturation"),
+                         "IngressBatcher._mark — the ingress "
+                         "accumulator reads full to backlogged() "
+                         "and to the admission line"),
     "wal.append": ("drop",
                    "Wal.flush — a journal frame short-writes (torn "
                    "tail on disk, as if the process crashed "

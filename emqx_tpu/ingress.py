@@ -40,11 +40,13 @@ poke the channel directly) fall back to the synchronous path:
 from __future__ import annotations
 
 import asyncio
+import collections
+import contextlib
 import logging
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, List, Optional, Tuple
+from typing import Deque, List, Optional, Tuple
 
 from emqx_tpu import faults
 from emqx_tpu.concurrency import (any_thread, owner_loop,
@@ -53,8 +55,34 @@ from emqx_tpu.types import Message
 
 log = logging.getLogger("emqx_tpu.ingress")
 
+# single-loop mode has no ``_plock``: the one event loop is the lock
+_UNLOCKED = contextlib.nullcontext()
 
-@shared_state(lock="_plock", attrs=("_pending",))
+# a parked reader's place in the admission line
+_WAITING, _GRANTED, _GONE = 0, 1, 2  # _GONE: timed out, or dead
+
+
+class _Waiter:
+    """One read loop parked in the admission line."""
+
+    __slots__ = ("fut", "weight", "deadline", "state")
+
+    def __init__(self, fut, weight: int,
+                 deadline: Optional[float]) -> None:
+        self.fut = fut  # on the reader's own loop: True = granted
+        self.weight = weight  # the PUBLISHes of the chunk it holds
+        self.deadline = deadline  # time.monotonic(); None = no bound
+        self.state = _WAITING
+
+
+def _running_loop() -> Optional[asyncio.AbstractEventLoop]:
+    try:
+        return asyncio.get_running_loop()
+    except RuntimeError:
+        return None
+
+
+@shared_state(lock="_plock", attrs=("_pending", "_line", "_granted"))
 class IngressBatcher:
     def __init__(self, broker, batch_size: int = 256,
                  linger_ms: float = 0.0, max_inflight: int = 4,
@@ -70,9 +98,9 @@ class IngressBatcher:
         # the hot path; the cap keeps steady-state traffic inside a
         # handful of already-compiled buckets
         self.batch_cap = batch_cap or batch_size * 4
-        # accumulator high-water mark: past it, connections PAUSE
-        # their read loops (wait_ready) until a flush drains the
-        # backlog — the reference bounds per-connection ingest with
+        # accumulator high-water mark: at it, connections PAUSE
+        # their read loops (the admission line, ``admit``) until a
+        # flush makes room — the reference bounds per-connection ingest with
         # active_n (src/emqx_connection.erl:99); without a bound, a
         # saturating publisher turns the accumulator into an
         # unbounded standing queue and every delivery's tail latency
@@ -96,24 +124,31 @@ class IngressBatcher:
         self._inflight = 0
         self._chain: Optional[asyncio.Task] = None  # ordered delivery
         self._pool: Optional[ThreadPoolExecutor] = None
-        self._ready: Optional[asyncio.Event] = None
+        # the admission line (``admit``): read loops that met the
+        # mark, first come first served; ``_granted`` is the room
+        # that woken readers hold until they resume, ``_timer`` the
+        # line's one deadline (its head's), ``_regrant`` a grant pass
+        # already scheduled behind the readers just woken
+        self._line: Deque[_Waiter] = collections.deque()
+        self._granted = 0
+        self._timer: Optional[asyncio.TimerHandle] = None
+        self._regrant = False
         # multi-loop front door (Node.start → bind_multiloop): the
         # accumulator is then fed from several event-loop threads —
-        # appends/takes go under _plock, flushes are marshaled onto
-        # the home loop, futures resolve on their own loops, and the
-        # backpressure event becomes per-loop. All None/empty on a
+        # appends/takes and the admission line go under _plock,
+        # flushes are marshaled onto the home loop, futures (acks and
+        # grants alike) resolve on their own loops. None on a
         # single-loop node: every hot-path branch below stays the
         # legacy code byte-for-byte
         self._plock: Optional[threading.Lock] = None
         self._home: Optional[asyncio.AbstractEventLoop] = None
-        self._ready_multi: Dict[int, tuple] = {}
         # overload protection (overload.py): at critical the monitor
         # divides the effective high-water mark by this, so publisher
         # read-pauses engage earlier; 1 = the configured mark, the
         # hot-path cost is one int compare
         self._pressure_div = 1
-        # bound on a publisher's wait_ready park (seconds; 0 =
-        # unbounded, the legacy behavior) — set from
+        # bound on a publisher's wait in the admission line (seconds;
+        # 0 = unbounded, the legacy behavior) — set from
         # [overload] ingress_wait_timeout_s by Node; connections shed
         # the publisher when it expires (docs/ROBUSTNESS.md)
         self.submit_wait_timeout = 0.0
@@ -162,10 +197,7 @@ class IngressBatcher:
             # only mutates the message's own headers — safe from any
             # submitting loop; idempotent for forwarded messages
             trc.stamp(msg)
-        try:
-            loop = asyncio.get_running_loop()
-        except RuntimeError:
-            loop = None
+        loop = _running_loop()
         if self._plock is not None:
             return self._submit_threadsafe(msg, want_result, loop)
         if loop is None:
@@ -216,10 +248,7 @@ class IngressBatcher:
         if trc is not None and trc.active:
             for msg in msgs:
                 trc.stamp(msg)
-        try:
-            loop = asyncio.get_running_loop()
-        except RuntimeError:
-            loop = None
+        loop = _running_loop()
         if self._plock is not None:
             for msg in msgs:
                 self._submit_threadsafe(msg, False, loop)
@@ -317,103 +346,193 @@ class IngressBatcher:
                     del self._pending[:cap]
                 else:
                     pending, self._pending = self._pending, []
-        elif cap and len(self._pending) > cap:
-            pending = self._pending[:cap]
-            # lint: ok-CD102 single-loop mode (_plock None): flush
-            # and submit both run on the one event loop
-            del self._pending[:cap]
+                self._grant_locked()
         else:
-            # lint: ok-CD102 single-loop mode (_plock None), as above
-            pending, self._pending = self._pending, []
+            if cap and len(self._pending) > cap:
+                pending = self._pending[:cap]
+                # lint: ok-CD102 single-loop mode (_plock None): flush
+                # and submit both run on the one event loop
+                del self._pending[:cap]
+            else:
+                # lint: ok-CD102 single-loop mode (_plock None), as above
+                pending, self._pending = self._pending, []
+            self._grant_locked()  # the room this take made
         if pending:
             self.flushes += 1
             self.max_batch = max(self.max_batch, len(pending))
-        self._signal_ready()
         return pending
 
-    # -- ingest backpressure ----------------------------------------------
+    # -- ingest backpressure: the admission line ---------------------------
 
-    def backlogged(self) -> bool:
-        """Accumulator at/over the high-water mark — connections
-        should pause reading (the active_n analogue). At critical
-        overload the effective mark shrinks (``set_pressure``), so
-        the pause engages earlier."""
+    def _mark(self) -> int:
+        """The effective high-water mark: the configured one, divided
+        at critical overload (``set_pressure``); 0, which nothing is
+        under, while the ``ingress.saturate`` fault reads "full"."""
         if faults.enabled and faults.fire("ingress.saturate"):
-            return True
+            return 0
         hw = self.queue_hiwater
         if self._pressure_div > 1:
             hw = max(1, hw // self._pressure_div)
-        return len(self._pending) >= hw
+        return hw
+
+    def backlogged(self) -> bool:
+        """Accumulator at/over the high-water mark — connections
+        pause reading (the active_n analogue). At critical overload
+        the effective mark shrinks (``set_pressure``), so the pause
+        engages earlier."""
+        return len(self._pending) >= self._mark()
+
+    def waiting(self) -> int:
+        """Read loops parked in the admission line."""
+        return len(self._line)
 
     def set_pressure(self, div: int) -> None:
         """Overload-monitor knob: divide the effective high-water
-        mark by ``div`` (1 restores the configured mark)."""
+        mark by ``div`` (1 restores the configured mark, and admits
+        what the restored mark holds)."""
         self._pressure_div = max(1, int(div))
+        with self._plock or _UNLOCKED:
+            self._grant_locked()
 
-    async def wait_ready(self, timeout: float = 0.0) -> bool:
-        """Park until a flush takes the backlog below the mark. On a
-        multi-loop node each loop parks on its OWN event (an asyncio
-        event belongs to one loop; waking them crosses threads).
-
-        ``timeout`` bounds the park (0 = wait forever): returns False
-        if the backlog still stands when it expires — the caller
-        sheds the publisher instead of letting it wedge the read
-        loop indefinitely."""
-        deadline = (time.monotonic() + timeout) if timeout > 0 else None
-
-        async def _wait(ev) -> bool:
-            if deadline is None:
-                await ev.wait()
-                return True
-            remain = deadline - time.monotonic()
-            if remain <= 0:
-                return False
-            try:
-                await asyncio.wait_for(ev.wait(), remain)
-                return True
-            except asyncio.TimeoutError:
-                return False
-        if self._plock is None:
-            while self.backlogged():
-                if self._ready is None or self._ready.is_set():
-                    self._ready = asyncio.Event()
-                if not await _wait(self._ready):
-                    return False
+    @any_thread
+    async def admit(self, weight: int) -> bool:
+        """A read loop's one question a chunk, asked before it hands
+        the chunk's ``weight`` PUBLISHes to the channel. True at once
+        where the queue is under the mark and nobody waits (no
+        barging). Otherwise the reader joins the tail of one FIFO
+        line and reads nothing more, so its publisher's next packets
+        stand in the TCP buffer; whatever makes room wakes from the
+        head the readers the room holds, once each, and a woken
+        reader is admitted: it does not ask again. False = the wait
+        outlasted ``submit_wait_timeout`` (0 = no bound): the caller
+        sheds the publisher instead of wedging its read loop."""
+        lock = self._plock or _UNLOCKED
+        with lock:
+            w = self._join_locked(weight)
+        if w is None:
             return True
-        loop = asyncio.get_running_loop()
-        key = id(loop)
-        while self.backlogged():
-            ent = self._ready_multi.get(key)
-            if ent is None or ent[1].is_set():
-                ent = (loop, asyncio.Event())
-                self._ready_multi[key] = ent
-            if not await _wait(ent[1]):
-                return False
+        tel = self.broker.telemetry
+        timed = tel is not None and tel.enabled
+        if timed:
+            t_park = time.perf_counter()
+            self.broker.metrics.inc("ingress.parks")
+        try:
+            return await w.fut
+        finally:
+            # the reader is back on its loop: by a grant, a time-out
+            # or its task's cancellation
+            if timed:
+                m = self.broker.metrics
+                m.inc("ingress.wakes")
+                m.inc("ingress.park.ns",
+                      int((time.perf_counter() - t_park) * 1e9))
+            with lock:
+                self._resumed_locked(w)
+
+    def _join_locked(self, weight: int) -> Optional[_Waiter]:
+        """None = admitted at once; else the caller's place at the
+        tail of the line."""
+        if not self._line and \
+                len(self._pending) + self._granted < self._mark():
+            return None
+        timeout = self.submit_wait_timeout
+        w = _Waiter(asyncio.get_running_loop().create_future(), weight,
+                    (time.monotonic() + timeout) if timeout > 0
+                    else None)
+        self._line.append(w)
+        # room behind a line (a grant that was handed back) goes to
+        # the head, not to the arrival; this arms the timer too
+        self._grant_locked()
+        return w
+
+    def _resumed_locked(self, w: _Waiter) -> None:
+        if w.state == _GRANTED:
+            # the grant is spent here: what the reader now submits
+            # counts in the queue itself. Room it does not use
+            # (nothing to add, cancelled) is offered again, behind
+            # the readers that were woken with it
+            self._granted -= w.weight
+            if self._line and not self._regrant:
+                self._regrant = True
+                w.fut.get_loop().call_soon(self._grant)
+        elif w.state == _WAITING:
+            self._line.remove(w)  # cancelled where it stood
+            self._arm_locked()
+
+    @any_thread
+    def _grant(self) -> None:
+        with self._plock or _UNLOCKED:
+            self._regrant = False
+            self._grant_locked()
+
+    def _grant_locked(self) -> None:
+        """Wake from the head what the room holds: while the queue
+        plus the room woken readers hold (this pass's grants too) is
+        under the mark. The head goes whatever its weight, so a chunk
+        larger than the mark is never starved. Invariant: with queue
+        + granted room under the mark, the line is empty."""
+        line = self._line
+        if not line:
+            return
+        mark = self._mark()
+        used = len(self._pending) + self._granted
+        while line and used < mark:
+            w = line.popleft()
+            if self._wake_locked(w, True):
+                self._granted += w.weight
+                used += w.weight
+        self._arm_locked()
+
+    def _wake_locked(self, w: _Waiter, granted: bool) -> bool:
+        """Resolve a waiter's future, on ITS loop; False = nobody is
+        there to wake (cancelled, or its front-door loop is gone):
+        the grant passes to the next."""
+        fut = w.fut
+        w.state = _GONE
+        if fut.done() or not fut.get_loop().is_running():
+            return False
+        if self._plock is None:
+            fut.set_result(granted)
+        else:
+            self._set_future(fut, granted, None)
+        if granted:
+            w.state = _GRANTED
         return True
 
-    def _signal_ready(self) -> None:
-        if self.backlogged():
-            return
-        if self._ready is not None and not self._ready.is_set():
-            self._ready.set()
-        if self._ready_multi:
-            # wake every parked loop on its own thread. A loop adding
-            # a fresh event right after this snapshot just parks until
-            # the next flush signals again
-            waiters = list(self._ready_multi.values())
-            self._ready_multi.clear()
-            try:
-                running = asyncio.get_running_loop()
-            except RuntimeError:
-                running = None
-            for lp, ev in waiters:
-                if lp is running:
-                    ev.set()
-                else:
-                    try:
-                        lp.call_soon_threadsafe(ev.set)
-                    except RuntimeError:
-                        pass
+    def _arm_locked(self) -> None:
+        """The line's one timer: armed for the head's deadline while
+        anyone waits (every wait carries the same time-out, so the
+        line is in deadline order), cancelled when the line empties.
+        It lives on the home loop."""
+        running = _running_loop()
+        home = self._home or running
+        if not self._line:
+            if self._timer is not None and running is home:
+                self._timer.cancel()
+                self._timer = None
+        elif self._timer is None and self._line[0].deadline is not None:
+            if running is home:
+                self._timer = home.call_at(self._line[0].deadline,
+                                           self._expire)
+            else:
+                try:  # a grant pass there arms it
+                    home.call_soon_threadsafe(self._grant)
+                except RuntimeError:
+                    pass  # home loop gone (shutdown race)
+
+    @owner_loop
+    def _expire(self) -> None:
+        """The timer: the waiters whose deadline has passed leave the
+        line unadmitted (the head, and whoever joined in its very
+        tick); the timer moves on to the new head."""
+        with self._plock or _UNLOCKED:
+            self._timer = None
+            line = self._line
+            now = time.monotonic()
+            while line and line[0].deadline is not None \
+                    and line[0].deadline <= now:
+                self._wake_locked(line.popleft(), False)
+            self._arm_locked()
 
     @owner_loop
     def _flush(self) -> None:
@@ -614,10 +733,7 @@ class IngressBatcher:
         futures must not be completed from the home thread — the ack
         callbacks hanging off them touch that loop's channel)."""
         floop = fut.get_loop()
-        try:
-            running = asyncio.get_running_loop()
-        except RuntimeError:
-            running = None
+        running = _running_loop()
 
         def _do(f=fut, v=value, e=exc):
             if f.done():

@@ -384,15 +384,18 @@ CHANNEL_METRICS = [
 ]
 
 # ingest backpressure as the connections feel it
-# (connection.Connection.run around IngressBatcher.wait_ready; gated
-# on [telemetry] enabled like ``dispatch.*``): ``parks`` = read loops
-# that found the accumulator at its high-water mark after submitting
-# what they had read and stopped reading, ``park.ns`` = from each park
-# to the reader's resumption on the loop (a shed publisher's park
-# counts up to its time-out). ns ÷ parks is what one park costs a
-# publisher; parks ÷ ``messages.received`` how often traffic meets it
+# (ingress.IngressBatcher.admit, the admission line; gated on
+# [telemetry] enabled like ``dispatch.*``): ``parks`` = read loops
+# that found the accumulator at its high-water mark (or others
+# waiting for it) before handing over what they had read, and joined
+# the line; ``wakes`` = parked readers back on their loop, by a grant,
+# a time-out or a cancellation (Σ wakes = Σ parks at rest: one wake a
+# park); ``park.ns`` = from each park to that resumption (a shed
+# publisher's park counts up to its time-out). ns ÷ parks is what one
+# park costs a publisher; parks ÷ ``messages.received`` how often
+# traffic meets it
 INGRESS_METRICS = [
-    "ingress.parks", "ingress.park.ns",
+    "ingress.parks", "ingress.park.ns", "ingress.wakes",
 ]
 
 ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS

@@ -751,7 +751,8 @@ def test_device_loss_classifies_rebuilds_and_auto_closes():
         # answer -> REBUILDING, device matching suspended
         assert _wait_for(lambda: br.state == DeviceBreaker.REBUILDING)
         assert rec.last_classification == "lost"
-        assert node.router.device_suspended()
+        # the recovery thread suspends a few lines after the state flips
+        assert _wait_for(node.router.device_suspended)
         assert any(a.name == "device_path_lost"
                    for a in node.alarms.get_alarms("activated"))
         # rebuild attempts fail while the backend is still gone
@@ -890,6 +891,8 @@ def test_breaker_fallback_never_rides_device():
         assert _wait_for(
             lambda: node.broker.breaker.state
             == DeviceBreaker.REBUILDING)
+        # the recovery thread suspends a few lines after the state flips
+        assert _wait_for(node.router.device_suspended)
         node.router.match_dispatch = boom
         node.router.match_ids = boom
         node.router._dispatch_sharded = boom

@@ -38,6 +38,7 @@ OWN = {
     "frames_per_flush.p2p": (["messages.sent"], "loop.flush.calls"),
     "parks_per_msg.p2p": (["ingress.parks"], "messages.received"),
     "park_wait_ms.p2p": (["ingress.park.ns"], "ingress.parks"),
+    "wakes_per_park.p2p": (["ingress.wakes"], "ingress.parks"),  # PR 36
 }
 
 
@@ -176,6 +177,13 @@ def test_the_park_metrics_read_nothing_from_a_program_without_the_counters():
                         "ingress.park.ns": 50 * 3_000_000}}
     assert reduce(run, **wait["args"]) == pytest.approx(3.0)  # ms a park
     assert reduce(run, **share["args"]) == pytest.approx(0.05)
+    # PR 36's counter: PR 35's program parks and counts no wake-up
+    wakes = _json("benchmark", "layer_metrics", "wakes_per_park.p2p.json")
+    assert reduce(parent, **wakes["args"]) is None
+    assert reduce(run, **wakes["args"]) is None
+    run["counters"]["ingress.wakes"] = 50
+    assert reduce(run, **wakes["args"]) == 1.0
+    assert wakes["better"] == "lower"  # a herd reads many
 
 
 def _plan(n_pool, n_pubs):

@@ -4,9 +4,9 @@ on its own topic, 64 subscriber sockets each on its own filter, bursts
 of three QoS 0 publishes and a QoS 1 fence over plain MQTT, beside a
 seeded ``mixed_tree`` population in an in-process sink. Every socket's
 and the sink's deliveries are held to ``benchmark/reference.py``'s
-plain trie; the read loops' parks on ingest backpressure are held to
-the two counters that count them (``ingress.parks``,
-``ingress.park.ns``); and a node that is to hold sockets takes the
+plain trie; the read loops' parks in the ingress admission line are
+held to the three counters that count them (``ingress.parks``,
+``ingress.wakes``, ``ingress.park.ns``); and a node that is to hold sockets takes the
 descriptors its host allows at start. Runs on the CPU backend; the
 chip's run is the cell."""
 
@@ -20,6 +20,7 @@ import sys
 
 import pytest
 
+from emqx_tpu import ingress as ingress_mod
 from emqx_tpu import vm
 from emqx_tpu.node import Node
 from emqx_tpu.telemetry import TelemetryConfig
@@ -74,20 +75,39 @@ class _Sink:
 
 
 class _Parks:
-    """``IngressBatcher.wait_ready`` counted from outside the program:
-    a call is a read loop that parked, its return the resumption."""
+    """The admission line counted from outside the program: a waiter
+    made inside a call of ``IngressBatcher.admit`` is a read loop that
+    parked, that call's return its resumption."""
 
     def __init__(self, ingress):
         self.entered = self.left = 0
-        self._inner = ingress.wait_ready
-        ingress.wait_ready = self
+        self._parked = set()  # the tasks whose call made a waiter
+        self._inner = ingress.admit
+        self._waiter = ingress_mod._Waiter
+        parks = self
 
-    async def __call__(self, timeout=0.0):
-        self.entered += 1
+        class Counted(self._waiter):
+            __slots__ = ()
+
+            def __init__(self, *args):
+                super().__init__(*args)
+                parks.entered += 1
+                parks._parked.add(asyncio.current_task())
+
+        ingress_mod._Waiter = Counted
+        ingress.admit = self
+
+    def restore(self):
+        ingress_mod._Waiter = self._waiter
+
+    async def __call__(self, weight):
         try:
-            return await self._inner(timeout)
+            return await self._inner(weight)
         finally:
-            self.left += 1
+            task = asyncio.current_task()
+            if task in self._parked:
+                self._parked.discard(task)
+                self.left += 1
 
 
 async def _serve(telemetry: bool) -> dict:
@@ -157,17 +177,19 @@ async def _serve(telemetry: bool) -> dict:
         await asyncio.sleep(0.3)  # a surplus delivery would come now
         surplus = sum(c.inbox.qsize() for c in subs)
         m1 = node.metrics.all()
-        ready = node.ingress._ready
         return {
             "sockets": got_sockets, "want_sockets": want_sockets,
             "sink": sink.got, "want_sink": want_sink, "surplus": surplus,
             "counters": {k: m1[k] - m0.get(k, 0) for k in m1},
             "entered": parks.entered, "left": parks.left,
             "backlogged": node.ingress.backlogged(),
-            "waiters": 0 if ready is None else len(ready._waiters),
+            "waiters": node.ingress.waiting(),
+            "granted": node.ingress._granted,
+            "max_queue": node.ingress.max_queue,
             "published": sum(len(m) for m in sent),
         }
     finally:
+        parks.restore()
         for c in subs + pubs:
             await c.close()
         await node.stop()
@@ -224,13 +246,27 @@ def test_no_reader_stays_parked_once_the_queue_drains(run, request):
     got = request.getfixturevalue(run)
     assert got["entered"] == got["left"] > 0
     assert got["backlogged"] is False and got["waiters"] == 0
+    assert got["granted"] == 0  # and no woken reader holds room back
+
+
+def test_every_park_is_woken_once(served):
+    c = served["counters"]
+    assert c["ingress.wakes"] == c["ingress.parks"] > 0
+
+
+def test_the_queue_stays_within_the_mark_and_one_pass_of_grants(served):
+    # a reader is admitted under the mark whatever it holds, and a
+    # pass grants while the queue with its grants is under the mark:
+    # at most the mark less one, and the last one's burst
+    assert 0 < served["max_queue"] <= HIWATER - 1 + BURST
 
 
 def test_with_telemetry_off_nothing_is_stamped(served_untimed):
     got = served_untimed
     c = got["counters"]
     assert got["entered"] > 0
-    assert c["ingress.parks"] == 0 and c["ingress.park.ns"] == 0
+    assert c["ingress.parks"] == c["ingress.park.ns"] == 0
+    assert c["ingress.wakes"] == 0
     assert c["loop.read.calls"] == 0  # the loop counters' gate
     assert got["sockets"] == got["want_sockets"] and got["surplus"] == 0
     assert got["sink"] == got["want_sink"]
