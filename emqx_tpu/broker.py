@@ -641,7 +641,7 @@ class Broker:
         if sp is not None:
             sp.start("match")
         pb.ids_dev, pb.ovf_dev, pb.id_map, pb.epoch = \
-            self.router.match_dispatch(uniq)
+            self.router.match_dispatch(uniq, span=sp)
         if sp is not None:
             # closes the match stage; the router's cache-split path
             # (telemetry-gated) left the cache_gather share to split
@@ -704,7 +704,8 @@ class Broker:
         # learned budgets)
         (pb.ids_dev, pb.subs_dense_d, pb.src_dense_d, bm, pb.ovf_dev,
          pb.movf_d, pb.id_map, pb.epoch, pb.sh_big) = \
-            self.router.publish_dispatch_sharded(uniq, fan_provider)
+            self.router.publish_dispatch_sharded(uniq, fan_provider,
+                                                 span=sp)
         if sp is not None:
             # the cache probe, the collective step's enqueue for the
             # misses (match + gather + ICI all-gather + the cache

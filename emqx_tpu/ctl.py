@@ -96,8 +96,9 @@ class Ctl:
                               "set-level <debug|info|warning|error> | show")
         self.register_command(
             "telemetry", self._telemetry,
-            "stages | slow | stalls | reset — publish-path stage "
-            "latency, slow batches, loop stalls")
+            "stages | loop | slow | stalls | reset — publish-path "
+            "stage latency, the loop's time ledger (who waits for "
+            "whom), slow batches, loop stalls")
         self.register_command(
             "cache", self._cache,
             "publish match-cache: hit/miss/stale, epoch-bump split, "
@@ -255,6 +256,8 @@ class Ctl:
                          f"{tel.slow_total} (threshold "
                          f"{tel.config.slow_threshold_ms}ms)")
             return "\n".join(lines)
+        if args[0] == "loop":
+            return self._telemetry_loop(tel)
         if args[0] == "slow":
             recs = tel.slow_records()
             return json.dumps(recs, indent=2) if recs else "(none)"
@@ -267,6 +270,49 @@ class Ctl:
             tel.reset()
             return "ok"
         raise ValueError(f"bad subcommand: {args[0]}")
+
+    def _telemetry_loop(self, tel) -> str:
+        """The loop's time ledger since the node started, as shares
+        of ``loop.wall.ns`` (metrics.LOOP_METRICS /
+        PIPELINE_METRICS): the sections timed outside publish
+        batches, the selector's time by what the loop was waiting
+        for, and the device path's occupancy."""
+        if tel.loop_clock() is None:
+            return "telemetry: disabled ([telemetry] enabled = false)"
+        val = self.node.metrics.val
+        wall = val("loop.wall.ns")
+        if not wall:
+            return "loop: no heartbeat yet"
+
+        def row(name, ns, calls=None, indent=""):
+            tail = f"  {calls} calls" if calls is not None else ""
+            return (f"{indent + name:<26}{ns / 1e9:>12.3f}s"
+                    f"{100.0 * ns / wall:>8.2f}%{tail}")
+
+        sel = val("loop.select.ns")
+        kinds = [(k, val(f"loop.select.{k}.ns"))
+                 for k in ("poll", "device", "clients")]
+        gc_ns = sum(val(f"gc.ns.gen{g}") for g in range(3))
+        lines = [row("wall", wall)]
+        for name in ("read", "flush", "stats", "select"):
+            lines.append(row(name, val(f"loop.{name}.ns"),
+                             val(f"loop.{name}.calls")))
+        for k, ns in kinds:
+            lines.append(row(k, ns, indent="  "))
+        # a linger timer, a batch past its fetch behind its predecessor
+        lines.append(row("other", sel - sum(ns for _k, ns in kinds),
+                         indent="  "))
+        lines.append(row("gc", gc_ns, sum(
+            val(f"gc.collections.gen{g}") for g in range(3))))
+        lines.append(row("stalls", val("loop.stall.ns"),
+                         val("loop.stalls")))
+        path = val("pipeline.device.ns")
+        lines.append(row("device path occupied", path))
+        if path:
+            lines.append(f"{'device path depth':<26}"
+                         f"{val('pipeline.device.batch_ns') / path:>12.3f}"
+                         f"  batches overlapping, mean")
+        return "\n".join(lines)
 
     def _log(self, args) -> str:
         import logging

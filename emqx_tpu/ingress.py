@@ -122,6 +122,13 @@ class IngressBatcher:
         self._t_done = 0.0
         self._handle = None
         self._inflight = 0
+        # batches on the DEVICE PATH (telemetry on only): enqueued by
+        # publish_begin, their publish_fetch not yet back on the loop.
+        # With ``_inflight`` and ``_pending`` it is what the selector's
+        # shadow (monitors.SysMon) reads to say what the loop waits
+        # for; all three change on the home loop (a peer loop's append
+        # wakes it), so the shadow takes no lock
+        self._on_path = 0
         self._chain: Optional[asyncio.Task] = None  # ordered delivery
         self._pool: Optional[ThreadPoolExecutor] = None
         # the admission line (``admit``): read loops that met the
@@ -578,6 +585,9 @@ class IngressBatcher:
                 self._resolve(pending, pb.results)
                 continue
             self._inflight += 1
+            if span is not None and not pb.done \
+                    and pb.host_topics is None:
+                self._on_path += 1  # down where its fetch returns
             loop = asyncio.get_running_loop()
             prev = self._chain if chain_active else None
             task = loop.create_task(self._complete(pb, pending, prev))
@@ -618,6 +628,9 @@ class IngressBatcher:
                     await loop.run_in_executor(
                         self._executor(), self.broker.publish_fetch,
                         pb)
+                finally:
+                    if sp is not None:
+                        self._on_path -= 1  # off the device path
             if prev is not None:
                 # ordered delivery across batches; a failed
                 # predecessor already resolved its own futures

@@ -328,7 +328,9 @@ LOOP_METRICS = [
     "loop.flush.ns", "loop.flush.calls", "loop.flush.wait_ns",
     # the loop inside its selector: waiting for a socket or a timer,
     # or polling with work queued (monitors.SysMon wraps the
-    # selector's select) — 1 − select ÷ wall is the loop's busy share
+    # selector's select). Polls are kernel work inside ``select``, so
+    # 1 − select ÷ wall understates the loop's busy share by
+    # ``loop.select.poll.ns`` (below)
     "loop.select.ns", "loop.select.calls",
     # every garbage collection by generation (monitors.SysMon's
     # gc.callbacks hook), not only those over long_gc_ms
@@ -343,6 +345,35 @@ LOOP_METRICS = [
     # ``*.ns`` over this one's is that section's share of the window
     # (to one beat, 20 ms), whoever cut the window and however late
     "loop.wall.ns",
+    # ``loop.select.ns`` by what the loop was waiting for, decided
+    # from the state at each call's entry (monitors.SysMon's shadow;
+    # any change of that state wakes the loop): ``poll`` = called
+    # with timeout 0, the loop had ready handles (kernel work, not a
+    # wait); ``device`` = a blocking call with a batch on the device
+    # path (enqueued by publish_begin, its publish_fetch not yet back
+    # on the loop: ingress.IngressBatcher._on_path); ``clients`` = a
+    # blocking call with nothing on the device path, no batch in the
+    # pipeline and nothing accumulated: nothing to do until a socket
+    # speaks. What is left of ``loop.select.ns`` (a linger timer, a
+    # batch past its fetch waiting on its predecessor) has no counter
+    "loop.select.poll.ns", "loop.select.device.ns",
+    "loop.select.clients.ns",
+    # the stats flush (Node._update_stats, once a stats interval):
+    # exclusive like read and flush
+    "loop.stats.ns", "loop.stats.calls",
+]
+
+# the device path's occupancy, from the publish spans' interval record
+# (telemetry.Telemetry.finish; gated on [telemetry] enabled): ``ns`` =
+# the union over device batches of [``t_enq``: the clock just before
+# the batch's first device call, the end of its ``fetch`` stage] — the
+# time the host held the device path occupied, whose complement in
+# ``loop.wall.ns`` is time in which the chip had been given nothing;
+# ``batch_ns`` = the same summed per batch, so batch_ns ÷ ns is the
+# mean number of batches overlapping on the path. Host batches add
+# nothing
+PIPELINE_METRICS = [
+    "pipeline.device.ns", "pipeline.device.batch_ns",
 ]
 
 # what a [matcher] mesh adds to the match dispatch (router.py's mesh
@@ -407,7 +438,7 @@ ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + OPS_METRICS + DURABILITY_METRICS + CLUSTER_METRICS
                + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS
                + MESH_METRICS + DISPATCH_METRICS + CHANNEL_METRICS
-               + INGRESS_METRICS)
+               + INGRESS_METRICS + PIPELINE_METRICS)
 
 #: registry names that are NOT monotonic — ``Metrics.dec`` runs on
 #: them in steady state (today: the retainer's live-entry count,
@@ -556,6 +587,11 @@ I_FLUSH_NS = _global._index["loop.flush.ns"]
 I_SELECT_NS = _global._index["loop.select.ns"]
 I_WALL_NS = _global._index["loop.wall.ns"]
 I_GC_NS = _global._index["gc.ns.gen0"]
+I_SELECT_POLL_NS = _global._index["loop.select.poll.ns"]
+I_SELECT_DEVICE_NS = _global._index["loop.select.device.ns"]
+I_SELECT_CLIENTS_NS = _global._index["loop.select.clients.ns"]
+I_STATS_NS = _global._index["loop.stats.ns"]
+I_PIPELINE_NS = _global._index["pipeline.device.ns"]
 
 
 def global_metrics() -> Metrics:

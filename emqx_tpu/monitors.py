@@ -36,7 +36,8 @@ from jax.profiler import TraceAnnotation
 
 from emqx_tpu.alarm import AlarmManager
 from emqx_tpu.concurrency import bg_thread
-from emqx_tpu.metrics import I_SELECT_NS, I_WALL_NS
+from emqx_tpu.metrics import (I_SELECT_CLIENTS_NS, I_SELECT_DEVICE_NS,
+                              I_SELECT_POLL_NS, I_WALL_NS)
 from emqx_tpu.telemetry import STALL_S
 from emqx_tpu.tracing import frame_stack
 
@@ -190,8 +191,12 @@ class SysMon:
     def __init__(self, metrics=None, hooks=None,
                  long_schedule_ms: float = 240.0,
                  long_gc_ms: float = 100.0,
-                 tick: float = 1.0, telemetry=None) -> None:
+                 tick: float = 1.0, telemetry=None,
+                 ingress=None) -> None:
         self.metrics = metrics
+        #: the node's IngressBatcher (None = none): the selector's
+        #: shadow reads from it what the loop is waiting for
+        self.ingress = ingress
         if metrics is not None:
             metrics.new("sysmon.long_gc")
             metrics.new("sysmon.long_schedule")
@@ -332,21 +337,40 @@ class SysMon:
         """``loop.select.ns``: time the loop spends inside its
         selector, by shadowing the selector's ``select`` on the
         instance (asyncio has no hook for it; a loop without a
-        ``_selector`` — not the stock selector loop — goes untimed)."""
+        ``_selector`` — not the stock selector loop — goes untimed).
+
+        Each call's time also goes to what the loop was waiting for
+        (``loop.select.poll.ns`` / ``.device.ns`` / ``.clients.ns``,
+        metrics.LOOP_METRICS), decided from the ingress's state at the
+        call's ENTRY: whatever changes that state (a fetch returning,
+        a socket speaking, a peer loop's submit) wakes the loop, so
+        it holds for the whole call. Attribute loads only: no lock
+        and no call in here."""
         sel = getattr(loop, "_selector", None)
         if lc is None or sel is None \
                 or "select" in getattr(sel, "__dict__", {"select": 0}):
             return  # untimed; or another node on this loop times it
         select = sel.select
         now = time.perf_counter
+        ing = self.ingress
 
         def timed_select(timeout=None):
             t0 = now()
             n0 = lc.inner
+            if timeout == 0:
+                kind = I_SELECT_POLL_NS  # ready handles: kernel work
+            elif ing is None:
+                kind = -1
+            elif ing._on_path:
+                kind = I_SELECT_DEVICE_NS  # a fetch is still out
+            elif ing._inflight or ing._pending:
+                kind = -1  # a linger timer, an ordered tail
+            else:
+                kind = I_SELECT_CLIENTS_NS  # nothing until a socket speaks
             try:
                 return select(timeout)
             finally:
-                lc.loop_leave(I_SELECT_NS, t0, n0)
+                lc.select_leave(kind, t0, n0)
 
         sel.select = timed_select
         self._selector = sel

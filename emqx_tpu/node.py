@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from typing import List, Optional
 
 from emqx_tpu import faults as _faults
@@ -25,7 +26,7 @@ from emqx_tpu.gc import GlobalGc, freeze_resident
 from emqx_tpu.hooks import Hooks
 from emqx_tpu.ingress import IngressBatcher
 from emqx_tpu.monitors import OsMon, SysMon, VmMon
-from emqx_tpu.metrics import Metrics
+from emqx_tpu.metrics import I_STATS_NS, Metrics
 from emqx_tpu.modules import ModuleRegistry
 from emqx_tpu.overload import (DeviceBreaker, OverloadConfig,
                                OverloadMonitor)
@@ -205,7 +206,8 @@ class Node:
         self.vm_mon = VmMon(self.alarms, self.cm.connection_count,
                             max_count=1024000)
         self.sys_mon = SysMon(metrics=self.metrics, hooks=self.hooks,
-                              telemetry=self.telemetry)
+                              telemetry=self.telemetry,
+                              ingress=self.ingress)
         self.global_gc = GlobalGc()
         # extension system
         self.modules = ModuleRegistry(self)
@@ -526,6 +528,22 @@ class Node:
                 log.exception("sys heartbeat failed")
 
     def _update_stats(self, stats: Stats) -> None:
+        """The stats flush, once a stats interval on the loop: a timed
+        section of the loop's ledger (``loop.stats.ns`` / ``.calls``,
+        exclusive like a read chunk or a flush wake-up) around
+        :meth:`_fold_stats`."""
+        lc = self.telemetry.loop_clock()
+        if lc is None:
+            self._fold_stats(stats)
+            return
+        t0 = time.perf_counter()
+        n0 = lc.inner
+        try:
+            self._fold_stats(stats)
+        finally:
+            lc.loop_leave(I_STATS_NS, t0, n0)
+
+    def _fold_stats(self, stats: Stats) -> None:
         # node lifecycle gauge (docs/OPERATIONS.md): 0 running /
         # 1 draining / 2 stopping — the fleet dashboard's one-glance
         # "is anything mid-maintenance" signal

@@ -10,9 +10,12 @@ Exposed as:
   - :func:`report` — what an operator needs from such a trace
     without TensorBoard: the device's busy share, and the longest
     device idle gaps, each with the host annotations
-    (``emqx/<stage>``, telemetry.py) that overlap it. The
-    annotations and the device's ``XLA Ops`` line sit in one
-    ``xplane.pb``, so they share a clock;
+    (``emqx/<stage>``, telemetry.py) that overlap it and the share
+    of it in which a batch was on the device path (between its
+    ``emqx/enqueue`` mark and the end of its ``emqx/fetch``), and
+    per batch what the host adds before the chip starts and after
+    it is done. The annotations and the device's ``XLA Ops`` line
+    sit in one ``xplane.pb``, so they share a clock;
   - ctl integration: ``profile start <dir>`` / ``profile stop`` /
     ``profile report <dir>`` on a live node (registered by Node via
     :func:`register_ctl`).
@@ -20,12 +23,13 @@ Exposed as:
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import glob
 import os
 from typing import Dict, List, Optional
 
-from emqx_tpu.telemetry import union_s
+from emqx_tpu.telemetry import ENQUEUE_ANN, union_s
 
 
 #: the in-checkout cache location, resolved from the package (never
@@ -116,17 +120,93 @@ def read_trace(trace_dir: str) -> tuple:
     return ops, anns
 
 
+#: the stage whose end closes a batch's stretch on the device path
+FETCH_ANN = "emqx/fetch"
+
+
+def path_stretches(anns: List[tuple]) -> List[tuple]:
+    """``(start_s, end_s, seq)`` of every batch the trace holds whole
+    on the device path: from the start of its ``emqx/enqueue`` mark
+    (telemetry.PublishSpan.enqueue: its first device call) to the end
+    of its ``emqx/fetch`` stage, sorted by start. A batch the trace
+    cut at either end is left out."""
+    enq: Dict[object, float] = {}
+    fetched: Dict[object, float] = {}
+    for a, b, name, seq in anns:
+        if seq is None:
+            continue
+        if name == ENQUEUE_ANN:
+            enq[seq] = min(a, enq.get(seq, a))
+        elif name == FETCH_ANN:
+            fetched[seq] = max(b, fetched.get(seq, b))
+    return sorted((a, fetched[seq], seq) for seq, a in enq.items()
+                  if fetched.get(seq, a) > a)
+
+
+def _spread(xs: List[float]) -> dict:
+    """Count, median and p99 (nearest rank), milliseconds."""
+    xs = sorted(xs)
+    n = len(xs)
+    if not n:
+        return {"count": 0, "median": None, "p99": None}
+    return {"count": n, "median": xs[n // 2] * 1e3,
+            "p99": xs[min(n - 1, int(0.99 * n))] * 1e3}
+
+
+def path_latencies(ops: List[tuple], stretches: List[tuple]) -> dict:
+    """What the host adds around the chip's work, per batch on the
+    device path: ``enqueue_to_first_op_ms`` (the ``emqx/enqueue``
+    mark → the start of the first device operation inside the batch's
+    stretch: launch latency) and ``device_done_to_fetch_ms`` (the end
+    of the last device operation inside the stretch → the end of
+    ``emqx/fetch``: the copy back, ``device_get``'s return, the
+    executor thread getting the interpreter back), each as ``{"count",
+    "median", "p99"}``. A batch with no device operation inside its
+    stretch is counted in ``no_device_op``, never as 0. Where
+    stretches overlap (a pipeline more than one deep) an operation
+    inside two of them counts for both: the trace does not say whose
+    it is."""
+    ops = sorted((a, b) for a, b, _n in ops)
+    starts = [a for a, _b in ops]
+    first, done, empty = [], [], 0
+    for lo, hi, _seq in stretches:
+        i = bisect.bisect_left(starts, lo)
+        last = None
+        while i < len(ops) and ops[i][0] < hi:
+            if ops[i][1] <= hi:
+                if last is None:
+                    first.append(ops[i][0] - lo)
+                    last = ops[i][1]
+                elif ops[i][1] > last:
+                    last = ops[i][1]
+            i += 1
+        if last is None:
+            empty += 1
+        else:
+            done.append(hi - last)
+    return {"batches": len(stretches), "no_device_op": empty,
+            "enqueue_to_first_op_ms": _spread(first),
+            "device_done_to_fetch_ms": _spread(done)}
+
+
 def attribute(ops: List[tuple], anns: List[tuple],
               top: int = 5) -> dict:
-    """Device busy share, and the ``top`` longest device idle gaps
-    with the host annotations that overlap each.
+    """Device busy share, the ``top`` longest device idle gaps with
+    the host annotations that overlap each, and the device path's
+    latencies.
 
     Returns ``{"window_s", "device_busy_s", "device_busy_share",
-    "device_ops", "annotations", "gaps"}``; a gap is ``{"start_s"``
-    (from the first event of the trace), ``"seconds", "before"`` (the
-    op that ended it), ``"host"``: ``[[name, seq, share of the gap],
-    ...]`` largest first, closed by ``[OUTSIDE, None, share]`` — the
-    part of the gap no annotation covers``}``. Without a device op
+    "device_ops", "annotations", "gaps", "device_path"}``; a gap is
+    ``{"start_s"`` (from the first event of the trace), ``"seconds",
+    "before"`` (the op that ended it), ``"in_flight"`` (the share of
+    the gap in which some batch stood between its ``emqx/enqueue``
+    mark and the end of its ``emqx/fetch``: a gap inside is launch,
+    transfer or wake-up latency, a gap outside is the host giving the
+    chip nothing; None where the trace holds no such mark),
+    ``"host"``: ``[[name, seq, share of the gap], ...]`` largest
+    first, closed by ``[OUTSIDE, None, share]`` — the part of the gap
+    no annotation covers``}``. ``device_path`` is
+    :func:`path_latencies` (None without a mark). Without a device op
     (the CPU backend has no device plane) the busy share is None."""
     if not ops and not anns:
         raise ValueError("the trace holds neither a device operation "
@@ -135,9 +215,13 @@ def attribute(ops: List[tuple], anns: List[tuple],
     t_hi = max(x[1] for x in ops + anns)
     out = {"window_s": t_hi - t_lo, "device_ops": len(ops),
            "annotations": len(anns), "device_busy_s": None,
-           "device_busy_share": None, "gaps": []}
+           "device_busy_share": None, "gaps": [], "device_path": None}
     if not ops:
         return out
+    marked = any(name == ENQUEUE_ANN for _a, _b, name, _s in anns)
+    stretches = path_stretches(anns) if marked else []
+    if marked:
+        out["device_path"] = path_latencies(ops, stretches)
     busy = union_s([(a, b) for a, b, _n in ops])
     out["device_busy_s"] = busy
     out["device_busy_share"] = busy / (t_hi - t_lo)
@@ -163,6 +247,9 @@ def attribute(ops: List[tuple], anns: List[tuple],
         out["gaps"].append({
             "start_s": g0 - t_lo, "seconds": length,
             "before": name.partition(" = ")[0].strip()[:80],
+            "in_flight": union_s(
+                (max(a, g0), min(b, g1)) for a, b, _seq in stretches
+                if min(b, g1) > max(a, g0)) / length if marked else None,
             "host": host})
     return out
 
@@ -184,13 +271,31 @@ def render_report(rep: dict, per_gap: int = 8) -> str:
                  f"{100.0 * rep['device_busy_share']:.3f}% of the "
                  f"trace")
     for i, g in enumerate(rep["gaps"], 1):
+        fl = g.get("in_flight")
         lines.append(f"gap {i}: {g['seconds'] * 1e3:.3f}ms at "
-                     f"+{g['start_s']:.3f}s, ended by {g['before']}")
+                     f"+{g['start_s']:.3f}s, ended by {g['before']}"
+                     + ("" if fl is None else
+                        f", a batch on the device path "
+                        f"{100.0 * fl:.1f}% of it"))
         # the largest annotations, then always the uncovered rest
         for name, seq, share in g["host"][:-1][:per_gap - 1] \
                 + g["host"][-1:]:
             tag = f" seq={seq}" if seq is not None else ""
             lines.append(f"    {100.0 * share:6.2f}%  {name}{tag}")
+    path = rep.get("device_path")
+    if path is None:
+        lines.append("device path: no emqx/enqueue mark in this trace")
+        return "\n".join(lines)
+    lines.append(f"device path: {path['batches']} batches from "
+                 f"emqx/enqueue to the end of emqx/fetch, "
+                 f"{path['no_device_op']} with no device op inside")
+    for key, what in (
+            ("enqueue_to_first_op_ms", "enqueue -> first device op"),
+            ("device_done_to_fetch_ms", "device done -> fetch returned")):
+        sp = path[key]
+        if sp["count"]:
+            lines.append(f"    {what}: {sp['count']} batches, median "
+                         f"{sp['median']:.3f}ms, p99 {sp['p99']:.3f}ms")
     return "\n".join(lines)
 
 

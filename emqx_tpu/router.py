@@ -42,6 +42,7 @@ from emqx_tpu.ops.csr import Automaton, build_automaton, device_view
 from emqx_tpu.ops.match import depth_bucket, match_batch
 from emqx_tpu.ops.patch import AutoPatcher, PatchOverflow
 from emqx_tpu.ops.tokenize import WordTable, encode_batch
+from emqx_tpu.telemetry import enqueue_mark
 from emqx_tpu.types import Route
 
 log = logging.getLogger("emqx_tpu.router")
@@ -1658,9 +1659,12 @@ class Router:
             hb *= 2
         return shapes
 
-    def match_dispatch(self, topics: Sequence[str]):
+    def match_dispatch(self, topics: Sequence[str], span=None):
         """Dispatch-only device match: encode + enqueue the compiled
-        walk and return WITHOUT any device→host sync.
+        walk and return WITHOUT any device→host sync. ``span`` is the
+        batch's telemetry span (None = untimed): the first device
+        call is made inside its ``emqx/enqueue`` mark
+        (telemetry.enqueue_mark).
 
         Returns ``(ids_dev, ovf_dev, id_map, epoch)`` — both arrays
         are in-flight device values ([B_pad, M] / [B_pad]); feed
@@ -1675,7 +1679,7 @@ class Router:
             return self._match_dispatch_sharded(topics)
         cache = self._match_cache()
         if cache is not None:
-            return self._match_dispatch_cached(topics, cache)
+            return self._match_dispatch_cached(topics, cache, span)
         dsnap = None
         if self._delta_active:
             main, dsnap = self._snapshot_pair()
@@ -1693,10 +1697,11 @@ class Router:
         with self._wt_lock:
             ids, n, sysm = self._encode(padded, cfg.max_levels)
         ids, n = depth_bucket(ids, n)
-        res = match_batch(auto, ids, n, sysm,
-                          k=self.effective_k(),
-                          m=cfg.max_matches, pack_ids=False,
-                          **self._walk_kw(ids.shape[1]))
+        with enqueue_mark(span):
+            res = match_batch(auto, ids, n, sysm,
+                              k=self.effective_k(),
+                              m=cfg.max_matches, pack_ids=False,
+                              **self._walk_kw(ids.shape[1]))
         out_ids, out_ovf = res.ids, res.overflow
         if dsnap is not None:
             # two-probe: union the side-automaton's raw emits +
@@ -1724,7 +1729,8 @@ class Router:
                 cfg.match_cache_slots, cfg.max_matches)
         return self._match_cache_obj
 
-    def _match_dispatch_cached(self, topics: Sequence[str], cache):
+    def _match_dispatch_cached(self, topics: Sequence[str], cache,
+                               span=None):
         """Cache-split device match: probe the epoch-guarded cache,
         walk ONLY the misses (``pack_ids=True`` — the per-topic
         compaction buys fixed-width rows the cache and merge reuse),
@@ -1772,10 +1778,11 @@ class Router:
             with self._wt_lock:
                 ids, n, sysm = self._encode(padded, cfg.max_levels)
             ids, n = depth_bucket(ids, n)
-            res = match_batch(auto, ids, n, sysm,
-                              k=self.effective_k(),
-                              m=cfg.max_matches, pack_ids=True,
-                              **self._walk_kw(ids.shape[1]))
+            with enqueue_mark(span):
+                res = match_batch(auto, ids, n, sysm,
+                                  k=self.effective_k(),
+                                  m=cfg.max_matches, pack_ids=True,
+                                  **self._walk_kw(ids.shape[1]))
             miss_rows, miss_ovf = res.ids, res.overflow
             if dsnap is not None:
                 # two-probe: fold the side-automaton + tombstone mask
@@ -1790,8 +1797,10 @@ class Router:
                     m=cfg.max_matches)
             cache.insert(probe, miss_rows, miss_ovf)
         t2 = time.perf_counter() if timed else 0.0
-        ids_dev, ovf_dev, _movf = cache.merge(bucket, probe,
-                                              miss_rows, miss_ovf)
+        # the first device call of a batch whose topics all hit
+        with enqueue_mark(span):
+            ids_dev, ovf_dev, _movf = cache.merge(bucket, probe,
+                                                  miss_rows, miss_ovf)
         if timed:
             # probe (host hash walk) + merge (HBM-gather dispatch) =
             # the cache_gather share of this dispatch; the remainder
@@ -1998,7 +2007,7 @@ class Router:
         return all_ids, ovf, id_map, epoch
 
     def publish_dispatch_sharded(self, topics: Sequence[str],
-                                 fan_provider, placed=None):
+                                 fan_provider, placed=None, span=None):
         """The PRODUCT multi-chip publish dispatch: match AND fan-out
         in one collective step (``parallel.sharded.publish_step`` with
         real per-shard fan tables, ``with_fanout=True``).
@@ -2030,12 +2039,13 @@ class Router:
         if topics is not None:
             self._count_mesh("mesh.batches", "mesh.topics", len(topics))
             if placed is None:
-                out = self._dispatch_fused(topics, fan_provider)
+                out = self._dispatch_fused(topics, fan_provider, span)
                 if out is not None:
                     self._count_mesh("mesh.fused")
                     return out
         out = self._dispatch_sharded(topics, fan=fan_provider,
-                                     with_big=True, placed=placed)
+                                     with_big=True, placed=placed,
+                                     span=span)
         if topics is None:
             return out
         from emqx_tpu.ops.pack import mask_pad_rows
@@ -2096,7 +2106,8 @@ class Router:
             self._sharded_cache_meta = meta
         return self._sharded_cache_obj
 
-    def _dispatch_fused(self, topics: Sequence[str], fan_provider):
+    def _dispatch_fused(self, topics: Sequence[str], fan_provider,
+                        span=None):
         """Cache-split mesh publish dispatch, or None when the cache
         does not apply (disabled, no fan state, or big-filter bitmaps
         live — a bitmap union row is megabytes at 10M subs, far past
@@ -2165,7 +2176,8 @@ class Router:
         lay, buf = cache.mesh_buffer(bucket, probe, enc, cfg.max_levels,
                                      len(topics), self._mesh_buf_len)
         self._mesh_buf_len = lay.size
-        buf = jax.device_put(buf, cache.sharding)
+        with enqueue_mark(span):  # the batch's one transfer
+            buf = jax.device_put(buf, cache.sharding)
         miss_vals = None
         if misses:
             # a collective program is enqueued for these topics
@@ -2192,7 +2204,7 @@ class Router:
         return (ids, subs, src, None, ovf, movf, id_map, epoch,
                 frozenset())
 
-    def encode_place_sharded(self, topics: Sequence[str]):
+    def encode_place_sharded(self, topics: Sequence[str], span=None):
         """Host half of the sharded dispatch: encode a topic batch
         (padded to a bucket that splits evenly over the data axis)
         and place it on the mesh. Returns ``(ids, n, sysm, rev)``
@@ -2214,10 +2226,12 @@ class Router:
         padded = list(topics) + ["\x00/pad"] * (bucket - B)
         with self._wt_lock:
             ids, n, sysm = self._encode(padded, cfg.max_levels)
-        return (*place_batch(mesh, ids, n, sysm), rev)
+        with enqueue_mark(span):  # the legacy dispatch's transfer
+            return (*place_batch(mesh, ids, n, sysm), rev)
 
     def _dispatch_sharded(self, topics: Sequence[str], fan=None,
-                          with_big: bool = False, placed=None):
+                          with_big: bool = False, placed=None,
+                          span=None):
         from emqx_tpu.parallel.sharded import publish_step
 
         cfg = self.config
@@ -2243,21 +2257,24 @@ class Router:
                     raise ValueError(
                         "stale placed batch (routes changed since "
                         "encode) and no topics to re-encode from")
-                ids, n, sysm, _ = self.encode_place_sharded(topics)
+                ids, n, sysm, _ = self.encode_place_sharded(topics,
+                                                            span)
         else:
-            ids, n, sysm, _ = self.encode_place_sharded(topics)
+            ids, n, sysm, _ = self.encode_place_sharded(topics, span)
         use_fan = fan_tables is not None
         if topics is not None:
             # a collective program is enqueued for these topics (the
             # cache-split path sends only its misses here)
             self._count_mesh("mesh.steps", "mesh.step.topics",
                              len(topics))
-        all_ids, subs, src, bm, ovf, movf, stats = publish_step(
-            mesh, auto, fan_tables if use_fan else self._dummy_fan,
-            ids, n, sysm, bmt, k=self.effective_k(), m=cfg.max_matches,
-            d=self.effective_d() if use_fan else 8,
-            mb=cfg.fanout_mb, with_fanout=use_fan,
-            **self._walk_kw(int(ids.shape[-1])))
+        with enqueue_mark(span):  # a pre-placed batch's first call
+            all_ids, subs, src, bm, ovf, movf, stats = publish_step(
+                mesh, auto, fan_tables if use_fan else self._dummy_fan,
+                ids, n, sysm, bmt, k=self.effective_k(),
+                m=cfg.max_matches,
+                d=self.effective_d() if use_fan else 8,
+                mb=cfg.fanout_mb, with_fanout=use_fan,
+                **self._walk_kw(int(ids.shape[-1])))
         self._dev_stats.append(stats)
         if with_big:
             return (all_ids, subs if use_fan else None,

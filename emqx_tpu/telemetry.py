@@ -108,16 +108,36 @@ the thread's line of the ``/host:CPU`` plane when one does — the same
 device ops share a clock (``ctl profile report``). Waits are the gaps
 between one batch's annotations.
 
+One instant is kept beside the intervals: ``t_enq``, the clock just
+before the batch's first device call (the put of its buffer or its
+first program; router.py's dispatch, through :func:`enqueue_mark`),
+held around that call as the annotation ``emqx/enqueue`` — where the
+batch enters the DEVICE PATH. It leaves it at the end of its
+``fetch`` interval. :meth:`Telemetry.finish` keeps the union of those
+stretches over batches in ``pipeline.device.ns`` (the time the host
+held the device path occupied; its complement in ``loop.wall.ns`` is
+time in which the chip had been given nothing) and their per-batch sum
+in ``pipeline.device.batch_ns`` (over the union: how many batches
+overlap on the path). A host batch has no ``t_enq`` and adds nothing.
+
 The loop outside publish batches is counted, not spanned:
 ``loop.read.*`` (socket read → parse → channel → submit, per read
 chunk), ``loop.flush.*`` (``Connection._flush_deliver`` per wake-up),
+``loop.stats.*`` (the stats flush, ``Node._update_stats``),
 ``loop.select.*`` (the loop inside its selector: waiting, or polling
 with work queued) and ``gc.ns.gen*`` / ``gc.collections.gen*`` are
 ``Metrics`` counters holding nanoseconds EXCLUSIVE of whatever nested
 inside them (:attr:`Telemetry.inner`), so on-loop stages −
-``gc_inside`` + read + flush + select + gc sum to the loop's
+``gc_inside`` + read + flush + stats + select + gc sum to the loop's
 attributed time without counting a second twice; what is left of the
-wall clock is loop work that has no name yet. Exact on a single-loop node; with ``[node] loops > 1``
+wall clock is loop work that has no name yet. Each selector call's
+time goes a second time to what the loop was waiting for, read from
+the ingress's state at the call's entry
+(:meth:`Telemetry.select_leave`): ``loop.select.poll.ns`` (timeout 0:
+kernel work, no wait), ``.device.ns`` (a batch on the device path),
+``.clients.ns`` (nothing anywhere: nothing to do until a socket
+speaks); the rest of ``loop.select.ns`` has no counter. Exact on a
+single-loop node; with ``[node] loops > 1``
 the peer loops share the one accumulator and the split is
 approximate. Loop stalls (the heartbeat of ``monitors.SysMon``
 overdue by more than 50 ms) land in a bounded ring beside the
@@ -126,14 +146,19 @@ slow-publish ring (:meth:`Telemetry.note_stall`).
 Cost model: disabled (``[telemetry] enabled = false``) the broker
 takes one predicate branch per batch and records nothing — the
 dispatch byte-stream is identical to the un-instrumented path (pinned
-by tests/test_telemetry.py) and none of the counters above moves.
-Enabled, the cost is ~20 ``perf_counter`` reads and ~8 annotation
-objects per batch (not per message), two clock reads per socket read
-chunk and three per delivery-flush wake-up.
+by tests/test_telemetry.py), the selector is not shadowed and none of
+the counters above moves. Enabled, the cost is ~21 ``perf_counter``
+reads and ~9 annotation objects per batch (not per message; ``t_enq``
+and its mark are one of each), a scan of the span's first few
+intervals and two counter adds at its finish, two clock reads per
+socket read chunk, three per delivery-flush wake-up, and per selector
+call two clock reads, a compare and — on a blocking call only — one
+to three attribute loads of the ingress.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import logging
@@ -144,7 +169,7 @@ from typing import Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
-from emqx_tpu.metrics import I_GC_NS
+from emqx_tpu.metrics import I_GC_NS, I_PIPELINE_NS, I_SELECT_NS
 
 log = logging.getLogger("emqx_tpu.telemetry")
 
@@ -168,6 +193,11 @@ STAGES = ("ingress_wait", "prepare", "match", "cache_gather", "pack",
 
 #: profiler annotation names, built once (``emqx/<stage>``)
 _ANN = {s: "emqx/" + s for s in STAGES}
+#: the mark around a batch's first device call
+#: (:meth:`PublishSpan.enqueue`): no stage, the start of the device
+#: path on the trace's clock
+ENQUEUE_ANN = "emqx/enqueue"
+_UNMARKED = contextlib.nullcontext()
 
 #: the heartbeat's lateness past which the loop counts as stalled
 #: (fixed, not a configuration key: monitors.SysMon)
@@ -197,6 +227,13 @@ def union_s(intervals) -> float:
             total += t1 - end
             end = t1
     return total
+
+
+def enqueue_mark(span):
+    """What the dispatch holds around a device call that may be the
+    batch's first: :meth:`PublishSpan.enqueue`, or nothing without a
+    span (telemetry off, a warm-up batch)."""
+    return span.enqueue() if span is not None else _UNMARKED
 
 
 @dataclasses.dataclass
@@ -305,7 +342,7 @@ class PublishSpan:
     __slots__ = ("seq", "t0", "t_mark", "home", "tel", "ivs", "stages",
                  "batch", "n_uniq", "bucket", "path", "cache_hit",
                  "cache_miss", "fallbacks", "inflight", "topic",
-                 "closed", "open")
+                 "closed", "open", "t_enq")
 
     def __init__(self, batch: int, seq: int = 0, tel=None,
                  t_first: Optional[float] = None,
@@ -317,6 +354,10 @@ class PublishSpan:
         #: end of the last interval on the batch's critical path —
         #: the next wait starts here (:meth:`wait_mark`)
         self.t_mark = now
+        #: the clock just before the batch's first device call (the
+        #: put of its buffer or its first program): where the batch
+        #: enters the device path. 0.0 = it never did (a host batch)
+        self.t_enq = 0.0
         self.seq = seq
         self.home = threading.get_ident()
         self.tel = tel
@@ -400,6 +441,17 @@ class PublishSpan:
         self.add_ms("match", -gather)
         self.add_ms("cache_gather", gather)
 
+    def enqueue(self):
+        """The batch's first device call is next (router.py's
+        dispatch, inside the ``match`` stage): stamp ``t_enq`` and
+        hand back the ``emqx/enqueue`` annotation to hold around the
+        call. Any later call of the batch is not its first: no
+        stamp, no mark."""
+        if self.t_enq:
+            return _UNMARKED
+        self.t_enq = _now()
+        return TraceAnnotation(ENQUEUE_ANN, seq=self.seq)
+
     # -- waits ------------------------------------------------------------
 
     def wait(self, stage: str, t_start: float, t_end: float) -> None:
@@ -434,6 +486,18 @@ class PublishSpan:
         """Seconds covered by the union of the span's intervals."""
         return union_s((iv[1], iv[2]) for iv in self.ivs)
 
+    def device_path(self) -> Optional[tuple]:
+        """``(t_enq, end of the fetch stage)``: the stretch this
+        batch held the device path, or None for a batch that never
+        entered it or never came back through a fetch (a host batch;
+        a dispatch that failed over to the host oracle)."""
+        t_enq = self.t_enq
+        if t_enq:
+            for iv in self.ivs:
+                if iv[0] == "fetch":
+                    return (t_enq, iv[2]) if iv[2] > t_enq else None
+        return None
+
     def record(self) -> dict:
         """The structured form (slow log / ctl telemetry slow)."""
         t0 = self.t0
@@ -456,6 +520,9 @@ class PublishSpan:
                  "wait" if tid == -1 else "executor"]
                 for st, a, b, tid in self.ivs],
         }
+        if self.t_enq:
+            # the first device call, ms after the span's t0
+            rec["t_enq"] = round((self.t_enq - t0) * 1000.0, 3)
         if self.cache_hit >= 0:
             rec["cache_hit"] = self.cache_hit
             rec["cache_miss"] = self.cache_miss
@@ -498,6 +565,9 @@ class Telemetry:
         self.inner = 0.0
         #: running seconds of garbage collection (all generations)
         self.gc_s = 0.0
+        #: where the union of the device path's occupied stretches
+        #: ends so far (``pipeline.device.ns``, :meth:`finish`)
+        self._path_end = 0.0
         #: automaton rebuilds running now / when the last one ended
         self.rebuilding = 0
         self.rebuild_end = 0.0
@@ -535,6 +605,19 @@ class Telemetry:
             if h is not None:
                 h.observe(ms)
         self.spans_total += 1
+        path = span.device_path()
+        if path is not None and self.metrics is not None:
+            # the device path's occupancy: batches finish in the order
+            # they began, so enqueue instants only rise and one end
+            # mark keeps the union of [t_enq, fetch returned]
+            t_enq, t_end = path
+            m = self.metrics
+            m.add_at(I_PIPELINE_NS + 1, int((t_end - t_enq) * 1e9))
+            end = self._path_end
+            if t_end > end:
+                m.add_at(I_PIPELINE_NS,
+                         int((t_end - max(t_enq, end)) * 1e9))
+                self._path_end = t_end
         if e2e >= self.config.slow_threshold_ms:
             self._slow(span, e2e)
         else:
@@ -581,29 +664,42 @@ class Telemetry:
     # -- the loop outside publish batches ---------------------------------
 
     def loop_leave(self, idx: int, t0: float, n0: float,
-                   wait_s: float = -1.0) -> None:
+                   wait_s: float = -1.0) -> int:
         """Close a timed section of the loop (a read chunk, a flush
         wake-up) opened with ``t0 = clock(); n0 = tel.inner``: its
-        exclusive nanoseconds go to the counter at ``idx``, one call
-        to ``idx + 1``, and ``wait_s`` (when given) to ``idx + 2``."""
+        exclusive nanoseconds go to the counter at ``idx`` (and back
+        to the caller), one call to ``idx + 1``, and ``wait_s`` (when
+        given) to ``idx + 2``."""
         dt = _now() - t0
-        own = dt - (self.inner - n0)
+        own = int((dt - (self.inner - n0)) * 1e9)
         self.inner = n0 + dt
         m = self.metrics
         lock = m._lock
         if lock is None:
             c = m._counters
-            c[idx] += int(own * 1e9)
+            c[idx] += own
             c[idx + 1] += 1
             if wait_s >= 0.0:
                 c[idx + 2] += int(wait_s * 1e9)
         else:
             with lock:
                 c = m._counters
-                c[idx] += int(own * 1e9)
+                c[idx] += own
                 c[idx + 1] += 1
                 if wait_s >= 0.0:
                     c[idx + 2] += int(wait_s * 1e9)
+        return own
+
+    def select_leave(self, kind: int, t0: float, n0: float) -> None:
+        """Close one call of the loop's selector
+        (monitors.SysMon's shadow): :meth:`loop_leave` into
+        ``loop.select.ns`` / ``.calls``, and the same exclusive
+        nanoseconds into the counter at ``kind`` (what the loop was
+        waiting for: ``loop.select.poll.ns`` / ``.device.ns`` /
+        ``.clients.ns``; -1 = none of them)."""
+        ns = self.loop_leave(I_SELECT_NS, t0, n0)
+        if kind >= 0:
+            self.metrics.add_at(kind, ns)
 
     def loop_clock(self) -> Optional["Telemetry"]:
         """``self`` when the loop counters are live (enabled, and a

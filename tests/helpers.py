@@ -20,6 +20,42 @@ def node_port(node):
     return node.listeners[0].port
 
 
+class Inbox:
+    """An in-process subscriber that keeps what it is delivered."""
+
+    def __init__(self, client_id="c"):
+        self.client_id = client_id
+        self.inbox = []
+
+    def deliver(self, topic, msg):
+        self.inbox.append((topic, msg))
+
+
+def record_spans(tel) -> list:
+    """Keep every span ``tel`` finishes from now on (the benchmark
+    harness's seam: ``Telemetry.finish`` shadowed on the instance)."""
+    spans = []
+    finish = tel.finish
+
+    def _record(span):
+        if not span.closed:
+            finish(span)
+            spans.append(span)
+    tel.finish = _record
+    return spans
+
+
+async def device_node(name: str, **kw) -> Node:
+    """A started node without listeners whose every batch takes the
+    device path (``device_min_filters = 0``)."""
+    from emqx_tpu.router import MatcherConfig
+
+    node = Node(name=name, boot_listeners=False,
+                matcher=MatcherConfig(device_min_filters=0), **kw)
+    await node.start()
+    return node
+
+
 class Wire:
     """A ``StreamWriter`` and its transport for a ``Connection`` that
     a test drives from an ``asyncio.StreamReader`` through

@@ -16,7 +16,7 @@ import pytest
 from emqx_tpu import profiling
 from emqx_tpu.broker import Broker, DispatchConfig
 from emqx_tpu.hooks import Hooks
-from emqx_tpu.metrics import LOOP_METRICS, Metrics
+from emqx_tpu.metrics import LOOP_METRICS, PIPELINE_METRICS, Metrics
 from emqx_tpu.monitors import SysMon
 from emqx_tpu.node import Node
 from emqx_tpu.router import MatcherConfig, Router
@@ -24,41 +24,15 @@ from emqx_tpu.telemetry import (STAGES, STALL_S, PublishSpan, Telemetry,
                                 TelemetryConfig)
 from emqx_tpu.types import Message
 
+from helpers import Inbox as Q
 from helpers import Wire
+from helpers import device_node as _device_node
+from helpers import record_spans as _record_spans
 from indie_mqtt import IndieClient
 
 #: the stages ISSUE 24 added to the span
 NEW_STAGES = ("ingress_wait", "prepare", "executor_wait", "chain_wait",
               "loop_wait", "tail_yield", "unattributed")
-
-
-class Q:
-    def __init__(self, client_id="c"):
-        self.client_id = client_id
-        self.inbox = []
-
-    def deliver(self, topic, msg):
-        self.inbox.append((topic, msg))
-
-
-def _record_spans(tel: Telemetry) -> list:
-    """Keep every finished span (the benchmark harness's seam)."""
-    spans = []
-    finish = tel.finish
-
-    def _record(span):
-        if not span.closed:
-            finish(span)
-            spans.append(span)
-    tel.finish = _record
-    return spans
-
-
-async def _device_node(name: str, **kw) -> Node:
-    node = Node(name=name, boot_listeners=False,
-                matcher=MatcherConfig(device_min_filters=0), **kw)
-    await node.start()
-    return node
 
 
 # -- part 1: the span records the whole life of a batch ---------------------
@@ -201,7 +175,7 @@ async def test_loop_counters_follow_the_telemetry_gate(enabled):
         gc.collect()
         await asyncio.sleep(3 * SysMon.BEAT_S)
         m = node.metrics
-        vals = {k: m.val(k) for k in LOOP_METRICS}
+        vals = {k: m.val(k) for k in LOOP_METRICS + PIPELINE_METRICS}
         if not enabled:
             assert not any(vals.values()), vals
             assert node.telemetry.spans_total == 0

@@ -44,6 +44,8 @@ BURST = 12          # QoS 0 publishes between two QoS 1 fences
 BREAKER = ("breaker.failures", "breaker.trips", "breaker.fallback.batches")
 MESH_COUNTERS = ("mesh.batches", "mesh.topics", "mesh.steps",
                  "mesh.step.topics", "mesh.fused")
+#: the device path's occupancy (PR 37), stamped on the mesh path too
+PATH_COUNTERS = ("pipeline.device.ns", "pipeline.device.batch_ns")
 
 
 # -- the configuration's edges -----------------------------------------------
@@ -265,7 +267,7 @@ async def _serve(matcher: dict, seed: int):
                              publisher(1, msgs[1:half:2]))
         phase_a = {"spans": list(spans),
                    "counters": {k: node.metrics.val(k) - m0.get(k, 0)
-                                for k in MESH_COUNTERS}}
+                                for k in MESH_COUNTERS + PATH_COUNTERS}}
         # phase B: one filter over fanout_threshold (bitmap rows)
         for b in bigs:
             node.broker.subscribe(b, BIG_FILTER)
@@ -404,6 +406,23 @@ def test_served_mesh_counters(runs):
     assert b["mesh.step.topics"] == b["mesh.topics"]
     # ... through the legacy whole dispatch
     assert b["mesh.fused"] == 0 and c["mesh.fused"] <= c["mesh.batches"]
+
+
+def test_served_mesh_holds_the_device_path(runs):
+    """``pipeline.device.*`` on the mesh: the fused dispatch stamps
+    ``t_enq`` at its one transfer (phase A), the legacy whole dispatch
+    at its placement (phase B); a one-chip node's batches count alike."""
+    mesh, one = runs
+    ca, c = mesh["phase_a"]["counters"], mesh["counters"]
+    for lo, hi in (({k: 0 for k in PATH_COUNTERS}, ca), (ca, c),
+                   ({k: 0 for k in PATH_COUNTERS}, one["counters"])):
+        ns, batch_ns = (hi[k] - lo[k] for k in PATH_COUNTERS)
+        # every batch held the path for a while; overlap counts once
+        assert 0 < ns <= batch_ns <= 4 * ns
+    assert c["pipeline.device.ns"] <= c["loop.wall.ns"] + 20_000_000
+    assert c["loop.select.device.ns"] > 0
+    assert sum(c[f"loop.select.{k}.ns"]
+               for k in ("poll", "device", "clients")) <= c["loop.select.ns"]
 
 
 def test_mesh_device_counters_reach_the_registry():

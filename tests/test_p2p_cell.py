@@ -31,6 +31,12 @@ TWINS = {
     "loop_select_share.p2p": "loop_select_share",
     "device_idle_share.p2p": "device_idle_share",
     "warmers_s.p2p": "warmers_s",
+    # PR 37: who waits for whom, and the device path's occupancy
+    "select_poll_share.p2p": "select_poll_share",
+    "select_wait_device_share.p2p": "select_wait_device_share",
+    "select_wait_clients_share.p2p": "select_wait_clients_share",
+    "device_path_share.p2p": "device_path_share",
+    "device_path_depth.p2p": "device_path_depth",
 }
 #: the cell's own readings: counters over counters
 OWN = {

@@ -222,9 +222,11 @@ def test_disabled_mode_records_nothing_and_dispatch_is_identical():
     assert tel.begin(4) is None  # the broker-facing contract
     # none of the loop counters of the host timeline moves either, and
     # the device.* totals are fed the same with or without telemetry
-    from emqx_tpu.metrics import DEVICE_METRICS, LOOP_METRICS
+    from emqx_tpu.metrics import (DEVICE_METRICS, LOOP_METRICS,
+                                  PIPELINE_METRICS)
     assert tel.loop_clock() is None
-    assert not any(b_off.metrics.val(k) for k in LOOP_METRICS)
+    assert not any(b_off.metrics.val(k)
+                   for k in LOOP_METRICS + PIPELINE_METRICS)
     assert [b_off.metrics.val(k) for k in DEVICE_METRICS] == \
         [b_ref.metrics.val(k) for k in DEVICE_METRICS]
     assert b_off.metrics.val("device.matches") > 0

@@ -85,7 +85,10 @@ def test_the_cells_per_layer_list():
         "prepare_us_per_msg.uniform", "device_idle_share.uniform",
         "warmers_s.uniform",
         # PR 34: the delivery walk's resolutions a delivery
-        "plan_resolve_share.uniform"}
+        "plan_resolve_share.uniform",
+        # PR 37: who waits for whom, and the device path's occupancy
+        "select_wait_device_share.uniform", "device_path_share.uniform",
+        "device_path_depth.uniform"}
     # no accepted metric's list was touched: none names the new cell
     assert all(CELL not in m["workloads"] for m in SPEC["per_layer"]
                if m not in METRICS)
