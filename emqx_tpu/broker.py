@@ -42,7 +42,7 @@ from emqx_tpu.ops.dispatch_plan import (big_rows_for, build_plan,
                                         preserialize_plan)
 from emqx_tpu.ops.fanout import expand_packed
 from emqx_tpu.ops.pack import (budget_for, bundle_i32, mask_pad_flags,
-                               mask_pad_rows, pack_fanout, pack_matches,
+                               pack_chip, pack_fanout, pack_matches,
                                pack_mesh, pack_union_rows)
 from emqx_tpu.router import MatcherConfig, Router
 from emqx_tpu.shared_sub import SharedSub
@@ -192,7 +192,7 @@ class PendingBatch:
         self.sh_big: frozenset = frozenset()
         self.movf_d = self.movf = None
         # the fetch's one buffer where the packer already laid it
-        # (mesh path; a re-pack bundles anew)
+        # (pack_chip / pack_mesh; a re-pack bundles anew)
         self.bundle_d = None
         self.f_ptr = self.subs_packed = None
         self.src_packed = None
@@ -627,7 +627,9 @@ class Broker:
         # unique topics into cache hits (one HBM gather, no NFA walk)
         # and misses (walked, then inserted) — transparent here, the
         # merged [B_pad, M] id array feeds the same fan-out/pack
-        # kernels either way.
+        # kernels either way. With the cache on the batch costs the
+        # loop one transfer and two or three programs: the router's
+        # walk + insert and merge, and one packer here.
         sp = pb.span
         if faults.enabled:
             faults.fire("device.walk")
@@ -640,6 +642,8 @@ class Broker:
             return self._publish_begin_mesh(pb, uniq, cfg)
         if sp is not None:
             sp.start("match")
+        # ids come back with their pad rows blanked (phantom pad-row
+        # matches must not reach the packers or the learned budgets)
         pb.ids_dev, pb.ovf_dev, pb.id_map, pb.epoch = \
             self.router.match_dispatch(uniq, span=sp)
         if sp is not None:
@@ -647,9 +651,6 @@ class Broker:
             # (telemetry-gated) left the cache_gather share to split
             sp.stop_match(self.router)
             sp.start("pack")
-        # phantom pad-row matches (wildcards match the pad topic) must
-        # not reach the fan-out/pack kernels or the learned budgets
-        pb.ids_dev = mask_pad_rows(pb.ids_dev, np.int32(len(uniq)))
         pb.st = self.helper.state(pb.epoch, pb.id_map)
         bucket = pb.ids_dev.shape[0]
         budgets = self._pack_budgets.setdefault(
@@ -657,22 +658,37 @@ class Broker:
                      budget_for(bucket, cfg.pack_q),
                      max(1, cfg.pack_rows)])
         pb.pm = budgets[0]
-        pb.m_ptr_d, pb.ids_packed_d = pack_matches(pb.ids_dev, pm=pb.pm)
         st = pb.st
-        if st is not None and st.fan is not None:
-            # fused sparse expansion: packed matches → packed
-            # deliveries, gather work proportional to actual traffic
+        fan = st.fan if st is not None else None
+        if fan is not None:
             pb.pq = budgets[1]
-            pb.f_ptr_d, pb.subs_packed_d, pb.src_packed_d, _tot = \
-                expand_packed(st.fan, pb.m_ptr_d, pb.ids_packed_d,
-                              q=pb.pq)
-        if st is not None and st.bm is not None:
-            rows_d, pb.bovf_d = rows_for_matches(
-                st.bm, pb.ids_dev, mb=cfg.fanout_mb)
-            union_d = or_bitmaps_auto(st.bm.bitmaps, rows_d)
-            has_big = (rows_d >= 0).any(axis=1)
-            pb.sel_d, pb.rows_packed_d, pb.bm_total_d = pack_union_rows(
-                union_d, has_big, pr=budgets[2])
+        if self.router.cache_slots() and (st is None or st.bm is None):
+            # the packers (matches, then the fused sparse expansion:
+            # packed matches → packed deliveries, gather work
+            # proportional to actual traffic) and what the fetch would
+            # bundle, one program; the fetch launches nothing
+            (pb.m_ptr_d, pb.ids_packed_d, pb.f_ptr_d, pb.subs_packed_d,
+             pb.src_packed_d, pb.bundle_d) = pack_chip(
+                fan, pb.ids_dev, pb.ovf_dev, pm=pb.pm,
+                pq=pb.pq if fan is not None else 0)
+            self.router.count_fused()
+        else:
+            # big-filter bitmaps live (their kernels need the dense
+            # ids) or the match cache off: the calls apart, and the
+            # fetch lays the bundle (docs/MATCH_CACHE.md)
+            pb.m_ptr_d, pb.ids_packed_d = pack_matches(pb.ids_dev,
+                                                       pm=pb.pm)
+            if fan is not None:
+                pb.f_ptr_d, pb.subs_packed_d, pb.src_packed_d, _tot = \
+                    expand_packed(fan, pb.m_ptr_d, pb.ids_packed_d,
+                                  q=pb.pq)
+            if st is not None and st.bm is not None:
+                rows_d, pb.bovf_d = rows_for_matches(
+                    st.bm, pb.ids_dev, mb=cfg.fanout_mb)
+                union_d = or_bitmaps_auto(st.bm.bitmaps, rows_d)
+                has_big = (rows_d >= 0).any(axis=1)
+                pb.sel_d, pb.rows_packed_d, pb.bm_total_d = \
+                    pack_union_rows(union_d, has_big, pr=budgets[2])
         if sp is not None:
             sp.bucket = bucket
             sp.stop()
@@ -822,6 +838,7 @@ class Broker:
                 # would raise out of the fallback itself)
                 pb.plan = None
                 pb.xgroups = None
+                pb.bundle_d = None  # a laid bundle dies with the fetch
                 pb.host_topics = [m.topic for _, m in pb.live]
                 pb.host_matched = None
                 pb.host_only = True

@@ -400,9 +400,15 @@ MESH_METRICS = [
 # (after the batch's own dedup, before padding), ``walk.topics`` =
 # those of them that walked the automaton; the rest were gathered
 # from the match cache. Current at any instant, where
-# ``cache.match.hit`` / ``.miss`` wait for the stats flush
+# ``cache.match.hit`` / ``.miss`` wait for the stats flush.
+# ``batches`` = device batches dispatched on one chip, ``fused`` =
+# those of them that left the loop as one host→device transfer and
+# two or three programs (``Broker._begin_device``; the rest: the
+# match cache off, or big-filter bitmaps live), the twins of
+# ``mesh.batches`` / ``mesh.fused``
 DISPATCH_METRICS = [
     "dispatch.topics", "dispatch.walk.topics",
+    "dispatch.batches", "dispatch.fused",
 ]
 
 # the publish run (connection.Connection.run →

@@ -337,19 +337,22 @@ def probe_raw(snap: DeltaSnapshot, word_ids, n_words, sys_mask,
     return ids, ovf
 
 
-def probe_packed(snap: DeltaSnapshot, word_ids, n_words, sys_mask,
-                 main_ids, main_ovf, *, m: int):
+def probe_packed(auto, mask, word_ids, n_words, sys_mask, main_ids,
+                 main_ovf, *, m: int, k: int, steps: int):
     """Two-probe merge for the PACKED (``pack_ids=True``) dispatch —
     the match-cache miss walk: union into the fixed ``[B, m]`` row
-    shape cache entries carry, then tombstone-mask."""
+    shape cache entries carry, then tombstone-mask. Traced inside the
+    walk's program (``ops/match_cache.walk_insert``), so it takes a
+    :class:`DeltaSnapshot`'s device half (``auto``, ``mask``; either
+    may be None) and its host half as statics (``k``, and ``steps`` =
+    ``steps_for`` the batch's depth)."""
     ids, ovf = main_ids, main_ovf
-    if snap.auto is not None:
+    if auto is not None:
         res = match_batch(
-            snap.auto, word_ids, n_words, sys_mask, k=snap.k, m=m,
-            pack_ids=True, steps=snap.steps_for(word_ids.shape[1]),
-            slots=2, take=1)
+            auto, word_ids, n_words, sys_mask, k=k, m=m,
+            pack_ids=True, steps=steps, slots=2, take=1)
         ids, u_ovf = _union_packed(ids, res.ids, m=m)
         ovf = ovf | res.overflow | u_ovf
-    if snap.mask is not None:
-        ids = _mask_ids(ids, snap.mask)
+    if mask is not None:
+        ids = _mask_ids(ids, mask)
     return ids, ovf

@@ -43,53 +43,64 @@ Layout and contract:
     can never observe a torn or reallocated row.
 
 All device work is async-dispatched: probe is pure host bookkeeping,
-``merge`` is one jit'd gather+scatter producing the combined
-``[B_pad, width]`` id array (hits from the table, misses from the
-fresh walk), ``insert`` one jit'd scatter. Nothing here ever forces a
-device→host sync — the publish path's coalesced fetch stays the only
-transfer.
+and nothing here ever forces a device→host sync — the publish path's
+coalesced fetch stays the only transfer back.
 
-The mesh variant (``Router._dispatch_fused``; docs/MATCH_CACHE.md
-"Mesh (sharded) variant") shares every line of the host bookkeeping
-above and has device programs of its own, because its row is three
-arrays wide and goes on to ``pack_fanout`` from dense rows:
+**The batch buffer, and which program owns what** (both dispatches:
+one chip's ``Router._match_dispatch_cached`` and the mesh's
+``Router._dispatch_fused``; docs/MATCH_CACHE.md "One buffer a batch").
+What the event loop pays for is not device work but hand-overs: every
+numpy argument is a host→device transfer of its own, every eager
+operation and every program a launch that gives up the interpreter
+lock. So a batch leaves as ONE transfer and two or three programs:
 
-  - **the mesh row** is one topic's ``ids [T·m] | subs [T·d] |
-    src [T·d]`` — everything the collective ``publish_step`` produces
-    for it; ``width = T·m + 2·T·d``. The table lives replicated on
-    every chip of the mesh (``MatchCache(sharding=…)``);
-  - **one buffer a batch** (:class:`MeshLayout`,
-    :meth:`MatchCache.mesh_buffer`): every integer the device needs
+  - **one buffer a batch** (:class:`BatchLayout`,
+    :meth:`MatchCache.batch_buffer`): every integer the device needs
     from the host for the batch is laid into one int32 numpy array
-    and put once, replicated. The programs take it whole and slice it
-    at static offsets: ``word_ids [MB·L] | n_words [MB] | sys_mask
-    [MB] | insert slots [MB] | miss_pos [MB] | hit_slots [HB] |
-    hit_pos [HB] | n_uniq [1]`` (MB, HB = the padded miss and hit
-    counts, L = ``max_levels``; an all-hit batch has MB = 0). Its
+    and put once (replicated on a mesh). The programs take it whole
+    and slice it at static offsets: from the front ``word_ids [MB·L]
+    | n_words [MB] | sys_mask [MB] | insert slots [MB]``, which the
+    walk reads, and up to the buffer's last word ``miss_pos [MB] |
+    hit_slots [HB] | hit_pos [HB] | n_uniq [1]``, which the merge
+    reads (MB, HB = the padded miss and hit counts; L = the batch's
+    depth bucket on one chip, ``max_levels`` on the mesh; an all-hit
+    batch has MB = 0). The walk's offsets depend on (MB, L) alone and
+    the merge's on (MB, HB) alone, so the walk stays one program a
+    (miss bucket, depth) whatever the batch's hits and the merge one
+    a (batch, hit, miss) triple whatever its depth. The buffer's
     LENGTH is a shape of every program that takes it, so it is not
     the sum of its sections but a capacity: a power of two from
-    ``MESH_BUF_FLOOR``, grown only when a batch needs more (the
-    router keeps the high-water mark) — the step stays one program a
-    miss bucket, whatever the batch's hits;
-  - **which program owns what**: the step's program
-    (``parallel/sharded.py::publish_step_insert``, keyed by the miss
-    bucket) walks the misses, lays ``flag | ids | subs | src`` rows,
-    gathers them over ``data`` once and scatters them into the table
-    (:func:`insert_rows`); the merge's (:func:`_mesh_merge_jit`, keyed
-    by the (batch, hit, miss) buckets like ``_merge_jit``) gathers the
-    hits from the PROBE'S snapshot, scatters hits and misses, splits
-    the row and blanks the pad rows; the packers are a third
-    (``ops/pack.py::pack_mesh``, keyed by (batch bucket, pm, pq), so a
-    grown budget costs one program a bucket, not one a triple);
+    ``BATCH_BUF_FLOOR``, grown only when a batch needs more (the
+    router keeps the high-water mark);
+  - **walk + insert** is one program, keyed by the miss bucket (and
+    the depth): one chip's :func:`walk_insert` (``match_batch`` over
+    the misses, a live delta snapshot's two-probe folded in), the
+    mesh's ``parallel/sharded.py::publish_step_insert`` (the
+    collective step; its row is ``ids [T·m] | subs [T·d] | src
+    [T·d]``, everything the step produces for a topic, in a table
+    replicated on every chip). Either lays ``flag | row``
+    (:func:`flag_rows`) and scatters it into the table
+    (:func:`insert_rows`) through :meth:`MatchCache.insert_through`.
+    Skipped when every topic hits;
+  - **merge + pad mask** is one program for both
+    (:func:`_mesh_merge_jit`, keyed by the (batch, hit, miss)
+    buckets): gathers the hits from the PROBE'S snapshot, scatters
+    hits and misses, blanks the pad rows (≥ ``n_uniq``) and, on the
+    mesh, splits the row into ids / subs / src;
+  - **the packers + the fetch's bundle** are a third
+    (``ops/pack.py::pack_chip`` / ``pack_mesh``, keyed by (batch
+    bucket, pm, pq), so a grown budget costs one program a bucket,
+    not one a triple);
   - **the table is not donated**: a probe holds its snapshot and its
     hits gather from it AFTER this or another batch's insert (the
     clock sweep may hand a hit's slot to a miss of the same batch), so
     an insert must leave the old array whole. The copy is device
     time on a chip that is mostly idle; the loop pays nothing for it;
-  - **the padding rule is a contract** with the benchmark's sweep
-    (``benchmark/warmers/mesh_buckets.py`` walks every (batch, hit,
-    miss) triple through ``publish_batch``): batch and misses pad to
-    a power of two from ``min_batch × data`` (``Router.pad_topics``),
+  - **the padding rule is a contract** with the benchmark's sweeps
+    (``benchmark/warmers/dispatch_buckets.py`` and ``mesh_buckets.py``
+    walk every walk variant and every (batch, hit, miss) triple
+    through ``publish_batch``): batch and misses pad to a power of two
+    from ``min_batch`` (× ``data`` on a mesh: ``Router.pad_topics``),
     hits from ``_MIN_PAD`` (:func:`pad_hits`); ``Router.
     dispatch_shapes`` lists the batches the rule allows. Change it and
     runs first use programs inside their window.
@@ -105,8 +116,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["MatchCache", "MeshLayout", "MESH_BUF_FLOOR", "flag_rows",
-           "insert_rows", "pad_hits", "ring_slots"]
+from emqx_tpu.ops.delta import probe_packed
+from emqx_tpu.ops.match import match_batch
+
+__all__ = ["MatchCache", "BatchLayout", "BATCH_BUF_FLOOR", "flag_rows",
+           "insert_rows", "pad_hits", "ring_slots", "walk_insert"]
 
 #: flag column values: _VALID = cached ids are the exact match set;
 #: _OVF = the walk overflowed (host fallback, match-only bound);
@@ -117,11 +131,12 @@ _OVF, _VALID, _FOVF = 0, 1, 2
 
 _MIN_PAD = 8
 
-#: the mesh batch buffer's smallest capacity, int32 words (128 KiB):
-#: the largest batch the default ingress forms (``batch_cap`` = 1,024
-#: unique topics) needs 22,529 at 16 levels, so a served node never
-#: grows it
-MESH_BUF_FLOOR = 1 << 15
+#: the batch buffer's smallest capacity, int32 words (128 KiB): the
+#: largest batch the default ingress forms (``batch_cap`` = 1,024
+#: unique topics) needs 20,497 words when all of them miss at 16
+#: levels, the deepest depth bucket and the mesh's ``max_levels``, so
+#: a served node never grows it
+BATCH_BUF_FLOOR = 1 << 15
 
 
 def _pow2(n: int, floor: int = 1) -> int:
@@ -143,48 +158,15 @@ def pad_hits(n: int) -> int:
     return _pow2(max(n, 1), _MIN_PAD)
 
 
-@functools.partial(jax.jit, static_argnames=("b_pad",))
-def _merge_jit(table, hit_slots, hit_pos, miss_rows, miss_ovf,
-               miss_movf, miss_pos, *, b_pad: int):
-    """Combined id rows + overflow flags for one batch: gather hit
-    rows from the table snapshot, scatter them and the fresh miss
-    rows into the ``[b_pad, width]`` output (OOB positions drop —
-    that is how both pad rows and absent hits/misses vanish)."""
-    S = table.shape[0]
-    width = table.shape[1] - 1
-    out = jnp.full((b_pad, width), -1, jnp.int32)
-    ovf = jnp.zeros((b_pad,), bool)
-    movf = jnp.zeros((b_pad,), bool)
-    hv = table[jnp.clip(hit_slots, 0, S - 1)]
-    flag = hv[:, 0]
-    out = out.at[hit_pos].set(hv[:, 1:], mode="drop")
-    ovf = ovf.at[hit_pos].set(flag != _VALID, mode="drop")
-    movf = movf.at[hit_pos].set(flag == _OVF, mode="drop")
-    out = out.at[miss_pos].set(miss_rows, mode="drop")
-    ovf = ovf.at[miss_pos].set(miss_ovf | miss_movf, mode="drop")
-    movf = movf.at[miss_pos].set(miss_movf, mode="drop")
-    return out, ovf, movf
+# -- the device half (the module header's three programs) ------------------
 
 
-@jax.jit
-def _insert_jit(table, idx, rows, ovf, movf):
-    """Scatter fresh miss rows into their slots. Overflowed rows are
-    stored as invalid markers (never as truncated results); padding
-    entries carry an out-of-range index and drop."""
-    flag = jnp.where(movf, _OVF, jnp.where(ovf, _FOVF, _VALID))
-    rows = jnp.where((ovf | movf)[:, None], -1, rows.astype(jnp.int32))
-    vals = jnp.concatenate(
-        [flag.astype(jnp.int32)[:, None], rows], axis=1)
-    return table.at[idx].set(vals, mode="drop")
-
-
-# -- the mesh's device half (traced inside the mesh programs) --------------
-
-
-class MeshLayout(NamedTuple):
-    """Where one mesh batch's host integers lie in its one int32
-    buffer (module header). Static: a program is compiled for the
-    sections it reads and for ``size``, the buffer's capacity."""
+class BatchLayout(NamedTuple):
+    """Where one batch's host integers lie in its one int32 buffer
+    (module header). Static: a program is compiled for the sections it
+    reads and for ``size``, the buffer's capacity. The walk's program
+    is keyed with ``hit`` = 0 and the merge's with ``levels`` = 0:
+    neither reads a section whose place depends on the other's."""
 
     levels: int   # L: word ids a topic
     miss: int     # MB: padded miss count (0 = the batch fully hit)
@@ -197,46 +179,89 @@ class MeshLayout(NamedTuple):
 
     def step_sections(self, buf):
         """``(word_ids [MB, L], n_words, sys_mask, slots)`` — all the
-        step's program reads; offsets depend on (MB, L) alone."""
+        walk's program reads, from the buffer's front; offsets depend
+        on (MB, L) alone."""
         mb, lv = self.miss, self.levels
         o = mb * lv
         return (buf[:o].reshape(mb, lv), buf[o:o + mb],
                 buf[o + mb:o + 2 * mb] != 0, buf[o + 2 * mb:o + 3 * mb])
 
     def merge_sections(self, buf):
-        """``(miss_pos [MB], hit_slots [HB], hit_pos [HB], n_uniq)``."""
+        """``(miss_pos [MB], hit_slots [HB], hit_pos [HB], n_uniq)``,
+        which end at the buffer's last word; offsets depend on (MB,
+        HB) and the capacity alone."""
         mb, hb = self.miss, self.hit
-        o = mb * (self.levels + 3)
-        h = o + mb
-        return (buf[o:h], buf[h:h + hb], buf[h + hb:h + 2 * hb],
-                buf[h + 2 * hb])
+        end = self.size - 1
+        h = end - 2 * hb
+        return buf[h - mb:h], buf[h:h + hb], buf[h + hb:end], buf[end]
 
 
 def flag_rows(rows, ovf, movf):
     """``flag | rows`` for fresh walk results: the table's row format,
     with the rows still RAW (an overflowed row's truncated ids are
-    what the merge hands on, as ``_merge_jit`` does; only the table
-    stores them blanked — :func:`insert_rows`)."""
+    what the merge hands on; only the table stores them blanked —
+    :func:`insert_rows`)."""
     flag = jnp.where(movf, _OVF, jnp.where(ovf, _FOVF, _VALID))
     return jnp.concatenate(
         [flag.astype(jnp.int32)[:, None], rows.astype(jnp.int32)], axis=1)
 
 
 def insert_rows(table, idx, vals):
-    """``_insert_jit``'s scatter for rows in :func:`flag_rows` form."""
-    marker = vals.at[:, 1:].set(-1)  # an overflowed row: flag alone
+    """Scatter rows in :func:`flag_rows` form into their slots.
+    Overflowed rows are stored as invalid markers (the flag alone,
+    never truncated results); padding entries carry an out-of-range
+    index and drop."""
+    marker = vals.at[:, 1:].set(-1)
     return table.at[idx].set(
         jnp.where(vals[:, :1] != _VALID, marker, vals), mode="drop")
 
 
+@functools.partial(
+    jax.jit, static_argnames=("lay", "k", "m", "steps", "slots", "take",
+                              "dk", "dsteps"))
+def walk_insert(auto, delta, table, buf, *, lay: BatchLayout, k: int,
+                m: int, steps, slots: int, take: int, dk: int = 0,
+                dsteps: int = 0):
+    """The one-chip dispatch's walk as ONE program (the twin of
+    ``parallel/sharded.py::publish_step_insert``): slice the misses'
+    operands out of the batch buffer, walk them (``pack_ids=True`` —
+    fixed-width rows are what the table holds), fold a live delta
+    snapshot's two-probe in (``delta`` = its ``(auto, mask)``, None
+    without one; ``dk`` / ``dsteps`` its lanes and its steps at this
+    depth: the side-automaton's union and the tombstone mask land in
+    the rows the cache stores, and a later delta mutation bumps the
+    revision, so they are never served stale), lay ``flag | row`` and
+    scatter the rows into the table (functionally: the old table stays
+    whole for the probes that hold it).
+
+    Returns ``(new_table, miss_vals [MB, 1 + width])``; keyed by the
+    miss bucket and the depth (and the buffer's capacity), never by
+    the batch's hits."""
+    word_ids, n_words, sys_mask, slots_ = lay.step_sections(buf)
+    res = match_batch(auto, word_ids, n_words, sys_mask, k=k, m=m,
+                      pack_ids=True, steps=steps, slots=slots, take=take)
+    rows, ovf = res.ids, res.overflow
+    if delta is not None:
+        rows, ovf = probe_packed(*delta, word_ids, n_words, sys_mask,
+                                 rows, ovf, m=m, k=dk, steps=dsteps)
+    vals = flag_rows(rows, ovf, ovf)
+    return insert_rows(table, slots_, vals), vals
+
+
 @functools.partial(jax.jit, static_argnames=("lay", "b_pad", "splits"))
-def _mesh_merge_jit(table, buf, miss_vals, *, lay: MeshLayout,
+def _mesh_merge_jit(table, buf, miss_vals, *, lay: BatchLayout,
                     b_pad: int, splits):
-    """The mesh batch's merge: ``_merge_jit`` over the batch buffer's
-    sections, then the row split at ``splits`` (the widths of ids and
-    subs) and the pad rows (≥ ``n_uniq``) blanked as
-    ``ops/pack.mask_pad_rows`` blanks them. ``miss_vals`` is the
-    step's ``flag | row`` output, None when the batch fully hit."""
+    """A batch's merge, on one chip as on the mesh (whose tests and
+    traces know it by this name): gather the hit rows from the table
+    snapshot, scatter them and the fresh miss rows into the ``[b_pad,
+    width]`` output (OOB positions drop — that is how pad entries and
+    absent hits/misses vanish), then blank the pad rows (≥ ``n_uniq``:
+    wildcards match the pad topic, and those phantom rows must not
+    reach the packers or the learned budgets). ``miss_vals`` is the
+    walk's ``flag | row`` output, None when the batch fully hit.
+    ``splits`` = the widths of ids and subs in a mesh row, which comes
+    back cut into ``(ids, subs, src, ovf, movf)``; None on one chip:
+    ``(ids, ovf, movf)``."""
     miss_pos, hit_slots, hit_pos, n_uniq = lay.merge_sections(buf)
     S = table.shape[0]
     out = jnp.full((b_pad, table.shape[1] - 1), -1, jnp.int32)
@@ -252,6 +277,8 @@ def _mesh_merge_jit(table, buf, miss_vals, *, lay: MeshLayout,
         movf = movf.at[pos].set(flag == _OVF, mode="drop")
     real = (jnp.arange(b_pad, dtype=jnp.int32) < n_uniq)[:, None]
     out = jnp.where(real, out, -1)
+    if splits is None:
+        return out, ovf, movf
     mw, dw = splits
     return out[:, :mw], out[:, mw:mw + dw], out[:, mw + dw:], ovf, movf
 
@@ -325,7 +352,7 @@ class MatchCache:
         if old is not None:
             self._index.pop(old, None)
         self._slot_topic[s] = topic
-        self._slot_key[s] = None  # pending until insert() lands
+        self._slot_key[s] = None  # pending until the insert lands
         self._index[topic] = s
         return s
 
@@ -333,7 +360,7 @@ class MatchCache:
               keys: Optional[Sequence[Any]] = None) -> _Probe:
         """Split a unique-topic batch into hits (slot per topic, key
         matches) and misses (slot assigned now, marked pending — a
-        crash before :meth:`insert` just leaves a permanent miss).
+        crash before the insert just leaves a permanent miss).
 
         ``keys`` (optional, parallel to ``topics``) overrides ``key``
         per topic: the router's partitioned-epoch probe passes one key
@@ -364,26 +391,49 @@ class MatchCache:
             self.misses += len(p.miss_pos)
             return p
 
-    # -- device ops --------------------------------------------------------
+    # -- device ops (module header) ---------------------------------------
 
-    def insert(self, probe: _Probe, rows, ovf, movf=None) -> None:
-        """Store the fresh walk results for ``probe``'s misses.
+    def batch_buffer(self, b_pad: int, probe: _Probe, enc, n_uniq: int,
+                     size: int):
+        """One batch's ``(layout, int32 buffer)``. ``enc`` is the
+        padded misses' ``(word_ids [MB, L], n_words, sys_mask)`` or
+        None when the batch fully hit; ``size`` the capacity so far —
+        the layout's is that or the next power of two that holds the
+        batch."""
+        mb, levels = (0, 0) if enc is None else enc[0].shape
+        hb = pad_hits(len(probe.hit_pos))
+        lay = BatchLayout(levels, mb, hb, _pow2(
+            BatchLayout.need(levels, mb, hb), max(size, BATCH_BUF_FLOOR)))
+        buf = np.zeros((lay.size,), np.int32)
+        end = lay.size - 1
+        h = end - 2 * hb
+        if mb:
+            n = len(probe.miss_slots)
+            o = mb * levels
+            buf[:o].reshape(mb, levels)[:] = enc[0]
+            buf[o:o + mb] = enc[1]
+            buf[o + mb:o + 2 * mb] = enc[2]
+            buf[o + 2 * mb:o + 3 * mb] = self.slots  # OOB pad -> drop
+            buf[o + 2 * mb:o + 2 * mb + n] = probe.miss_slots
+            buf[h - mb:h] = b_pad
+            buf[h - mb:h - mb + n] = probe.miss_pos
+        nh = len(probe.hit_pos)
+        buf[h:h + nh] = probe.hit_slots
+        buf[h + hb:end] = b_pad
+        buf[h + hb:h + hb + nh] = probe.hit_pos
+        buf[end] = n_uniq
+        return lay, buf
 
-        ``rows`` is the (possibly batch-padded) ``[Mb, width]`` device
-        result; rows past the real miss count drop via OOB indices.
-        ``ovf`` rows store invalid markers, never truncated ids."""
-        n = len(probe.miss_slots)
-        if n == 0:
-            return
-        mb = int(rows.shape[0])
-        idx = np.full((mb,), self.slots, np.int32)  # OOB pad -> drop
-        idx[:n] = probe.miss_slots
-        if movf is None:
-            movf = ovf
+    def insert_through(self, probe: _Probe, step):
+        """Store the fresh walk results for ``probe``'s misses, by the
+        caller's own program: ``step(table) -> (new_table, out)`` runs
+        under the lock against the CURRENT table (rows past the real
+        miss count drop via OOB indices; overflowed rows store invalid
+        markers, never truncated ids); returns ``out``."""
         with self._lock:
-            self._table = _insert_jit(self._table_now(), idx, rows,
-                                      ovf, movf)
+            self._table, out = step(self._table_now())
             self._key_inserted(probe)
+        return out
 
     def _key_inserted(self, probe: _Probe) -> None:
         """The misses' slots now hold their rows: key them (call
@@ -395,78 +445,16 @@ class MatchCache:
                 self._slot_key[s] = k
         self.inserts += len(probe.miss_slots)
 
-    def merge(self, b_pad: int, probe: _Probe, miss_rows=None,
-              miss_ovf=None, miss_movf=None):
-        """One jit'd gather+scatter producing the batch's combined
-        ``(ids[b_pad, width], ovf[b_pad], movf[b_pad])`` device
-        arrays. Pass the miss walk outputs (or nothing when the batch
-        fully hit)."""
-        hb = pad_hits(len(probe.hit_pos))
-        hit_slots = np.zeros((hb,), np.int32)
-        hit_pos = np.full((hb,), b_pad, np.int32)  # OOB pad -> drop
-        if probe.hit_pos:
-            hit_slots[:len(probe.hit_slots)] = probe.hit_slots
-            hit_pos[:len(probe.hit_pos)] = probe.hit_pos
-        if miss_rows is None:
-            miss_rows = jnp.full((1, self.width), -1, jnp.int32)
-            miss_ovf = jnp.zeros((1,), bool)
-            miss_movf = jnp.zeros((1,), bool)
-        elif miss_movf is None:
-            miss_movf = miss_ovf
-        mb = int(miss_rows.shape[0])
-        miss_pos = np.full((mb,), b_pad, np.int32)
-        miss_pos[:len(probe.miss_pos)] = probe.miss_pos
-        return _merge_jit(probe.table, hit_slots, hit_pos, miss_rows,
-                          miss_ovf, miss_movf, miss_pos, b_pad=b_pad)
-
-    # -- the mesh's device ops (module header) -----------------------------
-
-    def mesh_buffer(self, b_pad: int, probe: _Probe, enc, levels: int,
-                    n_uniq: int, size: int):
-        """One mesh batch's ``(layout, int32 buffer)``. ``enc`` is the
-        padded misses' ``(word_ids [MB, L], n_words, sys_mask)`` or
-        None when the batch fully hit; ``size`` the capacity so far —
-        the layout's is that or the next power of two that holds the
-        batch."""
-        mb = 0 if enc is None else int(enc[0].shape[0])
-        hb = pad_hits(len(probe.hit_pos))
-        lay = MeshLayout(levels, mb, hb, _pow2(
-            MeshLayout.need(levels, mb, hb), max(size, MESH_BUF_FLOOR)))
-        buf = np.zeros((lay.size,), np.int32)
-        o = mb * levels
-        if mb:
-            n = len(probe.miss_slots)
-            buf[:o] = enc[0].reshape(-1)
-            buf[o:o + mb] = enc[1]
-            buf[o + mb:o + 2 * mb] = enc[2]
-            buf[o + 2 * mb:o + 3 * mb] = self.slots  # OOB pad -> drop
-            buf[o + 2 * mb:o + 2 * mb + n] = probe.miss_slots
-            buf[o + 3 * mb:o + 4 * mb] = b_pad
-            buf[o + 3 * mb:o + 3 * mb + n] = probe.miss_pos
-        h = o + 4 * mb
-        nh = len(probe.hit_pos)
-        buf[h:h + nh] = probe.hit_slots
-        buf[h + hb:h + 2 * hb] = b_pad
-        buf[h + hb:h + hb + nh] = probe.hit_pos
-        buf[h + 2 * hb] = n_uniq
-        return lay, buf
-
-    def insert_through(self, probe: _Probe, step):
-        """:meth:`insert` for a caller whose own program scatters the
-        misses: ``step(table) -> (new_table, out)`` runs under the
-        lock against the CURRENT table; returns ``out``."""
-        with self._lock:
-            self._table, out = step(self._table_now())
-            self._key_inserted(probe)
-        return out
-
     @staticmethod
-    def merge_mesh(b_pad: int, probe: _Probe, lay: MeshLayout, buf,
-                   miss_vals, splits):
-        """The mesh batch's ``(ids, subs, src, ovf, movf)``, pad rows
-        blanked: one program, hits from ``probe``'s snapshot."""
-        return _mesh_merge_jit(probe.table, buf, miss_vals, lay=lay,
-                               b_pad=b_pad, splits=splits)
+    def merge_batch(b_pad: int, probe: _Probe, lay: BatchLayout, buf,
+                    miss_vals, splits=None):
+        """The batch's combined rows and flags, pad rows blanked —
+        ``(ids, ovf, movf)``, on a mesh (``splits``) ``(ids, subs,
+        src, ovf, movf)``: one program, hits from ``probe``'s
+        snapshot."""
+        return _mesh_merge_jit(probe.table, buf, miss_vals,
+                               lay=lay._replace(levels=0), b_pad=b_pad,
+                               splits=splits)
 
     # -- introspection -----------------------------------------------------
 

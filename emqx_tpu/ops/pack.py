@@ -35,6 +35,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from emqx_tpu.ops.fanout import expand_packed
+
 
 @jax.jit
 def mask_pad_rows(ids: jax.Array, n_rows: jax.Array) -> jax.Array:
@@ -146,6 +148,28 @@ def pack_mesh(ids: jax.Array, subs: jax.Array, src: jax.Array,
     f_ptr, packed_subs, packed_src = pack_fanout(subs, src, pq=pq)
     return (m_ptr, packed_ids, f_ptr, packed_subs, packed_src,
             bundle_i32(m_ptr, packed_ids, ovf, movf, f_ptr, packed_subs,
+                       packed_src))
+
+
+@functools.partial(jax.jit, static_argnames=("pm", "pq"))
+def pack_chip(fan, ids: jax.Array, ovf: jax.Array, *, pm: int, pq: int):
+    """:func:`pack_matches`, ``ops/fanout.expand_packed`` and the
+    fetch's :func:`bundle_i32` of one one-chip batch as ONE program
+    (the twin of :func:`pack_mesh`), keyed by (batch bucket, ``pm``,
+    ``pq``) with the fan-out table ``fan`` an argument (None = no
+    local subscriber: matches only, ``pq`` unused). Returns ``(m_ptr,
+    packed_ids, f_ptr, packed_subs, packed_src, bundle)``, ``bundle``
+    in ``Broker._fetch_device``'s order for a batch without bitmap
+    rows, so the fetch's thread launches nothing before its transfer;
+    the fetch's re-pack on overflow still calls the packers apart."""
+    m_ptr, packed_ids = pack_matches(ids, pm=pm)
+    if fan is None:
+        return (m_ptr, packed_ids, None, None, None,
+                bundle_i32(m_ptr, packed_ids, ovf))
+    f_ptr, packed_subs, packed_src, _total = expand_packed(
+        fan, m_ptr, packed_ids, q=pq)
+    return (m_ptr, packed_ids, f_ptr, packed_subs, packed_src,
+            bundle_i32(m_ptr, packed_ids, ovf, f_ptr, packed_subs,
                        packed_src))
 
 

@@ -480,7 +480,7 @@ def publish_step_insert(
     table: jax.Array,      # the mesh match cache's table, replicated
     buf: jax.Array,        # the batch's one int32 buffer, replicated
     *,
-    lay,                   # ops.match_cache.MeshLayout with hit = 0
+    lay,                   # ops.match_cache.BatchLayout with hit = 0
     k: int, m: int, d: int, mb: int, steps: int | None, slots: int,
     take: int,
 ):

@@ -350,9 +350,10 @@ class Router:
         self._match_cache_obj = None
         self._sharded_cache_obj = None
         self._sharded_cache_meta = None  # (T, m, d) the table is sized for
-        # the mesh batch buffer's capacity so far (a shape of every
-        # mesh program: ops/match_cache.py header); only ever grows
-        self._mesh_buf_len = 0
+        # the batch buffer's capacity so far (a shape of every program
+        # of the cache-split dispatch: ops/match_cache.py header);
+        # only ever grows
+        self._batch_buf_len = 0
         # publish-path telemetry (telemetry.Telemetry), wired by Node
         # alongside broker.telemetry. When enabled, the cache-split
         # dispatch leaves its per-batch probe/merge timing + hit/miss
@@ -1667,7 +1668,9 @@ class Router:
         (telemetry.enqueue_mark).
 
         Returns ``(ids_dev, ovf_dev, id_map, epoch)`` — both arrays
-        are in-flight device values ([B_pad, M] / [B_pad]); feed
+        are in-flight device values ([B_pad, M] / [B_pad]), the
+        padding rows of ``ids_dev`` (row ≥ ``len(topics)``: wildcards
+        match the pad topic) blanked to -1, ready for the packers; feed
         ``ids_dev`` straight into the fan-out/pack kernels and fetch
         everything in one coalesced transfer later
         (:meth:`Broker.publish_fetch`). ``(id_map, epoch)`` is the
@@ -1680,6 +1683,8 @@ class Router:
         cache = self._match_cache()
         if cache is not None:
             return self._match_dispatch_cached(topics, cache, span)
+        from emqx_tpu.ops.pack import mask_pad_rows
+
         dsnap = None
         if self._delta_active:
             main, dsnap = self._snapshot_pair()
@@ -1712,7 +1717,8 @@ class Router:
             out_ids, out_ovf = probe_raw(dsnap, ids, n, sysm,
                                          out_ids, out_ovf,
                                          m=cfg.max_matches)
-        return out_ids, out_ovf, id_map, epoch
+        return (mask_pad_rows(out_ids, np.int32(len(topics))), out_ovf,
+                id_map, epoch)
 
     # -- publish match cache (ops/match_cache.py) -------------------------
 
@@ -1734,13 +1740,23 @@ class Router:
         """Cache-split device match: probe the epoch-guarded cache,
         walk ONLY the misses (``pack_ids=True`` — the per-topic
         compaction buys fixed-width rows the cache and merge reuse),
-        merge one combined ``[B_pad, max_matches]`` id array and
-        insert the fresh rows. Same contract as the plain dispatch:
-        all device values in flight, no sync.
+        insert the fresh rows and merge one combined ``[B_pad,
+        max_matches]`` id array, pad rows blanked. Same contract as
+        the plain dispatch: all device values in flight, no sync.
+
+        What the event loop hands the device for the batch is ONE
+        host→device transfer (the batch's int32 buffer:
+        ops/match_cache.py's header has its layout) and at most two
+        programs here: the walk with the insert, for the misses, and
+        the merge. No numpy argument and no eager operation: each is
+        a transfer or a launch of its own, and gives up the
+        interpreter lock to the fetch's thread.
 
         Ordering: the revision is read BEFORE the automaton snapshot,
         so a racing mutation can only make fresh results look stale
         (re-walked, safe) — never stale results look fresh."""
+        from emqx_tpu.ops.match_cache import walk_insert
+
         cfg = self.config
         k_boost = self._k_boost  # read BEFORE the snapshot/walk: a
         # concurrent boost then stales these entries, never the reverse
@@ -1769,45 +1785,47 @@ class Router:
         t0 = time.perf_counter() if timed else 0.0
         probe = cache.probe(topics, key, keys)
         t1 = time.perf_counter() if timed else 0.0
-        self._count_dispatch(len(topics), len(probe.miss_topics))
-        miss_rows = miss_ovf = None
-        if probe.miss_topics:
-            mb = self.pad_topics(len(probe.miss_topics))
-            padded = list(probe.miss_topics) + \
-                ["\x00/pad"] * (mb - len(probe.miss_topics))
+        misses = probe.miss_topics
+        self._count_dispatch(len(topics), len(misses))
+        enc = None
+        if misses:
+            mb = self.pad_topics(len(misses))
+            padded = list(misses) + ["\x00/pad"] * (mb - len(misses))
             with self._wt_lock:
                 ids, n, sysm = self._encode(padded, cfg.max_levels)
-            ids, n = depth_bucket(ids, n)
-            with enqueue_mark(span):
-                res = match_batch(auto, ids, n, sysm,
-                                  k=self.effective_k(),
-                                  m=cfg.max_matches, pack_ids=True,
-                                  **self._walk_kw(ids.shape[1]))
-            miss_rows, miss_ovf = res.ids, res.overflow
+            enc = (*depth_bucket(ids, n), sysm)
+        lay, buf = cache.batch_buffer(bucket, probe, enc, len(topics),
+                                      self._batch_buf_len)
+        self._batch_buf_len = lay.size
+        with enqueue_mark(span):  # the batch's one transfer
+            buf = jax.device_put(buf)
+        miss_vals = None
+        if misses:
+            delta, dkw = None, {}
             if dsnap is not None:
-                # two-probe: fold the side-automaton + tombstone mask
-                # into the rows the cache stores — a later delta
-                # mutation bumps the partition/global revision, so
-                # these merged rows can never be served stale
-                from emqx_tpu.ops.delta import probe_packed
-
+                # two-probe, folded into the walk's program: the
+                # side-automaton's union + the tombstone mask
+                # (ops/delta.py)
                 self._delta_probes += 1
-                miss_rows, miss_ovf = probe_packed(
-                    dsnap, ids, n, sysm, miss_rows, miss_ovf,
-                    m=cfg.max_matches)
-            cache.insert(probe, miss_rows, miss_ovf)
+                delta = (dsnap.auto, dsnap.mask)
+                if dsnap.auto is not None:
+                    dkw = {"dk": dsnap.k,
+                           "dsteps": dsnap.steps_for(lay.levels)}
+            miss_vals = cache.insert_through(
+                probe, lambda table: walk_insert(
+                    auto, delta, table, buf, lay=lay._replace(hit=0),
+                    k=self.effective_k(), m=cfg.max_matches, **dkw,
+                    **self._walk_kw(lay.levels)))
         t2 = time.perf_counter() if timed else 0.0
-        # the first device call of a batch whose topics all hit
-        with enqueue_mark(span):
-            ids_dev, ovf_dev, _movf = cache.merge(bucket, probe,
-                                                  miss_rows, miss_ovf)
+        ids_dev, ovf_dev, _movf = cache.merge_batch(bucket, probe, lay,
+                                                    buf, miss_vals)
         if timed:
             # probe (host hash walk) + merge (HBM-gather dispatch) =
             # the cache_gather share of this dispatch; the remainder
-            # (encode + miss walk) is the match share
+            # (encode + transfer + miss walk) is the match share
             self._last_dispatch = {
                 "hit": len(probe.hit_pos),
-                "miss": len(probe.miss_topics),
+                "miss": len(misses),
                 "cache_gather_ms": ((t1 - t0) + (
                     time.perf_counter() - t2)) * 1000.0,
             }
@@ -2072,8 +2090,17 @@ class Router:
         flush."""
         m = self._live_metrics()
         if m is not None:
+            m.inc("dispatch.batches")
             m.inc("dispatch.topics", topics)
             m.inc("dispatch.walk.topics", walked)
+
+    def count_fused(self) -> None:
+        """The broker's: the batch :meth:`_count_dispatch` just
+        counted left the loop as one transfer and the fused packer
+        (``dispatch.fused``, the twin of ``mesh.fused``)."""
+        m = self._live_metrics()
+        if m is not None:
+            m.inc("dispatch.fused")
 
     def _count_mesh(self, events: str, topics: Optional[str] = None,
                     n: int = 0) -> None:
@@ -2173,9 +2200,9 @@ class Router:
                 # stay keyless (permanent miss), and the caller runs
                 # the legacy dispatch on the new snapshot
                 return None
-        lay, buf = cache.mesh_buffer(bucket, probe, enc, cfg.max_levels,
-                                     len(topics), self._mesh_buf_len)
-        self._mesh_buf_len = lay.size
+        lay, buf = cache.batch_buffer(bucket, probe, enc, len(topics),
+                                      self._batch_buf_len)
+        self._batch_buf_len = lay.size
         with enqueue_mark(span):  # the batch's one transfer
             buf = jax.device_put(buf, cache.sharding)
         miss_vals = None
@@ -2191,7 +2218,7 @@ class Router:
                     **self._walk_kw(cfg.max_levels)))
             self._dev_stats.append(stats)
         t2 = time.perf_counter() if timed else 0.0
-        ids, subs, src, ovf, movf = cache.merge_mesh(
+        ids, subs, src, ovf, movf = cache.merge_batch(
             bucket, probe, lay, buf, miss_vals,
             (n_trie * cfg.max_matches, n_trie * d))
         if timed:

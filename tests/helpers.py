@@ -1,6 +1,11 @@
 """Shared live-broker fixtures for the integration-tier suites."""
 
 import contextlib
+import sys
+
+import jax
+from jax._src import dispatch as jax_dispatch
+from jax._src.interpreters import pxla
 
 from emqx_tpu.node import Node
 
@@ -29,6 +34,21 @@ class Inbox:
 
     def deliver(self, topic, msg):
         self.inbox.append((topic, msg))
+
+
+class CounterTel:
+    """What ``Router._live_metrics`` asks of a telemetry: counters
+    live, stage timing off."""
+
+    enabled = False
+
+    def __init__(self):
+        from emqx_tpu.metrics import Metrics
+
+        self.metrics = Metrics()
+
+    def loop_clock(self):
+        return self
 
 
 def record_spans(tel) -> list:
@@ -116,3 +136,56 @@ class Compiles:
         from jax._src import monitoring
 
         monitoring.unregister_event_duration_listener(self._dur)
+
+
+# topics that overflow the match bound alone (> M filters on a shard)
+MOVF_FILTERS = [
+    "mo/#", "mo/+/#", "mo/a/#", "mo/+/b/#", "mo/a/b/#", "mo/+/+/#",
+    "mo/a/+/#", "mo/+/b/c", "mo/a/b/c", "mo/a/b/+", "mo/a/+/c",
+    "mo/+/+/c", "mo/+/+/+", "mo/a/+/+", "mo/+/b/+", "+/a/b/c",
+    "+/+/b/c", "+/a/+/c", "+/a/b/+", "+/+/+/c", "+/a/+/+", "+/+/b/+",
+    "+/+/+/+", "mo/a/b/c/#", "+/a/b/c/#", "+/+/b/c/#"]
+
+
+class LoopCost:
+    """Counting wrappers on what the event loop can hand the device:
+    eager operations (``apply_primitive`` looks its callable up through
+    ``dispatch.xla_primitive_callable``), host→device transfers
+    (``pxla.batched_device_put``: ``jax.device_put`` and every numpy
+    argument of a jitted call go through it) and the launches of every
+    jitted function the package's modules name."""
+
+    def __init__(self, monkeypatch):
+        self.eager = self.transfers = 0
+        self.programs = []
+        prim, put = jax_dispatch.xla_primitive_callable, \
+            pxla.batched_device_put
+
+        def counted_prim(*a, **kw):
+            self.eager += 1
+            return prim(*a, **kw)
+
+        def counted_put(*a, **kw):
+            self.transfers += 1
+            return put(*a, **kw)
+
+        monkeypatch.setattr(jax_dispatch, "xla_primitive_callable",
+                            counted_prim)
+        monkeypatch.setattr(pxla, "batched_device_put", counted_put)
+        jitted = type(jax.jit(lambda: 0))
+        for name, mod in list(sys.modules.items()):
+            if not name.startswith("emqx_tpu") or mod is None:
+                continue
+            for attr, fn in list(vars(mod).items()):
+                if isinstance(fn, jitted):
+                    monkeypatch.setattr(mod, attr, self._counted(attr, fn))
+
+    def _counted(self, name, fn):
+        def call(*a, **kw):
+            self.programs.append(name)
+            return fn(*a, **kw)
+        return call
+
+    def reset(self):
+        self.eager = self.transfers = 0
+        self.programs = []

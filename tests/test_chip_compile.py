@@ -172,20 +172,84 @@ def test_retained_match_compiles_at_1m_names(one_chip):
 
 
 def test_match_cache_and_delta_compile(one_chip):
-    """The match-cache gather/insert and the delta two-probe merge."""
+    """The match-cache merge (its gather and scatter; with misses and
+    fully hit) and the delta two-probe merge."""
     from emqx_tpu.ops.delta import _mask_ids, _union_packed
-    from emqx_tpu.ops.match_cache import _insert_jit, _merge_jit
+    from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
+                                          _mesh_merge_jit)
 
     sh = one_chip
     table = _s((65536, _M + 1), jnp.int32, sh)
     i32 = lambda *shape: _s(shape, jnp.int32, sh)  # noqa: E731
     b1 = lambda *shape: _s(shape, jnp.bool_, sh)   # noqa: E731
-    _merge_jit.lower(table, i32(_B), i32(_B), i32(_B, _M), b1(_B),
-                     b1(_B), i32(_B), b_pad=_B).compile()
-    _insert_jit.lower(table, i32(_B), i32(_B, _M), b1(_B),
-                      b1(_B)).compile()
+    lay = BatchLayout(0, _B, _B, BATCH_BUF_FLOOR)
+    _mesh_merge_jit.lower(table, i32(lay.size), i32(_B, _M + 1), lay=lay,
+                          b_pad=_B, splits=None).compile()
+    _mesh_merge_jit.lower(table, i32(lay.size), None,
+                          lay=lay._replace(miss=0), b_pad=_B,
+                          splits=None).compile()
     _union_packed.lower(i32(_B, _M), i32(_B, _M), m=_M).compile()
     _mask_ids.lower(i32(_B, _M), b1(_FCAP)).compile()
+
+
+@pytest.mark.parametrize("delta", ["no_delta", "adds", "tombstones",
+                                   "adds_and_tombstones"])
+def test_fused_chip_dispatch_compiles(one_chip, delta):
+    """The three programs a served one-chip batch enqueues
+    (``Router._match_dispatch_cached``, ``Broker._begin_device``), at
+    the cells' widths and the ingress's largest batch: the walk with
+    the cache insert (a live delta snapshot's two-probe folded in),
+    the merge with the pad mask, and the packers with the fetch's
+    bundle; the table is an argument the insert does not donate."""
+    from emqx_tpu.ops.fanout import FanoutTable
+    from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
+                                          _mesh_merge_jit, walk_insert)
+    from emqx_tpu.ops.pack import pack_chip
+
+    sh = one_chip
+    L, HB = 5, 256
+    lay = BatchLayout(L, _B, HB, BATCH_BUF_FLOOR)
+    assert BatchLayout.need(16, _B, 8) <= lay.size
+    table = _s((65536, _M + 1), jnp.int32, sh)
+    buf = _s((lay.size,), jnp.int32, sh)
+    side = None
+    if delta != "no_delta":
+        # delta_max_filters = 4,096 pending adds: a small narrow view
+        side_auto = Automaton(
+            row_ptr=None, edge_word=None, edge_child=None,
+            plus_child=None, hash_filter=None, end_filter=None,
+            n_states=0, n_edges=0,
+            wt=_s((1 << 13, NARROW_SLOTS * NARROW_SLOT), jnp.int32, sh),
+            wt_seed=_s((1,), jnp.uint32, sh),
+            node2=_s((1 << 14, 4), jnp.int32, sh))
+        side = (side_auto if "adds" in delta else None,
+                _s((_FCAP,), jnp.bool_, sh) if "tombstones" in delta
+                else None)
+    walk = walk_insert.lower(
+        _auto_shapes(sh, False), side, table, buf,
+        lay=lay._replace(hit=0), k=_K, m=_M, steps=L + 1,
+        slots=NARROW_SLOTS, take=1, dk=_K if "adds" in delta else 0,
+        dsteps=L + 1 if "adds" in delta else 0).compile()
+    ma = walk.memory_analysis()
+    # the new table beside the old: not donated (a probe holds it)
+    assert ma.output_size_in_bytes >= 65536 * (_M + 1) * 4
+    if delta != "no_delta":
+        return
+    vals = _s((_B, _M + 1), jnp.int32, sh)
+    for miss_vals, miss in ((vals, _B), (None, 0)):
+        _mesh_merge_jit.lower(
+            table, buf, miss_vals,
+            lay=lay._replace(levels=0, miss=miss), b_pad=_B,
+            splits=None).compile()
+    fan = FanoutTable(
+        row_ptr=_s((_FCAP + 1,), jnp.int32, sh),
+        sub_ids=_s((1 << 21,), jnp.int32, sh),
+        n_filters=_s((), jnp.int32, sh), n_entries=_s((), jnp.int32, sh),
+        row_pairs=_s((_FCAP, 2), jnp.int32, sh))
+    ids = _s((_B, _M), jnp.int32, sh)
+    ovf = _s((_B,), jnp.bool_, sh)
+    for f in (fan, None):
+        pack_chip.lower(f, ids, ovf, pm=8192, pq=16384).compile()
 
 
 @pytest.mark.parametrize("n_data,n_trie", [(4, 1), (2, 2)])
@@ -240,7 +304,7 @@ def test_fused_mesh_dispatch_compiles(topo, n_data, n_trie):
     merge and the packer run replicated, with no collective at all."""
     import re
 
-    from emqx_tpu.ops.match_cache import (MESH_BUF_FLOOR, MeshLayout,
+    from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
                                           _mesh_merge_jit)
     from emqx_tpu.ops.pack import pack_mesh
     from emqx_tpu.parallel.sharded import (ShardedAutomaton,
@@ -268,7 +332,7 @@ def test_fused_mesh_dispatch_compiles(topo, n_data, n_trie):
         sub_ids=_s((T, 1 << 20), jnp.int32, tr),
         row_pairs=_s((T, _FCAP, 2), jnp.int32, tr))
     kw = dict(k=_K, m=_M, d=d, mb=16, steps=6, slots=NARROW_SLOTS, take=1)
-    lay = MeshLayout(L, MB, HB, MESH_BUF_FLOOR)
+    lay = BatchLayout(L, MB, HB, BATCH_BUF_FLOOR)
     table = _s((65536, 1 + width), jnp.int32, rep)
     buf = _s((lay.size,), jnp.int32, rep)
     step = publish_step.lower(
@@ -281,7 +345,8 @@ def test_fused_mesh_dispatch_compiles(topo, n_data, n_trie):
     vals = _s((MB, 1 + width), jnp.int32, rep)
     for miss_vals, miss in ((vals, MB), (None, 0)):
         merge = _mesh_merge_jit.lower(
-            table, buf, miss_vals, lay=lay._replace(miss=miss), b_pad=B,
+            table, buf, miss_vals,
+            lay=lay._replace(levels=0, miss=miss), b_pad=B,
             splits=(T * _M, T * d)).compile()
         assert collectives(merge) == []
     i32 = lambda *shape: _s(shape, jnp.int32, rep)  # noqa: E731

@@ -30,6 +30,32 @@ class Q:
 # -- MatchCache unit ------------------------------------------------------
 
 
+def _insert(c, p, rows, ovf):
+    """Store walk results for ``p``'s misses as the dispatch's walk
+    program does (``walk_insert``: ``flag_rows`` → ``insert_rows``
+    through ``insert_through``); returns the ``flag | row`` values the
+    merge takes."""
+    from emqx_tpu.ops.match_cache import flag_rows, insert_rows
+
+    vals = flag_rows(np.asarray(rows), np.asarray(ovf), np.asarray(ovf))
+    idx = np.full((len(rows),), c.slots, np.int32)  # OOB pad -> drop
+    idx[:len(p.miss_slots)] = p.miss_slots
+    return c.insert_through(
+        p, lambda table: (insert_rows(table, idx, vals), vals))
+
+
+def _merge(c, b_pad, p, n_uniq, miss_vals=None):
+    """The batch's merge over its one buffer (``batch_buffer`` →
+    ``merge_batch``)."""
+    enc = None
+    if miss_vals is not None:
+        mb = miss_vals.shape[0]
+        enc = (np.zeros((mb, 2), np.int32), np.zeros(mb, np.int32),
+               np.zeros(mb, bool))
+    lay, buf = c.batch_buffer(b_pad, p, enc, n_uniq, 0)
+    return c.merge_batch(b_pad, p, lay, buf, miss_vals)
+
+
 def test_cache_unit_probe_insert_merge_roundtrip():
     from emqx_tpu.ops.match_cache import MatchCache
 
@@ -42,17 +68,20 @@ def test_cache_unit_probe_insert_merge_roundtrip():
                      [2, 3, -1, -1],
                      [4, 5, 6, -1]], np.int32)
     ovf = np.zeros(3, bool)
-    c.insert(p, rows, ovf)
+    _insert(c, p, rows, ovf)
+    assert c.inserts == 3
     # second probe: all hits, merged rows identical
     p2 = c.probe(["b", "a", "c", "d"], key)
     assert p2.hit_pos == [0, 1, 2] and p2.miss_topics == ["d"]
-    merged, ovf2, _ = c.merge(
-        8, p2, np.full((1, 4), -1, np.int32), np.zeros(1, bool))
+    miss_vals = _insert(c, p2, np.full((1, 4), -1, np.int32),
+                        np.zeros(1, bool))
+    merged, ovf2, _ = _merge(c, 8, p2, 4, miss_vals)
     merged = np.asarray(merged)
+    assert merged.shape == (8, 4)
     assert merged[0].tolist() == [2, 3, -1, -1]
     assert merged[1].tolist() == [1, -1, -1, -1]
     assert merged[2].tolist() == [4, 5, 6, -1]
-    assert not np.asarray(ovf2)[:3].any()
+    assert not np.asarray(ovf2)[:4].any()
     # epoch bump: everything is a (stale-counted) miss again
     p3 = c.probe(["a", "b"], ("e", 2))
     assert p3.miss_topics == ["a", "b"]
@@ -65,13 +94,56 @@ def test_cache_unit_overflow_rows_store_invalid_markers():
     c = MatchCache(8, 4)
     key = 7
     p = c.probe(["t"], key)
-    c.insert(p, np.array([[9, 9, 9, 9]], np.int32),
-             np.array([True]))
+    vals = _insert(c, p, np.array([[9, 9, 9, 9]], np.int32),
+                   np.array([True]))
+    # the walk's own batch hands its truncated row on, flagged
+    merged, ovf, _ = _merge(c, 4, p, 1, vals)
+    assert np.asarray(ovf)[0] and (np.asarray(merged)[0] == 9).all()
     p2 = c.probe(["t"], key)
     assert p2.hit_pos == [0]  # found — but flagged, never served
-    merged, ovf, _ = c.merge(4, p2)
+    merged, ovf, _ = _merge(c, 4, p2, 1)
     assert np.asarray(ovf)[0]            # caller must host-fallback
     assert (np.asarray(merged)[0] == -1).all()  # no truncated ids
+
+
+def test_the_buffers_sections_do_not_depend_on_each_other():
+    """The walk's sections lie from the buffer's front at offsets of
+    (MB, L) alone, the merge's end at its last word at offsets of (MB,
+    HB) alone: the walk is one program a (miss bucket, depth) whatever
+    the hits, the merge one a (batch, hit, miss) triple whatever the
+    depth."""
+    from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
+                                          MatchCache)
+
+    c = MatchCache(64, 4)
+    p = c.probe([f"t{i}" for i in range(20)], 0)
+    _insert(c, p, np.zeros((32, 4), np.int32), np.zeros(32, bool))
+    p = c.probe([f"t{i}" for i in range(10, 30)], 0)
+    assert len(p.hit_pos) == len(p.miss_pos) == 10
+    got = {}
+    for levels in (2, 5):
+        ids = np.arange(16 * levels, dtype=np.int32).reshape(16, levels)
+        enc = (ids, np.full(16, levels, np.int32), np.ones(16, bool))
+        lay, buf = c.batch_buffer(32, p, enc, 20, 0)
+        assert lay == BatchLayout(levels, 16, 16, BATCH_BUF_FLOOR)
+        w, n, sysm, slots = lay._replace(hit=0).step_sections(buf)
+        assert (w == ids).all() and (n == levels).all() and sysm.all()
+        assert slots[:10].tolist() == p.miss_slots
+        assert (slots[10:] == c.slots).all()
+        got[levels] = [np.asarray(x).tolist() for x in
+                       lay._replace(levels=0).merge_sections(buf)]
+    assert got[2] == got[5]
+    miss_pos, hit_slots, hit_pos, n_uniq = got[2]
+    assert miss_pos == p.miss_pos + [32] * 6
+    assert hit_slots[:10] == p.hit_slots and hit_pos == p.hit_pos + [32] * 6
+    assert n_uniq == 20
+    # a batch past the capacity doubles it, and it stays doubled
+    big = (np.zeros((2048, 16), np.int32), np.zeros(2048, np.int32),
+           np.zeros(2048, bool))
+    lay, _buf = c.batch_buffer(2048, c.probe(["x"], 0), big, 1, 0)
+    assert lay.size == 2 * BATCH_BUF_FLOOR
+    lay, _buf = c.batch_buffer(32, p, None, 20, lay.size)
+    assert lay == BatchLayout(0, 0, 16, 2 * BATCH_BUF_FLOOR)
 
 
 # -- single-device router path --------------------------------------------
@@ -197,7 +269,12 @@ def test_cache_off_restores_legacy_dispatch_bytes():
     res = match_batch(auto, ids, n, sysm, k=r.effective_k(),
                       m=cfg.max_matches, pack_ids=False,
                       **r._walk_kw(ids.shape[1]))
-    assert np.array_equal(np.asarray(ids_dev), np.asarray(res.ids))
+    # the dispatch hands its ids on with the pad rows blanked
+    from emqx_tpu.ops.pack import mask_pad_rows
+
+    assert np.array_equal(
+        np.asarray(ids_dev),
+        np.asarray(mask_pad_rows(res.ids, np.int32(len(topics)))))
     assert np.array_equal(np.asarray(ovf_dev),
                           np.asarray(res.overflow))
 

@@ -15,15 +15,12 @@ Its flags are equal and the host oracle resolves it either way."""
 import os
 import sys
 
-import jax
 import numpy as np
 import pytest
-from jax._src import dispatch as jax_dispatch
-from jax._src.interpreters import pxla
 
 from emqx_tpu import topic as topic_mod
 from emqx_tpu.broker import Broker
-from emqx_tpu.metrics import MESH_METRICS, Metrics
+from emqx_tpu.metrics import MESH_METRICS
 from emqx_tpu.ops import match_cache
 from emqx_tpu.ops.pack import (mask_pad_rows, pack_fanout, pack_matches,
                                pack_mesh)
@@ -32,7 +29,8 @@ from emqx_tpu.parallel.sharded import publish_step_insert
 from emqx_tpu.router import MatcherConfig, Router
 from emqx_tpu.types import Message
 from emqx_tpu.utils.batch import dedup_topics
-from helpers import Compiles
+from helpers import (MOVF_FILTERS, Compiles, CounterTel,
+                     LoopCost as _Loop)
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -51,26 +49,6 @@ class Q:
         self.n += 1
 
 
-class _Tel:
-    """What ``Router._count_mesh`` asks of a telemetry: counters live,
-    stage timing off."""
-
-    enabled = False
-
-    def __init__(self):
-        self.metrics = Metrics()
-
-    def loop_clock(self):
-        return self
-
-
-# topics that overflow the match bound alone (> M filters on a shard)
-MOVF_FILTERS = [
-    "mo/#", "mo/+/#", "mo/a/#", "mo/+/b/#", "mo/a/b/#", "mo/+/+/#",
-    "mo/a/+/#", "mo/+/b/c", "mo/a/b/c", "mo/a/b/+", "mo/a/+/c",
-    "mo/+/+/c", "mo/+/+/+", "mo/a/+/+", "mo/+/b/+", "+/a/b/c",
-    "+/+/b/c", "+/a/+/c", "+/a/b/+", "+/+/+/c", "+/a/+/+", "+/+/b/+",
-    "+/+/+/+", "mo/a/b/c/#", "+/a/b/c/#", "+/+/b/c/#"]
 # and the fan-out bound alone: few filters, five subscribers each
 FOVF_FILTERS = ["fan/x", "fan/+", "+/x", "fan/#", "fan/x/#"]
 
@@ -83,7 +61,7 @@ def _broker(mesh_shape, parts, **kw):
                fanout_threshold=64)
     cfg.update(kw)
     b = Broker(router=Router(MatcherConfig(**cfg), node="local"))
-    b.router.telemetry = _Tel()
+    b.router.telemetry = CounterTel()
     subs = {}
 
     def sub(flt, n=1):
@@ -236,50 +214,6 @@ def test_fused_equals_legacy_bit_for_bit(broker, case):
 
 
 # -- what a warm batch costs the event loop ----------------------------------
-
-
-class _Loop:
-    """Counting wrappers on what the event loop can hand the device:
-    eager operations (``apply_primitive`` looks its callable up through
-    ``dispatch.xla_primitive_callable``), host→device transfers
-    (``pxla.batched_device_put``: ``jax.device_put`` and every numpy
-    argument of a jitted call go through it) and the launches of every
-    jitted function the package's modules name."""
-
-    def __init__(self, monkeypatch):
-        self.eager = self.transfers = 0
-        self.programs = []
-        prim, put = jax_dispatch.xla_primitive_callable, \
-            pxla.batched_device_put
-
-        def counted_prim(*a, **kw):
-            self.eager += 1
-            return prim(*a, **kw)
-
-        def counted_put(*a, **kw):
-            self.transfers += 1
-            return put(*a, **kw)
-
-        monkeypatch.setattr(jax_dispatch, "xla_primitive_callable",
-                            counted_prim)
-        monkeypatch.setattr(pxla, "batched_device_put", counted_put)
-        jitted = type(jax.jit(lambda: 0))
-        for name, mod in list(sys.modules.items()):
-            if not name.startswith("emqx_tpu") or mod is None:
-                continue
-            for attr, fn in list(vars(mod).items()):
-                if isinstance(fn, jitted):
-                    monkeypatch.setattr(mod, attr, self._counted(attr, fn))
-
-    def _counted(self, name, fn):
-        def call(*a, **kw):
-            self.programs.append(name)
-            return fn(*a, **kw)
-        return call
-
-    def reset(self):
-        self.eager = self.transfers = 0
-        self.programs = []
 
 
 @pytest.mark.parametrize("mesh", list(MESHES))

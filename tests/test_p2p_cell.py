@@ -37,6 +37,8 @@ TWINS = {
     "select_wait_clients_share.p2p": "select_wait_clients_share",
     "device_path_share.p2p": "device_path_share",
     "device_path_depth.p2p": "device_path_depth",
+    # PR 38: batches that left the loop as one transfer
+    "fused_batch_share.p2p": "fused_batch_share",
 }
 #: the cell's own readings: counters over counters
 OWN = {
@@ -135,9 +137,12 @@ def test_the_cells_per_layer_list():
     assert {m["name"] for m in METRICS} == set(TWINS) | set(OWN)
     # appended in one stretch after everything the benchmark had; no
     # accepted metric's list names the new cell
+    # (PR 38's one behind PR 37's, at the list's end)
     names = [m["name"] for m in SPEC["per_layer"]]
     at = names.index("plan_resolve_share.uniform") + 1
-    assert SPEC["per_layer"][at:at + len(METRICS)] == METRICS
+    assert SPEC["per_layer"][at:at + len(METRICS) - 1] == METRICS[:-1]
+    assert METRICS[-1] == SPEC["per_layer"][-1]
+    assert METRICS[-1]["name"] == "fused_batch_share.p2p"
     assert all(CELL not in m["workloads"] for m in SPEC["per_layer"]
                if m not in METRICS)
 

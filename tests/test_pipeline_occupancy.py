@@ -657,7 +657,7 @@ def test_sixteen_files_and_no_other_entry():
     # the rest; nothing that was there moved
     at = names.index("wakes_per_park.p2p") + 1
     assert names[at:at + 5] == [b + ".p2p" for b in BASES]
-    assert set(names[at:]) == set(new) and len(names[at:]) == 16
+    assert set(names[at:at + 16]) == set(new)   # later PRs append behind
     # the mesh cell's list is pinned (benchmark/tests/test_mesh_cell.py)
     assert not any("fleet_10m_mesh.flood" in m["workloads"]
                    for m in spec["per_layer"][at:])

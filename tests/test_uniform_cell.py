@@ -88,7 +88,9 @@ def test_the_cells_per_layer_list():
         "plan_resolve_share.uniform",
         # PR 37: who waits for whom, and the device path's occupancy
         "select_wait_device_share.uniform", "device_path_share.uniform",
-        "device_path_depth.uniform"}
+        "device_path_depth.uniform",
+        # PR 38: batches that left the loop as one transfer
+        "fused_batch_share.uniform"}
     # no accepted metric's list was touched: none names the new cell
     assert all(CELL not in m["workloads"] for m in SPEC["per_layer"]
                if m not in METRICS)

@@ -22,7 +22,6 @@ import random
 
 import pytest
 
-from emqx_tpu import router as router_mod
 from emqx_tpu.broker import Broker
 from emqx_tpu.metrics import DISPATCH_METRICS, Metrics
 from emqx_tpu.ops import match_cache
@@ -128,20 +127,20 @@ class Recorder:
 
     def __init__(self, monkeypatch):
         self.walks, self.merges = set(), set()
-        walk, merge = router_mod.match_batch, match_cache._merge_jit
+        walk, merge = match_cache.walk_insert, match_cache._mesh_merge_jit
 
-        def rec_walk(auto, ids, *a, **kw):
-            self.walks.add(tuple(ids.shape))
-            return walk(auto, ids, *a, **kw)
+        def rec_walk(*a, lay, **kw):
+            assert lay.hit == 0     # never keyed by the batch's hits
+            self.walks.add((lay.miss, lay.levels))
+            return walk(*a, lay=lay, **kw)
 
-        def rec_merge(table, hit_slots, hit_pos, miss_rows, *a, b_pad):
-            mb = miss_rows.shape[0]
-            self.merges.add((b_pad, hit_slots.shape[0], mb if mb > 1 else 0))
-            return merge(table, hit_slots, hit_pos, miss_rows, *a,
-                         b_pad=b_pad)
+        def rec_merge(*a, lay, b_pad, **kw):
+            assert lay.levels == 0  # nor the merge by its depth
+            self.merges.add((b_pad, lay.hit, lay.miss))
+            return merge(*a, lay=lay, b_pad=b_pad, **kw)
 
-        monkeypatch.setattr(router_mod, "match_batch", rec_walk)
-        monkeypatch.setattr(match_cache, "_merge_jit", rec_merge)
+        monkeypatch.setattr(match_cache, "walk_insert", rec_walk)
+        monkeypatch.setattr(match_cache, "_mesh_merge_jit", rec_merge)
 
     def clear(self):
         self.walks.clear()
@@ -411,7 +410,7 @@ def test_dispatch_counters_equal_the_spans_sums(enabled):
         last = topics[-SLOTS:]
     got = {k: metrics.val(k) for k in DISPATCH_METRICS}
     if not enabled:
-        assert got == {"dispatch.topics": 0, "dispatch.walk.topics": 0}
+        assert got == dict.fromkeys(DISPATCH_METRICS, 0)
         assert not spans
         return
     assert len(spans) == 7 and all(p == "device" for *_x, p in spans)
