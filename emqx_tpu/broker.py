@@ -650,8 +650,12 @@ class Broker:
             # closes the match stage; the router's cache-split path
             # (telemetry-gated) left the cache_gather share to split
             sp.stop_match(self.router)
-            sp.start("pack")
+            # the fan-out tables brought up to the memberships that
+            # changed since the last batch: a compare where none did
+            sp.start("fan_sync")
         pb.st = self.helper.state(pb.epoch, pb.id_map)
+        if sp is not None:
+            sp.start("pack")
         bucket = pb.ids_dev.shape[0]
         budgets = self._pack_budgets.setdefault(
             bucket, [budget_for(bucket, cfg.pack_m),

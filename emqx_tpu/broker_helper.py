@@ -24,9 +24,15 @@ TPU-native redesign (SURVEY §2.2 "topic sharding → bitmap tiles"):
         Pallas OR-streaming kernel over the matched rows.
 
     This is the product wiring of the round-1 kernels: tables are
-    rebuilt lazily (dirty-flag) against the **automaton's id-map
-    snapshot**, so device match ids index them consistently even as
-    filter ids are recycled across automaton rebuilds.
+    built lazily against the **automaton's id-map snapshot**, so
+    device match ids index them consistently even as filter ids are
+    recycled across automaton rebuilds. Within one automaton epoch a
+    membership change costs what it changes: the changed rows are
+    written behind the table's live entries and their ``row_pairs``
+    repointed, on the host mirror and by one small scatter program on
+    the device (docs/DELTA.md "Fan-out tables"); a new epoch, a row
+    that is or becomes a bitmap, or a table out of room rebuilds
+    whole.
 
 Capacities grow in powers of two and never shrink, keeping device
 array shapes stable across rebuilds (no recompilation churn).
@@ -34,11 +40,13 @@ array shapes stable across rebuilds (no recompilation churn).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Set
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from emqx_tpu.ops.bitmap import BitmapTable, build_bitmaps
@@ -169,13 +177,37 @@ class ShardedFanoutState:
         self.d = d
 
 
+#: rows one launch of the patch program repoints
+_PATCH_ROWS = 256
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _patch_fan(pairs: jax.Array, subs: jax.Array, buf: jax.Array, *,
+               k: int):
+    """One chunk of a fan-out patch on the device: repoint up to ``k``
+    rows of ``row_pairs`` and write their entries, which lie in one
+    run behind the table's live ones. ``buf`` is the chunk's one
+    transfer: ``[first entry position, entries, k filter ids (pad: an
+    id past the table, dropped), k (start, end) pairs, the entries]``.
+    The tables are not donated: a batch in flight holds the old
+    ones."""
+    with jax.named_scope("fanout_patch"):
+        e = buf.shape[0] - 2 - 3 * k
+        pos = jnp.arange(e, dtype=jnp.int32)
+        at = jnp.where(pos < buf[1], buf[0] + pos, subs.shape[0])
+        return (pairs.at[buf[2:2 + k]].set(
+                    buf[2 + k:2 + 3 * k].reshape(k, 2), mode="drop"),
+                subs.at[at].set(buf[2 + 3 * k:], mode="drop"))
+
+
 class FanoutManager:
     """Host truth for local subscriber sets + lazy device tables.
 
     ``subscribe``/``unsubscribe`` maintain ``filter → {sid}``;
-    :meth:`state` returns the device tables for an automaton snapshot,
-    rebuilding only when membership changed or the automaton epoch
-    moved (filter ids are only meaningful per epoch).
+    :meth:`state` returns the device tables for an automaton snapshot.
+    They are built whole when the automaton epoch moves (filter ids
+    are only meaningful per epoch) and patched row by row when
+    membership changed inside one (:meth:`_patch`).
     """
 
     def __init__(self, threshold: int = 1024, use_device: bool = True):
@@ -187,6 +219,27 @@ class FanoutManager:
         self._version = 0
         self._state: Optional[FanoutState] = None
         self._sharded: Optional[ShardedFanoutState] = None
+        # what a patch works from (set by a whole build of the CSR
+        # table, dropped with it): the host mirror of ``row_pairs``
+        # and ``sub_ids`` with the first free entry position, the
+        # epoch's filter → id, how much of its id map has been read,
+        # and the filters whose membership changed since the tables
+        # were brought up to date
+        self._mirror: Optional[tuple] = None
+        self._tail = 0
+        self._fid_of: Dict[str, int] = {}
+        self._seen = 0
+        self._changed: Set[str] = set()
+        # entries a patch chunk carries: any CSR row fits in one
+        self._patch_entries = max(2048, 2 * threshold)
+        # publish-path telemetry (Node wires it next to the broker's):
+        # fanout.* count into its Metrics while it is enabled
+        self.telemetry = None
+        #: table syncs by kind and the rows the patches wrote
+        #: (cumulative; ``stats`` and the tests read them)
+        self.patches = 0
+        self.rebuilds = 0
+        self.rows_patched = 0
         # capacity retention (pow2, never shrinks → stable jit shapes)
         self._caps: Dict[str, Optional[int]] = {
             "filter": None, "entry": None, "row": None, "nsub": 1}
@@ -200,6 +253,8 @@ class FanoutManager:
             sid = self.registry.register(sub)
             self.rows.setdefault(filter_, set()).add(sid)
             self._version += 1
+            if self._mirror is not None:
+                self._changed.add(filter_)
             return sid
 
     def unsubscribe(self, filter_: str, sub: object) -> None:
@@ -213,6 +268,8 @@ class FanoutManager:
                 if not row:
                     del self.rows[filter_]
             self._version += 1
+            if self._mirror is not None:
+                self._changed.add(filter_)
 
     def release(self, sub: object) -> None:
         """Drop the subscriber's id (after its last unsubscribe).
@@ -244,6 +301,8 @@ class FanoutManager:
             "subscribers.count": self.registry.count(),
             "fanout.filters": len(self.rows),
             "fanout.version": self._version,
+            "fanout.patches": self.patches,
+            "fanout.rebuilds": self.rebuilds,
         }
 
     def invalidate_device(self) -> None:
@@ -256,65 +315,209 @@ class FanoutManager:
         with self._lock:
             self._state = None
             self._sharded = None
+            self._drop_mirror()
 
     # -- device snapshot ---------------------------------------------------
+
+    def _drop_mirror(self) -> None:
+        self._mirror = None
+        self._fid_of = {}
+        self._changed = set()
+
+    def _count_sync(self, kind: str, t0: float) -> None:
+        """One sync that changed the tables (metrics.FANOUT_METRICS;
+        gated on [telemetry] enabled like the dispatch's counters)."""
+        tel = self.telemetry
+        if tel is not None and tel.loop_clock() is not None:
+            m = tel.metrics
+            m.inc(kind)
+            m.inc("fanout.sync.ns",
+                  int((time.perf_counter() - t0) * 1e9))
 
     def state(self, epoch: int,
               id_map: Sequence[Optional[str]]) -> Optional[FanoutState]:
         """Device tables consistent with the automaton snapshot
         ``(epoch, id_map)``; ``None`` when there are no local
-        subscribers (device fan-out has nothing to do)."""
+        subscribers (device fan-out has nothing to do). Unchanged
+        membership costs one compare; a change inside the epoch costs
+        the rows it changed (:meth:`_patch`); a new epoch builds
+        whole."""
         with self._lock:
             st = self._state
-            if (st is not None and st.epoch == epoch
-                    and st.version == self._version):
-                return st
-            if not self.rows:
-                self._state = None
-                self.registry.flush_free()
-                return None
-            small: Dict[int, List[int]] = {}
-            big: Dict[int, Sequence[int]] = {}
-            big_fids = set()
-            for fid, f in enumerate(id_map):
-                if f is None:
-                    continue
-                row = self.rows.get(f)
-                if not row:
-                    continue
-                if len(row) > self.threshold:
-                    big[fid] = sorted(row)
-                    big_fids.add(fid)
-                else:
-                    small[fid] = sorted(row)
-            n_filters = len(id_map)
-            fan = bm = None
-            if small or not big:
-                fan = build_fanout(
-                    small, n_filters,
-                    filter_capacity=self._caps["filter"],
-                    entry_capacity=self._caps["entry"])
-                self._caps["filter"] = fan.row_ptr.shape[0] - 1
-                self._caps["entry"] = fan.sub_ids.shape[0]
-            if big:
-                nsub = max(self._caps["nsub"], self.registry.capacity())
-                bm = build_bitmaps(
-                    big, n_filters, nsub,
-                    row_capacity=self._caps["row"])
-                self._caps["row"] = bm.bitmaps.shape[0]
-                self._caps["nsub"] = nsub
-            if self.use_device:
-                if fan is not None:
-                    fan = jax.device_put(fan)
-                if bm is not None:
-                    bm = jax.device_put(bm)
-            st = FanoutState(epoch, self._version, fan, bm,
-                             frozenset(big_fids))
-            self._state = st
+            if st is not None and st.epoch == epoch:
+                if st.version == self._version \
+                        and (self._mirror is None
+                             or self._seen == len(id_map)):
+                    return st
+                if self._mirror is not None and self.rows:
+                    t0 = time.perf_counter()
+                    patched = self._patch(st, id_map)
+                    if patched is not None:
+                        self.patches += 1
+                        self._count_sync("fanout.patches", t0)
+                        self.registry.flush_free()
+                        return patched
+            t0 = time.perf_counter()
+            st = self._build(epoch, id_map)
+            if st is not None:
+                self.rebuilds += 1
+                self._count_sync("fanout.rebuilds", t0)
             # the previous state (the last table referencing any
             # quarantined sid) is gone; freed ids may recycle now
             self.registry.flush_free()
             return st
+
+    def _build(self, epoch: int,
+               id_map: Sequence[Optional[str]]) -> Optional[FanoutState]:
+        """The tables from scratch: every filter of ``id_map`` looked
+        up in ``rows``. What :meth:`_patch` is held to (call under the
+        lock)."""
+        self._drop_mirror()
+        if not self.rows:
+            self._state = None
+            return None
+        small: Dict[int, List[int]] = {}
+        big: Dict[int, Sequence[int]] = {}
+        big_fids = set()
+        fid_of: Dict[str, int] = {}
+        for fid, f in enumerate(id_map):
+            if f is None:
+                continue
+            fid_of[f] = fid
+            row = self.rows.get(f)
+            if not row:
+                continue
+            if len(row) > self.threshold:
+                big[fid] = sorted(row)
+                big_fids.add(fid)
+            else:
+                small[fid] = sorted(row)
+        n_filters = len(id_map)
+        fan = bm = None
+        if small or not big:
+            fan = build_fanout(
+                small, n_filters,
+                filter_capacity=self._caps["filter"],
+                entry_capacity=self._caps["entry"])
+            self._caps["filter"] = fan.row_ptr.shape[0] - 1
+            self._caps["entry"] = fan.sub_ids.shape[0]
+            # the arrays built here stay the host's copy; the state
+            # gets the device's (or, off the device, its own)
+            self._mirror = (fan.row_pairs, fan.sub_ids)
+            self._tail = fan.n_entries
+            self._fid_of = fid_of
+            self._seen = n_filters
+        if big:
+            nsub = max(self._caps["nsub"], self.registry.capacity())
+            bm = build_bitmaps(
+                big, n_filters, nsub,
+                row_capacity=self._caps["row"])
+            self._caps["row"] = bm.bitmaps.shape[0]
+            self._caps["nsub"] = nsub
+        if self.use_device:
+            if fan is not None:
+                fan = jax.device_put(fan)
+            if bm is not None:
+                bm = jax.device_put(bm)
+        elif fan is not None:
+            fan = fan._replace(row_pairs=fan.row_pairs.copy(),
+                               sub_ids=fan.sub_ids.copy())
+        st = FanoutState(epoch, self._version, fan, bm,
+                         frozenset(big_fids))
+        self._state = st
+        return st
+
+    def _patch(self, st: FanoutState,
+               id_map: Sequence[Optional[str]]) -> Optional[FanoutState]:
+        """Bring ``st``'s CSR table up to the membership changes and
+        the ids appended to ``id_map`` since it was current, in work
+        proportional to them: each changed row is written anew behind
+        the live entries (what it held before stays where it was,
+        unreachable) and its ``row_pairs`` entry repointed; a row that
+        lost its filter or its last member is pointed at nothing.
+        ``row_ptr`` keeps the last whole build's values: with
+        ``row_pairs`` there no program reads them. None where a whole
+        build is due instead: a row that is or would be a bitmap, an
+        id past the table's capacity, or no room behind the entries
+        (call under the lock)."""
+        pairs, subs = self._mirror
+        f_cap, fid_of = pairs.shape[0], self._fid_of
+        n_map = len(id_map)
+        if n_map > f_cap:
+            return None
+        changed = self._changed
+        touched: Dict[int, Optional[str]] = {}  # id → filter it bears
+        for fid in range(self._seen, n_map):
+            f = id_map[fid]
+            if f is not None:
+                # the filter came (back) under a new id: the id it
+                # had, if any, bears nothing now
+                old = fid_of.get(f)
+                if old is not None and old != fid:
+                    touched[old] = None
+                fid_of[f] = fid
+                touched[fid] = f
+        for f in changed:
+            fid = fid_of.get(f)
+            if fid is None:
+                continue  # not in this epoch's map: no row to keep
+            if id_map[fid] != f:
+                del fid_of[f]
+                touched[fid] = None
+            else:
+                touched[fid] = f
+        writes = []  # (id, sorted members or None)
+        room = subs.shape[0] - 1 - self._tail
+        for fid, f in touched.items():
+            row = self.rows.get(f) if f is not None else None
+            if fid in st.big_fids or (row and len(row) > self.threshold):
+                return None
+            if row:
+                room -= len(row)
+                writes.append((fid, sorted(row)))
+            elif pairs[fid, 0] != pairs[fid, 1]:
+                writes.append((fid, None))
+        if room < 0:
+            return None
+        self._seen = n_map
+        self._changed = set()
+        fan = st.fan
+        dev_pairs, dev_subs = fan.row_pairs, fan.sub_ids
+        k, e_cap = _PATCH_ROWS, self._patch_entries
+        i = 0
+        while i < len(writes):
+            buf = np.full(2 + 3 * k + e_cap, -1, np.int32)
+            buf[2:2 + k] = f_cap
+            start = tail = self._tail
+            n = 0
+            while i < len(writes) and n < k:
+                fid, members = writes[i]
+                m = len(members) if members else 0
+                if tail - start + m > e_cap:
+                    break
+                if m:
+                    subs[tail:tail + m] = members
+                    buf[2 + 3 * k + tail - start:
+                        2 + 3 * k + tail - start + m] = members
+                pairs[fid] = (tail, tail + m) if m else (0, 0)
+                buf[2 + n] = fid
+                buf[2 + k + 2 * n:2 + k + 2 * n + 2] = pairs[fid]
+                tail += m
+                n += 1
+                i += 1
+            buf[0], buf[1] = start, tail - start
+            self._tail = tail
+            # the chunk's one transfer (the buffer, as the launch's
+            # argument) and one launch
+            dev_pairs, dev_subs = _patch_fan(dev_pairs, dev_subs, buf,
+                                             k=k)
+        self.rows_patched += len(writes)
+        st = FanoutState(
+            st.epoch, self._version,
+            fan._replace(row_pairs=dev_pairs, sub_ids=dev_subs),
+            st.bm, st.big_fids)
+        self._state = st
+        return st
 
     def sharded_state(self, epoch: int,
                       id_map: Sequence[Optional[str]],

@@ -32,6 +32,7 @@ from emqx_tpu.acl_cache import AclCache
 from emqx_tpu.keepalive import Keepalive
 from emqx_tpu.limiter import TokenBucket
 from emqx_tpu.logger import set_metadata_clientid, set_metadata_peername
+from emqx_tpu.metrics import I_SESSION_CLOSE_NS, I_SESSION_OPEN_NS
 from emqx_tpu.mountpoint import mount, replvar, unmount
 from emqx_tpu.mqtt import constants as C
 from emqx_tpu.mqtt import reason_codes as RC
@@ -228,7 +229,26 @@ class Channel:
 
     # CONNECT ------------------------------------------------------------
 
+    def _loop_clock(self):
+        """The node's Telemetry while its loop counters are live,
+        else None (``loop.session.*``, metrics.LOOP_METRICS)."""
+        tel = getattr(self.broker, "telemetry", None)
+        return tel.loop_clock() if tel is not None else None
+
     def _in_connect(self, pkt: Connect) -> List[Packet]:
+        """A CONNECT; the section from here to its CONNACK is the
+        loop's ``session.open`` where the session opened."""
+        lc = self._loop_clock()
+        if lc is None:
+            return self._open(pkt)
+        t0 = time.perf_counter()
+        n0 = lc.inner
+        out = self._open(pkt)
+        if self.state == CONNECTED:
+            lc.loop_leave(I_SESSION_OPEN_NS, t0, n0)
+        return out
+
+    def _open(self, pkt: Connect) -> List[Packet]:
         self.broker.metrics.inc("packets.connect.received")
         self.broker.metrics.inc("client.connect")
         if self.state != IDLE:
@@ -1271,6 +1291,25 @@ class Channel:
         self.closed = True
         was_connected = self.state == CONNECTED
         self.state = DISCONNECTED
+        lc = self._loop_clock() if was_connected else None
+        if lc is None:
+            self._teardown(was_connected, publish_will, rc,
+                           close_transport, server_ref)
+            return
+        t0 = time.perf_counter()
+        n0 = lc.inner
+        try:
+            self._teardown(was_connected, publish_will, rc,
+                           close_transport, server_ref)
+        finally:
+            lc.loop_leave(I_SESSION_CLOSE_NS, t0, n0)
+
+    def _teardown(self, was_connected: bool,
+                  publish_will: Optional[bool], rc: Optional[int],
+                  close_transport: bool,
+                  server_ref: Optional[str]) -> None:
+        """What :meth:`_shutdown` does once: the loop's
+        ``session.close`` where the channel was connected."""
         if (rc is not None and was_connected
                 and self.proto_ver == C.MQTT_V5
                 and self.send_oob is not None):

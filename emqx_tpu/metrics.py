@@ -155,6 +155,11 @@ TRANSPORT_METRICS = [
 AUTOMATON_METRICS = [
     "automaton.delta.probes", "automaton.delta.filters",
     "automaton.delta.merges", "automaton.rebuild.stall_ms",
+    # route deletes the delta took: `delta.retracts` = of a filter
+    # still pending in the side-automaton (the add is withdrawn,
+    # nothing is masked), `delta.tombstones` = of a filter in the main
+    # tables (its id joins the mask until the next compaction)
+    "automaton.delta.tombstones", "automaton.delta.retracts",
     # level-compressed walk tables (ops/csr.py compress_automaton):
     # `compaction.chains` = compressed edges carrying a fused
     # single-child run, `compaction.fused_edges` = interior states
@@ -361,6 +366,20 @@ LOOP_METRICS = [
     # the stats flush (Node._update_stats, once a stats interval):
     # exclusive like read and flush
     "loop.stats.ns", "loop.stats.calls",
+    # the front door's two sections, exclusive like the rest
+    # (channel.Channel): ``session.open`` = a decoded CONNECT to its
+    # CONNACK handed to the connection (authentication, the connection
+    # manager's registration or the kick of the channel it takes over,
+    # the session made), counted where the CONNACK says success, so
+    # Σ calls = Σ ``client.connected``; ``session.close`` = a connected
+    # channel's teardown (the will, the session's unsubscribes and the
+    # route deletes and fan-out rows they cause, the connection
+    # manager's unregistration), Σ calls = Σ ``client.disconnected``.
+    # A takeover's kicked channel closes inside the new one's open and
+    # is taken out of it; both are taken out of the read chunk that
+    # brought them
+    "loop.session.open.ns", "loop.session.open.calls",
+    "loop.session.close.ns", "loop.session.close.calls",
 ]
 
 # the device path's occupancy, from the publish spans' interval record
@@ -435,6 +454,20 @@ INGRESS_METRICS = [
     "ingress.parks", "ingress.park.ns", "ingress.wakes",
 ]
 
+# the fan-out tables' syncs that changed them
+# (broker_helper.FanoutManager.state, once a batch on the event loop;
+# gated on [telemetry] enabled like ``dispatch.*``): ``patches`` =
+# syncs that wrote the rows a membership change touched and no other
+# (work proportional to the change), ``rebuilds`` = syncs that built
+# the tables from every filter (a new automaton epoch, a bitmap row
+# changed, a table out of room; the first of a node is one),
+# ``sync.ns`` = the time of both. A batch that found the tables
+# current counts nowhere. rebuilds ÷ (rebuilds + patches) under
+# subscribe churn is what the patch path keeps at 0
+FANOUT_METRICS = [
+    "fanout.patches", "fanout.rebuilds", "fanout.sync.ns",
+]
+
 ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + WILL_METRICS
                + DELIVERY_METRICS + CLIENT_METRICS + SESSION_METRICS
@@ -444,7 +477,7 @@ ALL_METRICS = (BYTES_METRICS + PACKET_METRICS + MESSAGE_METRICS
                + OPS_METRICS + DURABILITY_METRICS + CLUSTER_METRICS
                + TRACING_METRICS + FRAME_METRICS + LOOP_METRICS
                + MESH_METRICS + DISPATCH_METRICS + CHANNEL_METRICS
-               + INGRESS_METRICS + PIPELINE_METRICS)
+               + INGRESS_METRICS + PIPELINE_METRICS + FANOUT_METRICS)
 
 #: registry names that are NOT monotonic — ``Metrics.dec`` runs on
 #: them in steady state (today: the retainer's live-entry count,
@@ -597,6 +630,8 @@ I_SELECT_POLL_NS = _global._index["loop.select.poll.ns"]
 I_SELECT_DEVICE_NS = _global._index["loop.select.device.ns"]
 I_SELECT_CLIENTS_NS = _global._index["loop.select.clients.ns"]
 I_STATS_NS = _global._index["loop.stats.ns"]
+I_SESSION_OPEN_NS = _global._index["loop.session.open.ns"]
+I_SESSION_CLOSE_NS = _global._index["loop.session.close.ns"]
 I_PIPELINE_NS = _global._index["pipeline.device.ns"]
 
 

@@ -190,6 +190,7 @@ class Node:
                                    metrics=self.metrics)
         self.broker.telemetry = self.telemetry
         self.router.telemetry = self.telemetry
+        self.broker.helper.telemetry = self.telemetry
         # per-message span tracing ([tracing], tracing.py): always
         # constructed (like Telemetry) so reload/ctl can read the
         # config; with sample_rate = 0 no seam ever stamps a context

@@ -25,6 +25,15 @@ route set churns. The side structures are tiny (bounded by
     therefore no splits and no hop decay — a filter's walk cost is
     exactly its depth, and the automaton rebuilds from its own small
     trie in milliseconds when capacity doubles;
+  - a live delta looks the same to the walk's program whatever it
+    holds: the side tables are sized once for the configured bound
+    (``floor_states``; they double only past it), the mask is there
+    with no bit set where nothing is tombstoned, the side tables
+    with no filter in them where nothing is pending, and the side
+    walk's step count follows the batch's depth alone. Routes that
+    change while traffic flows therefore first-use one variant of
+    the walk's program a shape, not one for every size and mix the
+    delta passes through (2–7 s each on the event loop);
   - deletes never touch any automaton: the fid lands in a tombstone
     set, compiled into a device mask applied to the merged match ids
     (``-1``-ing them before the fan-out gathers — the id→filter map's
@@ -50,8 +59,9 @@ import numpy as np
 
 from emqx_tpu import topic as T
 from emqx_tpu.oracle import TrieOracle
-from emqx_tpu.ops.csr import (Automaton, build_automaton, device_view,
-                              finalize_automaton)
+from emqx_tpu.ops.csr import (NARROW_SLOTS, Automaton, build_automaton,
+                              buckets_for_capacity, capacity_for,
+                              device_view, finalize_automaton)
 from emqx_tpu.ops.match import match_batch
 from emqx_tpu.ops.patch import AutoPatcher, PatchOverflow
 
@@ -69,22 +79,24 @@ class _InternTable:
 
 
 class DeltaSnapshot(NamedTuple):
-    """One consistent, immutable view for lock-free matchers. ``auto``
-    is None when there are no pending adds (tombstone-only delta);
-    ``mask`` is None when there are no tombstones."""
+    """One consistent, immutable view for lock-free matchers. Both
+    halves are always there: ``auto`` holds no filter when no add is
+    pending (tombstone-only delta), ``mask`` has no bit set when
+    nothing is tombstoned, so the walk's program is one whatever the
+    delta holds."""
 
-    auto: Optional[Automaton]     # walkable device view (narrow)
-    hops: Optional[np.ndarray]    # host hops_for_level of the view
+    auto: Automaton               # walkable device view (narrow)
     k: int                        # active-set lanes the delta walk needs
-    mask: Optional[jax.Array]     # bool[cap] True = tombstoned fid
+    mask: jax.Array               # bool[cap] True = tombstoned fid
     version: int
     n_pending: int
 
-    def steps_for(self, lb: int) -> int:
-        hl = self.hops
-        if hl is None or len(hl) == 0:
-            return 1
-        return int(hl[min(lb, len(hl) - 1)])
+    @staticmethod
+    def steps_for(lb: int) -> int:
+        """Scan steps of the side walk for a batch ``lb`` levels deep:
+        the narrow tables take one hop a level and one more to close,
+        whatever they hold (a shallower delta idles the rest)."""
+        return lb + 1
 
 
 class DeltaAutomaton:
@@ -107,6 +119,10 @@ class DeltaAutomaton:
         self.log: List[Tuple[str, str, int]] = []
         self.has_plus = False
         self.version = 0
+        #: states the side tables are sized for from their first
+        #: flatten on (the router sets it from ``delta_max_filters``);
+        #: one capacity for the delta's life, doubled only past it
+        self.floor_states = 1 << 14
         self._host_auto: Optional[Automaton] = None
         self._dev_auto: Optional[Automaton] = None
         self._patcher: Optional[AutoPatcher] = None
@@ -177,6 +193,7 @@ class DeltaAutomaton:
         inside the window cancels and a delete of a pre-mark add
         becomes a tombstone against the NEW tables."""
         fresh = DeltaAutomaton(self.intern, self.use_device)
+        fresh.floor_states = self.floor_states
         for op, f, fid in self.log[mark:]:
             if op == "+":
                 fresh.add(f, fid)
@@ -202,10 +219,10 @@ class DeltaAutomaton:
         self._host_auto = None
         self._dev_auto = None
         self._patcher = None
-        self._flatten_dirty = bool(self.fids)
+        self._flatten_dirty = True
         self._mask_dev = None
         self._mask_cap = 0
-        self._mask_dirty = bool(self.tombs)
+        self._mask_dirty = True
         self._snap = None
         self._snap_key = None
 
@@ -222,11 +239,12 @@ class DeltaAutomaton:
     # -- snapshot (side tables + tombstone mask) --------------------------
 
     def _flatten(self) -> None:
-        cap = nb = None
+        cap, nb = self.floor_states, buckets_for_capacity(
+            self.floor_states, NARROW_SLOTS)
         if self._host_auto is not None \
                 and self._host_auto.node2 is not None:
-            cap = self._host_auto.node2.shape[0] * self._grow
-            nb = self._host_auto.wt.shape[0] * self._grow
+            cap = max(cap, self._host_auto.node2.shape[0] * self._grow)
+            nb = max(nb, self._host_auto.wt.shape[0] * self._grow)
         table = _InternTable(self.intern)
         base = build_automaton(self.trie, self.fids, table,
                                skip_hash=True)
@@ -251,37 +269,26 @@ class DeltaAutomaton:
                 and not self._flatten_dirty and not self._mask_dirty \
                 and (self._patcher is None or not self._patcher.dirty):
             return self._snap
-        auto = hops = None
-        if self.fids:
-            if self._flatten_dirty or self._host_auto is None:
-                self._flatten()
-            elif self._patcher is not None and self._patcher.dirty:
-                self._dev_auto = self._patcher.apply_updates(
-                    self._dev_auto)
-            auto = self._dev_auto
-            hops = (self._patcher.hops_for_level
-                    if self._patcher is not None
-                    else self._host_auto.hops_for_level)
-        if self.tombs:
-            cap = self._mask_cap
-            if cap < id_cap or cap == 0:
-                cap = 16
-                while cap < id_cap:
-                    cap *= 2
-            if self._mask_dirty or cap != self._mask_cap:
-                m = np.zeros(cap, bool)
+        if self._flatten_dirty or self._host_auto is None:
+            self._flatten()
+        elif self._patcher.dirty:
+            self._dev_auto = self._patcher.apply_updates(self._dev_auto)
+        cap = self._mask_cap
+        if cap < id_cap or cap == 0:
+            cap = capacity_for(id_cap)
+        if self._mask_dirty or cap != self._mask_cap:
+            m = np.zeros(cap, bool)
+            if self.tombs:
                 m[np.fromiter(self.tombs, np.int64,
                               len(self.tombs))] = True
-                self._mask_dev = jax.device_put(m) if self.use_device \
-                    else jnp.asarray(m)
-                self._mask_cap = cap
-                self._mask_dirty = False
-            mask = self._mask_dev
-        else:
-            mask = None
+            self._mask_dev = jax.device_put(m) if self.use_device \
+                else jnp.asarray(m)
+            self._mask_cap = cap
+            self._mask_dirty = False
         self._snap = DeltaSnapshot(
-            auto=auto, hops=hops, k=(k_cap if self.has_plus else 1),
-            mask=mask, version=self.version, n_pending=len(self.fids))
+            auto=self._dev_auto, k=(k_cap if self.has_plus else 1),
+            mask=self._mask_dev, version=self.version,
+            n_pending=len(self.fids))
         self._snap_key = key
         return self._snap
 
@@ -324,17 +331,12 @@ def probe_raw(snap: DeltaSnapshot, word_ids, n_words, sys_mask,
     walk the side-automaton over the already-encoded batch, CONCAT
     its raw emit slots onto the main walk's (downstream packing
     subsumes the union), OR the overflows, then tombstone-mask."""
-    ids, ovf = main_ids, main_ovf
-    if snap.auto is not None:
-        res = match_batch(
-            snap.auto, word_ids, n_words, sys_mask, k=snap.k, m=m,
-            pack_ids=False, steps=snap.steps_for(word_ids.shape[1]),
-            slots=2, take=1)
-        ids = jnp.concatenate([ids, res.ids], axis=1)
-        ovf = ovf | res.overflow
-    if snap.mask is not None:
-        ids = _mask_ids(ids, snap.mask)
-    return ids, ovf
+    res = match_batch(
+        snap.auto, word_ids, n_words, sys_mask, k=snap.k, m=m,
+        pack_ids=False, steps=snap.steps_for(word_ids.shape[1]),
+        slots=2, take=1)
+    ids = jnp.concatenate([main_ids, res.ids], axis=1)
+    return _mask_ids(ids, snap.mask), main_ovf | res.overflow
 
 
 def probe_packed(auto, mask, word_ids, n_words, sys_mask, main_ids,
@@ -343,16 +345,11 @@ def probe_packed(auto, mask, word_ids, n_words, sys_mask, main_ids,
     the match-cache miss walk: union into the fixed ``[B, m]`` row
     shape cache entries carry, then tombstone-mask. Traced inside the
     walk's program (``ops/match_cache.walk_insert``), so it takes a
-    :class:`DeltaSnapshot`'s device half (``auto``, ``mask``; either
-    may be None) and its host half as statics (``k``, and ``steps`` =
-    ``steps_for`` the batch's depth)."""
-    ids, ovf = main_ids, main_ovf
-    if auto is not None:
-        res = match_batch(
-            auto, word_ids, n_words, sys_mask, k=k, m=m,
-            pack_ids=True, steps=steps, slots=2, take=1)
-        ids, u_ovf = _union_packed(ids, res.ids, m=m)
-        ovf = ovf | res.overflow | u_ovf
-    if mask is not None:
-        ids = _mask_ids(ids, mask)
-    return ids, ovf
+    :class:`DeltaSnapshot`'s device half (``auto``, ``mask``) and its
+    host half as statics (``k``, and ``steps`` = ``steps_for`` the
+    batch's depth)."""
+    res = match_batch(
+        auto, word_ids, n_words, sys_mask, k=k, m=m,
+        pack_ids=True, steps=steps, slots=2, take=1)
+    ids, u_ovf = _union_packed(main_ids, res.ids, m=m)
+    return _mask_ids(ids, mask), main_ovf | res.overflow | u_ovf

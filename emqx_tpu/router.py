@@ -38,7 +38,8 @@ import numpy as np
 from emqx_tpu import faults
 from emqx_tpu import topic as T
 from emqx_tpu.oracle import TrieOracle
-from emqx_tpu.ops.csr import Automaton, build_automaton, device_view
+from emqx_tpu.ops.csr import (Automaton, build_automaton, capacity_for,
+                              device_view)
 from emqx_tpu.ops.match import depth_bucket, match_batch
 from emqx_tpu.ops.patch import AutoPatcher, PatchOverflow
 from emqx_tpu.ops.tokenize import WordTable, encode_batch
@@ -390,8 +391,10 @@ class Router:
         self._delta_probes = 0
         self._delta_filters = 0
         self._delta_merges = 0
+        self._delta_tombstones = 0
+        self._delta_retracts = 0
         self._rebuild_stall_ms = 0.0
-        self._auto_drained = (0, 0, 0, 0, 0, 0)
+        self._auto_drained = (0, 0, 0, 0, 0, 0, 0, 0)
 
     # -- engine dispatch (native C++ or pure Python) ----------------------
 
@@ -417,6 +420,10 @@ class Router:
 
             self._delta = DeltaAutomaton(self._intern_fn(),
                                          self.config.use_device)
+            # room for the bound's filters at four levels each: the
+            # side tables keep one capacity up to a compaction
+            self._delta.floor_states = capacity_for(
+                4 * self.config.delta_max_filters)
         return self._delta
 
     def _t_insert(self, filter_: str, fid: int) -> None:
@@ -603,6 +610,10 @@ class Router:
 
     def _delta_delete_locked(self, filter_: str, fid: int) -> None:
         d = self._ensure_delta()
+        if filter_ in d.fids:
+            self._delta_retracts += 1
+        else:
+            self._delta_tombstones += 1
         with self._wt_lock:  # retracting a pending add walks words
             d.delete(filter_, fid)
         self._map_set(fid, None)
@@ -1808,9 +1819,8 @@ class Router:
                 # (ops/delta.py)
                 self._delta_probes += 1
                 delta = (dsnap.auto, dsnap.mask)
-                if dsnap.auto is not None:
-                    dkw = {"dk": dsnap.k,
-                           "dsteps": dsnap.steps_for(lay.levels)}
+                dkw = {"dk": dsnap.k,
+                       "dsteps": dsnap.steps_for(lay.levels)}
             miss_vals = cache.insert_through(
                 probe, lambda table: walk_insert(
                     auto, delta, table, buf, lay=lay._replace(hit=0),
@@ -1961,7 +1971,8 @@ class Router:
         comp = self._compaction
         cur = (self._delta_probes, self._delta_filters,
                self._delta_merges, int(self._rebuild_stall_ms),
-               comp["fused_edges"], comp["chains"])
+               comp["fused_edges"], comp["chains"],
+               self._delta_tombstones, self._delta_retracts)
         prev = self._auto_drained
         self._auto_drained = cur
         return {
@@ -1973,6 +1984,8 @@ class Router:
             # a rebuild may shrink them)
             "compaction.fused_edges": cur[4] - prev[4],
             "compaction.chains": cur[5] - prev[5],
+            "delta.tombstones": cur[6] - prev[6],
+            "delta.retracts": cur[7] - prev[7],
         }
 
     def walk_info(self) -> Dict[str, object]:
@@ -2085,14 +2098,21 @@ class Router:
         """One batch of the one-chip match dispatch
         (metrics.DISPATCH_METRICS): its unique topics, and those that
         walk the automaton (the rest are the match cache's gather),
-        before padding; stamped per batch, so current at any instant,
-        where ``cache.match.hit`` / ``.miss`` wait for the stats
-        flush."""
+        before padding; stamped per batch, so current at any instant.
+        ``cache.match.*`` and ``automaton.*`` are brought up to the
+        batch before here as well (with telemetry off they wait for
+        the stats flush)."""
         m = self._live_metrics()
         if m is not None:
             m.inc("dispatch.batches")
             m.inc("dispatch.topics", topics)
             m.inc("dispatch.walk.topics", walked)
+            # the families the stats flush folds once a sys_interval
+            # (a minute): folded here too, so that a window cut at any
+            # two instants reads them to a batch (a drain hands out
+            # what moved since the last one, whoever asks)
+            m.fold_cache_stats(self.drain_cache_stats())
+            m.fold_automaton_stats(self.drain_automaton_stats())
 
     def count_fused(self) -> None:
         """The broker's: the batch :meth:`_count_dispatch` just

@@ -39,6 +39,11 @@ summed into ``stages``). Busy stages first, then the waits:
   ``cache_gather``   match-cache probe + HBM-row merge dispatch
                      (cache-split batches only; carved out of the
                      tail of the ``match`` interval).
+  ``fan_sync``       the fan-out tables brought up to the membership
+                     changes since the last batch
+                     (``FanoutManager.state``; one chip): one compare
+                     where none changed, a patch of the changed rows,
+                     or a build over every filter. On the loop.
   ``pack``           fan-out + sparse-compaction kernel dispatch.
   ``fetch``          the ONE coalesced device→host transfer — the
                      only synchronizing stage, so queued device
@@ -185,8 +190,9 @@ _observe_lock = threading.Lock()
 #: via :meth:`Telemetry.observe_stage` — it shares the histogram
 #: surfaces so a churn-driven rebuild storm shows up next to the
 #: publish latencies it would otherwise silently explain
-STAGES = ("ingress_wait", "prepare", "match", "cache_gather", "pack",
-          "executor_wait", "fetch", "dispatch_plan", "serialize",
+STAGES = ("ingress_wait", "prepare", "match", "cache_gather",
+          "fan_sync", "pack", "executor_wait", "fetch",
+          "dispatch_plan", "serialize",
           "chain_wait", "loop_wait", "host_fallback", "dispatch",
           "tail_yield", "xloop", "gc_inside", "rebuild",
           "unattributed", "end_to_end")

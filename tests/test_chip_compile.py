@@ -192,19 +192,29 @@ def test_match_cache_and_delta_compile(one_chip):
     _mask_ids.lower(i32(_B, _M), b1(_FCAP)).compile()
 
 
-@pytest.mark.parametrize("delta", ["no_delta", "adds", "tombstones",
-                                   "adds_and_tombstones"])
+@pytest.mark.parametrize("delta", ["no_delta", "live", "live_with_plus",
+                                   "live_grown"])
 def test_fused_chip_dispatch_compiles(one_chip, delta):
     """The three programs a served one-chip batch enqueues
     (``Router._match_dispatch_cached``, ``Broker._begin_device``), at
     the cells' widths and the ingress's largest batch: the walk with
     the cache insert (a live delta snapshot's two-probe folded in),
     the merge with the pad mask, and the packers with the fetch's
-    bundle; the table is an argument the insert does not donate."""
+    bundle; the table is an argument the insert does not donate.
+
+    A live delta is one variant of the walk whatever it holds
+    (``ops/delta.py``: side tables at the capacity
+    ``delta_max_filters`` gives, the mask always there, the side
+    walk's steps from the batch's depth); what is left to vary is a
+    pending '+' (the side walk's lanes) and tables grown past their
+    floor."""
+    from emqx_tpu.ops.csr import buckets_for_capacity, capacity_for
+    from emqx_tpu.ops.delta import DeltaSnapshot
     from emqx_tpu.ops.fanout import FanoutTable
     from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
                                           _mesh_merge_jit, walk_insert)
     from emqx_tpu.ops.pack import pack_chip
+    from emqx_tpu.router import MatcherConfig
 
     sh = one_chip
     L, HB = 5, 256
@@ -212,24 +222,27 @@ def test_fused_chip_dispatch_compiles(one_chip, delta):
     assert BatchLayout.need(16, _B, 8) <= lay.size
     table = _s((65536, _M + 1), jnp.int32, sh)
     buf = _s((lay.size,), jnp.int32, sh)
-    side = None
+    side, dkw = None, {"dk": 0, "dsteps": 0}
     if delta != "no_delta":
-        # delta_max_filters = 4,096 pending adds: a small narrow view
+        # the capacity Router._ensure_delta gives the side tables
+        states = capacity_for(4 * MatcherConfig().delta_max_filters)
+        if delta == "live_grown":
+            states *= 2
         side_auto = Automaton(
             row_ptr=None, edge_word=None, edge_child=None,
             plus_child=None, hash_filter=None, end_filter=None,
             n_states=0, n_edges=0,
-            wt=_s((1 << 13, NARROW_SLOTS * NARROW_SLOT), jnp.int32, sh),
+            wt=_s((buckets_for_capacity(states, NARROW_SLOTS),
+                   NARROW_SLOTS * NARROW_SLOT), jnp.int32, sh),
             wt_seed=_s((1,), jnp.uint32, sh),
-            node2=_s((1 << 14, 4), jnp.int32, sh))
-        side = (side_auto if "adds" in delta else None,
-                _s((_FCAP,), jnp.bool_, sh) if "tombstones" in delta
-                else None)
+            node2=_s((states, 4), jnp.int32, sh))
+        side = (side_auto, _s((_FCAP,), jnp.bool_, sh))
+        dkw = {"dk": _K if delta == "live_with_plus" else 1,
+               "dsteps": DeltaSnapshot.steps_for(L)}
     walk = walk_insert.lower(
         _auto_shapes(sh, False), side, table, buf,
         lay=lay._replace(hit=0), k=_K, m=_M, steps=L + 1,
-        slots=NARROW_SLOTS, take=1, dk=_K if "adds" in delta else 0,
-        dsteps=L + 1 if "adds" in delta else 0).compile()
+        slots=NARROW_SLOTS, take=1, **dkw).compile()
     ma = walk.memory_analysis()
     # the new table beside the old: not donated (a probe holds it)
     assert ma.output_size_in_bytes >= 65536 * (_M + 1) * 4

@@ -234,8 +234,12 @@ def test_fused_equals_the_calls_apart_bit_for_bit(case):
     delta = b.router._snapshot_pair()[1]
     assert (delta is not None) == (change is not None)
     if delta is not None:
-        assert (delta.auto is not None) == (change != _tombstones_only)
-        assert (delta.mask is not None) == (change != _adds_only)
+        # a live delta is one shape to the walk's program: both halves
+        # are there, and hold something or nothing
+        assert delta.auto is not None and delta.mask is not None
+        assert (delta.n_pending > 0) == (change != _tombstones_only)
+        assert bool(np.asarray(delta.mask).any()) == \
+            (change != _adds_only)
     want_delivered = [_want(b, t) if kw.get("local", True) else 0
                       for t in batch]
     for again in (False, True):
@@ -474,9 +478,12 @@ def _json(*path):
 def test_fused_batch_share_file_equals_its_entry_and_its_base(name):
     spec = _json("BENCHMARK.json")
     data = _json("benchmark", "layer_metrics", name + ".json")
-    # appended behind everything the benchmark had, in this order; the
-    # mesh cell's list is pinned and gets none
-    assert [m["name"] for m in spec["per_layer"][-4:]] == list(FUSED)
+    # appended behind everything the benchmark had then, in this order
+    # (what later PRs appended follows them); the mesh cell's list is
+    # pinned and gets none
+    names = [m["name"] for m in spec["per_layer"]]
+    at = names.index("fused_batch_share")
+    assert names[at:at + 4] == list(FUSED)
     entry = next(m for m in spec["per_layer"] if m["name"] == name)
     assert entry == {
         "name": name, "unit": "batches/batch", "better": "higher",

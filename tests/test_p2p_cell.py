@@ -137,12 +137,13 @@ def test_the_cells_per_layer_list():
     assert {m["name"] for m in METRICS} == set(TWINS) | set(OWN)
     # appended in one stretch after everything the benchmark had; no
     # accepted metric's list names the new cell
-    # (PR 38's one behind PR 37's, at the list's end)
+    # (PR 38's one behind PR 37's; what later PRs appended follows)
     names = [m["name"] for m in SPEC["per_layer"]]
     at = names.index("plan_resolve_share.uniform") + 1
     assert SPEC["per_layer"][at:at + len(METRICS) - 1] == METRICS[:-1]
-    assert METRICS[-1] == SPEC["per_layer"][-1]
-    assert METRICS[-1]["name"] == "fused_batch_share.p2p"
+    assert METRICS[-1] == SPEC["per_layer"][
+        names.index("fused_batch_share.p2p")]
+    assert names.index("fused_batch_share.p2p") > at
     assert all(CELL not in m["workloads"] for m in SPEC["per_layer"]
                if m not in METRICS)
 
