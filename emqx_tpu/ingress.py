@@ -17,20 +17,41 @@ sockets — along with everything else publish_fetch hangs off that
 thread: the dispatch-plan grouping pass and the egress
 pre-serialization of wire images/templates (docs/DISPATCH.md), so
 the loop-side tail is little more than buffer writes. Up to
-``max_inflight`` batches overlap their transfers —
-device round-trip latency is hidden behind the next batch's
-accumulation instead of serializing the whole node (the classic
-accelerator-serving double-buffering). Delivery stays ordered:
-batch N+1's delivery tail awaits batch N's, so per-publisher
-in-order semantics hold across batch boundaries.
+``max_inflight`` batches may be in the pipeline at once. What that
+depth is for: at ``batch_size`` pending (a flood) the next batch's
+``publish_begin`` runs on the loop while the executor fetches the
+last one's, so neither thread waits for the other. What it is not
+for: hiding the device's round trip behind ticks of a few messages.
+The loop has one thread; every batch begun costs it the same
+milliseconds of dispatch whatever the batch holds, and a landed
+batch's tail waits behind the begins of the batches opened beside it
+(PERF.md §6, PR 41). Delivery stays ordered: batch N+1's delivery
+tail awaits batch N's, so per-publisher in-order semantics hold
+across batch boundaries.
 
 Flush policy: a batch flushes when it reaches ``batch_size``, else on
 the next event-loop iteration (``call_soon`` — "everything that
 arrived this tick"), or after ``linger_ms`` when configured (trades
-latency for bigger device batches under light load). When all
-``max_inflight`` slots are busy, arrivals keep accumulating and flush
-as a bigger batch the moment a slot frees — backpressure becomes
-batch growth, exactly the regime the device prefers.
+latency for bigger device batches under light load) — **unless a
+batch stands on the device path** (between its ``publish_begin`` and
+the return of its fetch, ``_on_path``): a flush short of
+``batch_size`` is then held, and what it would have taken leaves with
+the flush that the landed batch's completion schedules, as one batch
+with whatever arrived meanwhile. The release is the *completion*
+(``_complete``'s ``finally``), not the fetch's return: the landed
+batch's wait for its predecessor in the ordered chain, its delivery
+tail and, on a multi-loop node, its cross-loop join (bounded by
+``Broker.XLOOP_JOIN_TIMEOUT``) all come first — nothing could be
+acked ahead of that batch anyway. A held flush waits for nothing but
+that or the size trigger: no timer, and with the path free at every
+tick the policy is the tick's. The size trigger is ``batch_size``,
+and beside an occupied path also the high-water mark where critical
+overload has divided it under ``batch_size`` (``_full``): the
+accumulator cannot pass the mark, and the rule must not pin an
+overloaded node's pipeline at depth 1. When all ``max_inflight``
+slots are busy, arrivals keep accumulating and flush as a bigger
+batch the moment a slot frees — backpressure becomes batch growth,
+exactly the regime the device prefers.
 
 Callers without a running event loop (sync drivers, unit tests that
 poke the channel directly) fall back to the synchronous path:
@@ -122,9 +143,10 @@ class IngressBatcher:
         self._t_done = 0.0
         self._handle = None
         self._inflight = 0
-        # batches on the DEVICE PATH (telemetry on only): enqueued by
-        # publish_begin, their publish_fetch not yet back on the loop.
-        # With ``_inflight`` and ``_pending`` it is what the selector's
+        # batches on the DEVICE PATH: enqueued by publish_begin, their
+        # publish_fetch not yet back on the loop. While it is not 0 a
+        # flush short of ``batch_size`` is held (``_flush``). With
+        # ``_inflight`` and ``_pending`` it is also what the selector's
         # shadow (monitors.SysMon) reads to say what the loop waits
         # for; all three change on the home loop (a peer loop's append
         # wakes it), so the shadow takes no lock
@@ -221,7 +243,7 @@ class IngressBatcher:
 
     def _appended(self, loop, first: bool) -> None:
         """After an append in single-loop mode (this thread IS the
-        home loop): flush at the ``batch_size`` boundary, else arm
+        home loop): flush at the size trigger (``_full``), else arm
         the tick's flush behind the accumulator's ``first`` message."""
         n = len(self._pending)
         if n > self.max_queue:
@@ -230,7 +252,7 @@ class IngressBatcher:
             tel = self.broker.telemetry
             if tel is not None and tel.enabled:
                 self._t_first = time.perf_counter()
-        if n >= self.batch_size:
+        if self._full(n):
             self._flush()  # the direct flush is the legacy fast path
         elif first:
             if self.linger_ms > 0:
@@ -298,7 +320,7 @@ class IngressBatcher:
                     self._t_first = time.perf_counter()
         home = self._home or loop
         if loop is home:
-            if n >= self.batch_size:
+            if self._full(n):
                 # lint: ok-CD101 guarded by `loop is home`: this
                 # submit is already running on the home loop
                 self._flush()
@@ -308,7 +330,7 @@ class IngressBatcher:
                         self.linger_ms / 1000.0, self._flush)
                 else:
                     self._handle = home.call_soon(self._flush)
-        elif n == 1 or n >= self.batch_size:
+        elif n == 1 or self._full(n):
             try:
                 home.call_soon_threadsafe(self._remote_kick)
             except RuntimeError:
@@ -323,7 +345,7 @@ class IngressBatcher:
         this tick"), and a lingering one arms the timer once."""
         if not self._pending:
             return
-        if len(self._pending) >= self.batch_size:
+        if self._full(len(self._pending)):
             self._flush()
             return
         if self._handle is not None:
@@ -377,10 +399,27 @@ class IngressBatcher:
         under, while the ``ingress.saturate`` fault reads "full"."""
         if faults.enabled and faults.fire("ingress.saturate"):
             return 0
+        return self._hiwater()
+
+    def _hiwater(self) -> int:
+        """The mark without the fault's say: what the accumulator can
+        reach before the readers park."""
         hw = self.queue_hiwater
         if self._pressure_div > 1:
             hw = max(1, hw // self._pressure_div)
         return hw
+
+    def _full(self, n: int) -> bool:
+        """``n`` pending is a batch's worth, the size trigger:
+        ``batch_size``; beside an occupied device path also the
+        high-water mark where that lies under ``batch_size`` (critical
+        overload divides it, ``set_pressure``). The accumulator then
+        stands at the mark with the readers parked and cannot reach
+        ``batch_size``: a flush held for it (``_flush``) would pin the
+        pipeline's depth at 1 just when the node is overloaded. With
+        the path free the tick's flush takes what there is, as ever."""
+        return n >= self.batch_size or (
+            self._on_path > 0 and n >= self._hiwater())
 
     def backlogged(self) -> bool:
         """Accumulator at/over the high-water mark — connections
@@ -546,6 +585,18 @@ class IngressBatcher:
         # a capped take can leave a backlog: keep flushing chunks
         # while pipeline slots are free
         while self._pending and self._inflight < self.max_inflight:
+            if self._on_path and not self._full(len(self._pending)):
+                # a batch stands on the device path and this is a
+                # tick's handful: it joins what arrives until the
+                # landed batch's completion flushes (``_complete``'s
+                # ``finally``) or the size trigger fires. Opening a
+                # slot for it would cost the loop one more
+                # ``publish_begin`` and the landed batch a wait
+                # behind it
+                tel = self.broker.telemetry
+                if tel is not None and tel.enabled:
+                    self.broker.metrics.inc("ingress.flush.held")
+                return
             pending = self._take_pending(cap=self.batch_cap)
             # while earlier batches are in flight, a host-path batch
             # must not route (and no batch may resolve) ahead of them
@@ -585,8 +636,7 @@ class IngressBatcher:
                 self._resolve(pending, pb.results)
                 continue
             self._inflight += 1
-            if span is not None and not pb.done \
-                    and pb.host_topics is None:
+            if not pb.done and pb.host_topics is None:
                 self._on_path += 1  # down where its fetch returns
             loop = asyncio.get_running_loop()
             prev = self._chain if chain_active else None
@@ -605,12 +655,16 @@ class IngressBatcher:
                 # publish_begin returned → this task got the loop
                 sp.wait_mark("loop_wait")
             if not pb.done and pb.host_topics is None:
-                if faults.enabled and self._pool is not None \
-                        and faults.fire("executor.death"):
-                    # injected: the fetch pool dies out from under
-                    # this batch — the supervision below must respawn
-                    self._pool.shutdown(wait=False)
+                # everything from here to the fetch's return sits
+                # under the ``finally`` that takes the batch off the
+                # device path: a held flush waits for that
                 try:
+                    if faults.enabled and self._pool is not None \
+                            and faults.fire("executor.death"):
+                        # injected: the fetch pool dies out from under
+                        # this batch — the supervision below must
+                        # respawn
+                        self._pool.shutdown(wait=False)
                     await loop.run_in_executor(
                         self._executor(), self.broker.publish_fetch,
                         pb)
@@ -629,8 +683,7 @@ class IngressBatcher:
                         self._executor(), self.broker.publish_fetch,
                         pb)
                 finally:
-                    if sp is not None:
-                        self._on_path -= 1  # off the device path
+                    self._on_path -= 1  # off the device path
             if prev is not None:
                 # ordered delivery across batches; a failed
                 # predecessor already resolved its own futures
@@ -707,7 +760,9 @@ class IngressBatcher:
             if sp is not None:
                 self._t_done = time.perf_counter()
             if self._pending:
-                # a slot freed while messages accumulated — but
+                # a slot freed while messages accumulated, or a flush
+                # was held while this batch stood on the device path
+                # (``_flush``): this is the landing's release — but
                 # flushing HERE would run inside this batch's
                 # completion, BEFORE its futures resolve below: a
                 # host-path flush can resolve newer publishes'

@@ -449,9 +449,14 @@ CHANNEL_METRICS = [
 # park); ``park.ns`` = from each park to that resumption (a shed
 # publisher's park counts up to its time-out). ns ÷ parks is what one
 # park costs a publisher; parks ÷ ``messages.received`` how often
-# traffic meets it
+# traffic meets it. ``flush.held`` (IngressBatcher._flush, gated
+# alike) = flushes short of ``batch_size`` that found a batch on the
+# device path and began nothing: what they held leaves with the flush
+# that batch's completion schedules. held ÷ ``dispatch.batches`` is
+# how often a tick met an occupied path
 INGRESS_METRICS = [
     "ingress.parks", "ingress.park.ns", "ingress.wakes",
+    "ingress.flush.held",
 ]
 
 # the fan-out tables' syncs that changed them

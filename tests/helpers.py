@@ -1,7 +1,9 @@
 """Shared live-broker fixtures for the integration-tier suites."""
 
+import asyncio
 import contextlib
 import sys
+import threading
 
 import jax
 from jax._src import dispatch as jax_dispatch
@@ -74,6 +76,63 @@ async def device_node(name: str, **kw) -> Node:
                 matcher=MatcherConfig(device_min_filters=0), **kw)
     await node.start()
     return node
+
+
+async def until(cond, what="condition never held"):
+    """Poll ``cond`` on the running loop, up to ~4 s."""
+    for _ in range(2000):
+        if cond():
+            return
+        await asyncio.sleep(0.002)
+    raise AssertionError(what)
+
+
+class PathGate:
+    """Keeps batches on the device path as long as a test wants:
+    every device fetch of ``node`` stands on its executor thread until
+    ``land()``. Keeps the topics of every ``publish_begin`` in order
+    (``$SYS`` aside), and counts from outside the flushes that began
+    nothing with a slot free and messages pending (``held``)."""
+
+    def __init__(self, node):
+        self.node = node
+        self.ing = ing = node.broker.ingress
+        self.gate = threading.Event()
+        self.began = []
+        self.held = 0
+        b = node.broker
+        fetch, begin, flush = b._fetch_device, b.publish_begin, ing._flush
+
+        def gated(pb):
+            assert self.gate.wait(30)
+            fetch(pb)
+
+        def record(msgs, *a, **kw):
+            topics = [m.topic for m in msgs
+                      if not m.topic.startswith("$SYS/")]
+            if topics:
+                self.began.append(topics)
+            return begin(msgs, *a, **kw)
+
+        def watched():
+            before = ing.flushes
+            flush()
+            if ing.flushes == before and ing._pending \
+                    and ing._inflight < ing.max_inflight:
+                self.held += 1
+
+        b._fetch_device, b.publish_begin, ing._flush = \
+            gated, record, watched
+
+    def land(self):
+        self.gate.set()
+
+    def counted(self):
+        return self.node.metrics.val("ingress.flush.held")
+
+    async def on_the_path(self, n=1):
+        await until(lambda: self.ing._on_path == n,
+                    f"never {n} on the device path")
 
 
 class Wire:

@@ -160,9 +160,12 @@ def test_nothing_the_benchmark_had_names_the_new_cell():
     assert {m["name"] for m in METRICS} == set(TWINS) | set(OWN)
     assert len(TWINS) == 23 and len(METRICS) == 31
     # appended in one stretch behind everything the benchmark had
-    assert SPEC["per_layer"][-len(METRICS):] == METRICS
+    # (what later PRs appended follows, and names other cells)
+    at = SPEC["per_layer"].index(METRICS[0])
+    assert SPEC["per_layer"][at:at + len(METRICS)] == METRICS
+    assert SPEC["per_layer"][at - 1]["name"] == "fused_batch_share.p2p"
     assert all(CELL not in m.get("workloads", [])
-               for m in SPEC["per_layer"][:-len(METRICS)])
+               for m in SPEC["per_layer"] if m not in METRICS)
     assert all(CELL not in m.get("workloads", [])
                for m in SPEC["end_to_end"])
 

@@ -21,7 +21,7 @@ from emqx_tpu.broker import DispatchConfig
 from emqx_tpu.mqtt import constants as C
 from emqx_tpu.router import MatcherConfig
 
-from helpers import broker_node, node_port
+from helpers import PathGate, broker_node, node_port, until
 from mqtt_client import TestClient
 
 #: metric keys whose deltas are timing-dependent (wakeup coalescing,
@@ -173,6 +173,47 @@ async def test_onloop_stays_zero_for_eligible_traffic_across_ring():
         assert node.metrics.val("delivery.serialize.onloop") == 0
         assert node.metrics.val("delivery.xloop.deliveries") > 0
         for cli in subs + [pub]:
+            await cli.close()
+
+
+async def test_a_held_flush_is_released_on_a_multi_loop_node():
+    """ISSUE 41 across the ring: a peer loop's submits that find a
+    batch on the device path are held on the home loop (the kick's
+    flush begins nothing), leave as one batch when that batch lands,
+    and are delivered and acknowledged in order."""
+    async with broker_node(
+            loops=2,
+            matcher=MatcherConfig(device_min_filters=0)) as node:
+        port = node_port(node)
+        sub, pub = TestClient("hs"), TestClient("hp")
+        await sub.connect(port=port)          # conn 1 -> loop 0 (home)
+        await pub.connect(port=port)          # conn 2 -> loop 1 (peer)
+        assert node.listeners[0].loop_connections() == [1, 1]
+        await sub.subscribe("h/+", qos=1)
+        await pub.publish("h/warm", qos=1)    # compiles, makes the pool
+        assert (await sub.recv(timeout=30.0)).topic == "h/warm"
+        p, ing = PathGate(node), node.ingress
+        try:
+            await pub.publish("h/0", qos=0)
+            await p.on_the_path()
+            await pub.publish("h/1", qos=0)
+            await pub.publish("h/2", qos=0)
+            ack = asyncio.get_running_loop().create_task(
+                pub.publish("h/3", qos=1, timeout=30.0))
+            await until(lambda: len(ing._pending) == 3)
+            await asyncio.sleep(0.05)
+            # the peer loop's kick reached the home loop and was held
+            assert p.began == [["h/0"]] and not ack.done()
+            assert ing._inflight == 1
+            assert p.held == p.counted() >= 1
+        finally:
+            p.land()
+        await asyncio.wait_for(ack, 30.0)
+        got = [(await sub.recv(timeout=5.0)).topic for _ in range(4)]
+        assert got == ["h/0", "h/1", "h/2", "h/3"]
+        assert p.began == [["h/0"], ["h/1", "h/2", "h/3"]]
+        await until(lambda: ing._on_path == 0 and ing._inflight == 0)
+        for cli in (sub, pub):
             await cli.close()
 
 

@@ -3,11 +3,16 @@ call, with QoS acks deferred to the batch flush (SURVEY §2.2 row 1;
 accumulator semantics after src/emqx_batch.erl:1-91)."""
 
 import asyncio
+import contextlib
 
+import pytest
+
+from emqx_tpu import faults
 from emqx_tpu.broker import Broker
 from emqx_tpu.ingress import IngressBatcher
 from emqx_tpu.node import Node
 from emqx_tpu.types import Message
+from helpers import PathGate, device_node
 from mqtt_client import TestClient
 
 
@@ -362,3 +367,247 @@ async def test_flush_during_completion_cannot_reorder_or_double_resolve():
     assert isinstance(fb.exception(), RuntimeError)
     # A's future resolved before B's, and each exactly once
     assert order == ["A", "B"]
+
+
+# -- a tick's flush waits for the landing (ISSUE 41) ------------------
+#
+# While a batch stands on the device path (between its publish_begin
+# and the return of its fetch, ``_on_path``), a flush short of
+# ``batch_size`` begins nothing: what it holds leaves with the flush
+# that the landed batch's completion schedules. The size trigger
+# begins beside an occupied path as before, up to ``max_inflight``.
+
+
+async def _path_node(name, **kw):
+    """(node, subscriber, PathGate) with the dispatch compiled and the
+    fetch pool alive before the gate goes in."""
+    node = await device_node(name, **kw)
+    s = Rec()
+    node.broker.subscribe(s, "w/+")
+    assert await node.broker.ingress.submit(Message(topic="w/warm")) == 1
+    await node.broker.ingress.drain()
+    s.got.clear()
+    return node, s, PathGate(node)
+
+
+async def _ticks(n=3):
+    for _ in range(n):
+        await asyncio.sleep(0)
+
+
+async def test_a_tick_beside_an_occupied_path_waits_for_the_landing():
+    node, s, p = await _path_node("held@test", batch_size=16)
+    try:
+        ing = p.ing
+        acked = []
+
+        def submit(i):
+            f = ing.submit(Message(topic=f"w/{i}"))
+            f.add_done_callback(lambda _f, i=i: acked.append(i))
+            return f
+
+        futs = [submit(0)]
+        await p.on_the_path()
+        assert p.began == [["w/0"]]
+        for tick in range(3):           # three ticks of two arrivals
+            futs += [submit(1 + 2 * tick), submit(2 + 2 * tick)]
+            await _ticks()
+        # nothing was begun beside the occupied path, nothing acked
+        assert p.began == [["w/0"]] and ing._inflight == 1
+        assert len(ing._pending) == 6 and acked == []
+        # one flush was armed (behind the first arrival) and held
+        assert p.held == p.counted() == 1
+        p.land()
+        assert await asyncio.gather(*futs) == [1] * 7
+        await _ticks()
+        # the held arrivals left as ONE batch, in arrival order
+        assert p.began == [["w/0"], [f"w/{i}" for i in range(1, 7)]]
+        assert s.got == [f"w/{i}" for i in range(7)]
+        assert acked == list(range(7))
+        assert ing._on_path == 0 and ing._inflight == 0
+        assert p.held == p.counted() == 1
+    finally:
+        await node.stop()
+
+
+async def test_batch_size_pending_begins_beside_an_occupied_path():
+    node, s, p = await _path_node("size@test", batch_size=4)
+    try:
+        ing = p.ing
+        assert ing.max_inflight == 4
+        futs = [ing.submit(Message(topic="w/0"))]
+        await p.on_the_path()
+        k = 1
+        for depth in (2, 3, 4):
+            # a tick's pair is held ...
+            futs += [ing.submit(Message(topic=f"w/{k + i}"))
+                     for i in range(2)]
+            await _ticks()
+            assert ing._on_path == depth - 1 and len(ing._pending) == 2
+            # ... and at batch_size pending the batch begins at once
+            futs += [ing.submit(Message(topic=f"w/{k + 2 + i}"))
+                     for i in range(2)]
+            assert ing._on_path == ing._inflight == depth
+            assert not ing._pending
+            assert p.began[-1] == [f"w/{k + i}" for i in range(4)]
+            k += 4
+        # every slot busy: batch_size pending stands, as ever
+        futs += [ing.submit(Message(topic=f"w/{k + i}"))
+                 for i in range(4)]
+        await _ticks()
+        assert ing._on_path == 4 and len(ing._pending) == 4
+        assert len(p.began) == 4
+        # held by the rule three times; the full pipeline is not its
+        assert p.held == p.counted() == 3
+        p.land()
+        assert await asyncio.gather(*futs) == [1] * len(futs)
+        assert s.got == [f"w/{i}" for i in range(k + 4)]
+        assert ing._on_path == 0 and ing._inflight == 0
+    finally:
+        await node.stop()
+
+
+def _fetch_raises(p):
+    fetch = p.node.broker.publish_fetch
+
+    def boom(pb):
+        p.node.broker.publish_fetch = fetch     # the first alone
+        assert p.gate.wait(30)
+        raise ValueError("boom")
+    p.node.broker.publish_fetch = boom
+    return contextlib.nullcontext()
+
+
+def _executor_dies(p):
+    return faults.injected("executor.death", times=1)
+
+
+def _breaker_turns_to_host(p):
+    return faults.injected("device.fetch", times=1)
+
+
+@pytest.mark.parametrize("how", [_fetch_raises, _executor_dies,
+                                 _breaker_turns_to_host])
+async def test_a_held_flush_is_released_whatever_the_landing(how):
+    """The landing that releases a held flush may be a fetch that
+    raised, one that met a dead executor and was run again, or one
+    the breaker turned to the host path."""
+    node, s, p = await _path_node(f"{how.__name__}@test", batch_size=16)
+    try:
+        ing = p.ing
+        with how(p):
+            first = ing.submit(Message(topic="w/0"))
+            await p.on_the_path()
+            held = [ing.submit(Message(topic=f"w/{i}"))
+                    for i in range(1, 4)]
+            await _ticks()
+            assert p.began == [["w/0"]] and len(ing._pending) == 3
+            p.land()
+            assert await asyncio.gather(*held) == [1, 1, 1]
+        if how is _fetch_raises:
+            assert isinstance(first.exception(), ValueError)
+            assert s.got == ["w/1", "w/2", "w/3"]
+        else:
+            assert await first == 1
+            assert s.got == ["w/0", "w/1", "w/2", "w/3"]
+        if how is _executor_dies:
+            assert node.metrics.val("overload.heal.executor") == 1
+        if how is _breaker_turns_to_host:
+            assert node.metrics.val("breaker.failures") == 1
+        assert p.began == [["w/0"], ["w/1", "w/2", "w/3"]]
+        assert p.held == p.counted() == 1
+        assert ing._on_path == 0 and ing._inflight == 0
+    finally:
+        await node.stop()
+
+
+async def test_at_critical_overload_the_mark_is_the_size_trigger():
+    """Under ``_pressure_div`` > 1 the mark lies under ``batch_size``
+    and the accumulator cannot pass it, so the mark is the size
+    trigger: a handful short of it is held, at the mark a batch begins
+    beside an occupied path, an overloaded node keeps its depth, and
+    the take a landing schedules grants the parked readers."""
+    node, s, p = await _path_node("parked@test", batch_size=16)
+    try:
+        ing = p.ing
+        ing.set_pressure(4)
+        assert ing._mark() == 4 < ing.batch_size
+        futs = [ing.submit(Message(topic="w/0"))]
+        await p.on_the_path()
+        k = 1
+        for depth in (2, 3, 4):
+            futs += [ing.submit(Message(topic=f"w/{k + i}"))
+                     for i in range(2)]
+            await _ticks()
+            assert ing._on_path == depth - 1 and len(ing._pending) == 2
+            futs += [ing.submit(Message(topic=f"w/{k + 2 + i}"))
+                     for i in range(2)]
+            assert ing._on_path == ing._inflight == depth
+            assert not ing._pending
+            assert p.began[-1] == [f"w/{k + i}" for i in range(4)]
+            k += 4
+        # every slot busy: the accumulator stands at the mark and a
+        # reader parks; neither is the rule's
+        futs += [ing.submit(Message(topic=f"w/{k + i}"))
+                 for i in range(4)]
+        await _ticks()
+        assert ing.backlogged() and len(ing._pending) == 4
+        assert len(p.began) == 4
+
+        async def reader():
+            assert await ing.admit(2) is True
+            return [ing.submit(Message(topic=f"w/{k + 4 + i}"))
+                    for i in range(2)]
+
+        r = asyncio.get_running_loop().create_task(reader())
+        await _ticks()
+        assert ing.waiting() == 1 and not r.done()
+        assert p.held == p.counted() == 3
+        p.land()
+        futs += await asyncio.wait_for(r, 10)
+        assert await asyncio.gather(*futs) == [1] * (k + 6)
+        assert s.got == [f"w/{i}" for i in range(k + 6)]
+        assert p.began[4] == [f"w/{k + i}" for i in range(4)]
+        assert ing.waiting() == 0 and ing._granted == 0
+        assert ing._on_path == 0 and ing._inflight == 0
+    finally:
+        await node.stop()
+
+
+async def test_the_rule_needs_no_telemetry():
+    """``_on_path`` moves with telemetry off, and the rule with it;
+    its counter is telemetry's and stays 0."""
+    from emqx_tpu.telemetry import TelemetryConfig
+
+    node, s, p = await _path_node(
+        "dark@test", batch_size=16,
+        telemetry=TelemetryConfig(enabled=False))
+    try:
+        ing = p.ing
+        futs = [ing.submit(Message(topic="w/0"))]
+        await p.on_the_path()
+        futs += [ing.submit(Message(topic=f"w/{i}")) for i in (1, 2)]
+        await _ticks()
+        assert ing._on_path == 1 and len(ing._pending) == 2
+        assert p.began == [["w/0"]] and p.held == 1
+        p.land()
+        assert await asyncio.gather(*futs) == [1] * 3
+        assert p.began == [["w/0"], ["w/1", "w/2"]]
+        assert s.got == ["w/0", "w/1", "w/2"]
+        assert ing._on_path == 0 and p.counted() == 0
+    finally:
+        await node.stop()
+
+
+async def test_a_free_path_flushes_every_tick():
+    """With no batch on the path the policy is the tick's, to the
+    batch: nothing is held and no message waits for a timer."""
+    node, s, p = await _path_node("free@test", batch_size=16)
+    try:
+        p.land()                        # fetches return at once
+        for i in range(4):
+            assert await p.ing.submit(Message(topic=f"w/{i}")) == 1
+        assert p.began == [[f"w/{i}"] for i in range(4)]
+        assert p.held == p.counted() == 0
+    finally:
+        await node.stop()
