@@ -223,6 +223,9 @@ class Broker:
         rcfg = self.router.config
         self.helper = FanoutManager(threshold=rcfg.fanout_threshold,
                                     use_device=rcfg.use_device)
+        # a compaction's swap keeps every filter's id: the fan-out
+        # tables go over to its epoch as they are (docs/DELTA.md)
+        self.router.on_swap = self.helper.carry
         # filter -> {subscriber: SubOpts}   (emqx_subscriber / emqx_suboption)
         self._subscribers: Dict[str, Dict[object, SubOpts]] = {}
         # subscriber -> {filter: SubOpts}   (emqx_subscription)
@@ -1137,6 +1140,7 @@ class Broker:
 
         router = self.router
         cfg = router.config
+        router.warm_delta()
         for shape, topics in warm_batches(
                 router.dispatch_shapes(max_topics), router.cache_slots()):
             t0 = time.monotonic()

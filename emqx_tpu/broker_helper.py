@@ -26,13 +26,15 @@ TPU-native redesign (SURVEY §2.2 "topic sharding → bitmap tiles"):
     This is the product wiring of the round-1 kernels: tables are
     built lazily against the **automaton's id-map snapshot**, so
     device match ids index them consistently even as filter ids are
-    recycled across automaton rebuilds. Within one automaton epoch a
-    membership change costs what it changes: the changed rows are
-    written behind the table's live entries and their ``row_pairs``
-    repointed, on the host mirror and by one small scatter program on
-    the device (docs/DELTA.md "Fan-out tables"); a new epoch, a row
-    that is or becomes a bitmap, or a table out of room rebuilds
-    whole.
+    recycled across automaton rebuilds. A membership change costs
+    what it changes: the changed rows are written behind the table's
+    live entries and their ``row_pairs`` repointed, on the host mirror
+    and by one small scatter program on the device (docs/DELTA.md
+    "Fan-out tables"). A compaction's swap hands the tables over to
+    its epoch (:meth:`FanoutManager.carry`: a swap keeps every
+    filter's id, and the new map says which freed ids were taken
+    again); any other new epoch, a row that is or becomes a bitmap,
+    or a table out of room rebuilds whole.
 
 Capacities grow in powers of two and never shrink, keeping device
 array shapes stable across rebuilds (no recompilation churn).
@@ -222,14 +224,23 @@ class FanoutManager:
         # what a patch works from (set by a whole build of the CSR
         # table, dropped with it): the host mirror of ``row_pairs``
         # and ``sub_ids`` with the first free entry position, the
-        # epoch's filter → id, how much of its id map has been read,
-        # and the filters whose membership changed since the tables
-        # were brought up to date
+        # epoch's filter → id, how much of its id map has been read
+        # (the ids appended to it, and its log of ids set in place),
+        # the filters whose membership changed since the tables were
+        # brought up to date, and the ids an earlier epoch's map had
+        # set in place when the tables were carried over
         self._mirror: Optional[tuple] = None
         self._tail = 0
         self._fid_of: Dict[str, int] = {}
         self._seen = 0
+        self._seen_reused = 0
         self._changed: Set[str] = set()
+        self._carried: Set[int] = set()
+        # the epoch the tables were carried over from and the map of
+        # the one they stand under: a batch matched just before the
+        # swap still asks with the old pair
+        self._behind: Optional[int] = None
+        self._map: Optional[Sequence[Optional[str]]] = None
         # entries a patch chunk carries: any CSR row fits in one
         self._patch_entries = max(2048, 2 * threshold)
         # publish-path telemetry (Node wires it next to the broker's):
@@ -240,6 +251,7 @@ class FanoutManager:
         self.patches = 0
         self.rebuilds = 0
         self.rows_patched = 0
+        self.carries = 0
         # capacity retention (pow2, never shrinks → stable jit shapes)
         self._caps: Dict[str, Optional[int]] = {
             "filter": None, "entry": None, "row": None, "nsub": 1}
@@ -323,6 +335,9 @@ class FanoutManager:
         self._mirror = None
         self._fid_of = {}
         self._changed = set()
+        self._carried = set()
+        self._seen_reused = 0
+        self._behind = self._map = None
 
     def _count_sync(self, kind: str, t0: float) -> None:
         """One sync that changed the tables (metrics.FANOUT_METRICS;
@@ -339,15 +354,23 @@ class FanoutManager:
         """Device tables consistent with the automaton snapshot
         ``(epoch, id_map)``; ``None`` when there are no local
         subscribers (device fan-out has nothing to do). Unchanged
-        membership costs one compare; a change inside the epoch costs
-        the rows it changed (:meth:`_patch`); a new epoch builds
-        whole."""
+        membership costs one compare; a change costs the rows it
+        changed (:meth:`_patch`), over a compaction's swap too
+        (:meth:`carry`); any other new epoch builds whole."""
         with self._lock:
             st = self._state
+            if st is not None and epoch == self._behind:
+                # a batch matched just before a swap: its ids are the
+                # new epoch's too, so it is served from the one table,
+                # brought up to date against the map that is live
+                epoch, id_map = st.epoch, self._map
             if st is not None and st.epoch == epoch:
                 if st.version == self._version \
                         and (self._mirror is None
-                             or self._seen == len(id_map)):
+                             or (self._seen == len(id_map)
+                                 and not self._carried
+                                 and self._seen_reused == len(
+                                     getattr(id_map, "reused", ())))):
                     return st
                 if self._mirror is not None and self.rows:
                     t0 = time.perf_counter()
@@ -407,6 +430,7 @@ class FanoutManager:
             self._tail = fan.n_entries
             self._fid_of = fid_of
             self._seen = n_filters
+            self._seen_reused = len(getattr(id_map, "reused", ()))
         if big:
             nsub = max(self._caps["nsub"], self.registry.capacity())
             bm = build_bitmaps(
@@ -445,31 +469,29 @@ class FanoutManager:
         n_map = len(id_map)
         if n_map > f_cap:
             return None
-        changed = self._changed
-        touched: Dict[int, Optional[str]] = {}  # id → filter it bears
-        for fid in range(self._seen, n_map):
-            f = id_map[fid]
-            if f is not None:
-                # the filter came (back) under a new id: the id it
-                # had, if any, bears nothing now
-                old = fid_of.get(f)
-                if old is not None and old != fid:
-                    touched[old] = None
-                fid_of[f] = fid
-                touched[fid] = f
-        for f in changed:
+        # the ids whose row may have changed: those appended to the
+        # map or set in place in it since (a freed id taken again),
+        # and those the changed filters bear or bore. What a touched
+        # id's row holds is then read from the map as it stands, so
+        # the order in which it was touched does not matter
+        reused = getattr(id_map, "reused", ())
+        touched = set(self._carried)
+        touched.update(range(self._seen, n_map))
+        touched.update(reused[self._seen_reused:])
+        for f in self._changed:
             fid = fid_of.get(f)
-            if fid is None:
-                continue  # not in this epoch's map: no row to keep
-            if id_map[fid] != f:
-                del fid_of[f]
-                touched[fid] = None
-            else:
-                touched[fid] = f
+            if fid is not None:
+                touched.add(fid)
         writes = []  # (id, sorted members or None)
         room = subs.shape[0] - 1 - self._tail
-        for fid, f in touched.items():
-            row = self.rows.get(f) if f is not None else None
+        for fid in sorted(touched):
+            f = id_map[fid]
+            row = None
+            if f is not None:
+                # the filter came (back) under this id: the id it
+                # had, if any, was touched when its route was dropped
+                fid_of[f] = fid
+                row = self.rows.get(f)
             if fid in st.big_fids or (row and len(row) > self.threshold):
                 return None
             if row:
@@ -479,8 +501,15 @@ class FanoutManager:
                 writes.append((fid, None))
         if room < 0:
             return None
+        for f in self._changed:
+            # a filter whose route was dropped bears no id any more
+            fid = fid_of.get(f)
+            if fid is not None and id_map[fid] != f:
+                del fid_of[f]
         self._seen = n_map
+        self._seen_reused = len(reused)
         self._changed = set()
+        self._carried = set()
         fan = st.fan
         dev_pairs, dev_subs = fan.row_pairs, fan.sub_ids
         k, e_cap = _PATCH_ROWS, self._patch_entries
@@ -518,6 +547,32 @@ class FanoutManager:
             st.bm, st.big_fids)
         self._state = st
         return st
+
+    def carry(self, epoch: int, id_map: Sequence[Optional[str]],
+              new_epoch: int,
+              new_map: Sequence[Optional[str]]) -> None:
+        """A compaction's swap (``Router.on_swap``, under the router's
+        lock on the compaction thread): the automaton epoch moves from
+        ``epoch``, whose map is ``id_map``, to ``new_epoch`` with
+        ``new_map`` (entry for entry the old one), and every filter
+        keeps its id; ids freed before the swap may be taken by later
+        route adds, which the new map's ``reused`` will say. The
+        tables stand as they are under the new epoch: what ``id_map``
+        had set in place and this manager had not read yet is kept for
+        the next :meth:`_patch`, which costs the rows that changed and
+        no other. Tables of another epoch, or none a patch can work
+        from, are left for :meth:`state` to build whole."""
+        with self._lock:
+            st = self._state
+            if st is None or st.epoch != epoch or self._mirror is None:
+                return
+            self._carried.update(
+                getattr(id_map, "reused", ())[self._seen_reused:])
+            self._seen_reused = 0
+            self._behind, self._map = epoch, new_map
+            self._state = FanoutState(new_epoch, st.version, st.fan,
+                                      st.bm, st.big_fids)
+            self.carries += 1
 
     def sharded_state(self, epoch: int,
                       id_map: Sequence[Optional[str]],

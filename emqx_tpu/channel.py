@@ -32,7 +32,8 @@ from emqx_tpu.acl_cache import AclCache
 from emqx_tpu.keepalive import Keepalive
 from emqx_tpu.limiter import TokenBucket
 from emqx_tpu.logger import set_metadata_clientid, set_metadata_peername
-from emqx_tpu.metrics import I_SESSION_CLOSE_NS, I_SESSION_OPEN_NS
+from emqx_tpu.metrics import (I_SESSION_CLOSE_NS, I_SESSION_OPEN_NS,
+                              I_SUBSCRIBE_NS, I_UNSUBSCRIBE_NS)
 from emqx_tpu.mountpoint import mount, replvar, unmount
 from emqx_tpu.mqtt import constants as C
 from emqx_tpu.mqtt import reason_codes as RC
@@ -813,7 +814,26 @@ class Channel:
 
     # SUBSCRIBE / UNSUBSCRIBE -------------------------------------------
 
+    def _timed_filters(self, idx: int, handle, pkt) -> List[Packet]:
+        """A SUBSCRIBE or UNSUBSCRIBE as a section of the loop's time
+        ledger (``loop.subscribe.*`` / ``loop.unsubscribe.*``,
+        metrics.LOOP_METRICS): exclusive nanoseconds, one call, and
+        the topic filters the packet carried."""
+        lc = self._loop_clock()
+        if lc is None:
+            return handle(pkt)
+        t0 = time.perf_counter()
+        n0 = lc.inner
+        try:
+            return handle(pkt)
+        finally:
+            lc.loop_leave(idx, t0, n0)
+            lc.metrics.add_at(idx + 2, len(pkt.topic_filters))
+
     def _in_subscribe(self, pkt: Subscribe) -> List[Packet]:
+        return self._timed_filters(I_SUBSCRIBE_NS, self._subscribe, pkt)
+
+    def _subscribe(self, pkt: Subscribe) -> List[Packet]:
         self.broker.metrics.inc("packets.subscribe.received")
         self.broker.metrics.inc("client.subscribe")
         tf = self.broker.hooks.run_fold(
@@ -901,6 +921,10 @@ class Channel:
         return mounted
 
     def _in_unsubscribe(self, pkt: Unsubscribe) -> List[Packet]:
+        return self._timed_filters(I_UNSUBSCRIBE_NS, self._unsubscribe,
+                                   pkt)
+
+    def _unsubscribe(self, pkt: Unsubscribe) -> List[Packet]:
         self.broker.metrics.inc("packets.unsubscribe.received")
         self.broker.metrics.inc("client.unsubscribe")
         tf = self.broker.hooks.run_fold(

@@ -113,6 +113,7 @@ def load(router, path: str, device: Optional[bool] = None) -> dict:
 
     from emqx_tpu.ops.csr import Automaton, device_view
     from emqx_tpu.ops.patch import AutoPatcher
+    from emqx_tpu.router import IdMap
 
     try:
         with np.load(path) as data:
@@ -234,7 +235,7 @@ def load(router, path: str, device: Optional[bool] = None) -> dict:
                                else AutoPatcher(host_auto, intern))
             router._install_walk_meta(host_auto)
             router._auto = auto
-            router._auto_map = list(router._id_to_filter)
+            router._auto_map = IdMap(router._id_to_filter)
             router._dirty = False
             router._published = (auto, router._auto_map,
                                  router._rebuilds,

@@ -255,6 +255,13 @@ class Ctl:
             lines.append(f"spans: {tel.spans_total}  slow: "
                          f"{tel.slow_total} (threshold "
                          f"{tel.config.slow_threshold_ms}ms)")
+            # a background compaction's `rebuild`, by stage
+            for s, st in tel.rebuild_stats().items():
+                if st["count"]:
+                    lines.append(f"{'rebuild.' + s:<18}{st['count']:>4}"
+                                 f"{st['p50_ms']:>10.3f}"
+                                 f"{st['p95_ms']:>10.3f}"
+                                 f"{st['p99_ms']:>10.3f}")
             return "\n".join(lines)
         if args[0] == "loop":
             return self._telemetry_loop(tel)
@@ -294,7 +301,8 @@ class Ctl:
                  for k in ("poll", "device", "clients")]
         gc_ns = sum(val(f"gc.ns.gen{g}") for g in range(3))
         lines = [row("wall", wall)]
-        for name in ("read", "flush", "stats", "select"):
+        for name in ("read", "flush", "stats", "subscribe",
+                     "unsubscribe", "select"):
             lines.append(row(name, val(f"loop.{name}.ns"),
                              val(f"loop.{name}.calls")))
         for k, ns in kinds:

@@ -167,6 +167,17 @@ AUTOMATON_METRICS = [
     # deltas (GAUGE_METRICS: a rebuild may shrink them); 0/0 means
     # the live tables walk narrow (no deep chains worth fusing)
     "automaton.compaction.fused_edges", "automaton.compaction.chains",
+    # a background compaction (router.Router._compact_offlock):
+    # `compaction.ns` = from its freeze to the end of its swap, on
+    # the compaction thread beside the loop (÷ `delta.merges`: what
+    # one merge takes, during which the next delta generation fills);
+    # `freeze.deferred` = route operations that arrived while a
+    # flatten held the trie frozen and went to the freeze log;
+    # `delta.grows` = flattens of the delta's side tables at a larger
+    # capacity than the one they are sized for (a new shape of the
+    # walk's program, first used on the loop: 0 is the design)
+    "automaton.compaction.ns", "automaton.freeze.deferred",
+    "automaton.delta.grows",
 ]
 
 # overload protection + self-healing (overload.py,
@@ -380,6 +391,17 @@ LOOP_METRICS = [
     # brought them
     "loop.session.open.ns", "loop.session.open.calls",
     "loop.session.close.ns", "loop.session.close.calls",
+    # a connected channel's SUBSCRIBE and UNSUBSCRIBE packets,
+    # exclusive like the rest (channel.Channel): from the decoded
+    # packet to its SUBACK / UNSUBACK handed to the connection (hooks,
+    # caps, ACL, the session, Broker.subscribe / unsubscribe with the
+    # route operation and the fan-out row it marks); `filters` = the
+    # topic filters the packets carried, so ns ÷ filters is what one
+    # subscription costs the loop
+    "loop.subscribe.ns", "loop.subscribe.calls",
+    "loop.subscribe.filters",
+    "loop.unsubscribe.ns", "loop.unsubscribe.calls",
+    "loop.unsubscribe.filters",
 ]
 
 # the device path's occupancy, from the publish spans' interval record
@@ -637,6 +659,8 @@ I_SELECT_CLIENTS_NS = _global._index["loop.select.clients.ns"]
 I_STATS_NS = _global._index["loop.stats.ns"]
 I_SESSION_OPEN_NS = _global._index["loop.session.open.ns"]
 I_SESSION_CLOSE_NS = _global._index["loop.session.close.ns"]
+I_SUBSCRIBE_NS = _global._index["loop.subscribe.ns"]
+I_UNSUBSCRIBE_NS = _global._index["loop.unsubscribe.ns"]
 I_PIPELINE_NS = _global._index["pipeline.device.ns"]
 
 

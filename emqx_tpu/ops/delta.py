@@ -123,6 +123,10 @@ class DeltaAutomaton:
         #: flatten on (the router sets it from ``delta_max_filters``);
         #: one capacity for the delta's life, doubled only past it
         self.floor_states = 1 << 14
+        #: flattens that made the side tables larger than the one
+        #: before (than the floor, the first): each is a new shape of
+        #: the walk's program (``automaton.delta.grows``)
+        self.grows = 0
         self._host_auto: Optional[Automaton] = None
         self._dev_auto: Optional[Automaton] = None
         self._patcher: Optional[AutoPatcher] = None
@@ -185,22 +189,26 @@ class DeltaAutomaton:
         self.tomb_filters.add(filter_)
         self._mask_dirty = True
 
-    def split_after(self, mark: int) -> "Optional[DeltaAutomaton]":
+    def split_after(self, mark: int) -> "DeltaAutomaton":
         """A fresh delta holding only the mutations after ``mark`` —
         everything before it is in the new main tables (the off-lock
         compaction flattened the trie they had already been applied
         to). Replays with live semantics, so an add+delete pair
         inside the window cancels and a delete of a pre-mark add
-        becomes a tombstone against the NEW tables."""
+        becomes a tombstone against the NEW tables. A generation that
+        holds nothing is a delta all the same: the walk's program
+        stays the one with a live delta over every swap, whatever
+        arrived during the flatten."""
         fresh = DeltaAutomaton(self.intern, self.use_device)
         fresh.floor_states = self.floor_states
+        # the side walk's lanes are a static of the walk's program:
+        # once a '+' was pending they stay, generation after generation
+        fresh.has_plus = self.has_plus
         for op, f, fid in self.log[mark:]:
             if op == "+":
                 fresh.add(f, fid)
             else:
                 fresh.delete(f, fid)
-        if not fresh.fids and not fresh.tombs:
-            return None
         return fresh
 
     def needs_compaction(self, max_filters: int, live: int) -> bool:
@@ -208,6 +216,18 @@ class DeltaAutomaton:
         dominating the live set — fold into the main tables."""
         return (len(self.fids) >= max_filters
                 or len(self.tombs) > max(1024, live))
+
+    def warm_apply(self) -> int:
+        """First-use the patch scatter at every rung of its chunk
+        ladder on the side tables' shape (chunks that write nothing),
+        so that a backlog of pending adds after a stall of the loop
+        first-uses no program; -> the rungs launched (call under the
+        router lock, after a :meth:`snapshot`)."""
+        if self._dev_auto is None or self._patcher is None:
+            return 0
+        from emqx_tpu.ops.patch import warm_chunks
+
+        return warm_chunks(self._dev_auto, self._patcher.sw)
 
     def invalidate_device(self) -> None:
         """Device-loss recovery (docs/ROBUSTNESS.md): the staged
@@ -250,8 +270,18 @@ class DeltaAutomaton:
                                skip_hash=True)
         host = finalize_automaton(base, force_mode="narrow",
                                   state_capacity=cap, n_buckets=nb)
+        was = self._host_auto
+        if host.node2.shape[0] > (self.floor_states if was is None
+                                  else was.node2.shape[0]) \
+                or (was is not None
+                    and host.wt.shape[0] > was.wt.shape[0]):
+            self.grows += 1
         self._host_auto = host
-        auto = device_view(host)
+        # the side walk's steps follow the batch's depth
+        # (DeltaSnapshot.steps_for), never the tables' own bound: it
+        # stays on the host, or its length (the deepest pending
+        # filter) would be a shape of the walk's program
+        auto = device_view(host)._replace(hops_for_level=None)
         if self.use_device:
             auto = jax.device_put(auto)
         self._dev_auto = auto

@@ -41,8 +41,7 @@ import jax
 import numpy as np
 
 from emqx_tpu import topic as T
-from emqx_tpu.ops.csr import (CW_PAD, NARROW_SLOT, WIDE_SLOT, Automaton,
-                              hash_mix)
+from emqx_tpu.ops.csr import CW_PAD, NARROW_SLOT, WIDE_SLOT, Automaton
 
 _OOB = np.int32(2**30)  # out-of-range pad index -> .set(mode="drop")
 _MAX_EVICT = 64
@@ -71,7 +70,7 @@ class AutoPatcher:
         self.hop = np.array(auto.v2_hop)
         self.depth = np.array(auto.v2_depth)
         self.hops_for_level = np.array(auto.hops_for_level)
-        self.seed = np.uint32(np.asarray(auto.wt_seed)[0])
+        self.seed = int(np.asarray(auto.wt_seed)[0])
         self.slots = int(auto.wt_slots)
         self.take = int(auto.wt_take)
         self.sw = WIDE_SLOT if self.take > 1 else NARROW_SLOT
@@ -106,11 +105,19 @@ class AutoPatcher:
     # -- host-mirror edge hash ops ----------------------------------------
 
     def _buckets(self, state: int, word: int) -> Tuple[int, int]:
-        with np.errstate(over="ignore"):
-            h1, h2 = hash_mix(np.array(state, np.int32),
-                              np.array(word, np.int32), self.seed)
-        mask = np.uint32(self.nb - 1)
-        return int(h1 & mask), int(h2 & mask)
+        """:func:`~emqx_tpu.ops.csr.hash_mix` for one edge key, in
+        Python's own integers (bit for bit the numpy form, which
+        costs ten times as much a scalar: tests/test_patch.py)."""
+        m = 0xFFFFFFFF
+        h = ((state & m) * 0x9E3779B9 + (word & m) * 0x85EBCA6B
+             + self.seed) & m
+        h ^= h >> 16
+        h = (h * 0x7FEB352D) & m
+        h ^= h >> 15
+        h2 = (h * 0x846CA68B) & m
+        h2 ^= h2 >> 16
+        mask = self.nb - 1
+        return h & mask, h2 & mask
 
     def _slot_view(self, b: int, s: int) -> np.ndarray:
         return self.wt[b, s * self.sw:(s + 1) * self.sw]
@@ -212,6 +219,8 @@ class AutoPatcher:
         """Keep the step bound ≥ hop+1 for every batch depth ≥ depth
         (monotone array; clamped at the uncompressed bound d+1)."""
         hl = self.hops_for_level
+        if depth < len(hl) and hl[depth] >= min(hop + 1, depth + 1):
+            return  # monotone: every deeper level holds the bound too
         if depth >= len(hl):
             # extension: past the old max depth the walk can always
             # fall back to one hop per extra level
@@ -470,6 +479,20 @@ class AutoPatcher:
 # every .at[].set chunk copy-on-writes the full table buffers, so
 # chunk count, not chunk size, is the cost that matters.
 _CHUNKS = (32768, 4096, 512)
+
+
+def warm_chunks(auto: Automaton, sw: int) -> int:
+    """Launch :func:`_apply_jit` once at every rung of the ladder on
+    ``auto``'s shapes with chunks that are all pad (nothing is
+    written, the result is dropped): a drain of any size then finds
+    its program made. -> the rungs launched."""
+    for n in _CHUNKS:
+        _apply_jit(auto, np.full((3, n), _OOB, np.int32),
+                   np.zeros((3, n), np.int32),
+                   np.full((n,), _OOB, np.int32),
+                   np.zeros((n,), np.int32),
+                   np.zeros((n, sw), np.int32))
+    return len(_CHUNKS)
 
 
 @jax.jit

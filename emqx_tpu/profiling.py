@@ -338,6 +338,14 @@ def register_ctl(ctl) -> None:
             return text or "(no samples)"
         raise ValueError(f"bad subcommand: loops {args[0]}")
 
+    def _rebuild_stages(tel) -> str:
+        """A background compaction by stage (telemetry.REBUILD_STAGES):
+        one line, empty while none has run."""
+        parts = [f"{s} {st['count']} x p50 {st['p50_ms']:.3f}ms "
+                 f"sum {st['sum_ms']:.3f}ms"
+                 for s, st in tel.rebuild_stats().items() if st["count"]]
+        return "\nrebuild stages: " + "; ".join(parts) if parts else ""
+
     def _profile(args):
         import jax
 
@@ -357,6 +365,7 @@ def register_ctl(ctl) -> None:
                         f"p50 {st['p50_ms']:.3f}ms "
                         f"p99 {st['p99_ms']:.3f}ms "
                         f"sum {st['sum_ms']:.3f}ms")
+                out += _rebuild_stages(tel)
             return out
         if args[0] == "loops":
             return _profile_loops(args[1:])
@@ -404,9 +413,14 @@ def register_ctl(ctl) -> None:
         if args[0] == "report":
             logdir = args[1] if len(args) > 1 else "/tmp/emqx_tpu_trace"
             try:
-                return render_report(report(logdir))
+                out = render_report(report(logdir))
             except (OSError, ValueError) as e:
                 return f"profile report failed: {e}"
+            tel = getattr(getattr(ctl, "node", None), "telemetry", None)
+            if tel is not None and tel.enabled:
+                # what an `emqx/rebuild` stretch of the trace was made of
+                out += _rebuild_stages(tel)
+            return out
         raise ValueError(f"bad subcommand: {args[0]}")
 
     ctl.register_command(

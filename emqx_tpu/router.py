@@ -62,6 +62,23 @@ class DispatchShape(NamedTuple):
     depth: int
 
 
+class IdMap(list):
+    """Filter id → filter as one automaton epoch publishes it: a list
+    that is appended and tombstoned in place while the epoch lasts
+    (what lock-free matchers rely on), and that says which of its ids
+    were given a filter in place: ``reused`` holds, in order, every
+    id below the map's length that went from ``None`` to a filter (an
+    id a flatten gave back, taken by a later route add). Who keeps
+    tables by filter id (``broker_helper.FanoutManager``) reads the
+    appended ids and this log, and so never walks the map."""
+
+    __slots__ = ("reused",)
+
+    def __init__(self, ids=()) -> None:
+        super().__init__(ids)
+        self.reused: List[int] = []
+
+
 @dataclass
 class MatcherConfig:
     max_levels: int = 16    # L — deeper topics fall back to the oracle
@@ -256,7 +273,7 @@ class Router:
         self._auto: Optional[Automaton] = None  # live device automaton
         # id→filter list the live automaton encodes: appended/tombstoned
         # in place by the patcher, REPLACED (new object) on rebuild
-        self._auto_map: List[Optional[str]] = []
+        self._auto_map: IdMap = IdMap()
         # (auto, map, epoch) snapshot: one-reference read for matchers
         # (attribute assignment is atomic — no lock on the match path)
         self._published: Optional[tuple] = None
@@ -304,6 +321,14 @@ class Router:
         self._compact_failures = 0
         self._compact_backoff_until = 0.0
         self.on_bg_error = None
+        # the hand-over of a compaction's swap: on_swap(old epoch,
+        # old id map, new epoch, new id map) runs under the lock on the
+        # compaction thread just before the swap, for whoever keeps tables by
+        # filter id (the broker's fan-out manager carries its tables
+        # over the epoch: a swap keeps every filter's id). If it
+        # raises, the swap is not made: the old tables and the old
+        # epoch stay live and the compaction counts as crashed
+        self.on_swap = None
         self._dummy_fan = None    # sharded publish_step filler fan
         # learned active-set boost: an overflow-storm batch (many
         # topics exceeding active_k) doubles the effective K (bounded)
@@ -394,7 +419,10 @@ class Router:
         self._delta_tombstones = 0
         self._delta_retracts = 0
         self._rebuild_stall_ms = 0.0
-        self._auto_drained = (0, 0, 0, 0, 0, 0, 0, 0)
+        self._compaction_ns = 0      # freeze to end of swap, off-lock
+        self._delta_grows = 0        # of delta generations retired
+        self._freeze_deferred = 0    # route ops a freeze log took
+        self._auto_drained = (0,) * 11
 
     # -- engine dispatch (native C++ or pure Python) ----------------------
 
@@ -494,6 +522,7 @@ class Router:
             return
         self._freeze = None
         self._rebuild_inflight = False
+        self._freeze_deferred += len(fz["log"])
         for op, f, fid in fz["log"]:
             if op == "+":
                 self._t_insert(f, fid)
@@ -622,6 +651,14 @@ class Router:
                               len(self._filter_ids)):
             self._maybe_compact_locked()
 
+    def _retire_delta(self, successor) -> None:
+        """Replace the delta generation (call under the lock): what
+        the outgoing one counted is kept for the drain."""
+        if self._delta is not None:
+            self._delta_grows += self._delta.grows
+        self._delta = successor
+        self._delta_ver += 1
+
     def _maybe_compact_locked(self) -> None:
         if not self._compacting and not self._dirty \
                 and self._needs_compaction_locked():
@@ -708,9 +745,14 @@ class Router:
             self._apply_patches_locked()
 
     def _map_set(self, fid: int, filter_: Optional[str]) -> None:
-        while fid >= len(self._auto_map):
-            self._auto_map.append(None)
-        self._auto_map[fid] = filter_
+        m = self._auto_map
+        if fid >= len(m):
+            m.extend([None] * (fid - len(m)))
+            m.append(filter_)
+            return
+        if filter_ is not None:
+            m.reused.append(fid)  # a recycled id, set in place
+        m[fid] = filter_
 
     def delete_route(self, filter_: str, dest: object = None) -> None:
         dest = self.node if dest is None else dest
@@ -902,13 +944,12 @@ class Router:
             # them); the trie had every mutation applied, so any
             # pending delta is folded by this flatten
             self._patcher = None
-            self._delta = None
-            self._delta_ver += 1
+            self._retire_delta(None)
         else:
             # the mirror copies host arrays (no device→host readback)
             self._patcher = AutoPatcher(host_auto, intern)
         self._auto = auto
-        self._auto_map = list(self._id_to_filter)  # NEW object: old
+        self._auto_map = IdMap(self._id_to_filter)  # NEW object: old
         # snapshots freeze, so quarantined ids may recycle now
         self._free_ids.extend(self._pending_free)
         self._pending_free.clear()
@@ -965,7 +1006,7 @@ class Router:
                 sub_ids=np.full((n_trie, 1), -1, np.int32),
                 row_pairs=np.zeros((n_trie, 1, 2), np.int32)))
         self._auto = auto
-        self._auto_map = list(self._id_to_filter)
+        self._auto_map = IdMap(self._id_to_filter)
         self._free_ids.extend(self._pending_free)
         self._pending_free.clear()
         self._patcher = None
@@ -1171,12 +1212,14 @@ class Router:
         matchers keep the published (main, delta) pair), then swap +
         replay under another short lock. The lock is held for
         milliseconds total — `automaton.rebuild.stall_ms` counts
-        exactly that."""
-        import time as _time
-
-        t_begin = _time.perf_counter()
+        exactly that, `automaton.compaction.ns` the whole of it; its
+        stages (``freeze``, ``flatten``, ``put``, ``handover``,
+        ``swap``: telemetry.REBUILD_STAGES) are observed beside the
+        ``rebuild`` stage."""
+        clock = time.perf_counter
+        t_begin = clock()
         with self._lock:
-            t0 = _time.perf_counter()
+            t0 = clock()
             if self._dirty or self._auto is None \
                     or not self._delta_active \
                     or not self._needs_compaction_locked():
@@ -1191,26 +1234,45 @@ class Router:
             if prev is not None and prev.node2 is not None:
                 cap_s2 = prev.node2.shape[0] * self._grow["state"]
                 nb = prev.wt.shape[0] * self._grow["edge"]
-            stall = _time.perf_counter() - t0
+            t_frozen = clock()
+            stall = t_frozen - t0
         tel = self.telemetry
         ann = tel.rebuild_begin() \
             if tel is not None and tel.enabled else None
+        took = {"freeze": t_frozen - t_begin}
         try:
             try:
                 host_auto = self._flatten_main(cap_s2, nb)
+                t_flat = clock()
+                took["flatten"] = t_flat - t_frozen
                 auto = device_view(host_auto)
                 if self.config.use_device:
                     auto = jax.device_put(auto)
+                took["put"] = clock() - t_flat
             except BaseException:
                 with self._lock:
                     self._unfreeze_locked()
                 raise
             with self._lock:
-                t1 = _time.perf_counter()
+                t1 = clock()
+                # a NEW object: snapshots of the old epoch freeze
+                new_map = IdMap(self._id_to_filter)
+                hand = self.on_swap
+                if hand is not None:
+                    # before anything of the router moves: a hand-over
+                    # that fails leaves the old tables live
+                    try:
+                        hand(self._rebuilds, self._auto_map,
+                             self._rebuilds + 1, new_map)
+                    except BaseException:
+                        self._unfreeze_locked()
+                        raise
+                t_hand = clock()
+                took["handover"] = t_hand - t1
                 self._install_walk_meta(host_auto)
                 self._auto = auto
                 self._patcher = None  # delta mode: no main-table mirror
-                self._auto_map = list(self._id_to_filter)
+                self._auto_map = new_map
                 # recycle ONLY ids quarantined before the freeze: an id
                 # freed DURING the flatten may still be emitted by the
                 # new tables (its path was in the snapshot) — it waits a
@@ -1225,20 +1287,23 @@ class Router:
                                    self._cache_rev)
                 # fold: log entries before the mark are in the new tables;
                 # the rest replay into a fresh delta generation
-                if self._delta is not None:
-                    self._delta = self._delta.split_after(mark)
-                self._delta_ver += 1
+                self._retire_delta(self._delta.split_after(mark)
+                                   if self._delta is not None else None)
                 self._delta_merges += 1
                 self._unfreeze_locked()
                 self._publish_pair_locked()
-                stall += _time.perf_counter() - t1
+                t_end = clock()
+                took["swap"] = t_end - t_hand
+                stall += t_end - t1
+                self._rebuild_stall_ms += stall * 1000.0
+                self._compaction_ns += int((t_end - t_begin) * 1e9)
         finally:
             if ann is not None:
                 tel.rebuild_done(ann)
-        self._rebuild_stall_ms += stall * 1000.0
         if ann is not None:
-            tel.observe_stage(
-                "rebuild", (_time.perf_counter() - t_begin) * 1000.0)
+            tel.observe_stage("rebuild", (clock() - t_begin) * 1000.0)
+            for stage, secs in took.items():
+                tel.observe_stage("rebuild." + stage, secs * 1000.0)
 
     def automaton(self) -> tuple:
         """(automaton, id→filter snapshot, epoch) — a consistent
@@ -1308,12 +1373,23 @@ class Router:
             self._published = main
         d = self._delta
         snap = None
-        if d is not None and (d.n_pending or d.tombs):
+        if d is not None:
             k_cap = max(self.config.active_k, self._k_boost)
             with self._wt_lock:  # a deferred-build flatten may intern
                 snap = d.snapshot(len(self._id_to_filter), k_cap)
         self._pub2 = (main, snap, self._delta_ver,
                       self._k_boost)
+
+    def warm_delta(self) -> int:
+        """The delta's part of the dispatch's warm-up
+        (``Broker.warm_dispatch``): with a delta live, its side tables
+        staged and the patch scatter first-used at every chunk size a
+        drain can take; -> programs launched (0 with no delta)."""
+        with self._lock:
+            if self._delta is None or self._auto is None or self._dirty:
+                return 0
+            self._publish_pair_locked()
+            return self._delta.warm_apply()
 
     def _snapshot_pair(self):
         """Consistent ``((auto, id_map, epoch, rev), delta_snap)``
@@ -1403,8 +1479,7 @@ class Router:
             # the delta's pending adds/deletes are all in the trie
             # (mutations apply immediately outside a freeze), so the
             # next flatten re-derives them — drop the side structures
-            self._delta = None
-            self._delta_ver += 1
+            self._retire_delta(None)
             self._pub2 = None
             self._dirty = True  # next device use must re-flatten
             self._free_ids.extend(self._pending_free)
@@ -1554,7 +1629,7 @@ class Router:
             self._install_walk_meta(host_auto)
             self._auto = auto
             self._patcher = None  # delta mode: no main-table mirror
-            self._auto_map = list(self._id_to_filter)
+            self._auto_map = IdMap(self._id_to_filter)
             # recycle ONLY ids quarantined before the freeze (the
             # compaction rule: an id freed mid-flatten waits a
             # generation)
@@ -1566,9 +1641,8 @@ class Router:
             self._bump_cache_rev()
             self._published = (auto, self._auto_map, self._rebuilds,
                                self._cache_rev)
-            if self._delta is not None:
-                self._delta = self._delta.split_after(mark)
-            self._delta_ver += 1
+            self._retire_delta(self._delta.split_after(mark)
+                               if self._delta is not None else None)
             self._unfreeze_locked()
             self._publish_pair_locked()
             self._device_suspended = False
@@ -1969,24 +2043,24 @@ class Router:
         into Metrics by the stats flush under the ``automaton.``
         prefix (docs/OBSERVABILITY.md)."""
         comp = self._compaction
+        d = self._delta
         cur = (self._delta_probes, self._delta_filters,
                self._delta_merges, int(self._rebuild_stall_ms),
                comp["fused_edges"], comp["chains"],
-               self._delta_tombstones, self._delta_retracts)
+               self._delta_tombstones, self._delta_retracts,
+               self._compaction_ns,
+               self._delta_grows + (d.grows if d is not None else 0),
+               self._freeze_deferred)
         prev = self._auto_drained
         self._auto_drained = cur
-        return {
-            "delta.probes": cur[0] - prev[0],
-            "delta.filters": cur[1] - prev[1],
-            "delta.merges": cur[2] - prev[2],
-            "rebuild.stall_ms": cur[3] - prev[3],
-            # table-state gauges carried as deltas (GAUGE_METRICS —
-            # a rebuild may shrink them)
-            "compaction.fused_edges": cur[4] - prev[4],
-            "compaction.chains": cur[5] - prev[5],
-            "delta.tombstones": cur[6] - prev[6],
-            "delta.retracts": cur[7] - prev[7],
-        }
+        keys = ("delta.probes", "delta.filters", "delta.merges",
+                "rebuild.stall_ms",
+                # table-state gauges carried as deltas (GAUGE_METRICS
+                # — a rebuild may shrink them)
+                "compaction.fused_edges", "compaction.chains",
+                "delta.tombstones", "delta.retracts",
+                "compaction.ns", "delta.grows", "freeze.deferred")
+        return {k: c - p for k, c, p in zip(keys, cur, prev)}
 
     def walk_info(self) -> Dict[str, object]:
         """Live walk facts for `ctl cache`: the level-compression

@@ -197,6 +197,15 @@ STAGES = ("ingress_wait", "prepare", "match", "cache_gather",
           "tail_yield", "xloop", "gc_inside", "rebuild",
           "unattributed", "end_to_end")
 
+#: what a background compaction's ``rebuild`` is made of
+#: (router.Router._compact_offlock), each observed as
+#: ``rebuild.<stage>`` beside it: the short lock that freezes the trie,
+#: the flatten off the lock, the new tables put on the device, the
+#: fan-out tables handed over the epoch, the swap under the second
+#: short lock. Not publish stages: ``ctl telemetry`` and ``ctl
+#: profile`` print them under the table (:meth:`Telemetry.rebuild_stats`)
+REBUILD_STAGES = ("freeze", "flatten", "put", "handover", "swap")
+
 #: profiler annotation names, built once (``emqx/<stage>``)
 _ANN = {s: "emqx/" + s for s in STAGES}
 #: the mark around a batch's first device call
@@ -555,6 +564,8 @@ class Telemetry:
         self.metrics = metrics
         self.hists: Dict[str, Histogram] = {
             s: Histogram(self.config.ring_size) for s in STAGES}
+        for s in REBUILD_STAGES:
+            self.hists["rebuild." + s] = Histogram(self.config.ring_size)
         self.spans_total = 0
         self.slow_total = 0
         self._seq = 0
@@ -755,6 +766,11 @@ class Telemetry:
         """Per-stage count/p50/p95/p99 from the sample rings — the
         ctl table and the $SYS heartbeat both read this."""
         return {s: self.hists[s].stats() for s in STAGES}
+
+    def rebuild_stats(self) -> Dict[str, dict]:
+        """A background compaction by stage (REBUILD_STAGES)."""
+        return {s: self.hists["rebuild." + s].stats()
+                for s in REBUILD_STAGES}
 
     def histograms(self) -> Dict[str, dict]:
         """Prometheus families: ``emqx_tpu_publish_stage_<stage>_ms``

@@ -280,6 +280,71 @@ def test_flatten_crash_alarms_backoff_then_retries():
                    for a in node.alarms.get_alarms("activated"))
 
 
+def test_handover_crash_alarms_backoff_and_the_old_tables_stay():
+    """PR 42: the fan-out tables' hand-over at a compaction's swap
+    (``Router.on_swap`` -> ``FanoutManager.carry``) fails: the swap is
+    not made, the old automaton, its epoch and the old fan-out tables
+    stay live and exact, the freeze is lifted, and the failure takes
+    the crashed flatten's path: alarm, backoff, retry."""
+    class _Sub:
+        def deliver(self, topic_filter, msg):
+            pass
+
+    node = _device_node(matcher=MatcherConfig(
+        device_min_filters=0, delta_max_filters=4))
+    r, helper, sub = node.router, node.broker.helper, _Sub()
+    for i in range(3):
+        node.broker.subscribe(sub, f"fl/{i}")
+    r.match_ids(["fl/0"])  # build the automaton (delta plane live)
+    auto, id_map, epoch = r.automaton()
+    st0 = helper.state(epoch, id_map)
+    assert st0 is not None and st0.epoch == epoch
+    carry, calls = r.on_swap, []
+
+    def boom(*a):
+        calls.append(a)
+        if len(calls) == 1:
+            raise RuntimeError("hand-over failed")
+        return carry(*a)
+
+    r.on_swap = boom
+    for i in range(3, 12):
+        node.broker.subscribe(sub, f"fl/{i}")
+    deadline = time.time() + 10
+    while r._compact_failures == 0 and time.time() < deadline:
+        time.sleep(0.01)
+    assert r._compact_failures == 1 and len(calls) == 1
+    # nothing of the swap was made: the old tables serve, exactly
+    assert r.automaton()[2] == epoch and r._auto is auto
+    assert r._freeze is None and not r._rebuild_inflight
+    assert r.delta_info()["merges"] == 0 and helper.carries == 0
+    st1 = helper.state(epoch, r.automaton()[1])
+    assert st1.epoch == epoch and helper.rebuilds == 1
+    assert sorted(r.host_match("fl/7")) == ["fl/7"]
+    assert r.match_filters(["fl/7", "fl/1"]) == [["fl/7"], ["fl/1"]]
+    node.drain_robustness_events()
+    assert any(a.name == "router_compaction_failed"
+               for a in node.alarms.get_alarms("activated"))
+    assert node.metrics.val("overload.heal.flatten") == 1
+    # once the backoff elapses the retry swaps, and the tables go over
+    r.retry_compaction()
+    assert r._compact_failures == 1
+    r._compact_backoff_until = 0.0
+    r.retry_compaction()
+    deadline = time.time() + 10
+    while (r._compacting or r._compact_failures) \
+            and time.time() < deadline:
+        time.sleep(0.01)
+    assert r._compact_failures == 0 and len(calls) == 2
+    assert r.automaton()[2] == epoch + 1 and helper.carries == 1
+    st2 = helper.state(*r.automaton()[2:0:-1])
+    assert st2.epoch == epoch + 1 and helper.rebuilds == 1
+    assert r.match_filters(["fl/9"]) == [["fl/9"]]
+    node.drain_robustness_events()
+    assert not any(a.name == "router_compaction_failed"
+                   for a in node.alarms.get_alarms("activated"))
+
+
 # -- multi-loop: dead loop, dropped handoff, stalled owner -------------------
 
 

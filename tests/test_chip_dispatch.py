@@ -411,6 +411,87 @@ def test_a_warm_batch_is_one_transfer_and_two_or_three_programs(
     assert c["dispatch.fused"] == c["dispatch.batches"] == 7
 
 
+_COMPILES = []
+
+
+def _count_compiles():
+    """Programs XLA compiled so far in this process (the harness's
+    own count: one ``backend_compile`` event each; the persistent
+    cache is off under test)."""
+    if not _COMPILES:
+        import jax.monitoring as mon
+
+        _COMPILES.append(0)
+
+        def _dur(name, _secs, **_kw):
+            if name.endswith("backend_compile_duration"):
+                _COMPILES[0] += 1
+
+        mon.register_event_duration_secs_listener(_dur)
+    return _COMPILES[0]
+
+
+def test_a_merge_leaves_the_walks_program_the_one_it_was():
+    """PR 42: a compaction's swap hands the match path a new generation
+    of the delta (empty, or holding what arrived during the flatten,
+    shallower or deeper than the last) and fan-out tables carried over
+    the epoch: the walk, the merge and the packer are the programs
+    they were, nothing is compiled again, whatever the generation
+    holds."""
+    import time
+
+    b = _broker(delta_max_filters=8)
+    r = b.router
+    for i in range(150):          # room in every table for what follows,
+        b.sub(f"base/{i}/a/b/c")  # and the main tables as deep as it
+
+    def batch(tag, n=24):
+        return [Message(topic=f"t/{i}/{tag}") for i in range(n)]
+
+    def settle(merges):
+        deadline = time.time() + 20
+        while (r._compacting or r._delta_merges < merges) \
+                and time.time() < deadline:
+            time.sleep(0.005)
+        assert r._delta_merges >= merges
+
+    b.publish_batch(batch("first", 1))      # the first flatten
+    b.sub("late/+/z")                       # a live delta, with a '+'
+    b.unsub("t/5/+")                        # and a tombstone
+    for tag in ("a", "b"):
+        b.publish_batch(batch(tag))         # all miss: the walk's shape
+    b.sub("late/0/y")                       # a patched side table
+    b.publish_batch(batch("b2"))
+    b.publish_batch(batch("b2"))            # all hit
+    deep = [Message(topic=f"base/{i}/a/b/{tag}") for i in range(12)
+            for tag in "cd"]
+    b.publish_batch(deep)                   # and a batch five levels deep
+    compiled = _count_compiles()
+    rebuilds = b.helper.rebuilds
+    for i in range(6):                      # the bound (2 + 6): a merge
+        b.sub(f"cmd/s0/d{i}/#")
+    settle(1)
+    assert r._delta is not None and r._delta.n_pending == 0
+    b.publish_batch(batch("c"))             # an empty generation
+    b.sub("cmd/s0/d9/#")                    # a shallow one
+    b.publish_batch(batch("d"))
+    b.sub("cmd/s0/+/d10/ack")               # a deeper one, with a '+'
+    b.unsub("t/3/+")                        # and a tombstone
+    b.publish_batch(batch("e"))
+    for i in range(11, 19):
+        b.sub(f"cmd/s0/d{i}/#")
+    settle(2)
+    msgs = batch("f")
+    assert b.publish_batch(msgs) == [_want(b, m.topic) for m in msgs]
+    msgs = [Message(topic="cmd/s0/x/d10/ack"),
+            Message(topic="cmd/s0/d4/probe"),
+            Message(topic="t/3/z/y/x")] + batch("g", 21)
+    assert b.publish_batch(msgs) == [_want(b, m.topic) for m in msgs]
+    assert _want(b, "cmd/s0/x/d10/ack") > _want(b, "cmd/s0/x/d11/ack")
+    assert _count_compiles() == compiled
+    assert b.helper.rebuilds == rebuilds and b.helper.carries == 2
+
+
 def test_the_buffers_capacity_is_reached_by_the_first_batch():
     """Its length is a shape of the walk and the merge: it must never
     grow under traffic the ingress can form (1,024 unique topics, all

@@ -303,3 +303,33 @@ def test_router_note_match_fallbacks_schedules_rebuild():
     assert r.stats()["rebuilds"] > rebuilds
     # the fresh patcher starts clean
     assert r._patcher.hop_fallbacks == 0
+
+
+def test_the_patchers_hash_is_the_builders_bit_for_bit():
+    """PR 42: ``AutoPatcher._buckets`` mixes one edge key in Python's
+    integers; the builder (numpy) and the walk (jnp) use
+    ``csr.hash_mix``. Same buckets for every key, negative ids and
+    the largest included."""
+    from emqx_tpu.ops.csr import hash_mix
+
+    trie, table = TrieOracle(), WordTable()
+    fids = {}
+    for i, f in enumerate(["a/b", "a/+/c", "d/#"]):
+        trie.insert(f)
+        fids[f] = i
+    p = AutoPatcher(build_automaton(trie, fids, table), table.intern)
+    rng = random.Random(7)
+    keys = [(0, 0), (-1, -1), (2**31 - 1, 2**31 - 1), (-2**31, 5)] + [
+        (rng.randrange(-2**31, 2**31), rng.randrange(-2**31, 2**31))
+        for _ in range(2000)]
+    for seed in (np.uint32(p.seed), np.uint32(0), np.uint32(0xFFFFFFFF)):
+        p.seed = int(seed)
+        for nb in (4, 256, 1 << 21):
+            p.nb = nb
+            for state, word in keys:
+                with np.errstate(over="ignore"):
+                    h1, h2 = hash_mix(np.array(state, np.int32),
+                                      np.array(word, np.int32), seed)
+                mask = np.uint32(nb - 1)
+                assert p._buckets(state, word) == (int(h1 & mask),
+                                                   int(h2 & mask))
