@@ -631,8 +631,9 @@ class Broker:
         # and misses (walked, then inserted) — transparent here, the
         # merged [B_pad, M] id array feeds the same fan-out/pack
         # kernels either way. With the cache on the batch costs the
-        # loop one transfer and two or three programs: the router's
-        # walk + insert and merge, and one packer here.
+        # loop one transfer and two programs: the router's match
+        # (walk + insert + merge; the merge alone where every topic
+        # hit) and one packer here.
         sp = pb.span
         if faults.enabled:
             faults.fire("device.walk")
@@ -1119,10 +1120,12 @@ class Broker:
             max(self._pack_budgets, default=1)))
 
     def warm_dispatch(self, max_topics: int):
-        """Drive every batch of :meth:`Router.dispatch_shapes` — one
-        for each (miss bucket × depth) walk variant and each (batch,
-        hit, miss) merge triple a batch of up to ``max_topics`` unique
-        topics can ask for (the ingress forms up to its
+        """Drive every batch of :meth:`Router.dispatch_shapes` — on
+        one chip one for each (batch bucket × depth) variant of the
+        match's program and one fully hit batch a bucket, on the mesh
+        one for each miss bucket of the step and each (batch, hit,
+        miss) merge triple, that a batch of up to ``max_topics``
+        unique topics can ask for (the ingress forms up to its
         ``batch_cap``) — through
         :meth:`_begin_device` / :meth:`_fetch_device`, over synthetic
         NUL-rooted topics (ops/warmup.py) that no real filter can

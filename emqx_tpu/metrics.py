@@ -444,12 +444,16 @@ MESH_METRICS = [
 # ``cache.match.hit`` / ``.miss`` wait for the stats flush.
 # ``batches`` = device batches dispatched on one chip, ``fused`` =
 # those of them that left the loop as one host→device transfer and
-# two or three programs (``Broker._begin_device``; the rest: the
-# match cache off, or big-filter bitmaps live), the twins of
-# ``mesh.batches`` / ``mesh.fused``
+# two programs (``Broker._begin_device``; the rest: the match cache
+# off, or big-filter bitmaps live), the twins of ``mesh.batches`` /
+# ``mesh.fused``. ``programs`` = the compiled programs the loop
+# launched for fused batches, counted where each is launched (the
+# router's match or merge, the broker's packer; the calls-apart paths
+# count none): programs ÷ batches reads 2.0 where every batch is
+# fused (3 a batch with a miss before the match became one program)
 DISPATCH_METRICS = [
     "dispatch.topics", "dispatch.walk.topics",
-    "dispatch.batches", "dispatch.fused",
+    "dispatch.batches", "dispatch.fused", "dispatch.programs",
 ]
 
 # the publish run (connection.Connection.run →

@@ -374,7 +374,7 @@ def probe_packed(auto, mask, word_ids, n_words, sys_mask, main_ids,
     """Two-probe merge for the PACKED (``pack_ids=True``) dispatch —
     the match-cache miss walk: union into the fixed ``[B, m]`` row
     shape cache entries carry, then tombstone-mask. Traced inside the
-    walk's program (``ops/match_cache.walk_insert``), so it takes a
+    match's program (``ops/match_cache.walk_merge``), so it takes a
     :class:`DeltaSnapshot`'s device half (``auto``, ``mask``) and its
     host half as statics (``k``, and ``steps`` = ``steps_for`` the
     batch's depth)."""

@@ -52,7 +52,10 @@ one chip's ``Router._match_dispatch_cached`` and the mesh's
 What the event loop pays for is not device work but hand-overs: every
 numpy argument is a host→device transfer of its own, every eager
 operation and every program a launch that gives up the interpreter
-lock. So a batch leaves as ONE transfer and two or three programs:
+lock (what one costs the loop on the cells' host: PERF.md §6, PR 43,
+timed around each call). So a batch leaves as ONE transfer and, on
+one chip, TWO programs (the match and the packer); on the mesh two or
+three:
 
   - **one buffer a batch** (:class:`BatchLayout`,
     :meth:`MatchCache.batch_buffer`): every integer the device needs
@@ -64,43 +67,54 @@ lock. So a batch leaves as ONE transfer and two or three programs:
     hit_slots [HB] | hit_pos [HB] | n_uniq [1]``, which the merge
     reads (MB, HB = the padded miss and hit counts; L = the batch's
     depth bucket on one chip, ``max_levels`` on the mesh; an all-hit
-    batch has MB = 0). The walk's offsets depend on (MB, L) alone and
-    the merge's on (MB, HB) alone, so the walk stays one program a
-    (miss bucket, depth) whatever the batch's hits and the merge one
-    a (batch, hit, miss) triple whatever its depth. The buffer's
-    LENGTH is a shape of every program that takes it, so it is not
-    the sum of its sections but a capacity: a power of two from
-    ``BATCH_BUF_FLOOR``, grown only when a batch needs more (the
-    router keeps the high-water mark);
-  - **walk + insert** is one program, keyed by the miss bucket (and
-    the depth): one chip's :func:`walk_insert` (``match_batch`` over
-    the misses, a live delta snapshot's two-probe folded in), the
-    mesh's ``parallel/sharded.py::publish_step_insert`` (the
-    collective step; its row is ``ids [T·m] | subs [T·d] | src
-    [T·d]``, everything the step produces for a topic, in a table
-    replicated on every chip). Either lays ``flag | row``
-    (:func:`flag_rows`) and scatters it into the table
-    (:func:`insert_rows`) through :meth:`MatchCache.insert_through`.
-    Skipped when every topic hits;
-  - **merge + pad mask** is one program for both
-    (:func:`_mesh_merge_jit`, keyed by the (batch, hit, miss)
-    buckets): gathers the hits from the PROBE'S snapshot, scatters
-    hits and misses, blanks the pad rows (≥ ``n_uniq``) and, on the
-    mesh, splits the row into ids / subs / src;
-  - **the packers + the fetch's bundle** are a third
+    batch has MB = 0). The buffer's LENGTH is a shape of every
+    program that takes it, so it is not the sum of its sections but a
+    capacity: a power of two from ``BATCH_BUF_FLOOR``, grown only
+    when a batch needs more (the router keeps the high-water mark);
+  - **one chip: MB = HB = the batch's bucket B**, so the layout has
+    no dimension of its own: a batch with a miss is ``(L, B, B)``, a
+    fully hit one ``(0, 0, B)``. Rows past the real counts are pad
+    topics with out-of-range slots and positions, which drop. **The
+    match is one program** (:func:`walk_merge`, keyed by (B, L) and
+    what the walk is keyed by: the delta's presence, its lanes and
+    steps, ``k``, the walk's own statics): ``match_batch`` over the
+    misses with a live delta snapshot's two-probe folded in,
+    :func:`flag_rows`, the scatter into the table
+    (:func:`insert_rows`, through :meth:`MatchCache.insert_through`),
+    the gather of the hits from the PROBE'S snapshot, the merge and
+    the pad mask. The walk runs over B rows whatever the batch
+    misses: device time on a chip that idles more than four fifths
+    of a served window (0.5–0.6 ms more a batch where few topics
+    miss, PERF.md §6, PR 43), against a launch of the loop's. A fully hit batch keeps
+    the walk-free merge (:func:`_mesh_merge_jit`), keyed by B alone;
+  - **the mesh: walk + insert, then merge**. The collective step
+    with the insert is one program, keyed by the miss bucket
+    (``parallel/sharded.py::publish_step_insert``; its row is ``ids
+    [T·m] | subs [T·d] | src [T·d]``, everything the step produces
+    for a topic, in a table replicated on every chip; its offsets
+    depend on (MB, L) alone, and it is skipped when every topic
+    hits); the merge + pad mask a second (:func:`_mesh_merge_jit`,
+    keyed by the (batch, hit, miss) buckets, whatever the depth),
+    which also splits the row into ids / subs / src;
+  - **the packers + the fetch's bundle** are the last
     (``ops/pack.py::pack_chip`` / ``pack_mesh``, keyed by (batch
-    bucket, pm, pq), so a grown budget costs one program a bucket,
-    not one a triple);
+    bucket, pm, pq), so a grown budget costs one program a bucket).
+    ``pack_chip`` is NOT folded into the match: a program that holds
+    the walk must not be keyed by a learned budget, or a budget that
+    grows in a warm round makes every depth variant of that bucket a
+    first use on the loop (2–7 s each);
   - **the table is not donated**: a probe holds its snapshot and its
     hits gather from it AFTER this or another batch's insert (the
     clock sweep may hand a hit's slot to a miss of the same batch), so
     an insert must leave the old array whole. The copy is device
     time on a chip that is mostly idle; the loop pays nothing for it;
   - **the padding rule is a contract** with the benchmark's sweeps
-    (``benchmark/warmers/dispatch_buckets.py`` and ``mesh_buckets.py``
-    walk every walk variant and every (batch, hit, miss) triple
-    through ``publish_batch``): batch and misses pad to a power of two
-    from ``min_batch`` (× ``data`` on a mesh: ``Router.pad_topics``),
+    (``benchmark/warmers/dispatch_buckets.py`` sends a misses-only
+    batch for every bucket at every depth and a batch for every
+    (batch, hit, miss) triple through ``publish_batch``, a superset
+    of one chip's keys; ``mesh_buckets.py`` walks the mesh's): a
+    batch pads to a power of two from ``min_batch`` (× ``data`` on a
+    mesh: ``Router.pad_topics``), as do the mesh's misses, the mesh's
     hits from ``_MIN_PAD`` (:func:`pad_hits`); ``Router.
     dispatch_shapes`` lists the batches the rule allows. Change it and
     runs first use programs inside their window.
@@ -120,7 +134,7 @@ from emqx_tpu.ops.delta import probe_packed
 from emqx_tpu.ops.match import match_batch
 
 __all__ = ["MatchCache", "BatchLayout", "BATCH_BUF_FLOOR", "flag_rows",
-           "insert_rows", "pad_hits", "ring_slots", "walk_insert"]
+           "insert_rows", "pad_hits", "ring_slots", "walk_merge"]
 
 #: flag column values: _VALID = cached ids are the exact match set;
 #: _OVF = the walk overflowed (host fallback, match-only bound);
@@ -152,9 +166,10 @@ def ring_slots(slots: int) -> int:
 
 
 def pad_hits(n: int) -> int:
-    """The padded length of a batch's ``n`` cache hits (a shape of the
-    merge's program): a power of two from ``_MIN_PAD``, also for none.
-    ``Router.dispatch_shapes`` lists the programs from it."""
+    """The padded length of a mesh batch's ``n`` cache hits (a shape
+    of its merge's program): a power of two from ``_MIN_PAD``, also
+    for none. ``Router.dispatch_shapes`` lists the programs from it.
+    One chip lays its hits at the batch's bucket."""
     return _pow2(max(n, 1), _MIN_PAD)
 
 
@@ -164,9 +179,12 @@ def pad_hits(n: int) -> int:
 class BatchLayout(NamedTuple):
     """Where one batch's host integers lie in its one int32 buffer
     (module header). Static: a program is compiled for the sections it
-    reads and for ``size``, the buffer's capacity. The walk's program
-    is keyed with ``hit`` = 0 and the merge's with ``levels`` = 0:
-    neither reads a section whose place depends on the other's."""
+    reads and for ``size``, the buffer's capacity. On one chip ``miss``
+    and ``hit`` are the batch's bucket (``miss`` 0 where the batch
+    fully hit), so the match's program is keyed by (bucket, depth). On
+    the mesh the step's program is keyed with ``hit`` = 0 and the
+    merge's with ``levels`` = 0: neither reads a section whose place
+    depends on the other's."""
 
     levels: int   # L: word ids a topic
     miss: int     # MB: padded miss count (0 = the batch fully hit)
@@ -216,43 +234,9 @@ def insert_rows(table, idx, vals):
         jnp.where(vals[:, :1] != _VALID, marker, vals), mode="drop")
 
 
-@functools.partial(
-    jax.jit, static_argnames=("lay", "k", "m", "steps", "slots", "take",
-                              "dk", "dsteps"))
-def walk_insert(auto, delta, table, buf, *, lay: BatchLayout, k: int,
-                m: int, steps, slots: int, take: int, dk: int = 0,
-                dsteps: int = 0):
-    """The one-chip dispatch's walk as ONE program (the twin of
-    ``parallel/sharded.py::publish_step_insert``): slice the misses'
-    operands out of the batch buffer, walk them (``pack_ids=True`` —
-    fixed-width rows are what the table holds), fold a live delta
-    snapshot's two-probe in (``delta`` = its ``(auto, mask)``, None
-    without one; ``dk`` / ``dsteps`` its lanes and its steps at this
-    depth: the side-automaton's union and the tombstone mask land in
-    the rows the cache stores, and a later delta mutation bumps the
-    revision, so they are never served stale), lay ``flag | row`` and
-    scatter the rows into the table (functionally: the old table stays
-    whole for the probes that hold it).
-
-    Returns ``(new_table, miss_vals [MB, 1 + width])``; keyed by the
-    miss bucket and the depth (and the buffer's capacity), never by
-    the batch's hits."""
-    word_ids, n_words, sys_mask, slots_ = lay.step_sections(buf)
-    res = match_batch(auto, word_ids, n_words, sys_mask, k=k, m=m,
-                      pack_ids=True, steps=steps, slots=slots, take=take)
-    rows, ovf = res.ids, res.overflow
-    if delta is not None:
-        rows, ovf = probe_packed(*delta, word_ids, n_words, sys_mask,
-                                 rows, ovf, m=m, k=dk, steps=dsteps)
-    vals = flag_rows(rows, ovf, ovf)
-    return insert_rows(table, slots_, vals), vals
-
-
-@functools.partial(jax.jit, static_argnames=("lay", "b_pad", "splits"))
-def _mesh_merge_jit(table, buf, miss_vals, *, lay: BatchLayout,
-                    b_pad: int, splits):
-    """A batch's merge, on one chip as on the mesh (whose tests and
-    traces know it by this name): gather the hit rows from the table
+def _merge(table, buf, miss_vals, lay: BatchLayout, b_pad: int, splits):
+    """A batch's merge (traced inside :func:`walk_merge` and
+    :func:`_mesh_merge_jit`): gather the hit rows from the table
     snapshot, scatter them and the fresh miss rows into the ``[b_pad,
     width]`` output (OOB positions drop — that is how pad entries and
     absent hits/misses vanish), then blank the pad rows (≥ ``n_uniq``:
@@ -281,6 +265,53 @@ def _mesh_merge_jit(table, buf, miss_vals, *, lay: BatchLayout,
         return out, ovf, movf
     mw, dw = splits
     return out[:, :mw], out[:, mw:mw + dw], out[:, mw + dw:], ovf, movf
+
+
+@functools.partial(
+    jax.jit, static_argnames=("lay", "k", "m", "steps", "slots", "take",
+                              "dk", "dsteps"))
+def walk_merge(auto, delta, snap, table, buf, *, lay: BatchLayout, k: int,
+               m: int, steps, slots: int, take: int, dk: int = 0,
+               dsteps: int = 0):
+    """One chip's match of a batch with a miss as ONE program: slice
+    the misses' operands out of the batch buffer, walk them
+    (``pack_ids=True`` — fixed-width rows are what the table holds),
+    fold a live delta snapshot's two-probe in (``delta`` = its
+    ``(auto, mask)``, None without one; ``dk`` / ``dsteps`` its lanes
+    and its steps at this depth: the side-automaton's union and the
+    tombstone mask land in the rows the cache stores, and a later
+    delta mutation bumps the revision, so they are never served
+    stale), lay ``flag | row``, scatter the rows into ``table`` (the
+    cache's CURRENT table; functionally: the old array stays whole for
+    the probes that hold it), gather the hits from ``snap`` (the
+    PROBE'S snapshot: the same array as ``table`` unless another
+    batch's insert landed in between) and merge both into the batch's
+    ``[B, m]`` ids, pad rows blanked.
+
+    Returns ``(new_table, (ids, ovf))``. ``lay`` is ``(L, B, B)``:
+    keyed by the batch's bucket and the depth (and the buffer's
+    capacity), never by how many of the batch's topics hit or miss,
+    and never by a pack budget (module header)."""
+    word_ids, n_words, sys_mask, slots_ = lay.step_sections(buf)
+    res = match_batch(auto, word_ids, n_words, sys_mask, k=k, m=m,
+                      pack_ids=True, steps=steps, slots=slots, take=take)
+    rows, ovf = res.ids, res.overflow
+    if delta is not None:
+        rows, ovf = probe_packed(*delta, word_ids, n_words, sys_mask,
+                                 rows, ovf, m=m, k=dk, steps=dsteps)
+    vals = flag_rows(rows, ovf, ovf)
+    ids, ovf, _movf = _merge(snap, buf, vals, lay, lay.hit, None)
+    return insert_rows(table, slots_, vals), (ids, ovf)
+
+
+@functools.partial(jax.jit, static_argnames=("lay", "b_pad", "splits"))
+def _mesh_merge_jit(table, buf, miss_vals, *, lay: BatchLayout,
+                    b_pad: int, splits):
+    """A batch's merge as a program of its own (:func:`_merge`): the
+    mesh's (whose tests and traces know it by this name), after its
+    collective step, and one chip's for a batch that fully hit
+    (``miss_vals`` None, keyed by the batch's bucket alone)."""
+    return _merge(table, buf, miss_vals, lay, b_pad, splits)
 
 
 class _Probe:
@@ -394,14 +425,23 @@ class MatchCache:
     # -- device ops (module header) ---------------------------------------
 
     def batch_buffer(self, b_pad: int, probe: _Probe, enc, n_uniq: int,
-                     size: int):
+                     size: int, rows: Optional[int] = None):
         """One batch's ``(layout, int32 buffer)``. ``enc`` is the
-        padded misses' ``(word_ids [MB, L], n_words, sys_mask)`` or
-        None when the batch fully hit; ``size`` the capacity so far —
-        the layout's is that or the next power of two that holds the
-        batch."""
+        misses' ``(word_ids [n, L], n_words, sys_mask)`` or None when
+        the batch fully hit; ``size`` the capacity so far — the
+        layout's is that or the next power of two that holds the
+        batch. ``rows`` = one chip's bucket, at which hits and misses
+        are both laid: ``enc`` then holds the real misses and ONE pad
+        topic behind them, whose row fills the misses' section up to
+        ``rows`` (a pad topic encodes alike wherever it stands, and
+        the loop does not pay for encoding it ``rows`` times). None =
+        the mesh: ``enc`` comes padded to the miss bucket, the hits
+        pad by :func:`pad_hits`."""
         mb, levels = (0, 0) if enc is None else enc[0].shape
-        hb = pad_hits(len(probe.hit_pos))
+        if rows is None:
+            hb = pad_hits(len(probe.hit_pos))
+        else:
+            mb, hb = rows if mb else 0, rows
         lay = BatchLayout(levels, mb, hb, _pow2(
             BatchLayout.need(levels, mb, hb), max(size, BATCH_BUF_FLOOR)))
         buf = np.zeros((lay.size,), np.int32)
@@ -409,10 +449,13 @@ class MatchCache:
         h = end - 2 * hb
         if mb:
             n = len(probe.miss_slots)
+            k = min(len(enc[1]), mb)    # rows encoded; the last fills
             o = mb * levels
-            buf[:o].reshape(mb, levels)[:] = enc[0]
-            buf[o:o + mb] = enc[1]
-            buf[o + mb:o + 2 * mb] = enc[2]
+            for at, width, part in ((0, levels, enc[0]), (o, 1, enc[1]),
+                                    (o + mb, 1, enc[2])):
+                sec = buf[at:at + mb * width].reshape(mb, width)
+                sec[:k] = part[:k].reshape(k, width)
+                sec[k:] = part[k - 1]
             buf[o + 2 * mb:o + 3 * mb] = self.slots  # OOB pad -> drop
             buf[o + 2 * mb:o + 2 * mb + n] = probe.miss_slots
             buf[h - mb:h] = b_pad
@@ -426,10 +469,11 @@ class MatchCache:
 
     def insert_through(self, probe: _Probe, step):
         """Store the fresh walk results for ``probe``'s misses, by the
-        caller's own program: ``step(table) -> (new_table, out)`` runs
-        under the lock against the CURRENT table (rows past the real
-        miss count drop via OOB indices; overflowed rows store invalid
-        markers, never truncated ids); returns ``out``."""
+        caller's own program (:func:`walk_merge`, the mesh's
+        ``publish_step_insert``): ``step(table) -> (new_table, out)``
+        runs under the lock against the CURRENT table (rows past the
+        real miss count drop via OOB indices; overflowed rows store
+        invalid markers, never truncated ids); returns ``out``."""
         with self._lock:
             self._table, out = step(self._table_now())
             self._key_inserted(probe)
@@ -451,7 +495,7 @@ class MatchCache:
         """The batch's combined rows and flags, pad rows blanked —
         ``(ids, ovf, movf)``, on a mesh (``splits``) ``(ids, subs,
         src, ovf, movf)``: one program, hits from ``probe``'s
-        snapshot."""
+        snapshot. One chip's batches come here only fully hit."""
         return _mesh_merge_jit(probe.table, buf, miss_vals,
                                lay=lay._replace(levels=0), b_pad=b_pad,
                                splits=splits)

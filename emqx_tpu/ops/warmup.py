@@ -6,8 +6,11 @@ the dispatch can be asked for. This module turns that list into the
 topics of each batch; the device work happens in
 ``Broker.warm_dispatch``, which drives the REAL ``_begin_device`` /
 ``_fetch_device`` seams over them, so exactly the production kernel
-set compiles: encode → walk (cache-miss shape) → cache insert and
-merge → pack → fan-out expand → bundle → fetch. The device-loss
+set compiles: encode → the match's program (walk, cache insert and
+merge; one chip keys it by the batch's bucket and the depth, so one
+batch of misses a (bucket, depth) and one fully hit batch a bucket
+are the whole list) → pack → fan-out expand → bundle → fetch. The
+device-loss
 rewarm (``Broker.warm_device_path``, docs/ROBUSTNESS.md "Device-loss
 recovery") and a harness's warm-up are the same walk.
 

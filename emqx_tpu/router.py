@@ -1651,11 +1651,13 @@ class Router:
 
     def pad_topics(self, n: int) -> int:
         """The padding rule of the match dispatch: a batch's unique
-        topics, and those of them that miss the match cache, each pad
-        to a power of two from ``min_batch`` (on a mesh from
-        ``min_batch × data``: a bucket has to split evenly over the
-        data axis); the cache pads the hits (``match_cache.pad_hits``).
-        Every padded length is a shape some program is compiled for."""
+        topics pad to a power of two from ``min_batch`` (on a mesh
+        from ``min_batch × data``: a bucket has to split evenly over
+        the data axis). One chip lays a batch's cache hits and its
+        misses at that same bucket; on the mesh the misses pad by this
+        rule on their own and the cache pads the hits
+        (``match_cache.pad_hits``). Every padded length is a shape
+        some program is compiled for."""
         cfg = self.config
         bucket = cfg.min_batch
         if cfg.mesh is not None:
@@ -1676,36 +1678,51 @@ class Router:
 
     def shape_programs(self, shape: DispatchShape):
         """``(walk, merge)``: the keys of the programs a batch of this
-        shape asks the dispatch for. ``walk`` = ``(miss bucket,
-        depth)``, the automaton walk (with the cache's insert) over
-        the topics that miss, None where none does; the mesh encodes
-        at ``max_levels`` whatever the topics, so its depth is that.
-        ``merge`` = ``(batch, hit, miss)`` buckets, the cache's merge
-        (miss 0 = the batch fully hit); None with the match cache
-        off, where the batch walks whole. The packers and the fetch's
-        bundle that follow are keyed by the batch bucket and the
-        budgets the broker learns for it."""
+        shape asks the dispatch for.
+
+        One chip: ``walk`` = ``(batch bucket, depth)``, the match's
+        one program (the walk of the misses, the cache's insert and
+        the merge: ``match_cache.walk_merge``), None where every topic
+        hits; ``merge`` = ``(batch bucket, batch bucket, 0)``, the
+        walk-free merge of a batch that fully hit, None where one
+        misses. Neither holds how many topics hit or miss. With the
+        match cache off the batch walks whole: ``walk`` alone.
+
+        The mesh: ``walk`` = ``(miss bucket, max_levels)``, the
+        collective step with the insert over the topics that miss
+        (the mesh encodes at ``max_levels`` whatever the topics),
+        None where none does; ``merge`` = ``(batch, hit, miss)``
+        buckets, its merge (miss 0 = the batch fully hit).
+
+        The packers and the fetch's bundle that follow are keyed by
+        the batch bucket and the budgets the broker learns for it."""
         cfg = self.config
         hits, misses, depth = shape
-        if cfg.mesh is not None:
-            depth = cfg.max_levels
+        bucket = self.pad_topics(hits + misses)
         if not self.cache_slots():
-            return (self.pad_topics(hits + misses), depth), None
+            return (bucket, cfg.max_levels if cfg.mesh is not None
+                    else depth), None
+        if cfg.mesh is None:
+            return ((bucket, depth), None) if misses else (
+                None, (bucket, bucket, 0))
         from emqx_tpu.ops.match_cache import pad_hits
 
         mb = self.pad_topics(misses) if misses else 0
-        return ((mb, depth) if misses else None,
-                (self.pad_topics(hits + misses), pad_hits(hits), mb))
+        return ((mb, cfg.max_levels) if misses else None,
+                (bucket, pad_hits(hits), mb))
 
     def dispatch_shapes(self, max_topics: int) -> List[DispatchShape]:
         """One batch for every program the match dispatch can be asked
         for by a batch of up to ``max_topics`` unique topics (the
-        ingress forms up to ``batch_cap``): every miss bucket at every
-        depth from 2 to the deepest level live traffic has used
-        (:meth:`observed_levels`), then every reachable (batch, hit,
-        miss) triple of the cache's merge, smallest first. A bucket is
-        reached by the fewest and by the most topics that pad to it,
-        and a batch hits no more topics than the cache holds.
+        ingress forms up to ``batch_cap``), smallest first. One chip:
+        a batch of misses for every batch bucket at every depth from 2
+        to the deepest level live traffic has used
+        (:meth:`observed_levels`), then a fully hit batch for every
+        bucket the cache can fill. The mesh: every miss bucket, then
+        every reachable (batch, hit, miss) triple of the cache's
+        merge. A bucket is reached by the fewest and by the most
+        topics that pad to it, and a batch hits no more topics than
+        the cache holds.
 
         This is the list the served path must have met before traffic
         is free of first-use stalls (2–7 s each on the event loop):
@@ -1713,8 +1730,6 @@ class Router:
         the device-loss rewarm and for a harness's warm-up. The
         learned axes (``boost_k`` / ``boost_d``, the pack budgets, a
         pending delta) are at what they are now."""
-        from emqx_tpu.ops.match_cache import pad_hits
-
         floor, top = self.pad_topics(1), self.pad_topics(max_topics)
         buckets = [floor]
         while buckets[-1] < top:
@@ -1724,13 +1739,20 @@ class Router:
             # the fewest and the most topics that pad to bucket b
             return (1 if b == lowest else b // 2 + 1, min(b, most))
 
-        depths = [2] if self.config.mesh is not None else list(
+        mesh = self.config.mesh is not None
+        depths = [2] if mesh else list(
             range(2, max(self.observed_levels() + [2]) + 1))
         shapes = [DispatchShape(0, ends(mb, floor, top)[0], d)
                   for mb in buckets for d in depths]
         slots = self.cache_slots()
         if not slots:
             return shapes
+        if not mesh:
+            return shapes + [
+                DispatchShape(ends(b, floor, top)[0], 0, depths[-1])
+                for b in buckets if ends(b, floor, top)[0] <= slots]
+        from emqx_tpu.ops.match_cache import pad_hits
+
         done = {self.shape_programs(s)[1] for s in shapes}
         hb = pad_hits(0)
         while hb <= pad_hits(min(top, slots)):
@@ -1831,16 +1853,20 @@ class Router:
 
         What the event loop hands the device for the batch is ONE
         host→device transfer (the batch's int32 buffer:
-        ops/match_cache.py's header has its layout) and at most two
-        programs here: the walk with the insert, for the misses, and
-        the merge. No numpy argument and no eager operation: each is
-        a transfer or a launch of its own, and gives up the
-        interpreter lock to the fetch's thread.
+        ops/match_cache.py's header has its layout) and ONE program
+        here: ``walk_merge`` (the walk of the misses with a live
+        delta's two-probe, the insert, the gather of the hits and the
+        merge) or, where every topic hit, the merge alone. Hits and
+        misses are both laid at the batch's bucket, so the program is
+        keyed by (bucket, depth) and never by how the batch splits.
+        No numpy argument and no eager operation: each is a transfer
+        or a launch of its own, and gives up the interpreter lock to
+        the fetch's thread.
 
         Ordering: the revision is read BEFORE the automaton snapshot,
         so a racing mutation can only make fresh results look stale
         (re-walked, safe) — never stale results look fresh."""
-        from emqx_tpu.ops.match_cache import walk_insert
+        from emqx_tpu.ops.match_cache import walk_merge
 
         cfg = self.config
         k_boost = self._k_boost  # read BEFORE the snapshot/walk: a
@@ -1871,47 +1897,51 @@ class Router:
         probe = cache.probe(topics, key, keys)
         t1 = time.perf_counter() if timed else 0.0
         misses = probe.miss_topics
-        self._count_dispatch(len(topics), len(misses))
+        # one launch below, whichever branch takes it
+        self._count_dispatch(len(topics), len(misses), programs=1)
         enc = None
         if misses:
-            mb = self.pad_topics(len(misses))
-            padded = list(misses) + ["\x00/pad"] * (mb - len(misses))
+            # the misses and one pad topic: the buffer lays them at
+            # the batch's bucket, like the hits, so the program's
+            # shapes are the batch's, whatever misses
             with self._wt_lock:
-                ids, n, sysm = self._encode(padded, cfg.max_levels)
+                ids, n, sysm = self._encode(
+                    list(misses) + ["\x00/pad"], cfg.max_levels)
             enc = (*depth_bucket(ids, n), sysm)
         lay, buf = cache.batch_buffer(bucket, probe, enc, len(topics),
-                                      self._batch_buf_len)
+                                      self._batch_buf_len, rows=bucket)
         self._batch_buf_len = lay.size
         with enqueue_mark(span):  # the batch's one transfer
             buf = jax.device_put(buf)
-        miss_vals = None
         if misses:
             delta, dkw = None, {}
             if dsnap is not None:
-                # two-probe, folded into the walk's program: the
+                # two-probe, folded into the match's program: the
                 # side-automaton's union + the tombstone mask
                 # (ops/delta.py)
                 self._delta_probes += 1
                 delta = (dsnap.auto, dsnap.mask)
                 dkw = {"dk": dsnap.k,
                        "dsteps": dsnap.steps_for(lay.levels)}
-            miss_vals = cache.insert_through(
-                probe, lambda table: walk_insert(
-                    auto, delta, table, buf, lay=lay._replace(hit=0),
+            # hits from the probe's snapshot, the insert into the
+            # current table: one array for both unless another
+            # batch's insert landed in between
+            ids_dev, ovf_dev = cache.insert_through(
+                probe, lambda table: walk_merge(
+                    auto, delta, probe.table, table, buf, lay=lay,
                     k=self.effective_k(), m=cfg.max_matches, **dkw,
                     **self._walk_kw(lay.levels)))
-        t2 = time.perf_counter() if timed else 0.0
-        ids_dev, ovf_dev, _movf = cache.merge_batch(bucket, probe, lay,
-                                                    buf, miss_vals)
+        else:
+            ids_dev, ovf_dev, _movf = cache.merge_batch(
+                bucket, probe, lay, buf, None)
         if timed:
-            # probe (host hash walk) + merge (HBM-gather dispatch) =
-            # the cache_gather share of this dispatch; the remainder
-            # (encode + transfer + miss walk) is the match share
+            # the probe (a host hash walk) is the cache_gather share
+            # of this dispatch; the remainder (encode + transfer +
+            # the one launch) is the match share
             self._last_dispatch = {
                 "hit": len(probe.hit_pos),
                 "miss": len(misses),
-                "cache_gather_ms": ((t1 - t0) + (
-                    time.perf_counter() - t2)) * 1000.0,
+                "cache_gather_ms": (t1 - t0) * 1000.0,
             }
         return ids_dev, ovf_dev, id_map, epoch
 
@@ -2168,19 +2198,24 @@ class Router:
             return tel.metrics
         return None
 
-    def _count_dispatch(self, topics: int, walked: int) -> None:
+    def _count_dispatch(self, topics: int, walked: int,
+                        programs: int = 0) -> None:
         """One batch of the one-chip match dispatch
         (metrics.DISPATCH_METRICS): its unique topics, and those that
         walk the automaton (the rest are the match cache's gather),
-        before padding; stamped per batch, so current at any instant.
-        ``cache.match.*`` and ``automaton.*`` are brought up to the
-        batch before here as well (with telemetry off they wait for
-        the stats flush)."""
+        before padding, and on the cache-split path the one program
+        it launches (the broker adds the fused packer:
+        :meth:`count_fused`; the cache-off dispatch and the packers
+        kept apart count none); stamped per batch, so current at any
+        instant. ``cache.match.*`` and ``automaton.*`` are brought up
+        to the batch before here as well (with telemetry off they wait
+        for the stats flush)."""
         m = self._live_metrics()
         if m is not None:
             m.inc("dispatch.batches")
             m.inc("dispatch.topics", topics)
             m.inc("dispatch.walk.topics", walked)
+            m.inc("dispatch.programs", programs)
             # the families the stats flush folds once a sys_interval
             # (a minute): folded here too, so that a window cut at any
             # two instants reads them to a batch (a drain hands out
@@ -2191,10 +2226,12 @@ class Router:
     def count_fused(self) -> None:
         """The broker's: the batch :meth:`_count_dispatch` just
         counted left the loop as one transfer and the fused packer
-        (``dispatch.fused``, the twin of ``mesh.fused``)."""
+        (``dispatch.fused``, the twin of ``mesh.fused``), its second
+        program (``dispatch.programs``)."""
         m = self._live_metrics()
         if m is not None:
             m.inc("dispatch.fused")
+            m.inc("dispatch.programs")
 
     def _count_mesh(self, events: str, topics: Optional[str] = None,
                     n: int = 0) -> None:

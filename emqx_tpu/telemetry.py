@@ -36,9 +36,14 @@ summed into ``stages``). Busy stages first, then the waits:
                      encode + enqueue, NOT device execution — that
                      surfaces in ``fetch``); host regime: the actual
                      trie walk.
-  ``cache_gather``   match-cache probe + HBM-row merge dispatch
-                     (cache-split batches only; carved out of the
-                     tail of the ``match`` interval).
+  ``cache_gather``   the match-cache probe (cache-split batches
+                     only; carved out of the ``match`` interval). On
+                     one chip the probe alone since the match became
+                     one program: the merge's launch is ``match``'s.
+                     On the mesh the probe + the HBM-row merge
+                     dispatch. Rows of one stage from before and
+                     after that change do not compare; their sum
+                     does.
   ``fan_sync``       the fan-out tables brought up to the membership
                      changes since the last batch
                      (``FanoutManager.state``; one chip): one compare

@@ -195,31 +195,35 @@ def test_match_cache_and_delta_compile(one_chip):
 @pytest.mark.parametrize("delta", ["no_delta", "live", "live_with_plus",
                                    "live_grown"])
 def test_fused_chip_dispatch_compiles(one_chip, delta):
-    """The three programs a served one-chip batch enqueues
+    """The two programs a served one-chip batch enqueues
     (``Router._match_dispatch_cached``, ``Broker._begin_device``), at
-    the cells' widths and the ingress's largest batch: the walk with
-    the cache insert (a live delta snapshot's two-probe folded in),
-    the merge with the pad mask, and the packers with the fetch's
-    bundle; the table is an argument the insert does not donate.
+    the cells' widths and the ingress's largest batch: the match (the
+    walk with a live delta snapshot's two-probe folded in, the cache
+    insert, the gather of the hits and the merge with the pad mask:
+    ``walk_merge``, keyed by the batch's bucket and the depth, hits
+    and misses both laid at that bucket) or, for a batch that fully
+    hit, the merge alone; and the packers with the fetch's bundle. The
+    table is an argument the insert does not donate, and the probe's
+    snapshot a second one.
 
-    A live delta is one variant of the walk whatever it holds
-    (``ops/delta.py``: side tables at the capacity
+    A live delta is one variant of the match whatever it holds, adds,
+    tombstones or both (``ops/delta.py``: side tables at the capacity
     ``delta_max_filters`` gives, the mask always there, the side
-    walk's steps from the batch's depth); what is left to vary is a
-    pending '+' (the side walk's lanes) and tables grown past their
-    floor."""
+    walk's steps from the batch's depth): ``live``. What is left to
+    vary is a pending '+' (the side walk's lanes) and tables grown
+    past their floor."""
     from emqx_tpu.ops.csr import buckets_for_capacity, capacity_for
     from emqx_tpu.ops.delta import DeltaSnapshot
     from emqx_tpu.ops.fanout import FanoutTable
     from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
-                                          _mesh_merge_jit, walk_insert)
+                                          _mesh_merge_jit, walk_merge)
     from emqx_tpu.ops.pack import pack_chip
     from emqx_tpu.router import MatcherConfig
 
     sh = one_chip
-    L, HB = 5, 256
-    lay = BatchLayout(L, _B, HB, BATCH_BUF_FLOOR)
-    assert BatchLayout.need(16, _B, 8) <= lay.size
+    L = 5
+    lay = BatchLayout(L, _B, _B, BATCH_BUF_FLOOR)
+    assert BatchLayout.need(16, _B, _B) <= lay.size
     table = _s((65536, _M + 1), jnp.int32, sh)
     buf = _s((lay.size,), jnp.int32, sh)
     side, dkw = None, {"dk": 0, "dsteps": 0}
@@ -239,21 +243,18 @@ def test_fused_chip_dispatch_compiles(one_chip, delta):
         side = (side_auto, _s((_FCAP,), jnp.bool_, sh))
         dkw = {"dk": _K if delta == "live_with_plus" else 1,
                "dsteps": DeltaSnapshot.steps_for(L)}
-    walk = walk_insert.lower(
-        _auto_shapes(sh, False), side, table, buf,
-        lay=lay._replace(hit=0), k=_K, m=_M, steps=L + 1,
-        slots=NARROW_SLOTS, take=1, **dkw).compile()
-    ma = walk.memory_analysis()
-    # the new table beside the old: not donated (a probe holds it)
-    assert ma.output_size_in_bytes >= 65536 * (_M + 1) * 4
+    match = walk_merge.lower(
+        _auto_shapes(sh, False), side, table, table, buf, lay=lay, k=_K,
+        m=_M, steps=L + 1, slots=NARROW_SLOTS, take=1, **dkw).compile()
+    ma = match.memory_analysis()
+    # the new table beside the old: not donated (a probe holds it);
+    # and the batch's ids and flags
+    assert ma.output_size_in_bytes >= (65536 * (_M + 1) + _B * _M) * 4
     if delta != "no_delta":
         return
-    vals = _s((_B, _M + 1), jnp.int32, sh)
-    for miss_vals, miss in ((vals, _B), (None, 0)):
-        _mesh_merge_jit.lower(
-            table, buf, miss_vals,
-            lay=lay._replace(levels=0, miss=miss), b_pad=_B,
-            splits=None).compile()
+    _mesh_merge_jit.lower(
+        table, buf, None, lay=BatchLayout(0, 0, _B, lay.size), b_pad=_B,
+        splits=None).compile()
     fan = FanoutTable(
         row_ptr=_s((_FCAP + 1,), jnp.int32, sh),
         sub_ids=_s((1 << 21,), jnp.int32, sh),
@@ -263,6 +264,36 @@ def test_fused_chip_dispatch_compiles(one_chip, delta):
     ovf = _s((_B,), jnp.bool_, sh)
     for f in (fan, None):
         pack_chip.lower(f, ids, ovf, pm=8192, pq=16384).compile()
+
+
+@pytest.mark.parametrize("B,L", [(8, 2), (64, 3), (256, 16)])
+def test_the_match_program_compiles_at_every_end_of_its_keys(one_chip,
+                                                             B, L):
+    """Its keys are the batch's bucket and the depth: the smallest
+    bucket at the shallowest depth, a paced batch's, and a flood
+    batch's at ``max_levels``, each beside the bucket's walk-free
+    merge. No key holds a count of hits or misses or a pack budget:
+    the program's only static shapes are the layout's."""
+    import inspect
+
+    from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
+                                          _mesh_merge_jit, walk_merge)
+
+    sh = one_chip
+    lay = BatchLayout(L, B, B, BATCH_BUF_FLOOR)
+    table = _s((65536, _M + 1), jnp.int32, sh)
+    buf = _s((lay.size,), jnp.int32, sh)
+    compiled = walk_merge.lower(
+        _auto_shapes(sh, False), None, table, table, buf, lay=lay, k=_K,
+        m=_M, steps=L + 1, slots=NARROW_SLOTS, take=1).compile()
+    assert compiled.memory_analysis().output_size_in_bytes >= \
+        65536 * (_M + 1) * 4
+    _mesh_merge_jit.lower(
+        table, buf, None, lay=BatchLayout(0, 0, B, lay.size), b_pad=B,
+        splits=None).compile()
+    names = set(inspect.signature(
+        walk_merge.__wrapped__).parameters)
+    assert not names & {"pm", "pq", "hit", "miss", "b_pad"}
 
 
 @pytest.mark.parametrize("n_data,n_trie", [(4, 1), (2, 2)])

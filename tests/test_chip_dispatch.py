@@ -1,12 +1,13 @@
 """One chip's match dispatch as the event loop pays for it
 (``Router._match_dispatch_cached`` + ``Broker._begin_device``): a
-batch leaves as ONE host→device transfer and two or three compiled
-programs, with no numpy argument and no eager operation, and hands
-``Broker._fetch_device`` bit for bit what the calls apart hand it:
-walk → (the delta's two-probe) → ``flag_rows`` → insert → merge →
+batch leaves as ONE host→device transfer and TWO compiled programs
+(the match: ``walk_merge``, or the merge alone where every topic hit;
+and ``pack_chip``), with no numpy argument and no eager operation, and
+hands ``Broker._fetch_device`` bit for bit what the calls apart hand
+it: walk → (the delta's two-probe) → ``flag_rows`` → insert → merge →
 ``mask_pad_rows`` → ``pack_matches`` → ``expand_packed`` →
 ``bundle_i32``. The one-chip twin of ``tests/test_mesh_dispatch.py``;
-the chip's runs are ``benchmark/``'s five one-chip cells.
+the chip's runs are ``benchmark/``'s seven one-chip cells.
 
 The calls apart are written here (``_apart``) over a second broker
 that is given the same subscriptions and the same batches, so both
@@ -100,7 +101,9 @@ def _same(got, want, what):
 def _apart(b, topics, pm, pq):
     """The dispatch with its calls apart, as it was before they were
     fused: every numpy argument a transfer, every step a program or an
-    eager operation of its own. The merge is plain numpy."""
+    eager operation of its own, the misses walked at a bucket of their
+    own (the one program walks them at the batch's). The merge is
+    plain numpy."""
     r = b.router
     cfg = r.config
     cache = r._match_cache()
@@ -249,7 +252,8 @@ def test_fused_equals_the_calls_apart_bit_for_bit(case):
         walked = 0 if again else len(uniq) - n_hit
         assert moved == {"dispatch.batches": 1, "dispatch.fused": 1,
                          "dispatch.topics": len(uniq),
-                         "dispatch.walk.topics": walked}, again
+                         "dispatch.walk.topics": walked,
+                         "dispatch.programs": 2}, again
         assert b.router._delta_probes - probes == (
             1 if delta is not None and walked else 0)
         _same(got, want, f"{case}: again={again}")
@@ -276,9 +280,9 @@ def test_fused_equals_the_calls_apart_bit_for_bit(case):
 
 def test_a_bitmap_filter_keeps_the_calls_apart():
     """Big-filter bitmaps live: the dispatch is still one transfer and
-    two programs, but the packers run apart (the bitmap kernels need
-    the dense ids), the fetch lays the bundle, and ``dispatch.fused``
-    stands still."""
+    one program, but the packers run apart (the bitmap kernels need
+    the dense ids: five device functions a batch), the fetch lays the
+    bundle, and ``dispatch.fused`` stands still."""
     kw = dict(fanout_threshold=4)
     b, ref = _broker(**kw), _broker(**kw)
     for x in (b, ref):
@@ -294,7 +298,9 @@ def test_a_bitmap_filter_keeps_the_calls_apart():
         assert b.publish_finish(pb) == [_want(b, t) for t in T[:20]]
     assert _counters(b) == {
         "dispatch.batches": 2, "dispatch.fused": 0,
-        "dispatch.topics": 40, "dispatch.walk.topics": 20}
+        "dispatch.topics": 40, "dispatch.walk.topics": 20,
+        # the dispatch's one launch; the packers apart count none
+        "dispatch.programs": 2}
 
 
 def test_the_cache_off_dispatch_keeps_the_calls_apart():
@@ -305,9 +311,11 @@ def test_the_cache_off_dispatch_keeps_the_calls_apart():
         assert (np.asarray(pb.ids_dev)[20:] == -1).all()
         b.publish_fetch(pb)
         assert b.publish_finish(pb) == [_want(b, t) for t in T[:20]]
+    # ``dispatch.programs`` counts the cache-split path's launches alone
     assert _counters(b) == {
         "dispatch.batches": 2, "dispatch.fused": 0,
-        "dispatch.topics": 40, "dispatch.walk.topics": 40}
+        "dispatch.topics": 40, "dispatch.walk.topics": 40,
+        "dispatch.programs": 0}
 
 
 # -- the fetch ----------------------------------------------------------------
@@ -372,7 +380,7 @@ def test_a_breaker_fallback_drops_the_laid_bundle():
 
 
 @pytest.mark.parametrize("delta", [False, True], ids=["plain", "delta"])
-def test_a_warm_batch_is_one_transfer_and_two_or_three_programs(
+def test_a_warm_batch_is_one_transfer_and_two_programs(
         delta, monkeypatch):
     b = _broker()
 
@@ -382,19 +390,18 @@ def test_a_warm_batch_is_one_transfer_and_two_or_three_programs(
     b.publish_batch(batch(0, 1, "first"))   # the first flatten
     if delta:
         _delta_live(b)
-    # warm every shape the counted batches use: (32, hit 8, miss 32),
-    # (32, hit 32, no miss), (32, hit 16, miss 16)
+    # warm both programs the counted batches use: the match at
+    # (bucket 32, depth 3) and the bucket's walk-free merge; a batch
+    # that splits another way (12 hits, 12 misses) is no new shape
     b.publish_batch(batch(0, 24, "w"))
     b.publish_batch(batch(0, 24, "w"))
-    b.publish_batch(batch(12, 36, "w"))
     loop = _Loop(monkeypatch)
+    before = _counters(b)
     for what, msgs, programs in (
-            ("misses", batch(40, 64, "c"),
-             ["walk_insert", "_mesh_merge_jit", "pack_chip"]),
+            ("misses", batch(40, 64, "c"), ["walk_merge", "pack_chip"]),
             ("all hit", batch(40, 64, "c"),
              ["_mesh_merge_jit", "pack_chip"]),
-            ("mixed", batch(52, 76, "c"),
-             ["walk_insert", "_mesh_merge_jit", "pack_chip"])):
+            ("mixed", batch(52, 76, "c"), ["walk_merge", "pack_chip"])):
         loop.reset()
         pb = b.publish_begin(msgs)
         assert (loop.eager, loop.transfers) == (0, 1), what
@@ -408,7 +415,50 @@ def test_a_warm_batch_is_one_transfer_and_two_or_three_programs(
             what
         assert b.publish_finish(pb) == [_want(b, m.topic) for m in msgs]
     c = _counters(b)
-    assert c["dispatch.fused"] == c["dispatch.batches"] == 7
+    assert c["dispatch.fused"] == c["dispatch.batches"] == 6
+    # the counter reads what the wrappers counted: two a batch
+    assert c["dispatch.programs"] - before["dispatch.programs"] == 6
+    assert c["dispatch.programs"] == 2 * c["dispatch.batches"]
+
+
+def test_hits_are_read_from_the_probes_snapshot(monkeypatch):
+    """Another batch's insert can land between a batch's probe and its
+    own insert (a second loop, the rewarm's thread): the one program
+    takes both tables, gathers the hits from the one the probe saw
+    and inserts into the current one."""
+    import types
+
+    import jax.numpy as jnp
+
+    b, ref = _broker(), _broker()
+    b.publish_batch([Message(topic=t) for t in T[:10]])
+    _apart(ref, T[:10], 64, 64)
+    cache = b.router._match_cache()
+    real, seen = cache.insert_through, []
+
+    def raced(self, probe, step):
+        with self._lock:
+            # the other batch's insert: a new array, in which this
+            # batch's hits no longer lie where the probe found them
+            self._table = self._table_now().at[
+                jnp.asarray(probe.hit_slots)].set(-1)
+            seen.append(self._table is not probe.table)
+        return real(probe, step)
+
+    monkeypatch.setattr(cache, "insert_through",
+                        types.MethodType(raced, cache))
+    got, delivered, (pm, pq), moved = _fused(b, T[:20])
+    monkeypatch.undo()
+    assert seen == [True] and moved["dispatch.programs"] == 2
+    _same(got, _apart(ref, T[:20], pm, pq), "raced")
+    assert delivered == [_want(b, t) for t in T[:20]]
+    # the misses went into the current table: they hit now, while the
+    # overwritten rows read as overflowed and are answered by the host
+    got, delivered, _budgets, moved = _fused(b, T[:20])
+    assert moved["dispatch.walk.topics"] == 0
+    assert got[1][:10].all() and not got[1][10:].any()
+    assert (got[0][10:20] >= 0).any(axis=1).all()
+    assert delivered == [_want(b, t) for t in T[:20]]
 
 
 _COMPILES = []
@@ -493,9 +543,10 @@ def test_a_merge_leaves_the_walks_program_the_one_it_was():
 
 
 def test_the_buffers_capacity_is_reached_by_the_first_batch():
-    """Its length is a shape of the walk and the merge: it must never
-    grow under traffic the ingress can form (1,024 unique topics, all
-    missing, at ``max_levels``)."""
+    """Its length is a shape of the match's program and of the merge:
+    it must never grow under traffic the ingress can form (1,024
+    unique topics, hits and misses both laid at that bucket, at
+    ``max_levels``)."""
     from emqx_tpu.ops.match_cache import BATCH_BUF_FLOOR, BatchLayout
 
     b = _broker()
@@ -505,7 +556,7 @@ def test_the_buffers_capacity_is_reached_by_the_first_batch():
     b.publish_batch([Message(topic=deep)] + [
         Message(topic=f"t/{i}/cap") for i in range(1023)])
     assert b.router._batch_buf_len == BATCH_BUF_FLOOR
-    assert BatchLayout.need(b.router.config.max_levels, 1024, 8) \
+    assert BatchLayout.need(b.router.config.max_levels, 1024, 1024) \
         <= BATCH_BUF_FLOOR
 
 
@@ -535,6 +586,7 @@ def test_fused_equals_batches_equals_the_spans(enabled):
         return
     assert got["dispatch.fused"] == got["dispatch.batches"] == len(spans) \
         == 5
+    assert got["dispatch.programs"] == 2 * got["dispatch.batches"]
     # every batch stamped its one transfer as its first device call
     assert all(path == "device" and enq for path, enq, *_x in spans)
     assert got["dispatch.walk.topics"] == sum(s[3] for s in spans) == 40

@@ -6,6 +6,7 @@ the sharded (mesh) cache on the 1×1 fast path."""
 import random
 
 import numpy as np
+import pytest
 
 from emqx_tpu.broker import Broker
 from emqx_tpu.oracle import TrieOracle
@@ -144,6 +145,50 @@ def test_the_buffers_sections_do_not_depend_on_each_other():
     assert lay.size == 2 * BATCH_BUF_FLOOR
     lay, _buf = c.batch_buffer(32, p, None, 20, lay.size)
     assert lay == BatchLayout(0, 0, 16, 2 * BATCH_BUF_FLOOR)
+
+
+@pytest.mark.parametrize("n_miss", [1, 10, 32])
+def test_one_chip_lays_hits_and_misses_at_the_batchs_bucket(n_miss):
+    """``rows`` = the batch's bucket: the layout is (L, B, B) however
+    the batch splits (and (0, 0, B) where it fully hit); the misses
+    come with ONE pad topic behind them, whose row fills their section,
+    bit for bit what encoding the pad B - n times would lay."""
+    from emqx_tpu.ops.match_cache import (BATCH_BUF_FLOOR, BatchLayout,
+                                          MatchCache)
+
+    B, L = 32, 3
+    c = MatchCache(64, 4)
+    topics = [f"t{i}" for i in range(B)]
+    p = c.probe(topics[n_miss:], 0)
+    _insert(c, p, np.zeros((B, 4), np.int32), np.zeros(B, bool))
+    p = c.probe(topics, 0)
+    assert len(p.miss_pos) == n_miss and len(p.hit_pos) == B - n_miss
+    rng = np.random.default_rng(n_miss)
+    real = (rng.integers(1, 99, (n_miss, L)).astype(np.int32),
+            np.full(n_miss, L, np.int32), rng.random(n_miss) < 0.5)
+    pad = (np.array([[7, 8, -1]], np.int32), np.array([2], np.int32),
+           np.array([False]))
+    enc = tuple(np.concatenate([r, q]) for r, q in zip(real, pad))
+    lay, buf = c.batch_buffer(B, p, enc, B, 0, rows=B)
+    assert lay == BatchLayout(L, B, B, BATCH_BUF_FLOOR)
+    full = tuple(np.concatenate([r] + [q] * (B - n_miss))
+                 for r, q in zip(real, pad))
+    lay2, buf2 = c.batch_buffer(B, p, full, B, 0, rows=B)
+    assert lay2 == lay and (buf2 == buf).all()
+    w, n, sysm, slots = lay.step_sections(buf)
+    assert (w == full[0]).all() and (n == full[1]).all()
+    assert (np.asarray(sysm) == full[2]).all()
+    assert slots[:n_miss].tolist() == p.miss_slots
+    assert (slots[n_miss:] == c.slots).all()
+    miss_pos, hit_slots, hit_pos, n_uniq = lay.merge_sections(buf)
+    assert miss_pos.tolist() == p.miss_pos + [B] * (B - n_miss)
+    assert hit_pos.tolist() == p.hit_pos + [B] * n_miss
+    assert hit_slots[:B - n_miss].tolist() == p.hit_slots and n_uniq == B
+    # the same topics again hit whole: no walk section, the bucket alone
+    lay, buf = c.batch_buffer(B, c.probe(topics[n_miss:], 0), None,
+                              B - n_miss, 0, rows=B)
+    assert lay == BatchLayout(0, 0, B, BATCH_BUF_FLOOR)
+    assert lay.merge_sections(buf)[3] == B - n_miss
 
 
 # -- single-device router path --------------------------------------------

@@ -7,8 +7,10 @@ topics. Held here at small size on the CPU:
     re-inserts, batch after batch;
 (b) ``Router.dispatch_shapes`` is complete: after ``Broker.
     warm_dispatch`` no batch of such traffic first-uses a program, and
-    below 512 unique topics the list holds all that the benchmark's
-    older sweep (``warmers/dispatch_buckets.py``) asked for;
+    below 512 unique topics the benchmark's older sweep
+    (``warmers/dispatch_buckets.py``) meets every program key it
+    lists; the match's program is keyed by the batch's bucket and the
+    depth, never by how many topics hit or miss;
 (c) the device-loss rewarm walks the same list;
 (d) the per-batch counters ``dispatch.topics`` /
     ``dispatch.walk.topics`` equal the spans' sums.
@@ -122,24 +124,29 @@ def _publish(b, sink, topics):
 
 class Recorder:
     """The shapes the dispatch's two keyed programs were called for:
-    the walk as ``(miss bucket, depth)``, the merge as ``(batch, hit,
-    miss)`` buckets (miss 0 = the batch fully hit)."""
+    one chip's match (walk, insert and merge) as ``(batch bucket,
+    depth)``, the merge of a batch that fully hit as ``(batch, hit,
+    miss)`` buckets = ``(bucket, bucket, 0)``."""
 
     def __init__(self, monkeypatch):
         self.walks, self.merges = set(), set()
-        walk, merge = match_cache.walk_insert, match_cache._mesh_merge_jit
+        walk, merge = match_cache.walk_merge, match_cache._mesh_merge_jit
 
         def rec_walk(*a, lay, **kw):
-            assert lay.hit == 0     # never keyed by the batch's hits
+            # hits and misses both at the batch's bucket: no count of
+            # either is a shape
+            assert lay.miss == lay.hit
             self.walks.add((lay.miss, lay.levels))
             return walk(*a, lay=lay, **kw)
 
-        def rec_merge(*a, lay, b_pad, **kw):
-            assert lay.levels == 0  # nor the merge by its depth
+        def rec_merge(table, buf, miss_vals, *, lay, b_pad, **kw):
+            # one chip comes here fully hit, keyed by its bucket alone
+            assert miss_vals is None and lay.levels == 0
+            assert (lay.miss, lay.hit) == (0, b_pad)
             self.merges.add((b_pad, lay.hit, lay.miss))
-            return merge(*a, lay=lay, b_pad=b_pad, **kw)
+            return merge(table, buf, miss_vals, lay=lay, b_pad=b_pad, **kw)
 
-        monkeypatch.setattr(match_cache, "walk_insert", rec_walk)
+        monkeypatch.setattr(match_cache, "walk_merge", rec_walk)
         monkeypatch.setattr(match_cache, "_mesh_merge_jit", rec_merge)
 
     def clear(self):
@@ -153,7 +160,7 @@ def _programs(router, shapes):
         w, m = router.shape_programs(s)
         walks.add(w)
         merges.add(m)
-    return walks - {None}, merges
+    return walks - {None}, merges - {None}
 
 
 @pytest.fixture
@@ -234,28 +241,66 @@ def _sweep_plan(floor, batch_size, depths):
                     if h + m <= top and key not in done:
                         done.add(key)
                         plan.append((h, m, depths[-1]))
-    walks = {(pad(m), d) for _h, m, d in plan if m}
-    merges = {(pad(h + m), pad(max(h, 1)), pad(m) if m else 0)
-              for h, m, _d in plan}
-    return walks, merges
+    # what the sweep's batches ask the program for: a batch with a
+    # miss the match at (batch bucket, depth), a fully hit one the
+    # bucket's merge
+    walks = {(pad(h + m), d) for h, m, d in plan if m}
+    merges = {(pad(h),) * 2 + (0,) for h, m, _d in plan if not m}
+    return walks, merges, len(plan)
 
 
 def test_the_list_holds_what_the_benchmarks_sweep_asked_for():
     """The padding rule for batches of up to 512 unique topics did not
-    change: the accepted one-chip cells are still warmed by
-    ``dispatch_buckets``, and the program's list covers its plan."""
+    change, and the accepted one-chip cells ``fleet_1m`` and
+    ``fanout_1k`` are still warmed by ``dispatch_buckets``: its plan
+    (a batch of misses for every bucket at every depth, a batch for
+    every (batch, hit, miss) triple) meets every program key the
+    program lists, so a run of theirs first uses nothing in its
+    window. The sweep is a superset now (its triples are batches of
+    programs it has met), no longer equal to the list."""
     r = Router(MatcherConfig())
     r._seen_levels.update((2, 3, 4, 5))
-    walks, merges = _programs(r, r.dispatch_shapes(512))
-    want_walks, want_merges = _sweep_plan(8, 256, [2, 3, 4, 5])
-    assert want_walks <= walks and want_merges <= merges
-    assert len(want_merges) > 60
+    shapes = r.dispatch_shapes(512)
+    walks, merges = _programs(r, shapes)
+    sweep_walks, sweep_merges, n_plan = _sweep_plan(8, 256, [2, 3, 4, 5])
+    assert walks <= sweep_walks and merges <= sweep_merges
+    assert walks == {(b, d) for b in (8, 16, 32, 64, 128, 256, 512)
+                     for d in (2, 3, 4, 5)}
+    assert merges == {(b, b, 0) for b in (8, 16, 32, 64, 128, 256, 512)}
+    # one batch a key, where the sweep sends one a triple as well
+    assert len(shapes) == len(walks) + len(merges) == 35 < 100 < n_plan
     # and the list for the ingress's cap goes one bucket further
     walks_cap, merges_cap = _programs(r, r.dispatch_shapes(CAP))
     assert walks < walks_cap and merges < merges_cap
     assert {(1024, d) for d in (2, 3, 4, 5)} == walks_cap - walks
-    assert all(b == 1024 or mb == 1024 or hb == 1024
-               for b, hb, mb in merges_cap - merges)
+    assert merges_cap - merges == {(1024, 1024, 0)}
+
+
+@pytest.mark.parametrize("hits,misses", [
+    (0, 20), (1, 19), (10, 10), (19, 1), (3, 14), (16, 1), (0, 17)])
+def test_the_match_is_keyed_by_the_bucket_and_the_depth_alone(
+        hits, misses, monkeypatch):
+    """However a batch of bucket 32 splits into hits and misses, it
+    asks for the one program of (32, its deepest miss); the same
+    topics again for the bucket's merge."""
+    r = Router(MatcherConfig())
+    for d in (2, 3, 4):
+        assert r.shape_programs(DispatchShape(hits, misses, d)) == (
+            (32, d), None)
+    assert r.shape_programs(DispatchShape(hits + misses, 0, 4)) == (
+        None, (32, 32, 0))
+    b, sink = _broker(_population(5)[:200], match_cache_slots=4096,
+                      device_min_filters=0)
+    rng = random.Random(hits * 31 + misses)
+    old = _unique(rng, 64)
+    _publish(b, sink, old)
+    rec = Recorder(monkeypatch)
+    topics = old[:hits] + _unique(rng, misses, avoid=set(old))
+    depth = max(t.count("/") + 1 for t in topics[hits:])
+    for want in (({(32, depth)}, set()), (set(), {(32, 32, 0)})):
+        _publish(b, sink, topics)
+        assert (rec.walks, rec.merges) == want
+        rec.clear()
 
 
 def test_on_a_mesh_the_list_follows_the_mesh_rule():
@@ -325,7 +370,11 @@ def test_after_the_warm_function_traffic_first_uses_no_program(
     list_walks, list_merges = _programs(b.router, shapes)
     rec = Recorder(monkeypatch)
     driven = [s for _secs, s in b.warm_dispatch(CAP)]
-    assert [s for s in driven if s in set(shapes)] == shapes
+    # the list, and the one batch that lays the topics to hit (it has
+    # the shape of one of the list's batches of misses)
+    hot = DispatchShape(0, max(s.hits for s in shapes), 2)
+    assert sorted(driven) == sorted(shapes + [hot])
+    assert [s for s in driven if s.hits] == [s for s in shapes if s.hits]
     assert (rec.walks, rec.merges) == (list_walks, list_merges)
     rec.clear()
     c0, last, n_big = compiles.compiles, [], 0
@@ -349,8 +398,9 @@ def test_after_the_warm_function_traffic_first_uses_no_program(
     assert compiles.compiles == c0, "a program was first used after the walk"
     assert n_big > 5 and rec.walks <= list_walks
     assert rec.merges <= list_merges
-    assert {hb for _b, hb, _mb in rec.merges} >= {8, 64}
-    assert any(b_ == 1024 for b_, _hb, _mb in rec.merges)
+    # batches of the largest bucket walked, and small ones hit whole
+    assert {b_ for b_, _d in rec.walks} >= {8, 64, 1024}
+    assert {b_ for b_, _hb, _mb in rec.merges} >= {8}
     # a second walk has nothing to make ready either
     assert sum(1 for _ in b.warm_dispatch(CAP)) == len(driven)
     assert compiles.compiles == c0
@@ -375,7 +425,8 @@ def test_the_devloss_rewarm_walks_the_list_for_the_traffic_seen(
     rec = Recorder(monkeypatch)
     n = b.warm_device_path()
     assert (rec.walks, rec.merges) == want
-    assert n >= len(b.router.dispatch_shapes(64)) > 30
+    # 4 buckets × 4 depths, and a fully hit batch a bucket
+    assert n >= len(b.router.dispatch_shapes(64)) == 20
     assert max(b._pack_budgets) == 64   # and learns no larger bucket
     topics = _unique(rng, 60)
     trie = DictTrie(filters)
