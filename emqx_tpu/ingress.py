@@ -18,7 +18,7 @@ thread: the dispatch-plan grouping pass and the egress
 pre-serialization of wire images/templates (docs/DISPATCH.md), so
 the loop-side tail is little more than buffer writes. Up to
 ``max_inflight`` batches may be in the pipeline at once. What that
-depth is for: at ``batch_size`` pending (a flood) the next batch's
+depth is for: at the size trigger (a flood) the next batch's
 ``publish_begin`` runs on the loop while the executor fetches the
 last one's, so neither thread waits for the other. What it is not
 for: hiding the device's round trip behind ticks of a few messages.
@@ -29,13 +29,13 @@ batch's tail waits behind the begins of the batches opened beside it
 tail awaits batch N's, so per-publisher in-order semantics hold
 across batch boundaries.
 
-Flush policy: a batch flushes when it reaches ``batch_size``, else on
-the next event-loop iteration (``call_soon`` — "everything that
+Flush policy: a batch flushes when it reaches the size trigger, else
+on the next event-loop iteration (``call_soon`` — "everything that
 arrived this tick"), or after ``linger_ms`` when configured (trades
 latency for bigger device batches under light load) — **unless a
 batch stands on the device path** (between its ``publish_begin`` and
-the return of its fetch, ``_on_path``): a flush short of
-``batch_size`` is then held, and what it would have taken leaves with
+the return of its fetch, ``_on_path``): a flush short of the size
+trigger is then held, and what it would have taken leaves with
 the flush that the landed batch's completion schedules, as one batch
 with whatever arrived meanwhile. The release is the *completion*
 (``_complete``'s ``finally``), not the fetch's return: the landed
@@ -44,14 +44,23 @@ tail and, on a multi-loop node, its cross-loop join (bounded by
 ``Broker.XLOOP_JOIN_TIMEOUT``) all come first — nothing could be
 acked ahead of that batch anyway. A held flush waits for nothing but
 that or the size trigger: no timer, and with the path free at every
-tick the policy is the tick's. The size trigger is ``batch_size``,
-and beside an occupied path also the high-water mark where critical
-overload has divided it under ``batch_size`` (``_full``): the
-accumulator cannot pass the mark, and the rule must not pin an
-overloaded node's pipeline at depth 1. When all ``max_inflight``
-slots are busy, arrivals keep accumulating and flush as a bigger
-batch the moment a slot frees — backpressure becomes batch growth,
-exactly the regime the device prefers.
+tick the policy is the tick's.
+
+The size trigger (``_trigger``) is ``batch_size`` with the pipeline
+empty. **While a begun batch has not completed** (``_inflight`` > 0:
+on the device path, or landed with its tail not done) it is
+``2 × batch_size``, or the high-water mark where that is lower (an
+explicit ``queue_hiwater``, or critical overload dividing the mark:
+the accumulator cannot pass the mark, and the rule must not pin an
+overloaded node's pipeline at depth 1). The dispatch is a fixed price
+a batch, so what arrives during one batch's life leaves as one batch
+at its completion (PERF.md §6, PR 44); at the grown trigger the next
+slot opens beside the batches in flight, up to ``max_inflight``. The
+default mark is the same ``2 × batch_size``: the readers park there
+(why twice and not ``batch_cap``: docs/DISPATCH.md, "Why twice"). When
+all ``max_inflight`` slots are busy, arrivals keep accumulating and
+flush as a bigger batch the moment a slot frees — backpressure becomes
+batch growth, exactly the regime the device prefers.
 
 Callers without a running event loop (sync drivers, unit tests that
 poke the channel directly) fall back to the synchronous path:
@@ -127,8 +136,9 @@ class IngressBatcher:
         # unbounded standing queue and every delivery's tail latency
         # becomes queue depth (round-4: 627ms p99 at saturation).
         # Bounding here moves the queue into the publishers' TCP
-        # buffers, where backpressure belongs.
-        self.queue_hiwater = queue_hiwater or batch_size
+        # buffers, where backpressure belongs. The default is the
+        # size trigger beside a batch in the pipeline (``_trigger``)
+        self.queue_hiwater = queue_hiwater or 2 * batch_size
         # delivery-tail streaming: yield to the event loop every this
         # many finished rows so early deliveries flush while later
         # rows still route
@@ -145,7 +155,7 @@ class IngressBatcher:
         self._inflight = 0
         # batches on the DEVICE PATH: enqueued by publish_begin, their
         # publish_fetch not yet back on the loop. While it is not 0 a
-        # flush short of ``batch_size`` is held (``_flush``). With
+        # flush short of the size trigger is held (``_flush``). With
         # ``_inflight`` and ``_pending`` it is also what the selector's
         # shadow (monitors.SysMon) reads to say what the loop waits
         # for; all three change on the home loop (a peer loop's append
@@ -265,8 +275,9 @@ class IngressBatcher:
     def submit_many(self, msgs: List[Message]) -> bool:
         """Queue a connection's run of fire-and-forget messages
         (QoS0: no future) as ``submit(msg, want_result=False)`` called
-        on each in turn would — a flush at exactly the ``batch_size``
-        boundary, so the batches are the same lists — with one look
+        on each in turn would — a flush at exactly the size trigger's
+        boundary (``_trigger``, read again after every flush), so the
+        batches are the same lists — with one look
         at the loop and at tracing for the run. False = no running
         loop, nothing queued: the caller publishes synchronously.
 
@@ -291,7 +302,7 @@ class IngressBatcher:
             # up to the boundary; over it (a standing backlog: every
             # slot was busy at the last flush) one at a time, each
             # with its own try at a flush, as submit() does
-            j = min(n, i + max(1, self.batch_size - len(pend)))
+            j = min(n, i + max(1, self._trigger() - len(pend)))
             pend.extend([(m, None) for m in msgs[i:j]])
             self.submitted += j - i
             i = j
@@ -409,17 +420,26 @@ class IngressBatcher:
             hw = max(1, hw // self._pressure_div)
         return hw
 
+    def _trigger(self) -> int:
+        """The size trigger: ``batch_size`` with the pipeline empty;
+        while a begun batch has not completed (on the device path, or
+        landed with its tail not done) ``2 × batch_size``, so that
+        what arrives during one batch's life pays one dispatch and not
+        two; or the high-water mark where that is lower (an explicit
+        ``queue_hiwater``; critical overload divides it,
+        ``set_pressure``): the accumulator stands at the mark with the
+        readers parked, and a flush held for more (``_flush``) would
+        pin the pipeline's depth at 1 just when the node is
+        overloaded."""
+        if not self._inflight:
+            return self.batch_size
+        return min(2 * self.batch_size, self._hiwater())
+
     def _full(self, n: int) -> bool:
-        """``n`` pending is a batch's worth, the size trigger:
-        ``batch_size``; beside an occupied device path also the
-        high-water mark where that lies under ``batch_size`` (critical
-        overload divides it, ``set_pressure``). The accumulator then
-        stands at the mark with the readers parked and cannot reach
-        ``batch_size``: a flush held for it (``_flush``) would pin the
-        pipeline's depth at 1 just when the node is overloaded. With
-        the path free the tick's flush takes what there is, as ever."""
-        return n >= self.batch_size or (
-            self._on_path > 0 and n >= self._hiwater())
+        """``n`` pending is a batch's worth (``_trigger``). Short of
+        it, with the path free, the tick's flush takes what there is,
+        as ever."""
+        return n >= self._trigger()
 
     def backlogged(self) -> bool:
         """Accumulator at/over the high-water mark — connections
@@ -610,6 +630,8 @@ class IngressBatcher:
             span = None
             tel = self.broker.telemetry
             if tel is not None and tel.enabled:
+                if len(pending) > self.batch_size:
+                    self.broker.metrics.inc("ingress.flush.grown")
                 # the span starts at the batch's first arrival; a
                 # capped take restarts the clock for what it leaves
                 # behind (those arrivals carry no stamp of their own)

@@ -476,13 +476,16 @@ CHANNEL_METRICS = [
 # publisher's park counts up to its time-out). ns ÷ parks is what one
 # park costs a publisher; parks ÷ ``messages.received`` how often
 # traffic meets it. ``flush.held`` (IngressBatcher._flush, gated
-# alike) = flushes short of ``batch_size`` that found a batch on the
+# alike) = flushes short of the size trigger that found a batch on the
 # device path and began nothing: what they held leaves with the flush
 # that batch's completion schedules. held ÷ ``dispatch.batches`` is
-# how often a tick met an occupied path
+# how often a tick met an occupied path. ``flush.grown`` (gated alike)
+# = takes of more than ``batch_size`` messages: batches that grew
+# beside a batch in the pipeline (the size trigger is 2 × batch_size
+# there, IngressBatcher._trigger) or behind a full one
 INGRESS_METRICS = [
     "ingress.parks", "ingress.park.ns", "ingress.wakes",
-    "ingress.flush.held",
+    "ingress.flush.held", "ingress.flush.grown",
 ]
 
 # the fan-out tables' syncs that changed them

@@ -127,8 +127,28 @@ class PathGate:
     def land(self):
         self.gate.set()
 
-    def counted(self):
-        return self.node.metrics.val("ingress.flush.held")
+    def hold_tails(self):
+        """Every batch whose fetch returns from now on delivers and
+        then waits, its tail not done, where a multi-loop node's
+        cross-loop join stands in ``_complete``."""
+        self.tails = tails = asyncio.Event()
+        self.node.broker.xloop_event = lambda pb: tails
+
+    def finish(self):
+        self.tails.set()
+
+    async def landed(self, n=1):
+        """``n`` batches off the device path, their tails not done."""
+        await until(lambda: self.ing._on_path == 0
+                    and self.ing._inflight == n,
+                    f"never {n} landed with the tail not done")
+
+    def counted(self, what="held"):
+        return self.node.metrics.val(f"ingress.flush.{what}")
+
+    def grown(self):
+        """Takes of more than ``batch_size`` messages, from outside."""
+        return sum(len(b) > self.ing.batch_size for b in self.began)
 
     async def on_the_path(self, n=1):
         await until(lambda: self.ing._on_path == n,

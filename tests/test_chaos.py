@@ -571,6 +571,29 @@ def test_queue_depth_drives_level_and_ingress_pressure():
     assert not ing.backlogged()
 
 
+def test_a_full_accumulator_of_a_healthy_node_stays_ok():
+    """The monitor's queue ratio is taken against the mark the
+    admission line uses (``2 × batch_size`` by default): the fullest a
+    healthy node's accumulator gets, the mark less one and a reader's
+    whole 64 KiB chunk of small PUBLISHes, reads under ``queue_warn``
+    with the default guard, and no transition is counted."""
+    node = _device_node()
+    ing, ov = node.ingress, node.overload
+    assert ing.queue_hiwater == ing._mark() == 2 * ing.batch_size
+    full = ing.queue_hiwater - 1 + 234
+    ing._pending.extend([(None, None)] * full)
+    assert ing.backlogged()
+    for _ in range(4):
+        assert ov.tick(0.0) == OK
+    assert ov.samples["ingress_hiwater"] == ing._mark()
+    assert ov.samples["ingress_queue"] / ov.samples["ingress_hiwater"] \
+        < 1.5 < ov.cfg.queue_warn
+    assert node.metrics.val("overload.transitions") == 0
+    # against batch_size, which the mark was, the same queue would warn
+    assert full / ing.batch_size >= ov.cfg.queue_warn
+    ing._pending.clear()
+
+
 def test_warn_sheds_qos0_at_mqueue_pressure():
     node = _device_node()
     sess = Session("shed", broker=node.broker, max_mqueue_len=8,

@@ -373,9 +373,12 @@ async def test_flush_during_completion_cannot_reorder_or_double_resolve():
 #
 # While a batch stands on the device path (between its publish_begin
 # and the return of its fetch, ``_on_path``), a flush short of
-# ``batch_size`` begins nothing: what it holds leaves with the flush
+# the size trigger begins nothing: what it holds leaves with the flush
 # that the landed batch's completion schedules. The size trigger
-# begins beside an occupied path as before, up to ``max_inflight``.
+# (``_trigger``) is ``batch_size`` with the pipeline empty and
+# ``2 × batch_size`` (or the mark, where that is lower) while a begun
+# batch has not completed; at it the next slot opens beside the
+# batches in flight, up to ``max_inflight``.
 
 
 async def _path_node(name, **kw):
@@ -430,39 +433,158 @@ async def test_a_tick_beside_an_occupied_path_waits_for_the_landing():
         await node.stop()
 
 
-async def test_batch_size_pending_begins_beside_an_occupied_path():
+async def test_twice_batch_size_pending_begins_beside_an_occupied_path():
     node, s, p = await _path_node("size@test", batch_size=4)
     try:
         ing = p.ing
-        assert ing.max_inflight == 4
+        assert ing.max_inflight == 4 and ing.queue_hiwater == 8
         futs = [ing.submit(Message(topic="w/0"))]
         await p.on_the_path()
         k = 1
         for depth in (2, 3, 4):
-            # a tick's pair is held ...
-            futs += [ing.submit(Message(topic=f"w/{k + i}"))
-                     for i in range(2)]
-            await _ticks()
-            assert ing._on_path == depth - 1 and len(ing._pending) == 2
-            # ... and at batch_size pending the batch begins at once
-            futs += [ing.submit(Message(topic=f"w/{k + 2 + i}"))
+            # a tick's pair is held, and so is batch_size pending ...
+            for n in (2, 4, 6):
+                futs += [ing.submit(Message(topic=f"w/{k + n - 2 + i}"))
+                         for i in range(2)]
+                await _ticks()
+                assert ing._on_path == depth - 1
+                assert len(ing._pending) == n
+            # ... and at twice batch_size the batch begins at once
+            futs += [ing.submit(Message(topic=f"w/{k + 6 + i}"))
                      for i in range(2)]
             assert ing._on_path == ing._inflight == depth
             assert not ing._pending
-            assert p.began[-1] == [f"w/{k + i}" for i in range(4)]
-            k += 4
-        # every slot busy: batch_size pending stands, as ever
+            assert p.began[-1] == [f"w/{k + i}" for i in range(8)]
+            k += 8
+        # every slot busy: the accumulator stands at the mark, as ever
         futs += [ing.submit(Message(topic=f"w/{k + i}"))
-                 for i in range(4)]
+                 for i in range(8)]
         await _ticks()
-        assert ing._on_path == 4 and len(ing._pending) == 4
-        assert len(p.began) == 4
+        assert ing._on_path == 4 and len(ing._pending) == 8
+        assert ing.backlogged() and len(p.began) == 4
         # held by the rule three times; the full pipeline is not its
         assert p.held == p.counted() == 3
         p.land()
         assert await asyncio.gather(*futs) == [1] * len(futs)
-        assert s.got == [f"w/{i}" for i in range(k + 4)]
+        assert s.got == [f"w/{i}" for i in range(k + 8)]
         assert ing._on_path == 0 and ing._inflight == 0
+        # three batches of 8 beside the path and the backlog's
+        assert p.grown() == p.counted("grown") == 4
+    finally:
+        await node.stop()
+
+
+@pytest.mark.parametrize("where", ["on_the_path", "landed"])
+async def test_a_batch_grows_while_a_batch_is_in_the_pipeline(where):
+    """Arrivals past ``batch_size`` and short of twice it begin
+    nothing while a begun batch has not completed, be it on the device
+    path or landed with its tail not done, and leave as one batch at
+    that completion; with arrivals stopped nothing strands."""
+    node, s, p = await _path_node(f"grown-{where}@test", batch_size=4)
+    try:
+        ing = p.ing
+        p.hold_tails()
+        futs = [ing.submit(Message(topic="w/0"))]
+        await p.on_the_path()
+        futs += [ing.submit(Message(topic=f"w/{i}")) for i in (1, 2)]
+        await _ticks()
+        if where == "landed":
+            p.land()
+            await p.landed()
+        for i in range(3, 8):           # past batch_size = 4, one a tick
+            futs.append(ing.submit(Message(topic=f"w/{i}")))
+            await _ticks()
+            assert p.began == [["w/0"]] and ing._inflight == 1
+            assert len(ing._pending) == i
+        assert not any(f.done() for f in futs[1:])
+        p.land()
+        p.finish()
+        assert await asyncio.gather(*futs) == [1] * 8
+        await _ticks()
+        assert p.began == [["w/0"], [f"w/{i}" for i in range(1, 8)]]
+        assert s.got == [f"w/{i}" for i in range(8)]
+        assert not ing._pending
+        assert ing._on_path == 0 and ing._inflight == 0
+        assert p.grown() == p.counted("grown") == 1
+    finally:
+        await node.stop()
+
+
+async def test_beside_a_landed_batch_twice_batch_size_opens_a_slot():
+    """The trigger reads the pipeline (``_inflight``), not the path:
+    beside a batch that landed with its tail not done, ``batch_size``
+    pending begins nothing and twice it begins a batch at once."""
+    node, s, p = await _path_node("landed-size@test", batch_size=4)
+    try:
+        ing = p.ing
+        p.hold_tails()
+        futs = [ing.submit(Message(topic="w/0")),
+                ing.submit(Message(topic="w/1"))]
+        await p.on_the_path()
+        futs.append(ing.submit(Message(topic="w/2")))
+        await _ticks()
+        p.land()
+        await p.landed()
+        futs += [ing.submit(Message(topic=f"w/{i}")) for i in range(3, 8)]
+        await _ticks()
+        assert ing._trigger() == 8 and len(ing._pending) == 6
+        assert len(p.began) == 1
+        futs += [ing.submit(Message(topic=f"w/{i}")) for i in (8, 9)]
+        assert not ing._pending and ing._inflight == 2
+        assert p.began[1] == [f"w/{i}" for i in range(2, 10)]
+        p.finish()
+        assert await asyncio.gather(*futs) == [1] * 10
+        assert s.got == [f"w/{i}" for i in range(10)]
+        assert ing._on_path == 0 and ing._inflight == 0
+        assert p.grown() == p.counted("grown") == 1
+    finally:
+        await node.stop()
+
+
+async def test_an_empty_pipeline_triggers_at_batch_size():
+    node, s, p = await _path_node("empty@test", batch_size=4)
+    try:
+        ing = p.ing
+        assert ing._inflight == 0 and ing._trigger() == 4
+        futs = [ing.submit(Message(topic=f"w/{i}")) for i in range(4)]
+        # the fourth began the batch inline, no tick between
+        assert p.began == [[f"w/{i}" for i in range(4)]]
+        assert ing._inflight == 1 and not ing._pending
+        assert ing._trigger() == 8
+        p.land()
+        assert await asyncio.gather(*futs) == [1] * 4
+        assert ing._trigger() == 4
+        assert p.grown() == p.counted("grown") == 0
+    finally:
+        await node.stop()
+
+
+@pytest.mark.parametrize("mark", [3, 6, 8, 100])
+async def test_an_explicit_mark_under_twice_batch_size_is_the_trigger(mark):
+    """``queue_hiwater`` is honoured as given: beside a batch in the
+    pipeline the size trigger is the mark where that lies under
+    ``2 × batch_size`` (the accumulator cannot pass it), else twice
+    ``batch_size``; with the pipeline empty it is ``batch_size``."""
+    node, s, p = await _path_node(f"mark{mark}@test", batch_size=4)
+    try:
+        ing = p.ing
+        ing.queue_hiwater = mark
+        want = min(8, mark)
+        assert ing._trigger() == 4
+        futs = [ing.submit(Message(topic="w/0"))]
+        await p.on_the_path()
+        assert ing._trigger() == want
+        for i in range(1, want):
+            futs.append(ing.submit(Message(topic=f"w/{i}")))
+            await _ticks()
+            assert len(ing._pending) == i and len(p.began) == 1
+        futs.append(ing.submit(Message(topic=f"w/{want}")))
+        assert not ing._pending and ing._inflight == 2
+        assert p.began[1] == [f"w/{i}" for i in range(1, want + 1)]
+        p.land()
+        assert await asyncio.gather(*futs) == [1] * (want + 1)
+        assert s.got == [f"w/{i}" for i in range(want + 1)]
+        assert p.grown() == p.counted("grown") == int(want > 4)
     finally:
         await node.stop()
 
@@ -530,10 +652,11 @@ async def test_at_critical_overload_the_mark_is_the_size_trigger():
     node, s, p = await _path_node("parked@test", batch_size=16)
     try:
         ing = p.ing
-        ing.set_pressure(4)
-        assert ing._mark() == 4 < ing.batch_size
+        ing.set_pressure(8)     # critical: 2 × batch_size = 32 → 4
+        assert ing._mark() == 4 < ing.batch_size == ing._trigger()
         futs = [ing.submit(Message(topic="w/0"))]
         await p.on_the_path()
+        assert ing._trigger() == 4      # the divided mark
         k = 1
         for depth in (2, 3, 4):
             futs += [ing.submit(Message(topic=f"w/{k + i}"))
@@ -595,6 +718,17 @@ async def test_the_rule_needs_no_telemetry():
         assert p.began == [["w/0"], ["w/1", "w/2"]]
         assert s.got == ["w/0", "w/1", "w/2"]
         assert ing._on_path == 0 and p.counted() == 0
+        # a batch grows with telemetry off too, and counts nothing
+        p.gate.clear()
+        futs = [ing.submit(Message(topic="w/3"))]
+        await p.on_the_path()
+        futs += [ing.submit(Message(topic=f"w/{i}")) for i in range(4, 24)]
+        await _ticks()
+        assert len(ing._pending) == 20 > ing.batch_size
+        p.land()
+        assert await asyncio.gather(*futs) == [1] * 21
+        assert p.began[-1] == [f"w/{i}" for i in range(4, 24)]
+        assert p.grown() == 1 and p.counted("grown") == 0
     finally:
         await node.stop()
 

@@ -33,8 +33,8 @@ class _Broker:
         self.telemetry = types.SimpleNamespace(enabled=timed)
 
 
-def _batcher(mode, mark=MARK, timed=True):
-    ing = IngressBatcher(_Broker(timed), batch_size=10 ** 6,
+def _batcher(mode, mark=MARK, timed=True, batch_size=10 ** 6):
+    ing = IngressBatcher(_Broker(timed), batch_size=batch_size,
                          queue_hiwater=mark)
     if mode == "multi":
         ing.broker.metrics.enable_threadsafe()
@@ -103,6 +103,37 @@ async def test_under_the_mark_with_nobody_waiting_is_admitted_at_once(mode):
     assert await ing.admit(500) is True  # whatever it holds
     assert ing.waiting() == 0 and ing._timer is None
     assert _counts(ing) == (0, 0)  # it never parked
+
+
+@MODES
+async def test_the_default_mark_is_twice_batch_size(mode):
+    """With no ``queue_hiwater`` given the readers are admitted up to
+    ``2 × batch_size`` pending, the size trigger beside a batch in the
+    pipeline, and park there; a wake a park."""
+    ing = _batcher(mode, mark=0, batch_size=8)
+    assert ing.queue_hiwater == ing._mark() == 16
+    ing._inflight = 1  # a batch in the pipeline: nothing here flushes
+    assert ing._trigger() == 16
+    r = _Readers(ing)
+    for name in "abc":  # 5 + 5 + 5 = 15: under the mark, all at once
+        r.start(name, 5)
+    await _settle()
+    assert r.names() == ["a", "b", "c"] and len(ing._pending) == 15
+    assert _counts(ing) == (0, 0)
+    r.start("d", 5)  # 15 < 16: the head goes whatever its weight
+    await _settle()
+    assert len(ing._pending) == 20 and ing.backlogged()
+    r.start("e", 5)
+    r.start("f", 5)
+    await _settle()
+    assert ing.waiting() == 2 and _counts(ing) == (2, 0)
+    assert _take(ing, 20) == 20  # the grown batch leaves as one
+    await _settle(8)
+    assert r.names() == list("abcdef") and len(ing._pending) == 10
+    assert ing.waiting() == 0 and ing._granted == 0
+    assert _counts(ing) == (2, 2) and _invariant(ing)
+    ing.set_pressure(4)  # critical overload divides the new mark
+    assert ing._mark() == ing._trigger() == 4
 
 
 @MODES

@@ -21,6 +21,7 @@ import asyncio
 import json
 import os
 import random
+import types
 
 import pytest
 
@@ -349,24 +350,39 @@ async def test_counters_a_run_moves_are_the_ones_ctl_metrics_shows():
 # -- submit_many against N x submit ------------------------------------------
 
 
-async def _batches(how, sizes, backlog_at=None, batch_size=16):
+async def _batches(how, sizes, backlog_at=None, batch_size=16,
+                   inflight=None, multi=False):
+    """The batches that ``sizes`` runs form, submitted as runs
+    (``many``) or a message at a time. At run ``backlog_at`` the
+    pipeline reads ``inflight`` batches (default: every slot busy, so
+    arrivals stand in the accumulator past the boundary; 1 = a batch
+    in the pipeline, so the size trigger is twice ``batch_size``)."""
     node = await _node(f"pr-sm-{how}@test", batch_size=batch_size)
     try:
         ing = node.broker.ingress
+        if multi:
+            node.metrics.enable_threadsafe()
+            ing.bind_multiloop(types.SimpleNamespace(
+                home=asyncio.get_running_loop()))
         got = []
         begin = node.broker.publish_begin
+        appended, calls = ing._appended, [0]
 
         def record(msgs, *a, **kw):
             got.append([m.topic for m in msgs
                         if not m.topic.startswith("$SYS/")])
             return begin(msgs, *a, **kw)
+
+        def counted(*a):
+            calls[0] += 1
+            appended(*a)
         node.broker.publish_begin = record
+        ing._appended = counted
         k = 0
         for r, size in enumerate(sizes):
             if r == backlog_at:
-                # every pipeline slot busy: arrivals stand in the
-                # accumulator past the boundary
-                ing._inflight = ing.max_inflight
+                ing._inflight = ing.max_inflight if inflight is None \
+                    else inflight
             run = [Message(topic=f"t/{k + i}") for i in range(size)]
             k += size
             if how == "many":
@@ -375,34 +391,54 @@ async def _batches(how, sizes, backlog_at=None, batch_size=16):
                 for m in run:
                     assert ing.submit(m, want_result=False) is ing._DONE
             if r == backlog_at:
-                assert len(ing._pending) >= batch_size
+                if inflight is None:
+                    assert len(ing._pending) >= batch_size
                 ing._inflight = 0
             if r % 3 == 2:
                 await asyncio.sleep(0)   # the call_soon'd flush
         await ing.drain()
         return [b for b in got if b], ing.flushes, ing.submitted, \
-            ing.max_queue
+            ing.max_queue, calls[0]
     finally:
         await node.stop()
 
 
-@pytest.mark.parametrize("sizes,backlog_at", [
-    ([32] * 6, None),
-    ([1, 15, 16, 17, 31, 32, 3], None),
-    ([5, 32, 32, 7, 1, 1, 30], None),
-    ([16, 16, 16], None),
-    ([32, 20, 9, 32], 1),
-    ([7, 32, 32, 32, 2], 2),
+@pytest.mark.parametrize("multi", [False, True])
+@pytest.mark.parametrize("sizes,backlog_at,inflight", [
+    ([32] * 6, None, None),
+    ([1, 15, 16, 17, 31, 32, 3], None, None),
+    ([5, 32, 32, 7, 1, 1, 30], None, None),
+    ([16, 16, 16], None, None),
+    ([32, 20, 9, 32], 1, None),
+    ([7, 32, 32, 32, 2], 2, None),
+    # a batch in the pipeline at run ``backlog_at``: the boundary is
+    # twice batch_size there, and batch_size again after it
+    ([3, 100, 20], 1, 1),
+    ([16, 70, 5, 40], 1, 1),
+    ([9, 9, 31, 33, 64], 3, 1),
+    ([200], 0, 2),
 ])
-async def test_submit_many_flushes_where_n_submits_would(sizes,
-                                                         backlog_at):
-    many = await _batches("many", sizes, backlog_at)
-    one = await _batches("one", sizes, backlog_at)
+async def test_submit_many_flushes_where_n_submits_would(
+        sizes, backlog_at, inflight, multi):
+    many = await _batches("many", sizes, backlog_at, inflight=inflight,
+                          multi=multi)
+    one = await _batches("one", sizes, backlog_at, inflight=inflight,
+                         multi=multi)
     assert many[0] == one[0]          # the batches, list for list
-    assert many[1:] == one[1:]        # flushes, submitted, max_queue
+    assert many[1:4] == one[1:4]      # flushes, submitted, max_queue
     assert sum(len(b) for b in many[0]) == sum(sizes)
     if backlog_at is None:
         assert max(len(b) for b in many[0]) <= 16
+    elif inflight is not None:
+        assert max(len(b) for b in many[0]) == 32
+    if multi:
+        return  # a peer loop's appends interleave: one at a time
+    # ``_appended`` once a boundary and once for what a run leaves
+    # short of it, not once a message (over a standing backlog a
+    # message at a time, as submit() does)
+    assert one[4] == sum(sizes)
+    if inflight is not None or backlog_at is None:
+        assert many[4] <= len(many[0]) + len(sizes)
 
 
 def test_submit_many_without_a_loop_queues_nothing():
