@@ -1161,19 +1161,40 @@ class Broker:
         (must run where broker state is owned, i.e. the event loop)."""
         if pb.done:
             return pb.results
-        if pb.host_topics is not None:
-            self.publish_host_chunk(pb, 0, len(pb.live))
-            pb.done = True
-            return pb.results
-        if pb.plan is not None:
-            self.publish_finish_planned(pb, 0, pb.plan.n_groups)
-            # multi-loop: block until the cross-loop handoffs report
-            # back, then fold (no-op on a single-loop node)
-            self.xloop_join_sync(pb)
-        else:
-            self.publish_finish_chunk(pb, 0, len(pb.live))
+        for _ in self.finish_steps(pb):
+            pass
+        # multi-loop: block until the cross-loop handoffs report
+        # back, then fold (no-op on a single-loop node)
+        self.xloop_join_sync(pb)
         pb.done = True
         return pb.results
+
+    @owner_loop
+    def finish_steps(self, pb: PendingBatch,
+                     chunk: Optional[int] = None):
+        """The delivery tail of a begun (and, on the device path,
+        fetched) batch as a generator: picks the tail once, runs
+        ``chunk`` units a step (``None``: all in one) and yields
+        BETWEEN steps, so the async ingress can give the loop back
+        there and finished work's deliveries flush to subscriber
+        sockets while the rest still routes. The unit depends on the
+        tail: deferred host routing and the legacy packed walk step
+        over LIVE ROWS; a planned batch steps over SUBSCRIBER GROUPS
+        (each session still gets its whole batch in one deliver_many
+        and one wakeup). The caller joins the cross-loop handoffs
+        (:meth:`xloop_join_sync` / :meth:`xloop_event`) and sets
+        ``pb.done``."""
+        if pb.host_topics is not None:
+            step, n = self.publish_host_chunk, len(pb.live)
+        elif pb.plan is not None:
+            step, n = self.publish_finish_planned, pb.plan.n_groups
+        else:
+            step, n = self.publish_finish_chunk, len(pb.live)
+        chunk = chunk or max(1, n)
+        for s in range(0, max(1, n), chunk):
+            step(pb, s, min(s + chunk, n))
+            if s + chunk < n:
+                yield
 
     @owner_loop
     def _plan_prologue(self, pb: PendingBatch) -> None:

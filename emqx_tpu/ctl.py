@@ -204,8 +204,9 @@ class Ctl:
             # cumulative lock-stall the off-lock compaction design
             # keeps near zero
             "delta": r.delta_info(),
-            # the live tables' level-compression snapshot
-            # (docs/PERF_NOTES.md "Round 6: path compression")
+            # the live tables' level-compression snapshot (single-
+            # child literal chains fused into multi-word edges,
+            # ops/csr.py compress_automaton)
             "walk": r.walk_info(),
         }
         for name, c in (("single", r._match_cache_obj),

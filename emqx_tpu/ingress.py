@@ -375,26 +375,14 @@ class IngressBatcher:
         if self._handle is not None:
             self._handle.cancel()
             self._handle = None
-        lock = self._plock
-        if lock is not None:
-            # multi-loop: peer loops append concurrently — the swap
-            # must be atomic with their appends or a message lands in
-            # a list already captured by the flush
-            with lock:
-                if cap and len(self._pending) > cap:
-                    pending = self._pending[:cap]
-                    del self._pending[:cap]
-                else:
-                    pending, self._pending = self._pending, []
-                self._grant_locked()
-        else:
+        # multi-loop: peer loops append concurrently — the swap must be
+        # atomic with their appends or a message lands in a list
+        # already captured by the flush
+        with self._plock or _UNLOCKED:
             if cap and len(self._pending) > cap:
                 pending = self._pending[:cap]
-                # lint: ok-CD102 single-loop mode (_plock None): flush
-                # and submit both run on the one event loop
                 del self._pending[:cap]
             else:
-                # lint: ok-CD102 single-loop mode (_plock None), as above
                 pending, self._pending = self._pending, []
             self._grant_locked()  # the room this take made
         if pending:
@@ -640,14 +628,9 @@ class IngressBatcher:
                                  inflight=self._inflight)
                 self._t_first = span.t_mark if self._pending else 0.0
             try:
-                msgs = [m for m, _ in pending]
-                if span is not None:
-                    pb = self.broker.publish_begin(
-                        msgs, defer_host=chain_active, span=span)
-                else:
-                    # the pre-telemetry call, unchanged
-                    pb = self.broker.publish_begin(
-                        msgs, defer_host=chain_active)
+                pb = self.broker.publish_begin(
+                    [m for m, _ in pending], defer_host=chain_active,
+                    span=span)
             except Exception as e:
                 log.exception("ingress batch publish failed")
                 if span is not None:
@@ -724,53 +707,35 @@ class IngressBatcher:
             if pb.done:
                 results = self.broker.publish_finish(pb)
             else:
-                # stream the delivery tail: finish in chunks, yielding
-                # between chunks so finished work's deliveries flush
-                # to subscriber sockets while the rest still routes.
-                # The chunk unit depends on the path: deferred host
-                # routing and the legacy packed walk chunk over LIVE
-                # ROWS; a planned batch (dispatch planner) chunks over
-                # SUBSCRIBER GROUPS — each session still gets its
-                # whole batch in one deliver_many + one wakeup
-                if pb.host_topics is not None:
-                    chunk_fn = self.broker.publish_host_chunk
-                    n_units = len(pb.live)
-                elif pb.plan is not None:
-                    chunk_fn = self.broker.publish_finish_planned
-                    n_units = pb.plan.n_groups
-                else:
-                    chunk_fn = self.broker.publish_finish_chunk
-                    n_units = len(pb.live)
-                for s in range(0, max(1, n_units), self.finish_chunk):
-                    chunk_fn(pb, s, min(s + self.finish_chunk, n_units))
-                    if s + self.finish_chunk < n_units:
-                        await asyncio.sleep(0)
-                        if sp is not None:
-                            # what the tail gave back to the loop
-                            sp.wait_mark("tail_yield")
-                if pb.plan is not None:
-                    # multi-loop: the batch's results/metrics fold —
-                    # and therefore the ack futures below — wait for
-                    # the cross-loop handoffs to report back. None on
-                    # a single-loop node
-                    ev = self.broker.xloop_event(pb)
-                    if ev is not None:
-                        # bounded, like the sync join: a wedged or
-                        # dead owning loop must not hang this batch
-                        # (and every batch chained behind it) forever
-                        # — fold partial counts with the loss counted
-                        # (delivery.xloop.orphaned)
-                        try:
-                            await asyncio.wait_for(
-                                ev.wait(),
-                                self.broker.XLOOP_JOIN_TIMEOUT)
-                        except asyncio.TimeoutError:
-                            log.error(
-                                "cross-loop delivery handoff "
-                                "incomplete after %.0fs — folding "
-                                "partial counts",
-                                self.broker.XLOOP_JOIN_TIMEOUT)
-                        self.broker.xloop_fold(pb)
+                # stream the delivery tail: the loop gets a turn
+                # between the broker's steps, so finished work's
+                # deliveries flush to subscriber sockets while the
+                # rest still routes
+                for _ in self.broker.finish_steps(pb, self.finish_chunk):
+                    await asyncio.sleep(0)
+                    if sp is not None:
+                        # what the tail gave back to the loop
+                        sp.wait_mark("tail_yield")
+                # multi-loop: the batch's results/metrics fold — and
+                # therefore the ack futures below — wait for the
+                # cross-loop handoffs to report back. None on a
+                # single-loop node
+                ev = self.broker.xloop_event(pb)
+                if ev is not None:
+                    # bounded, like the sync join: a wedged or dead
+                    # owning loop must not hang this batch (and every
+                    # batch chained behind it) forever — fold partial
+                    # counts with the loss counted
+                    # (delivery.xloop.orphaned)
+                    try:
+                        await asyncio.wait_for(
+                            ev.wait(), self.broker.XLOOP_JOIN_TIMEOUT)
+                    except asyncio.TimeoutError:
+                        log.error(
+                            "cross-loop delivery handoff incomplete "
+                            "after %.0fs — folding partial counts",
+                            self.broker.XLOOP_JOIN_TIMEOUT)
+                    self.broker.xloop_fold(pb)
                 pb.done = True
                 results = pb.results
         except Exception as e:

@@ -318,8 +318,8 @@ TRACING_METRICS = [
 ]
 
 # MQTT frame-parser engine (emqx_tpu/mqtt/frame.py NativeParser,
-# docs/PERF_NOTES.md "Round 7"): `frame.native.frames` = MQTT frames
-# decoded through the C++ incremental parser, `frame.fallback` =
+# docs/OBSERVABILITY.md "Frame parser"): `frame.native.frames` = MQTT
+# frames decoded through the C++ incremental parser, `frame.fallback` =
 # connections that asked for frame="native" but got the Python parser
 # (shared library missing or built without the parser symbols),
 # `frame.oversize` = frames rejected at header-decode time for
@@ -429,7 +429,7 @@ PIPELINE_METRICS = [
 # left as one transfer and two or three programs
 # (Router._dispatch_fused); batches − fused fell to the legacy whole
 # dispatch (cache off, a big-filter bitmap live, a snapshot that moved
-# under the split, a pre-placed batch)
+# under the split)
 MESH_METRICS = [
     "mesh.batches", "mesh.topics", "mesh.steps", "mesh.step.topics",
     "mesh.fused",

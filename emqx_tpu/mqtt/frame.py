@@ -30,34 +30,6 @@ class FrameTooLarge(FrameError):
     pass
 
 
-# native C frame scanner (ops/native.py) — resolved on first use;
-# False = unavailable, stick with the Python framing loop
-_scan = None
-
-
-def _get_scan():
-    """The C frame scanner is OPT-IN (EMQX_TPU_NATIVE_FRAME=1):
-    measured on the live mixed workload the ctypes call boundary
-    costs more than the C parse saves (~8% slower end-to-end; it
-    only wins clean bulk-parse microbenches by ~13%). Kept correct
-    under the fuzz suites for interpreters/workloads where the
-    trade-off differs."""
-    global _scan
-    if _scan is None:
-        import os
-
-        if os.environ.get("EMQX_TPU_NATIVE_FRAME", "0") != "1":
-            _scan = False
-            return _scan
-        try:
-            from emqx_tpu.ops import native as _nat
-
-            _scan = _nat.mqtt_scan if _nat.available() else False
-        except Exception:
-            _scan = False
-    return _scan
-
-
 # -- primitive readers -----------------------------------------------------
 
 def _read_u8(b: bytes, i: int) -> Tuple[int, int]:
@@ -226,18 +198,8 @@ class Parser:
         """Bytes buffered awaiting the rest of a partial frame."""
         return len(self._buf)
 
-    # below this buffer size the ctypes call overhead exceeds the C
-    # scanner's parse savings (measured: single small frames parse
-    # ~2x faster in pure Python; bulk pipelined reads ~15% faster
-    # through the scanner) — the server's loaded reads are bulk
-    _NATIVE_MIN = 1024
-
     def feed(self, data: bytes) -> List[Packet]:
         self._buf += data
-        if len(self._buf) >= self._NATIVE_MIN:
-            scan = _get_scan()
-            if scan is not False:
-                return self._feed_native(scan)
         out = []
         # moving offset + ONE compaction at the end: B packets in a
         # read cost O(buflen), not O(B·buflen) of per-packet del-shift.
@@ -257,66 +219,6 @@ class Parser:
         finally:
             if pos:
                 del self._buf[:pos]
-
-    def _feed_native(self, scan) -> List[Packet]:
-        """Framing through the C scanner; PUBLISH frames build from
-        the pre-sliced (topic, pid, payload) layout, everything else
-        (and every error) goes through the same Python body parsers
-        as the pure-Python loop — identical observable behavior."""
-        out: List[Packet] = []
-        while True:
-            flat, nf, consumed, err, err_size = scan(self._buf,
-                                                     self.max_size)
-            view = memoryview(self._buf)
-            fstart = 0  # current frame's first byte (error semantics:
-            # a frame whose BODY parse fails stays in the buffer,
-            # exactly like the Python loop's raise-before-consume)
-            try:
-                for k in range(nf):
-                    (header, boff, blen, toff, tlen,
-                     pid, pp) = flat[k * 7:k * 7 + 7]
-                    ptype = header >> 4
-                    if toff >= 0 and ptype == C.PUBLISH:
-                        qos = (header >> 1) & 0x03
-                        if qos > 0 and self.strict and pid == 0:
-                            raise FrameError("bad_packet_id")
-                        try:
-                            topic = bytes(
-                                view[toff:toff + tlen]).decode("utf-8")
-                        except UnicodeDecodeError as e:
-                            raise FrameError(
-                                "utf8_string_invalid") from e
-                        props: Dict[str, Any] = {}
-                        if self.version == C.MQTT_V5:
-                            body = bytes(view[boff:boff + blen])
-                            props, j = _parse_props(body, pp - boff)
-                            payload = body[j:]
-                        else:
-                            payload = bytes(view[pp:boff + blen])
-                        pkt = Publish(
-                            dup=bool(header & 0x08), qos=qos,
-                            retain=bool(header & 0x01), topic=topic,
-                            packet_id=pid if qos > 0 else None,
-                            properties=props, payload=payload)
-                    else:
-                        body = bytes(view[boff:boff + blen])
-                        pkt = self._parse_packet(header, body)
-                    out.append(pkt)
-                    if isinstance(pkt, Connect):
-                        self.version = pkt.proto_ver
-                    fstart = boff + blen
-            except Exception:
-                view.release()
-                del self._buf[:fstart]
-                raise
-            view.release()
-            del self._buf[:consumed]
-            if err == -1:
-                raise FrameError("malformed_variable_byte_integer")
-            if err == -2:
-                raise FrameTooLarge(f"frame_too_large: {err_size}")
-            if nf == 0 or not self._buf:
-                return out
 
     def _try_parse(self, pos: int = 0) -> Tuple[Optional[Packet], int]:
         buf = self._buf
@@ -531,7 +433,7 @@ class NativeParser(Parser):
 
     The retained partial-frame remainder lives C-side; each feed
     ships only the new bytes across the ctypes boundary and gets back
-    frame descriptors (the mqtt_scan 7-int rows) over the handle's
+    frame descriptors (the C scanner's 7-int rows) over the handle's
     buffer, which PUBLISH topic/payload slice zero-copy through a
     memoryview. Only packet bodies are decoded in Python — by exactly
     the same ``_parse_packet`` code the pure parser runs, so parity

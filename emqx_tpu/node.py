@@ -81,8 +81,9 @@ class Node:
         else:
             self.loop_group = None
         # [node] frame: wire-framing parser variant for every
-        # listener this node boots ("py" | "native",
-        # docs/PERF_NOTES.md "Native front door"). Stored as
+        # listener this node boots ("py" | "native": the Python
+        # Parser or the per-connection C++ handle behind
+        # NativeParser, mqtt/frame.py make_parser). Stored as
         # CONFIGURED (reload diffs file vs config); the EMQX_TPU_FRAME
         # env override resolves at listener construction.
         if frame not in ("py", "native"):

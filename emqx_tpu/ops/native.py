@@ -103,10 +103,6 @@ def load_library():
             C.c_void_p, C.c_int64, C.c_int64, _i32p, _i32p, _i32p,
             _i32p, _i32p, _i32p]
         lib.trie_flatten.restype = C.c_int64
-        lib.mqtt_scan.argtypes = [C.c_char_p, C.c_int64, C.c_int64,
-                                  C.c_int32, C.POINTER(C.c_int32),
-                                  C.POINTER(C.c_int64)]
-        lib.mqtt_scan.restype = C.c_int32
         lib.trie_match.argtypes = [C.c_void_p, C.c_char_p, C.c_int32,
                                    _i32p, C.c_int32]
         lib.trie_match.restype = C.c_int32
@@ -147,39 +143,7 @@ def available() -> bool:
     return load_library() is not None
 
 
-_SCAN_CAP = 512  # frames per scan call (the parser loops on more)
-_scan_tls = threading.local()
-
-
-def mqtt_scan(buf, max_size: int):
-    """Scan MQTT frames out of ``buf`` (bytes-like) with the C
-    scanner. Returns ``(flat int list [n*7], n, consumed, err,
-    err_size)``; err: 0 ok, -1 malformed varint, -2 frame over
-    ``max_size`` (with its total in err_size). None when the native
-    library is absent (callers use the Python framing loop).
-
-    Scratch buffers are per-thread and reused: a parser feed runs
-    this on every socket read, so per-call allocation is the
-    difference between helping and hurting the single-frame path."""
-    lib = load_library()
-    if lib is None:
-        return None
-    scratch = getattr(_scan_tls, "v", None)
-    if scratch is None:
-        scratch = ((C.c_int32 * (_SCAN_CAP * 7))(),
-                   (C.c_int64 * 2)())
-        _scan_tls.v = scratch
-    out, state = scratch
-    if isinstance(buf, bytearray):
-        # zero-copy view of the parser's accumulation buffer (only
-        # held for the duration of the C call)
-        cbuf = (C.c_char * len(buf)).from_buffer(buf)
-    else:
-        cbuf = bytes(buf)
-    rc = lib.mqtt_scan(cbuf, len(buf), max_size, _SCAN_CAP, out, state)
-    if rc < 0:
-        return [], 0, int(state[0]), int(rc), int(state[1])
-    return out[: rc * 7], rc, int(state[0]), 0, 0
+_SCAN_CAP = 512  # frame descriptors per feed (the parser loops on more)
 
 
 def has_frame_parser() -> bool:
@@ -201,9 +165,7 @@ class FrameHandle:
     """Raw ctypes surface of one per-connection C parser handle.
 
     Owns the retained partial-frame remainder C-side, so each socket
-    read ships only its NEW bytes across the FFI boundary (the
-    stateless :func:`mqtt_scan` seam re-marshalled the whole
-    accumulation buffer per read — measured slower than Python).
+    read ships only its NEW bytes across the FFI boundary.
     Packet-body semantics stay in :class:`emqx_tpu.mqtt.frame.
     NativeParser`, which drives this handle."""
 

@@ -280,13 +280,6 @@ class Router:
         self._dirty = True
         self._rebuilds = 0
         self._patches = 0
-        # vocabulary revision: bumped when a filter INSERT completes
-        # (inserts intern new words; the word table is append-only,
-        # so deletes never invalidate an encoding). A batch encoded
-        # at revision R is only valid to dispatch at R —
-        # encode_place_sharded stamps it, publish_dispatch_sharded
-        # verifies and re-encodes on mismatch
-        self._mut_rev = 0
         # O(delta) maintenance (ops/patch.py): host mirror of the live
         # automaton; None until the first flatten. Mesh mode keeps ONE
         # PATCHER PER TRIE SHARD (stable hash assignment — a mutation
@@ -613,13 +606,6 @@ class Router:
                     self._delta_add_locked(filter_, fid)
                 else:
                     self._patch_insert(filter_, fid)
-                # bump AFTER the insert interned its words: a batch
-                # encoded concurrently (encode takes _wt_lock only)
-                # then reads the OLD revision and looks stale at
-                # dispatch — re-encoded, safe. Bumping first would
-                # let it carry the new revision over a pre-intern
-                # word table: accepted stale, silent match miss
-                self._mut_rev += 1
                 # the new filter may change cached topics' match sets
                 # — invalidate its partition (literal root) or the
                 # whole epoch (root wildcard); see ops/match_cache.py
@@ -764,10 +750,6 @@ class Router:
             if dests[dest] <= 0:
                 del dests[dest]
             if not dests:
-                # no revision bump: the word table is append-only, so
-                # removing a filter can never invalidate an existing
-                # encoding — bumping here would spuriously stale every
-                # in-flight pre-placed batch under unsubscribe churn
                 del self._routes[filter_]
                 self._drop_filter_locked(filter_)
 
@@ -2142,7 +2124,7 @@ class Router:
         return all_ids, ovf, id_map, epoch
 
     def publish_dispatch_sharded(self, topics: Sequence[str],
-                                 fan_provider, placed=None, span=None):
+                                 fan_provider, span=None):
         """The PRODUCT multi-chip publish dispatch: match AND fan-out
         in one collective step (``parallel.sharded.publish_step`` with
         real per-shard fan tables, ``with_fanout=True``).
@@ -2150,39 +2132,28 @@ class Router:
         ``fan_provider(epoch, id_map) -> ShardedFanoutState | None``
         supplies fan tables (CSR + big-filter bitmaps) consistent
         with the automaton snapshot (the broker's FanoutManager).
-        ``placed`` (from :meth:`encode_place_sharded`) skips the host
-        encode + host→device transfer — a pipelined caller overlaps
-        that host half with in-flight device steps instead of paying
-        a synchronous transfer per call.
         Returns ``(ids_dev [B_pad, T·m], subs_dev [B_pad, T·d],
         src_dev [B_pad, T·d], bm [(union, has_big, bovf) | None],
         ovf_dev [B_pad], movf_dev [B_pad], id_map, epoch, big_fids)``
         — ``movf_dev`` is the match-only overflow (the ``boost_k``
         signal; fan overflow must not grow k); no device→host sync.
-        Given ``topics``, the padding rows of ids / subs / src (row ≥
-        ``len(topics)``: wildcards match the pad topic) come back
-        blanked to -1, ready for the packers.
+        The padding rows of ids / subs / src (row ≥ ``len(topics)``:
+        wildcards match the pad topic) come back blanked to -1, ready
+        for the packers.
         Reference: the dispatch fold src/emqx_broker.erl:283-309 run
         as one compiled mesh program.
 
         With the publish match cache enabled (and no big-filter
         bitmaps live), repeat topics skip the collective step and the
         whole batch leaves as one transfer and two or three programs
-        (:meth:`_dispatch_fused`). A pre-``placed`` batch bypasses the
-        cache (its host half was already paid, and splitting it would
-        re-encode)."""
-        if topics is not None:
-            self._count_mesh("mesh.batches", "mesh.topics", len(topics))
-            if placed is None:
-                out = self._dispatch_fused(topics, fan_provider, span)
-                if out is not None:
-                    self._count_mesh("mesh.fused")
-                    return out
-        out = self._dispatch_sharded(topics, fan=fan_provider,
-                                     with_big=True, placed=placed,
-                                     span=span)
-        if topics is None:
+        (:meth:`_dispatch_fused`)."""
+        self._count_mesh("mesh.batches", "mesh.topics", len(topics))
+        out = self._dispatch_fused(topics, fan_provider, span)
+        if out is not None:
+            self._count_mesh("mesh.fused")
             return out
+        out = self._dispatch_sharded(topics, fan=fan_provider,
+                                     with_big=True, span=span)
         from emqx_tpu.ops.pack import mask_pad_rows
 
         n = np.int32(len(topics))
@@ -2362,34 +2333,23 @@ class Router:
         return (ids, subs, src, None, ovf, movf, id_map, epoch,
                 frozenset())
 
-    def encode_place_sharded(self, topics: Sequence[str], span=None):
-        """Host half of the sharded dispatch: encode a topic batch
-        (padded to a bucket that splits evenly over the data axis)
-        and place it on the mesh. Returns ``(ids, n, sysm, rev)``
-        where ``rev`` is the route-table mutation revision the batch
-        was encoded at — :meth:`publish_dispatch_sharded` verifies it
-        and re-encodes if routes changed in between (a filter added
-        after encode may intern words the stale encoding mapped to
-        the unknown sentinel: its matches would silently miss)."""
+    def _encode_place_sharded(self, topics: Sequence[str], span=None):
+        """Host half of :meth:`_dispatch_sharded`: encode a topic
+        batch (padded to a bucket that splits evenly over the data
+        axis) and place it on the mesh. Returns ``(ids, n, sysm)``."""
         from emqx_tpu.parallel.sharded import place_batch
 
         cfg = self.config
-        mesh = cfg.mesh
-        # capture BEFORE encoding: a mutation racing the encode makes
-        # the batch look stale (re-encoded at dispatch) — never the
-        # reverse
-        rev = self._mut_rev
         B = len(topics)
         bucket = self.pad_topics(B)
         padded = list(topics) + ["\x00/pad"] * (bucket - B)
         with self._wt_lock:
             ids, n, sysm = self._encode(padded, cfg.max_levels)
         with enqueue_mark(span):  # the legacy dispatch's transfer
-            return (*place_batch(mesh, ids, n, sysm), rev)
+            return place_batch(cfg.mesh, ids, n, sysm)
 
     def _dispatch_sharded(self, topics: Sequence[str], fan=None,
-                          with_big: bool = False, placed=None,
-                          span=None):
+                          with_big: bool = False, span=None):
         from emqx_tpu.parallel.sharded import publish_step
 
         cfg = self.config
@@ -2404,28 +2364,12 @@ class Router:
                 fan_tables = st.fan
                 bmt = st.bm
                 big_fids = st.big_fids
-        if placed is not None:
-            ids, n, sysm, rev = placed
-            if rev != self._mut_rev:
-                # routes changed since the batch was encoded — its
-                # word ids may predate newly interned vocabulary.
-                # Re-encode (correct, costs the transfer the caller
-                # tried to hide); requires the original topics
-                if topics is None:
-                    raise ValueError(
-                        "stale placed batch (routes changed since "
-                        "encode) and no topics to re-encode from")
-                ids, n, sysm, _ = self.encode_place_sharded(topics,
-                                                            span)
-        else:
-            ids, n, sysm, _ = self.encode_place_sharded(topics, span)
+        ids, n, sysm = self._encode_place_sharded(topics, span)
         use_fan = fan_tables is not None
-        if topics is not None:
-            # a collective program is enqueued for these topics (the
-            # cache-split path sends only its misses here)
-            self._count_mesh("mesh.steps", "mesh.step.topics",
-                             len(topics))
-        with enqueue_mark(span):  # a pre-placed batch's first call
+        # a collective program is enqueued for these topics (the
+        # cache-split path sends only its misses here)
+        self._count_mesh("mesh.steps", "mesh.step.topics", len(topics))
+        with enqueue_mark(span):
             all_ids, subs, src, bm, ovf, movf, stats = publish_step(
                 mesh, auto, fan_tables if use_fan else self._dummy_fan,
                 ids, n, sysm, bmt, k=self.effective_k(),

@@ -61,7 +61,7 @@ STATS_KEYS = [
     # and ack age on a replicating primary
     "durability.repl.lag_records", "durability.repl.lag_bytes",
     "durability.repl.last_ack_age_s",
-    # walk-table level compression (docs/PERF_NOTES.md "Round 6"):
+    # walk-table level compression (ops/csr.py compress_automaton):
     # permille of deepest-level walk steps the compressed tables
     # save over one-hop-per-level (0 = narrow mode / nothing saved)
     "automaton.compaction.ratio",

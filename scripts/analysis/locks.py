@@ -86,6 +86,10 @@ def _self_attr(node) -> Optional[str]:
 
 def _is_lock_expr(item, lock: str, aliases: Set[str]) -> bool:
     e = item.context_expr
+    if isinstance(e, ast.BoolOp) and isinstance(e.op, ast.Or):
+        # `with self.<lock> or <stand-in>`: a mode that has no lock
+        # (one thread owns the state) passes a null context
+        e = e.values[0]
     if isinstance(e, ast.Attribute) and \
             isinstance(e.value, ast.Name) and e.value.id == "self" \
             and e.attr == lock:

@@ -53,7 +53,7 @@ echo "== delta-automaton parity + off-lock compaction (docs/DELTA.md) =="
 # match-correctness bug, fail fast
 python -m pytest tests/test_delta.py -q
 
-echo "== compressed-walk parity (docs/PERF_NOTES.md round 6) =="
+echo "== compressed-walk parity (ops/csr.py compress_automaton) =="
 # walk-vs-oracle parity on narrow and wide tables, native-vs-numpy
 # chain-fuser parity, and the randomized compressed-walk property
 # suite (deep spines, $share, churn, devloss rebuild, checkpoint
@@ -192,7 +192,7 @@ echo "== trace-export smoke (docs/OBSERVABILITY.md) =="
 python -m pytest \
     tests/test_tracing.py::test_trace_chain_is_continuous_across_two_loops -q
 
-echo "== native frame-parser parity (docs/PERF_NOTES.md round 7) =="
+echo "== native frame-parser parity (docs/OBSERVABILITY.md \"Frame parser\") =="
 # differential fuzz of the C++ incremental parser vs the Python
 # parser vs the independent test codec (parsed packets, error
 # classes, buffered remainders, resume at every byte split), the

@@ -261,72 +261,6 @@ def test_pure_garbage_streams():
             pass
 
 
-def test_native_scanner_parity_with_python_parser():
-    """The C frame scanner (opt-in fast path) must produce EXACTLY
-    the Python parser's packets for valid streams, across versions,
-    QoS levels, chunk boundaries, and packet types."""
-    import random
-
-    import pytest
-
-    from emqx_tpu.mqtt import constants as C
-    from emqx_tpu.mqtt import frame as F
-    from emqx_tpu.mqtt.packet import (Connect, Pingreq, PubAck, Publish,
-                                      Subscribe)
-    from emqx_tpu.ops import native
-
-    if not native.available():
-        pytest.skip("native library unavailable")
-
-    rng = random.Random(99)
-    for ver in (C.MQTT_V4, C.MQTT_V5):
-        pkts = [Connect(client_id="fz", clean_start=True,
-                        proto_ver=ver)]
-        for i in range(80):
-            r = rng.random()
-            if r < 0.6:
-                props = ({"Message-Expiry-Interval": 9}
-                         if ver == C.MQTT_V5 and rng.random() < 0.3
-                         else {})
-                qos = rng.choice([0, 0, 1, 2])
-                pkts.append(Publish(
-                    topic=f"fz/{i}/t", qos=qos,
-                    packet_id=(i + 1 if qos else None),
-                    retain=bool(rng.random() < 0.2),
-                    properties=props,
-                    payload=bytes(rng.randbytes(rng.randrange(64)))))
-            elif r < 0.8:
-                pkts.append(PubAck(type=C.PUBACK, packet_id=i + 1))
-            elif r < 0.9:
-                pkts.append(Subscribe(
-                    packet_id=i + 1,
-                    topic_filters=[(f"fz/{i}/+", {"qos": 1})]))
-            else:
-                pkts.append(Pingreq())
-        stream = b"".join(F.serialize(p, ver) for p in pkts)
-        for chunk in (1, 7, 1024, len(stream)):
-            py = F.Parser(version=ver)
-            nat = F.Parser(version=ver)
-            nat._NATIVE_MIN = 0  # force the native path regardless
-            saved = F._scan
-            got_py, got_nat = [], []
-            try:
-                F._scan = False
-                for o in range(0, len(stream), chunk):
-                    got_py += py.feed(stream[o:o + chunk])
-                F._scan = None
-                import os
-                os.environ["EMQX_TPU_NATIVE_FRAME"] = "1"
-                F._get_scan()
-                assert F._scan is not False
-                for o in range(0, len(stream), chunk):
-                    got_nat += nat.feed(stream[o:o + chunk])
-            finally:
-                F._scan = saved
-                os.environ.pop("EMQX_TPU_NATIVE_FRAME", None)
-            assert got_py == got_nat, (ver, chunk)
-
-
 # -- 3-way differential: NativeParser vs Parser vs the indie codec ---------
 #
 # Three independent implementations of the same wire format: the C++

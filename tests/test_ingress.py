@@ -160,7 +160,7 @@ async def test_flush_failure_sends_no_ack():
         c = TestClient("c", version=4)
         await c.connect(port=lst.port)
 
-        def boom(msgs, defer_host=False):
+        def boom(msgs, defer_host=False, span=None):
             raise RuntimeError("device gone")
 
         orig = n.broker.publish_begin
@@ -180,7 +180,7 @@ async def test_flush_failure_sends_no_ack():
 
 async def test_flush_error_resolves_futures():
     class Boom(Broker):
-        def publish_begin(self, msgs, defer_host=False):
+        def publish_begin(self, msgs, defer_host=False, span=None):
             raise RuntimeError("device gone")
 
     bat = IngressBatcher(Boom(), batch_size=2)
@@ -347,11 +347,11 @@ async def test_flush_during_completion_cannot_reorder_or_double_resolve():
     orig_begin = b.publish_begin
     calls = [0]
 
-    def begin(msgs, defer_host=False):
+    def begin(msgs, defer_host=False, span=None):
         calls[0] += 1
         if calls[0] == 2:
             raise RuntimeError("boom")  # batch B fails at begin
-        return orig_begin(msgs, defer_host=defer_host)
+        return orig_begin(msgs, defer_host=defer_host, span=span)
 
     b.publish_begin = begin
     bat = IngressBatcher(b, batch_size=100, max_inflight=1)

@@ -306,23 +306,6 @@ def test_a_grown_budget_costs_one_program_a_bucket(compiles):
 # -- who still takes the legacy dispatch --------------------------------------
 
 
-def _placed(b, msgs):
-    """A pre-``placed`` batch: the router's own entry point (the
-    broker never places)."""
-    r = b.router
-    topics = [m.topic for m in msgs]
-
-    def fan(epoch, id_map):
-        return b.helper.sharded_state(epoch, id_map, r.config.mesh,
-                                      r.effective_d())
-
-    out = r.publish_dispatch_sharded(
-        topics, fan, placed=r.encode_place_sharded(topics))
-    ids, id_map = np.asarray(out[0]), out[6]
-    return [sum(b.filters[id_map[j]] for j in row if j >= 0)
-            for row in ids[:len(topics)]]
-
-
 def _moved_snapshot(b, msgs):
     """The snapshot moves (a rebuild) between the probe and the step."""
     r = b.router
@@ -353,7 +336,6 @@ LEGACY = {
     "cache_off": (dict(match_cache=False), Broker.publish_batch),
     "big_filter_bitmap": ({}, _big_filter),
     "moved_snapshot": ({}, _moved_snapshot),
-    "placed_batch": ({}, _placed),
 }
 
 

@@ -521,88 +521,6 @@ def test_sharded_bitmap_mb_truncation_falls_back_exact():
         assert sorted(s.got) == sorted(colliding)
 
 
-def test_placed_batch_parity_with_inline_encode():
-    """``encode_place_sharded`` + ``placed=`` produces the exact
-    dispatch a plain ``publish_dispatch_sharded(topics, ...)`` call
-    does — the pre-placed host half (used by the pipelined bench and
-    any ingress that overlaps encode with in-flight device steps)
-    must not change semantics."""
-    import random
-
-    import numpy as np
-
-    from emqx_tpu.broker_helper import ShardedFanoutState
-    from emqx_tpu.parallel.mesh import default_mesh
-    from emqx_tpu.parallel.sharded import (build_sharded_fanout,
-                                           place_sharded, shard_of)
-    from emqx_tpu.router import MatcherConfig, Router
-
-    rng = random.Random(7)
-    mesh = default_mesh(4)
-    n_trie = mesh.shape["trie"]
-    filters = [f"a/{i}/+" for i in range(100)] + ["a/#"]
-    r = Router(MatcherConfig(mesh=mesh, fanout_d=8))
-    for f in filters:
-        r.add_route(f)
-    topics = [f"a/{rng.randrange(100)}/x" for _ in range(32)]
-    r.match_ids(topics)  # flatten
-    rows = [{} for _ in range(n_trie)]
-    for f in filters:
-        fid = r.filter_id(f)
-        rows[shard_of(f, n_trie)][fid] = [fid]
-    fan = place_sharded(mesh, build_sharded_fanout(
-        rows, len(r._id_to_filter)))
-    st = ShardedFanoutState(0, 0, fan, None, frozenset(), 8)
-    provider = lambda epoch, id_map: st  # noqa: E731
-
-    plain = r.publish_dispatch_sharded(topics, provider)
-    placed = r.publish_dispatch_sharded(
-        topics, provider, placed=r.encode_place_sharded(topics))
-    for i in (0, 1, 2, 4):  # ids, subs, src, ovf
-        a, b = np.asarray(plain[i]), np.asarray(placed[i])
-        assert a.shape == b.shape and (a == b).all(), i
-
-
-def test_placed_batch_stale_after_route_add_reencodes():
-    """A pre-placed batch encoded BEFORE a route add must not miss
-    the new filter: publish_dispatch_sharded detects the stale
-    mutation revision and re-encodes from the original topics (a
-    filter added after encode can intern words the old encoding
-    mapped to the unknown sentinel)."""
-    import numpy as np
-
-    from emqx_tpu.broker_helper import ShardedFanoutState
-    from emqx_tpu.parallel.mesh import default_mesh
-    from emqx_tpu.parallel.sharded import (build_sharded_fanout,
-                                           place_sharded, shard_of)
-    from emqx_tpu.router import MatcherConfig, Router
-
-    mesh = default_mesh(4)
-    n_trie = mesh.shape["trie"]
-    r = Router(MatcherConfig(mesh=mesh, fanout_d=8))
-    r.add_route("a/+")
-    topics = ["a/x", "brandnew/word"]
-    r.match_ids(topics)  # flatten
-    pl = r.encode_place_sharded(topics)
-    # mutation AFTER encode: interns words the encoding never saw
-    r.add_route("brandnew/word")
-    rows = [{} for _ in range(n_trie)]
-    for f in ("a/+", "brandnew/word"):
-        fid = r.filter_id(f)
-        rows[shard_of(f, n_trie)][fid] = [fid]
-    fan = place_sharded(mesh, build_sharded_fanout(
-        rows, len(r._id_to_filter)))
-    st = ShardedFanoutState(0, 0, fan, None, frozenset(), 8)
-    out = r.publish_dispatch_sharded(
-        topics, lambda e, m: st, placed=pl)
-    ids = np.asarray(out[0])[:2]
-    id_map = out[6]
-    matched = [sorted(id_map[i] for i in row if i >= 0
-                      and id_map[i] is not None) for row in ids]
-    assert matched[0] == ["a/+"]
-    assert matched[1] == ["brandnew/word"], matched
-
-
 def test_finalize_parts_demotes_all_shards_on_wide_guard():
     """ADVICE r5: a shard whose trie trips compress_automaton's
     wide-mode fallback guard (depth > 31) stays narrow even under
