@@ -11,10 +11,14 @@ One process plays one role, given on its first line of stdin:
 ``pub``  the cell's publishers. The loop kind (``loops/<kind>.py``)
          decides when each message is sent.
 ``sub``  a share of the cell's subscriber sockets. It stamps every
-         PUBLISH with the clock at the ``recv`` that brought it, and
-         at the end of a phase compares, socket by socket, the
-         multiset of message ids it received with what the plain
-         reference predicate (``reference.matches``) expects.
+         PUBLISH with the clock at the ``recv`` that brought it,
+         answers a QoS 1 PUBLISH with its PUBACK where the
+         configuration's ``deliver_qos`` grants QoS 1, and at the end
+         of a phase compares, socket by socket, the multiset of
+         message ids it received with what the plain reference
+         predicate (``reference.matches``) expects; the sockets of a
+         shared group (``$share/<group>/<filter>``) are compared
+         together, by ``reference.share_of``'s rule.
 
 The parent drives both with JSON lines on stdin (``connect``,
 ``phase``, ``finish``, ``exit``); each answers with one JSON line on
@@ -170,6 +174,17 @@ class Plan:
         self.n_pubs = tr["publishers"]
         self.payload_len = cfg["payload_bytes"]
         self.sub_qos = cfg["guarantees"]["deliver_qos"]
+        if self.sub_qos not in (0, 1):
+            raise ValueError("the subscribers acknowledge QoS 1 alone: "
+                             f"deliver_qos {self.sub_qos!r}")
+        # the least QoS a delivery may bear: what the publishers send
+        # at under the grant (a burst's fence goes out at QoS 1
+        # whatever publish_qos says, so between the two is right too)
+        self.low_qos = min(self.sub_qos,
+                           cfg["guarantees"].get("publish_qos", 0))
+        # a control's switch (benchmark/tests): subscribers that keep
+        # their PUBACKs back, which a run must not survive
+        self.sub_acks = tr.get("subscriber_acks", True)
         # socket index -> its filters
         self.sockets: list = []
         for grp in cfg["sockets"]:
@@ -177,6 +192,37 @@ class Plan:
                 self.sockets.append(
                     [f.format(i=i + grp.get("first", 0))
                      for f in grp["filters"]])
+        self.groups = self.shared_groups(tr.get("subscriber_procs", 1))
+
+    def shared_groups(self, n_procs: int) -> dict:
+        """group -> (its member sockets, its filters). A group is
+        compared as one subscriber, so its sockets live in one
+        subscriber process, each holds every filter of the group, and
+        none is in two groups: any other split is refused."""
+        import reference
+
+        members: dict = {}
+        for s, filters in enumerate(self.sockets):
+            mine: dict = {}
+            for f in filters:
+                group, real = reference.share_of(f)
+                if group is not None:
+                    mine.setdefault(group, set()).add(real)
+            if len(mine) > 1:
+                raise ValueError(f"socket {s} is in two shared groups")
+            for group, reals in mine.items():
+                members.setdefault(group, []).append((s, reals))
+        out = {}
+        for group, socks in members.items():
+            if len({s % n_procs for s, _r in socks}) > 1:
+                raise ValueError(
+                    f"shared group {group!r} is split over subscriber "
+                    f"processes: its sockets have to live in one")
+            if any(r != socks[0][1] for _s, r in socks):
+                raise ValueError(f"the sockets of shared group {group!r} "
+                                 f"do not hold the same filters")
+            out[group] = ([s for s, _r in socks], sorted(socks[0][1]))
+        return out
 
     def pool(self) -> list:
         """The seeded topic pool: position -> topic. Publisher ``p``
@@ -339,7 +385,8 @@ class Subscribers:
         holders: dict = {}
         for k, s in enumerate(self.mine):
             for f in plan.sockets[s]:
-                holders.setdefault(f, []).append(k)
+                if reference.share_of(f)[0] is None:
+                    holders.setdefault(f, []).append(k)
         trie = reference.Trie()
         for f in holders:
             trie.insert(f)
@@ -354,7 +401,21 @@ class Subscribers:
             if by_pred != trie.match(t):
                 raise ValueError(f"trie and predicate disagree on {t!r}")
         # match[k, i]: copies socket mine[k] must get of pool position i
+        # (by its plain filters; a shared group's come on top, below)
         self.match = hit[:, pool_pos]
+        # this process's shared groups: (member sockets k, copies the
+        # group as a whole must get of pool position i: one for each
+        # of its filters that matches)
+        k_of = {s: k for k, s in enumerate(self.mine)}
+        self.groups: list = []
+        for socks, reals in plan.groups.values():
+            if socks[0] not in k_of:
+                continue
+            per_topic = np.fromiter(
+                (sum(reference.matches(t, f) for f in reals)
+                 for t in distinct), dtype=np.int8, count=len(distinct))
+            self.groups.append(([k_of[s] for s in socks],
+                                per_topic[pool_pos]))
         self.pool_b = [t.encode("utf-8") for t in pool]
         self.prep_s = time.monotonic() - t0
         self.socks: list = []
@@ -405,6 +466,7 @@ class Subscribers:
                 self.sel.register(s, selectors.EVENT_READ, k)
                 self.socks.append(s)
         self.left = [b""] * len(self.mine)
+        self.unsent = [b""] * len(self.mine)  # PUBACKs a send left over
         return {"connected": len(self.socks), "refused": self.refused}
 
     def _on_data(self, k: int, sock: socket.socket) -> None:
@@ -429,6 +491,9 @@ class Subscribers:
         phase = self.phase
         pool_b = self.pool_b
         unpack = HEADER.unpack_from
+        sub_qos = self.plan.sub_qos
+        low_qos = self.plan.low_qos
+        acks = b""
         got = 0
         while n - pos >= 2:
             ln = buf[pos + 1]
@@ -453,8 +518,14 @@ class Subscribers:
                 tl = (buf[p] << 8) | buf[p + 1]
                 q = p + 2 + tl
                 if b0 & 0x06:
-                    self.bad_qos += 1  # the deployment delivers QoS 0
+                    # above the granted QoS, or sent a second time
+                    if (b0 >> 1) & 3 > sub_qos or b0 & 0x08:
+                        self.bad_qos += 1
+                    else:
+                        acks += b"\x40\x02" + buf[q:q + 2]
                     q += 2
+                elif low_qos:
+                    self.bad_qos += 1  # under what was granted
                 ph, i, pub, seq, due = unpack(buf, q)
                 if ph == phase:
                     ids.append((pub << 32) | seq)
@@ -466,6 +537,15 @@ class Subscribers:
                     self.stale += 1
             pos = end
         self.left[k] = buf[pos:] if pos < n else b""
+        if acks and self.plan.sub_acks:
+            out = self.unsent[k] + acks
+            try:
+                sent = sock.send(out)
+            except BlockingIOError:
+                sent = 0
+            except OSError:
+                sent = len(out)  # closed: the next recv counts it
+            self.unsent[k] = out[sent:]
         if got:
             self.received += got
             self.last_arrival = t
@@ -475,25 +555,39 @@ class Subscribers:
 
     def expected(self, sent: list):
         """Per socket of this process, the sorted message ids it must
-        have received in the phase, from the publishers' counts."""
+        have received in the phase by its plain filters, from the
+        publishers' counts; and per shared group of this process the
+        ids its sockets must have received between them."""
         np = self.np
-        out = []
         seqs = [np.arange(max(n, 0), dtype=np.int64) for n in sent]
         pos = [(self.plan.base(p, self.start) + s) % self.plan.n_pool
                for p, s in enumerate(seqs)]
-        for k in range(len(self.mine)):
-            parts = []
-            for p, s in enumerate(seqs):
-                copies = self.match[k][pos[p]]
-                ids = (np.int64(p) << 32) | s
-                parts.append(np.repeat(ids, copies))
-            out.append(np.sort(np.concatenate(parts)).astype(np.uint64))
-        return out
+
+        def ids_of(copies_of):
+            parts = [np.repeat((np.int64(p) << 32) | s, copies_of[pos[p]])
+                     for p, s in enumerate(seqs)]
+            return np.sort(np.concatenate(parts)).astype(np.uint64)
+
+        return ([ids_of(self.match[k]) for k in range(len(self.mine))],
+                [ids_of(copies) for _members, copies in self.groups])
+
+    def _diff(self, got, want):
+        """Two id arrays as multisets -> (the ids of either, how often
+        each is missing from ``got``, how often surplus in it)."""
+        np = self.np
+        ug, cg = np.unique(got, return_counts=True)
+        uw, cw = np.unique(want, return_counts=True)
+        allv = np.union1d(ug, uw)
+        g = np.zeros(len(allv), dtype=np.int64)
+        e = np.zeros(len(allv), dtype=np.int64)
+        g[np.searchsorted(allv, ug)] = cg
+        e[np.searchsorted(allv, uw)] = cw
+        return allv, np.clip(e - g, 0, None), np.clip(g - e, 0, None)
 
     def finish(self, cmd: dict, out_dir: str) -> dict:
         np = self.np
-        want = self.expected(cmd["sent"])
-        total = sum(len(w) for w in want)
+        want, group_want = self.expected(cmd["sent"])
+        total = sum(len(w) for w in want) + sum(len(w) for w in group_want)
         # wait for what is still in flight; give up when nothing has
         # arrived for ``quiesce_s``
         deadline_quiet = cmd.get("quiesce_s", 5.0)
@@ -505,19 +599,27 @@ class Subscribers:
         while time.monotonic() < t_grace:
             self.pump(0.05)
         missing = surplus = 0
+        member = {k: g for g, (members, _c) in enumerate(self.groups)
+                  for k in members}
+        # what a group's sockets got beyond their plain filters' due
+        beyond = [[] for _ in self.groups]
         for k, w in enumerate(want):
             got = np.sort(np.frombuffer(self.ids[k], dtype=np.uint64))
-            if len(got) == len(w) and np.array_equal(got, w):
+            if k not in member and len(got) == len(w) \
+                    and np.array_equal(got, w):
                 continue
-            ug, cg = np.unique(got, return_counts=True)
-            uw, cw = np.unique(w, return_counts=True)
-            allv = np.union1d(ug, uw)
-            g = np.zeros(len(allv), dtype=np.int64)
-            e = np.zeros(len(allv), dtype=np.int64)
-            g[np.searchsorted(allv, ug)] = cg
-            e[np.searchsorted(allv, uw)] = cw
-            missing += int(np.clip(e - g, 0, None).sum())
-            surplus += int(np.clip(g - e, 0, None).sum())
+            allv, short, over = self._diff(got, w)
+            missing += int(short.sum())
+            if k in member:
+                beyond[member[k]].append(np.repeat(allv, over))
+            else:
+                surplus += int(over.sum())
+        for parts, w in zip(beyond, group_want):
+            # each message once on one socket of the group: never on
+            # two (surplus), never on none (missing)
+            _v, short, over = self._diff(np.concatenate(parts), w)
+            missing += int(short.sum())
+            surplus += int(over.sum())
         lat_file = os.path.join(
             out_dir, f"lat_{self.index}_{self.phase}.f64")
         with open(lat_file, "wb") as f:

@@ -5,6 +5,8 @@ Two forms of the same semantics, so each checks the other's users:
 
 - :func:`matches` — the per-filter predicate. The load generator uses
   it to say which socket must receive which topic.
+- :func:`share_of` — a shared subscription's group and filter, and
+  the rule a group is held to.
 - :class:`Trie` — a dict trie over a whole filter population. The
   benchmark uses it to say which of the deployment's filters must
   deliver a sampled topic to the in-process subscriber.
@@ -43,6 +45,27 @@ def matches(topic: str, flt: str) -> bool:
         if w != "+" and w != t[i]:
             return False
     return len(t) == len(f)
+
+
+def share_of(flt: str):
+    """``$share/<group>/<filter>`` -> ``(group, filter)``; a plain
+    filter -> ``(None, flt)``.
+
+    The rule a shared subscription is held to (MQTT 5 §4.8.2, which
+    EMQX serves to 3.1.1 clients too): the sessions that subscribed
+    ``$share/<group>/<filter>`` are one subscriber between them. A
+    message whose topic matches ``filter`` (by :func:`matches`) goes
+    to exactly one session of the group: never to two, never to none,
+    whichever the broker's strategy picks. Which one is not stated, so
+    the comparison is the group's: the deliveries of all its sockets
+    together hold each matching message once for each of the group's
+    filters that matches."""
+    if flt.startswith("$share/"):
+        parts = flt.split("/", 2)
+        if len(parts) != 3 or not parts[1] or not parts[2]:
+            raise ValueError(f"no shared subscription: {flt!r}")
+        return parts[1], parts[2]
+    return None, flt
 
 
 class Trie:

@@ -98,6 +98,30 @@ def test_traced_run_reports_the_layer_metrics(bench_dir, capsys):
     assert all(n.startswith("device_idle") for n in want - got)
 
 
+#: what a toy window may not form: no merge ends in a window in which
+#: the pending adds never reach ``delta_max_filters``
+_TOY_CANNOT = {"merge_s.storm", "rebuild_stall_ms_per_merge.storm"}
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in _spec(toy.BENCH)
+                                  ["workloads"] if w["chips"] == 1])
+def test_a_traced_run_forms_every_reading_the_cell_lists(bench_dir, capsys,
+                                                         cell):
+    """An entry whose reducer finds nothing in a cell it lists is a
+    fault of the list (a traced run whose line lacks it is refused),
+    found by running each cell, not by reading. The four-chip cell's
+    is ``test_mesh_cell.py``'s."""
+    out, lines = _run(bench_dir, capsys, cell, trace=1, seed=991,
+                      seconds=4)
+    assert out["correct"] is True, "\n".join(lines[-15:])
+    spec = _spec(bench_dir)
+    from_trace = {m["name"] for m in spec["per_layer"]
+                  if m["source"] == "device_trace"}
+    missing = _names(spec, "per_layer", cell) - set(out["metrics"])
+    assert missing <= from_trace | _TOY_CANNOT, sorted(missing)
+    assert not set(out["metrics"]) - _names(spec, "per_layer", cell)
+
+
 @pytest.mark.parametrize("name,nth", [(n, 0) for n in sorted(sabotage.ALL)]
                          + [("wrong_filter", 1)])
 def test_control_turns_correct_false(bench_dir, capsys, name, nth):
